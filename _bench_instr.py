@@ -2,7 +2,9 @@ import asyncio, time, os, json
 os.environ.setdefault("BENCH_CONCURRENCY", "128")
 os.environ.setdefault("BENCH_REQUESTS", "256")
 import jax
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 import bench as B
 from dynamo_tpu.engines.tpu import engine as eng_mod
 

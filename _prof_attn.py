@@ -14,7 +14,9 @@ import jax, jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 
 NEG_INF = -1e30
 

@@ -2,8 +2,9 @@
 import os, sys, time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"))
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import qwen2_500m_config
 import dynamo_tpu.ops.attention as A
@@ -19,11 +20,10 @@ rng = jax.random.PRNGKey(1)
 temp = jnp.ones((B,), jnp.float32); topk = jnp.zeros((B,), jnp.int32); topp = jnp.full((B,), 0.95, jnp.float32)
 
 BQ = int(sys.argv[1])
-real = A._load_decode_kernel()
 import functools
-def patched_loader():
-    return functools.partial(real, batch_block=BQ)
-A._load_decode_kernel = patched_loader
+A.paged_attention_decode_kernel = functools.partial(
+    A.paged_attention_decode_kernel, batch_block=BQ
+)
 
 def run(params, k, v):
     return llama.decode_multi(params, cfg, tokens, start_pos, active, tables, k, v,

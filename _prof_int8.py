@@ -7,10 +7,9 @@ import sys, time
 import numpy as np
 import jax, jax.numpy as jnp
 import os
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"),
-)
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import llama3_3b_config
 from dynamo_tpu.models.quantize import init_quantized_params

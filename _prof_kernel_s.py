@@ -1,7 +1,9 @@
 import time, functools
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import qwen2_500m_config
 import dynamo_tpu.ops.attention as att
@@ -17,11 +19,10 @@ rng = jax.random.PRNGKey(1)
 t = jnp.ones((B,), jnp.float32); tk = jnp.zeros((B,), jnp.int32); tp = jnp.ones((B,), jnp.float32)
 
 def run(label, use_kernel, S=None):
-    if S is not None:
-        orig = pk.paged_attention_kernel
-        att._kernel_fn = functools.partial(orig, pages_per_step=S)
-    else:
-        att._kernel_fn = None; att._kernel_load_failed = False
+    att.paged_attention_kernel = (
+        pk.paged_attention_kernel if S is None
+        else functools.partial(pk.paged_attention_kernel, pages_per_step=S)
+    )
     def step(p_, k_, v_):
         return llama.decode_multi(p_, cfg, tok, pos, act, tables, k_, v_, rng, t, tk, tp,
                                   num_steps=32, use_kernel=use_kernel, want_logprobs=False)

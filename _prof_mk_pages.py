@@ -20,7 +20,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig

@@ -1,7 +1,9 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 print("backend", jax.default_backend(), jax.devices())
 
 # HBM read roofline: reduce a big bf16 array

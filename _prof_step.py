@@ -1,7 +1,9 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import qwen2_500m_config
 from dynamo_tpu.ops.sampling import sample_tokens, compute_logprobs

@@ -9,10 +9,9 @@ import time
 import numpy as np
 import jax, jax.numpy as jnp
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"),
-)
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import qwen2_500m_config
 from dynamo_tpu.ops.sampling import sample_tokens

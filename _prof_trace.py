@@ -16,8 +16,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from dynamo_tpu.utils.jax_env import configure_compile_cache
+
+configure_compile_cache()
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import llama3_8b_config

@@ -25,11 +25,18 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import signal
 import subprocess
 import sys
 import time
 from typing import Optional
+
+from dynamo_tpu.utils.jax_env import (
+    COMPILE_CACHE_ENV,
+    compile_cache_dir,
+    configure_compile_cache,
+)
 
 
 def _worker_body(model_dir: str, workdir: str) -> None:
@@ -39,10 +46,7 @@ def _worker_body(model_dir: str, workdir: str) -> None:
 
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir", os.path.join(workdir, "jax_cache")
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    configure_compile_cache()  # the parent placed it (run())
 
     import asyncio
 
@@ -99,10 +103,14 @@ def _worker_body(model_dir: str, workdir: str) -> None:
     signal.pause()  # hold until the parent SIGKILLs us
 
 
-def _spawn_and_time(model_dir: str, workdir: str) -> dict:
+def _spawn_and_time(model_dir: str, workdir: str, compile_cache: str) -> dict:
     """Spawn one worker, wait for its first token, return timings. The
     returned process is already SIGKILLed (crash semantics)."""
-    env = {**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)}
+    env = {
+        **os.environ,
+        "PYTHONPATH": os.pathsep.join(sys.path),
+        COMPILE_CACHE_ENV: compile_cache,
+    }
     t0 = time.perf_counter()
     proc = subprocess.Popen(
         [sys.executable, "-m", "dynamo_tpu.bench.restart",
@@ -149,8 +157,13 @@ def _spawn_and_time(model_dir: str, workdir: str) -> dict:
 
 def run(model_dir: str, workdir: str) -> dict:
     os.makedirs(workdir, exist_ok=True)
-    cold = _spawn_and_time(model_dir, workdir)
-    warm = _spawn_and_time(model_dir, workdir)
+    # The cold leg needs an EMPTY compile cache and the warm leg the cold
+    # leg's: a fixed sub-directory of the one cache location, emptied
+    # here, handed to both workers through the variable.
+    compile_cache = os.path.join(compile_cache_dir(), "restart_bench")
+    shutil.rmtree(compile_cache, ignore_errors=True)
+    cold = _spawn_and_time(model_dir, workdir, compile_cache)
+    warm = _spawn_and_time(model_dir, workdir, compile_cache)
     assert not cold["weights_hit"] and warm["weights_hit"], (cold, warm)
     return {
         "metric": "kill-to-first-token recovery",
@@ -177,8 +190,8 @@ def main() -> None:
     ap.add_argument("--model-dir", required=True)
     ap.add_argument(
         "--workdir", default=None,
-        help="cache root (weights shm/disk + jax compile cache); a warm "
-        "workdir from a previous run makes even the 'cold' leg warm",
+        help="weight cache root (shm/disk tiers); a warm workdir from a "
+        "previous run makes even the 'cold' leg's weight load warm",
     )
     args = ap.parse_args()
     workdir = args.workdir or tempfile.mkdtemp(prefix="restart-bench-")

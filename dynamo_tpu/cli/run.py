@@ -61,8 +61,10 @@ def build_engine_and_card(args) -> Tuple[Any, ModelDeploymentCard, Any]:
         return engine, card, tiny_tokenizer()
 
     from dynamo_tpu.engines.tpu import JaxEngine, JaxEngineArgs
+    from dynamo_tpu.utils.jax_env import configure_compile_cache
     from dynamo_tpu.worker.__main__ import BUILTIN_CONFIGS
 
+    configure_compile_cache()
     model_path = None
     if args.model in BUILTIN_CONFIGS:
         config = BUILTIN_CONFIGS[args.model]()

@@ -46,6 +46,15 @@ class PrefillRouter:
             self._client = await self._factory()
         return self._client
 
+    async def activate(self) -> None:
+        """Start watching discovery for prefill instances NOW (ref:
+        prefill_router.rs activate). A client first created by the first
+        request has an empty instance list until its watch's snapshot
+        lands, so that request — and on a quiet frontend it may be the
+        only one — would be served aggregated beside a live prefill
+        worker."""
+        await self._prefill_client()
+
     async def generate(
         self, request: Any, context: Context, next: AsyncEngine
     ) -> AsyncIterator[Any]:

@@ -1,9 +1,9 @@
 """KV wire format v2: pool-native multi-tensor block transfer.
 
 The v1 wire format was always DENSE: int8 pools were dequantized to bf16
-before export, shipping 2x the bytes the pool actually holds — on the
-transfer-bound disagg leg that IS the bottleneck (BENCH_r04: 92.8 vs 593.2
-tok/s aggregated, TTFT +376 ms). v2 carries the pool-native form end to
+before export, shipping 2x the bytes the pool actually holds — on a
+transfer-bound disagg path that IS the bottleneck (whether it is, on this
+installation: not measured, ROADMAP S2). v2 carries the pool-native form end to
 end: a quantized pool ships ``{q8, scales}`` (≈ 0.53x the dense bf16 bytes
 at head_dim 64), a dense pool ships its storage dtype, and the importer
 installs whatever arrives into whatever pool it runs:

@@ -9,12 +9,12 @@ verification is ONE [S, spec_k+1]-token dispatch scoring every position
 (llama.forward_paged all_logits). Greedy-only: a tick with sampling /
 logprobs / logits-processor requests falls back to the fused decode path.
 
-Measured on the v5e (BENCH_SPEC=ngram, see docs/design_docs/
-performance.md): wins on extractive/repetitive workloads where proposals
-hit; loses on random-token workloads (every miss costs a dispatch that
-fused decode would have spent on decode_steps tokens) — hence the
-``tick()`` early-outs that keep the engine on the fused path whenever
-nothing proposes.
+By construction it wins on extractive/repetitive workloads where
+proposals hit and loses on random-token workloads (every miss costs a
+dispatch that fused decode would have spent on decode_steps tokens) —
+hence the ``tick()`` early-outs that keep the engine on the fused path
+whenever nothing proposes. Not measured on this installation (ROADMAP
+S7).
 """
 
 from __future__ import annotations

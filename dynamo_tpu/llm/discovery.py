@@ -222,11 +222,11 @@ class ModelWatcher:
                     .client()
                 )
 
-            operators.append(
-                PrefillRouter(
-                    prefill_client, threshold_tokens=self.disagg_threshold_tokens
-                )
+            prefill_router = PrefillRouter(
+                prefill_client, threshold_tokens=self.disagg_threshold_tokens
             )
+            await prefill_router.activate()
+            operators.append(prefill_router)
         pipeline = build_pipeline(operators, client)
         monitor = None
         liveness = None

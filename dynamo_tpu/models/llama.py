@@ -218,10 +218,13 @@ def unstack_layer_params(layers, n_layers: int):
     loop body references them directly. A list (not tuple) so the axes
     tree mirrors it without tripping param_shardings' tuple is_leaf.
 
-    Conversion runs leaf-by-leaf as a DONATED jit split so peak extra HBM
-    is bounded by one stacked leaf (~1.9 GB at 8B) instead of the whole
-    weight tree, and dispatch count is one per leaf rather than
-    n_layers × n_leaves eager slices."""
+    Conversion runs leaf-by-leaf as a jit split: one dispatch per leaf
+    rather than n_layers × n_leaves eager slices. The split asks to donate
+    its input, but on the chip XLA reports the donation unusable (one
+    stacked buffer cannot alias 36 outputs), so the stacked and per-layer
+    copies coexist until the caller drops the stacked tree: loading
+    Qwen3-8B int8 (8.2 GB of weights) peaks at 15.15 GB in use on a
+    16.9 GB v5e (my chip run, PR 21; PERF.md open questions)."""
     splits: Dict[Tuple[Any, ...], Any] = {}
 
     def split_leaf(a):

@@ -17,6 +17,10 @@ from dynamo_tpu import config
 from dynamo_tpu.multimodal.encoder import VisionEncoderConfig
 from dynamo_tpu.multimodal.handlers import EncodeWorkerHandler
 from dynamo_tpu.runtime.distributed import DistributedRuntime
+from dynamo_tpu.utils.jax_env import (
+    configure_compile_cache,
+    require_serving_platform,
+)
 from dynamo_tpu.utils.logging import configure_logging
 
 
@@ -48,6 +52,8 @@ async def main() -> None:
         )
 
     configure_logging()
+    configure_compile_cache()
+    require_serving_platform()
     runtime = DistributedRuntime.from_settings()
     if args.clip_model:
         from dynamo_tpu.multimodal.encoder import load_clip_vision

@@ -8,13 +8,16 @@ fallback (and the oracle in tests), so the framework never hard-requires a
 toolchain at runtime.
 
 Build model: g++ compiles the .cpp into a shared library under
-``native/_build`` on first use (~1s, cached by source mtime); set
-``DYN_TPU_NATIVE=0`` to force the Python fallbacks.
+``native/_build`` on first use (~1s). The artifact's name carries a hash
+of the source text and the compile flags, so a binary built from other
+source — e.g. one that rode along in a copy of the tree — can never load;
+set ``DYN_TPU_NATIVE=0`` to force the Python fallbacks.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -41,24 +44,23 @@ def _build_and_load(
     lib = None
     if NATIVE.get():
         src = os.path.join(_HERE, source)
-        # Flags participate in the artifact name: changing link flags must
-        # rebuild, not reuse a stale .so built differently.
-        import hashlib
-
-        tag = (
-            "-" + hashlib.md5(" ".join(extra_flags).encode()).hexdigest()[:8]
-            if extra_flags
-            else ""
-        )
-        out = os.path.join(_BUILD_DIR, f"lib{name}{tag}.so")
+        flags = ["-O2", "-shared", "-fPIC", "-std=c++17"]
         try:
-            if not os.path.exists(out) or os.path.getmtime(out) < os.path.getmtime(src):
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(
+                    f.read() + " ".join([*flags, *extra_flags]).encode()
+                ).hexdigest()[:12]
+            out = os.path.join(_BUILD_DIR, f"lib{name}-{digest}.so")
+            if not os.path.exists(out):
                 os.makedirs(_BUILD_DIR, exist_ok=True)
+                # Build to a private name, then rename: a concurrent
+                # process never dlopens a half-written file.
+                tmp = f"{out}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src,
-                     "-o", out, *extra_flags],
+                    ["g++", *flags, src, "-o", tmp, *extra_flags],
                     check=True, capture_output=True, timeout=120,
                 )
+                os.replace(tmp, out)
                 logger.info("built native component %s", name)
             lib = ctypes.CDLL(out)
         except (OSError, subprocess.SubprocessError) as exc:

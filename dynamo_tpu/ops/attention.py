@@ -16,57 +16,19 @@ Two implementations:
 
 from __future__ import annotations
 
-import logging
 from functools import partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.ops.pallas.paged_attention import (
+    paged_attention_decode_kernel,
+    paged_attention_kernel,
+)
 from dynamo_tpu.runtime.device_observe import watched_jit
 
-logger = logging.getLogger(__name__)
-
 NEG_INF = -1e30
-
-_kernel_fn = None
-_kernel_load_failed = False
-_decode_kernel_fn = None
-_decode_kernel_load_failed = False
-
-
-def _load_kernel_attr(attr: str, cache: str, flag: str):
-    """Resolve a pallas kernel once; on any failure fall back (caller uses
-    the XLA path) with a loud warning instead of letting the engine
-    crash-loop (round-1 failure mode: ModuleNotFoundError retried forever)."""
-    g = globals()
-    if g[cache] is not None or g[flag]:
-        return g[cache]
-    try:
-        import dynamo_tpu.ops.pallas.paged_attention as mod
-
-        g[cache] = getattr(mod, attr)
-    except Exception:
-        g[flag] = True
-        logger.exception(
-            "pallas kernel %s unavailable; falling back to the XLA gather "
-            "path (expect much lower decode throughput)", attr,
-        )
-    return g[cache]
-
-
-def _load_kernel():
-    return _load_kernel_attr(
-        "paged_attention_kernel", "_kernel_fn", "_kernel_load_failed"
-    )
-
-
-def _load_decode_kernel():
-    return _load_kernel_attr(
-        "paged_attention_decode_kernel",
-        "_decode_kernel_fn",
-        "_decode_kernel_load_failed",
-    )
 
 
 def paged_attention(
@@ -103,18 +65,14 @@ def paged_attention(
             # grid's per-step overhead over 8-16 sequences per iteration
             # (the generic (B, pages) grid runs B×P tiny steps — measured
             # 3.3× of an 8B verify dispatch before this route).
-            decode_kernel = _load_decode_kernel()
-            if decode_kernel is not None:
-                return decode_kernel(
-                    q, k_cache, v_cache, block_tables, start_pos,
-                    sm_scale=sm_scale, window=window, logit_cap=logit_cap,
-                )
-        kernel = _load_kernel()
-        if kernel is not None:
-            return kernel(
-                q, k_cache, v_cache, block_tables, start_pos, chunk_lens,
+            return paged_attention_decode_kernel(
+                q, k_cache, v_cache, block_tables, start_pos,
                 sm_scale=sm_scale, window=window, logit_cap=logit_cap,
             )
+        return paged_attention_kernel(
+            q, k_cache, v_cache, block_tables, start_pos, chunk_lens,
+            sm_scale=sm_scale, window=window, logit_cap=logit_cap,
+        )
     return _paged_attention_xla(
         q, k_cache, v_cache, block_tables, start_pos, chunk_lens, window,
         sm_scale=sm_scale, logit_cap=logit_cap,

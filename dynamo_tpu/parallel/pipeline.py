@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dynamo_tpu.parallel.sharding import shard_map_unchecked
-
 from dynamo_tpu.models.config import ModelConfig
 
 
@@ -150,17 +148,18 @@ def forward_paged_pp(
         return out, k_c, v_c
 
     replicated = P()
-    out, k_cache, v_cache = shard_map_unchecked(
+    out, k_cache, v_cache = jax.shard_map(
         stage_fn,
-        mesh,
-        (
+        mesh=mesh,
+        in_specs=(
             layer_specs,  # layer stack sharded over pp
             P(axis),  # per-layer windows
             P(axis),  # k_cache on layers
             P(axis),  # v_cache
             replicated, replicated, replicated, replicated,
         ),
-        (replicated, P(axis), P(axis)),
+        out_specs=(replicated, P(axis), P(axis)),
+        check_vma=False,
     )(params["layers"], windows, k_cache, v_cache, x_mb, sp_mb, cl_mb, bt_mb)
 
     x = out.reshape(B, C, -1)

@@ -101,13 +101,13 @@ def ring_attention(
 def make_ring_attention(mesh: Mesh, axis: str = "sp", *, causal: bool = True):
     """Jitted [B, T, H, D] ring attention with T sharded over ``axis``."""
     spec = P(None, axis, None, None)
-    from dynamo_tpu.parallel.sharding import shard_map_unchecked
     from dynamo_tpu.runtime.device_observe import watched_jit
 
-    fn = shard_map_unchecked(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis=axis, causal=causal),
-        mesh,
-        (spec, spec, spec),
-        spec,
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
     )
     return watched_jit("parallel.ring_attention", jax.jit(fn))

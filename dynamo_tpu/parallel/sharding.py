@@ -20,22 +20,6 @@ from dynamo_tpu.parallel.mesh import AxisNames
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions: the
-    public `jax.shard_map` (with `check_vma`) landed after 0.4.x, where
-    the API lives in jax.experimental with the `check_rep` spelling —
-    callers (ring attention, pipeline parallel) use this shim so one tree
-    serves both jaxlibs."""
-    try:
-        sm = jax.shard_map  # jax >= 0.6 public API
-        kw = {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        kw = {"check_rep": False}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 @dataclass(frozen=True)
 class ShardingRules:
     """logical axis name → mesh axis (or None = replicate)."""

@@ -24,6 +24,7 @@ class KvIndexer:
         from dynamo_tpu.native.radix import make_radix_tree
 
         self.tree = make_radix_tree()
+        logger.info("router index: %s", type(self.tree).__name__)
         self._events_applied = 0
         self._last_event_id: Dict[WorkerKey, int] = {}
 

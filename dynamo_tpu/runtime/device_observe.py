@@ -332,6 +332,7 @@ def device_memory_stats() -> List[Dict[str, Any]]:
             {
                 "id": getattr(d, "id", None),
                 "platform": getattr(d, "platform", None),
+                "device_kind": getattr(d, "device_kind", None),
                 "memory_stats": stats,
             }
         )

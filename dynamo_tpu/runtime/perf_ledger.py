@@ -25,8 +25,8 @@ This module makes performance a first-class, self-comparing observable
   windows drifting past the band for ``anomaly_streak`` consecutive
   evaluations raise a typed anomaly: lint-pinned counter
   (``PERF_ANOMALIES_TOTAL``), a "perf" flight-ring event, and a verdict
-  on ``GET /debug/perf`` — a Mosaic demotion or a quietly slower kernel
-  becomes a paged fact, not a post-hoc diff. A corrupt or vanished
+  on ``GET /debug/perf`` — a quietly slower kernel becomes a paged
+  fact, not a post-hoc diff. A corrupt or vanished
   fingerprint file degrades to cold start (counted, flight-recorded),
   never crashes.
 

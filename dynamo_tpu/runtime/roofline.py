@@ -19,11 +19,27 @@ where the serving deps don't.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
-# Public hardware specs the roofline derives from (v5e chip class).
-V5E_BW = 819e9  # B/s HBM
-V5E_PEAK_BF16 = 197e12  # FLOP/s
+
+class DevicePeaks(NamedTuple):
+    hbm_bytes_per_s: float
+    bf16_flops_per_s: float
+    int8_ops_per_s: float
+
+
+# Published peaks of one chip, keyed by the ``device_kind`` JAX reports.
+# A device that is not here has no roofline: callers get None, never
+# another chip's numbers.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
+    # int8, 16 GB HBM at 819 GB/s.
+    "TPU v5 lite": DevicePeaks(819e9, 197e12, 393e12),
+}
+
+
+def device_peaks(device_kind: Optional[str]) -> Optional[DevicePeaks]:
+    return DEVICE_PEAKS.get(device_kind or "")
 
 
 def param_count(cfg) -> int:
@@ -73,7 +89,7 @@ def decode_roofline_toks_per_sec(
     batch: int,
     avg_ctx: float,
     quant: Optional[str],
-    hbm_bw: float = V5E_BW,
+    hbm_bw: float,
 ) -> float:
     """Bandwidth-roofline decode throughput (tokens/s, whole chip) for
     this model/batch/context: ``batch / (step_bytes / hbm_bw)``."""
@@ -84,13 +100,19 @@ def decode_roofline_toks_per_sec(
 
 
 def make_roofline_fn(
-    cfg, quant: Optional[str], hbm_bw: float = V5E_BW
-) -> Callable[[int, float], float]:
-    """Close over a config: ``(batch, avg_ctx) -> roofline tok/s``. The
+    cfg, quant: Optional[str], device_kind: Optional[str]
+) -> Optional[Callable[[int, float], float]]:
+    """Close over a config and the device's published bandwidth:
+    ``(batch, avg_ctx) -> roofline tok/s``, or None for a device without
+    published peaks (the perf ledger then reports no roofline share). The
     shape the perf ledger stores at configure time — the ledger itself
     stays model-agnostic."""
+    peaks = device_peaks(device_kind)
+    if peaks is None:
+        return None
+
     def fn(batch: int, avg_ctx: float) -> float:
         return decode_roofline_toks_per_sec(
-            cfg, batch, avg_ctx, quant, hbm_bw=hbm_bw
+            cfg, batch, avg_ctx, quant, hbm_bw=peaks.hbm_bytes_per_s
         )
     return fn

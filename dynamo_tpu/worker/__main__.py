@@ -27,6 +27,10 @@ from dynamo_tpu.models.config import (
 from dynamo_tpu.parallel import MeshConfig, make_mesh
 from dynamo_tpu.router import KvEventPublisher, LoadPublisher
 from dynamo_tpu.runtime.distributed import DistributedRuntime
+from dynamo_tpu.utils.jax_env import (
+    configure_compile_cache,
+    require_serving_platform,
+)
 from dynamo_tpu.utils.logging import configure_logging, get_logger
 
 logger = get_logger(__name__)
@@ -190,6 +194,7 @@ async def main() -> None:
             )
 
     configure_logging()
+    configure_compile_cache()
 
     # Multi-host: join the jax.distributed runtime BEFORE any JAX use (the
     # backend must not exist yet). One process per host; rank 0 is the
@@ -198,6 +203,9 @@ async def main() -> None:
     from dynamo_tpu.parallel.multihost import init_multihost
 
     topo = init_multihost(args.coordinator, args.num_processes, args.process_id)
+    # Before the (possibly minutes-long) weight load: no chip and no
+    # explicit JAX_PLATFORMS=cpu means this worker must not start.
+    require_serving_platform()
 
     runtime = DistributedRuntime.from_settings() if topo.is_leader else None
 

@@ -78,15 +78,17 @@ def test_decode_kernel_matches_xla_oracle(B, H, KH, D, bs, P, maxstart, BQ):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_use_kernel_flag_falls_back_without_crash(monkeypatch):
-    """use_kernel=True must never raise even if the kernel can't load
-    (round-1 regression: crash-loop on missing module)."""
+def test_use_kernel_failure_propagates(monkeypatch):
+    """use_kernel=True reaches the Pallas kernel or raises: a kernel that
+    cannot run is never served from the XLA gather path behind the
+    caller's back (the import is at module top; there is no loader to
+    swallow an error)."""
     import dynamo_tpu.ops.attention as attn
 
-    monkeypatch.setattr(attn, "_kernel_fn", None)
-    monkeypatch.setattr(attn, "_kernel_load_failed", True)
-    monkeypatch.setattr(attn, "_decode_kernel_fn", None)
-    monkeypatch.setattr(attn, "_decode_kernel_load_failed", True)
+    def refuse(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(attn, "paged_attention_decode_kernel", refuse)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((1, 1, 4, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((4, 16, 2, 64)), jnp.float32)
@@ -94,7 +96,9 @@ def test_use_kernel_flag_falls_back_without_crash(monkeypatch):
     bt = jnp.zeros((1, 2), jnp.int32)
     start = jnp.zeros((1,), jnp.int32)
     cl = jnp.ones((1,), jnp.int32)
-    out = paged_attention(q, k, v, bt, start, cl, use_kernel=True)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        paged_attention(q, k, v, bt, start, cl, use_kernel=True)
+    out = paged_attention(q, k, v, bt, start, cl, use_kernel=False)
     ref = _paged_attention_xla(q, k, v, bt, start, cl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 

@@ -345,6 +345,17 @@ async def test_debug_device_routes_reflect_live_engine():
             + split["free_bytes"] == split["total_bytes"]
         )
         assert isinstance(body["devices"], list) and body["devices"]
+        # chip_smoke.py reads the device the PROCESS holds from these rows.
+        assert all(
+            d["platform"] == "cpu" and d["device_kind"] == "cpu"
+            for d in body["devices"]
+        )
+
+        status, body = await _get(server.port, "/engine/stats")
+        assert status == 200
+        assert body["decode_path"] == "xla"
+        assert body["attention_impl"] == "xla"
+        assert "cpu" in body["attention_reason"]
 
         status, body = await _get(server.port, "/debug/compiles")
         assert status == 200

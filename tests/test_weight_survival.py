@@ -131,8 +131,7 @@ def test_restart_bench_warm_beats_cold_3x(tmp_path):
 
     model_dir = _model_dir(tmp_path)
     out = run(model_dir, str(tmp_path / "caches"))
-    # Unloaded this measures ~5.6x overall (performance.md). Under
-    # full-suite contention on the single host core the compile/jit legs
+    # Under full-suite contention on the host the compile/jit legs
     # jitter by multiples (a loaded host reproducibly measured the old
     # 1.5x end-to-end gate at 1.38x), so the hard gates are the
     # contention-robust STRUCTURAL invariants: the warm worker actually

@@ -275,10 +275,10 @@ def _raw_decode(r, nb=1):
     )
 
 
-def test_transient_decode_error_does_not_demote(monkeypatch):
-    """A transient (non-compile-shaped) error at first dispatch must
-    PROPAGATE instead of permanently demoting the engine to the XLA
-    decode path — the ADVICE r5 finding against `except Exception`."""
+def test_decode_error_at_first_dispatch_propagates(monkeypatch):
+    """An error from the fused decode dispatch reaches the caller whatever
+    its shape — there is no classifier deciding which errors may be
+    swallowed — and the runner keeps the path it chose at start."""
     from dynamo_tpu.ops.pallas import fused_layer
 
     r = _mk_runner()
@@ -289,45 +289,41 @@ def test_transient_decode_error_does_not_demote(monkeypatch):
     monkeypatch.setattr(fused_layer, "fused_decoder_layer", boom)
     with pytest.raises(ValueError):
         _raw_decode(r)
-    assert r.use_megakernel, "transient error demoted the megakernel"
+    assert r.use_megakernel
+    assert r.mk_fused_bursts == 0 and r.mk_fallback_bursts == 0
 
 
-def test_transient_at_unproven_width_propagates(monkeypatch):
-    """Provenness is per table-width bucket: after a success at width 1, a
-    TRANSIENT error at the never-compiled width 2 still propagates (it is
-    not compile-shaped), keeping the megakernel armed."""
+def test_compile_error_at_first_dispatch_propagates(monkeypatch):
+    """A compile-shaped error (Mosaic's words) at the very first dispatch
+    propagates too: the kernel the runner selected either lowers or is a
+    bug, and no XLA burst is served in its place."""
+    from dynamo_tpu.ops.pallas import fused_layer
+
+    r = _mk_runner()
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(fused_layer, "fused_decoder_layer", boom)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        _raw_decode(r)
+    assert r.use_megakernel
+    assert r.mk_fallback_bursts == 0
+
+
+def test_compile_error_at_wider_bucket_propagates(monkeypatch):
+    """Each pow2 table-width bucket compiles at its first dispatch. A
+    lowering failure at a wider, never-compiled bucket (the first
+    long-context request tripping a VMEM limit the short-context program
+    never hit) propagates — it does not route that bucket to the XLA
+    program — and the bucket that already compiled keeps dispatching
+    fused."""
     from dynamo_tpu.ops.pallas import fused_layer
 
     r = _mk_runner()
     toks, _, _, _ = _raw_decode(r, nb=1)
     assert toks.shape[0] == 4
-    assert (1, False, False) in r._mk_proven_keys
-
-    XlaRuntimeError = type("XlaRuntimeError", (RuntimeError,), {})
-
-    def boom(*a, **k):
-        raise XlaRuntimeError("UNAVAILABLE: Socket closed")
-
-    monkeypatch.setattr(fused_layer, "fused_decoder_layer", boom)
-    # nb=2 forces a fresh trace (new table width) so the patch takes hold
-    with pytest.raises(RuntimeError):
-        _raw_decode(r, nb=2)
-    assert r.use_megakernel, "transient at new width demoted the megakernel"
-
-
-def test_unproven_width_compile_error_demotes(monkeypatch):
-    """A DETERMINISTIC lowering failure at a wider, never-proven bucket
-    (e.g. the first long-context request tripping an SMEM/VMEM limit the
-    short-context program never hit) must demote THAT (width, variant)
-    key to the XLA path — long-context serving degrades instead of
-    erroring forever — while every other bucket/variant (including the
-    already-proven base key) keeps dispatching fused."""
-    from dynamo_tpu.ops.pallas import fused_layer
-
-    r = _mk_runner()
-    _raw_decode(r, nb=1)
-    assert (1, False, False) in r._mk_proven_keys
-    fused_before = r.mk_fused_bursts
+    assert r.mk_bursts_by_variant == {"w1": 1}
 
     real = fused_layer.fused_decoder_layer
 
@@ -335,31 +331,23 @@ def test_unproven_width_compile_error_demotes(monkeypatch):
         raise RuntimeError("Mosaic lowering failed: scoped VMEM over budget")
 
     monkeypatch.setattr(fused_layer, "fused_decoder_layer", boom)
-    toks, _, _, _ = _raw_decode(r, nb=2)  # demotes the key, serves via XLA
-    assert toks.shape[0] == 4
-    assert (2, False, False) in r._mk_demoted_keys
-    assert r.mk_fallback_bursts == 1
-    # Fallback ISOLATION: the megakernel stays armed and the proven base
-    # key still dispatches fused (restore the real kernel — the width-1
-    # program is already compiled, but a later engine may re-trace).
+    # nb=2 forces a fresh trace (new table width) so the patch takes hold
+    with pytest.raises(RuntimeError, match="VMEM"):
+        _raw_decode(r, nb=2)
+    assert r.mk_fallback_bursts == 0, "a burst was served from XLA"
+    assert r.mk_bursts_by_variant == {"w1": 1}
     monkeypatch.setattr(fused_layer, "fused_decoder_layer", real)
-    assert r.use_megakernel, "per-key demotion must not disable the kernel"
     toks, _, _, _ = _raw_decode(r, nb=1)
     assert toks.shape[0] == 4
-    assert r.mk_fused_bursts == fused_before + 1, (
-        "proven key stopped dispatching fused after an unrelated demotion"
-    )
-    # ... and the demoted key keeps serving via XLA without re-raising.
-    toks, _, _, _ = _raw_decode(r, nb=2)
-    assert toks.shape[0] == 4
-    assert r.mk_fallback_bursts == 2
+    assert r.mk_bursts_by_variant == {"w1": 2}
 
 
 async def test_engine_megakernel_past_old_table_ceiling():
     """A prompt past the old 256-token ceiling (decode table bucket of 32
     pages > the removed MAX_TABLE_PAGES=16) must decode THROUGH the
-    megakernel — _mk_proven_keys shows a fused dispatch actually ran, i.e. no
-    silent width-gate fallback — and match the XLA path token-for-token."""
+    megakernel — mk_bursts_by_variant shows a fused dispatch actually ran at
+    that width, i.e. no silent width gate — and match the XLA path
+    token-for-token."""
     from dynamo_tpu.engines.tpu import JaxEngine, JaxEngineArgs
     from dynamo_tpu.llm.protocols.common import (
         PreprocessedRequest,
@@ -386,10 +374,13 @@ async def test_engine_megakernel_past_old_table_ceiling():
             )
             outs = await collect(e.generate(req, Context()))
             if use_mk:
-                assert e.runner.use_megakernel, "demoted mid-run"
-                assert e.runner._mk_proven_keys, "megakernel never ran"
+                assert e.runner.mk_fallback_bursts == 0
+                widths = [
+                    int(k[1:]) for k in e.runner.mk_bursts_by_variant
+                ]
+                assert widths, "megakernel never ran"
                 # the decode table bucket exceeded the old 16-page ceiling
-                assert max(k[0] for k in e.runner._mk_proven_keys) > 16
+                assert max(widths) > 16
             return [t for d in outs for t in d.token_ids]
         finally:
             await e.stop()
