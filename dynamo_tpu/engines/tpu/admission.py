@@ -185,9 +185,10 @@ class Admitter:
             return 0
         e._admission_failure_streak = 0
         free_iter = (i for i, s in enumerate(e._slots) if s is None)
-        for (seq, prep), f in zip(pending.batch, pending.first):
-            tok, logp, top = f
-            e._install(seq, prep, next(free_iter), tok, logp, top)
+        with e.step_metrics.phase("tick.install", rows=len(pending.batch)):
+            for (seq, prep), f in zip(pending.batch, pending.first):
+                tok, logp, top = f
+                e._install(seq, prep, next(free_iter), tok, logp, top)
         return len(pending.batch)
 
     def _contain_admission_failure(self, seqs: "List[Any]", exc: Exception) -> None:
@@ -455,69 +456,76 @@ class Admitter:
         mm_embeds, mm_slot_of = pending.mm_embeds, pending.mm_slot_of
         Bp = pending.Bp
 
-        while any(pos[r] < len(prompts[r]) for r in range(rows)):
-            if e._tick_budget_left is not None and e._tick_budget_left <= 0:
-                return False
-            chunks = [
-                prompts[r][pos[r] : pos[r] + args.prefill_chunk] for r in range(rows)
-            ]
-            c_bucket = min(
-                _next_pow2(max(len(c) for c in chunks)), args.prefill_chunk
-            )
-            tok_arr = np.zeros((Bp, c_bucket), dtype=np.int32)
-            start = np.zeros(Bp, dtype=np.int32)
-            lens = np.zeros(Bp, dtype=np.int32)
-            for r in range(rows):
-                ch = chunks[r][:c_bucket]
-                tok_arr[r, : len(ch)] = ch
-                start[r] = pos[r]
-                lens[r] = len(ch)
-            mm_chunk = None
-            if mm_slot_of is not None:
-                mm_chunk = np.full((Bp, c_bucket), -1, dtype=np.int32)
-                n0 = int(lens[0])
-                mm_chunk[0, :n0] = mm_slot_of[pos[0] : pos[0] + n0]
-            # Fresh prefills (no prefix-cache hit, first chunk round) take
-            # the dense in-chunk attention program — zero paged reads.
-            first_chunk = bool(np.all(start[:rows] == 0))
-            t0 = time.monotonic()
-            toks, logps, topv, topi = await e._device(
-                e._run_step,
-                tok_arr, start, lens, tables,
-                temp, topk, topp, adapter,
-                mm_embeds, mm_chunk, procs, want_top, first_chunk, salts,
-            )
-            dt = time.monotonic() - t0
-            e.step_metrics.observe_prefill(
-                # Occupancy counts rows still prefilling this round — short
-                # prompts finish earlier chunk rounds and ride along with
-                # lens == 0.
-                dt,
-                int(np.count_nonzero(lens[:rows])),
-                int(lens.sum()),
-            )
-            # Per-token prefill cost EWMA — the basis for the plane's
-            # prefill-seconds-saved estimate.
-            kv_reuse_plane().note_prefill_cost(dt, int(lens.sum()))
-            # Perf ledger: prefill tokens/s per pow2 chunk bucket (the
-            # attribution sibling of the decode-shape windows).
-            e._perf.observe_prefill(c_bucket, dt, int(lens.sum()))
-            if e._tick_budget_left is not None:
-                e._tick_budget_left -= int(lens.sum())
-            for r in range(rows):
-                n = int(lens[r])
-                if n == 0:
-                    continue
-                e.prefill_tokens += n
-                pos[r] += n
-                if pos[r] >= len(prompts[r]):
-                    top = None
-                    if topv is not None:
-                        top = [
-                            (int(topi[r, j]), float(topv[r, j]))
-                            for j in range(topv.shape[1])
-                        ]
-                    first[r] = (int(toks[r]), float(logps[r]), top)
+        phase = e.step_metrics.phase
+        with phase("tick.prefill_build", rows=rows):
+            while any(pos[r] < len(prompts[r]) for r in range(rows)):
+                if e._tick_budget_left is not None and e._tick_budget_left <= 0:
+                    return False
+                chunks = [
+                    prompts[r][pos[r] : pos[r] + args.prefill_chunk] for r in range(rows)
+                ]
+                c_bucket = min(
+                    _next_pow2(max(len(c) for c in chunks)), args.prefill_chunk
+                )
+                tok_arr = np.zeros((Bp, c_bucket), dtype=np.int32)
+                start = np.zeros(Bp, dtype=np.int32)
+                lens = np.zeros(Bp, dtype=np.int32)
+                for r in range(rows):
+                    ch = chunks[r][:c_bucket]
+                    tok_arr[r, : len(ch)] = ch
+                    start[r] = pos[r]
+                    lens[r] = len(ch)
+                mm_chunk = None
+                if mm_slot_of is not None:
+                    mm_chunk = np.full((Bp, c_bucket), -1, dtype=np.int32)
+                    n0 = int(lens[0])
+                    mm_chunk[0, :n0] = mm_slot_of[pos[0] : pos[0] + n0]
+                # Fresh prefills (no prefix-cache hit, first chunk round) take
+                # the dense in-chunk attention program — zero paged reads.
+                first_chunk = bool(np.all(start[:rows] == 0))
+                t0 = time.monotonic()
+                with phase(
+                    "tick.prefill_wait", rows=rows, chunk=c_bucket,
+                    nb=tables.shape[1], tokens=int(lens.sum()),
+                ):
+                    toks, logps, topv, topi = await e._device(
+                        e._run_step,
+                        tok_arr, start, lens, tables,
+                        temp, topk, topp, adapter,
+                        mm_embeds, mm_chunk, procs, want_top, first_chunk,
+                        salts,
+                    )
+                dt = time.monotonic() - t0
+                e.step_metrics.observe_prefill(
+                    # Occupancy counts rows still prefilling this round — short
+                    # prompts finish earlier chunk rounds and ride along with
+                    # lens == 0.
+                    dt,
+                    int(np.count_nonzero(lens[:rows])),
+                    int(lens.sum()),
+                )
+                # Per-token prefill cost EWMA — the basis for the plane's
+                # prefill-seconds-saved estimate.
+                kv_reuse_plane().note_prefill_cost(dt, int(lens.sum()))
+                # Perf ledger: prefill tokens/s per pow2 chunk bucket (the
+                # attribution sibling of the decode-shape windows).
+                e._perf.observe_prefill(c_bucket, dt, int(lens.sum()))
+                if e._tick_budget_left is not None:
+                    e._tick_budget_left -= int(lens.sum())
+                for r in range(rows):
+                    n = int(lens[r])
+                    if n == 0:
+                        continue
+                    e.prefill_tokens += n
+                    pos[r] += n
+                    if pos[r] >= len(prompts[r]):
+                        top = None
+                        if topv is not None:
+                            top = [
+                                (int(topi[r, j]), float(topv[r, j]))
+                                for j in range(topv.shape[1])
+                            ]
+                        first[r] = (int(toks[r]), float(logps[r]), top)
         assert all(f is not None for f in first)
         return True
 

@@ -677,11 +677,14 @@ class DeviceRunner:
         use_kernel = self.use_kernel
         num_top = self.args.top_logprobs_cap if want_top else 0
 
-        def step(params, lora, k_cache, v_cache, tokens, start_pos, chunk_lens,
-                 block_tables, salts, rng, temp, topk, topp, adapter_ids,
-                 mm_embeds, mm_slot,
-                 minp=None, rep=None, pres=None, freq=None,
-                 bias_ids=None, bias_vals=None, pmask=None):
+        # The function's name is the program's name in a device trace
+        # (``jit_prefill_step``): benchmark/trace_names/ tells the steps
+        # apart by it.
+        def prefill_step(params, lora, k_cache, v_cache, tokens, start_pos,
+                         chunk_lens, block_tables, salts, rng, temp, topk,
+                         topp, adapter_ids, mm_embeds, mm_slot,
+                         minp=None, rep=None, pres=None, freq=None,
+                         bias_ids=None, bias_vals=None, pmask=None):
             logits, k_cache, v_cache = llama.forward_paged(
                 params, cfg, tokens, start_pos, chunk_lens, block_tables,
                 k_cache, v_cache, use_kernel=use_kernel,
@@ -695,19 +698,21 @@ class DeviceRunner:
             # per-step fold so a preempted sequence's recompute redraws
             # identical noise for the same position.
             row_keys = fold_row_keys(rng, salts, start_pos + chunk_lens)
-            if want_procs:
-                from dynamo_tpu.ops import logits_process as lp
+            with jax.named_scope("sample"):
+                if want_procs:
+                    from dynamo_tpu.ops import logits_process as lp
 
-                # At the first sampled token only the prompt has been seen.
-                pp = lp.ProcParams(rep=rep, pres=pres, freq=freq,
-                                   bias_ids=bias_ids, bias_vals=bias_vals)
-                logits = lp.apply_prompt_only(logits, pmask, pp)
-                toks = sample_tokens(logits, None, temp, topk, topp, minp,
-                                     row_keys=row_keys)
-            else:
-                toks = sample_tokens(logits, None, temp, topk, topp,
-                                     row_keys=row_keys)
-            logp = compute_logprobs(logits, toks)
+                    # At the first sampled token only the prompt has been
+                    # seen.
+                    pp = lp.ProcParams(rep=rep, pres=pres, freq=freq,
+                                       bias_ids=bias_ids, bias_vals=bias_vals)
+                    logits = lp.apply_prompt_only(logits, pmask, pp)
+                    toks = sample_tokens(logits, None, temp, topk, topp, minp,
+                                         row_keys=row_keys)
+                else:
+                    toks = sample_tokens(logits, None, temp, topk, topp,
+                                         row_keys=row_keys)
+                logp = compute_logprobs(logits, toks)
             if num_top > 0:
                 from dynamo_tpu.ops.sampling import top_logprobs as top_op
 
@@ -718,7 +723,7 @@ class DeviceRunner:
             return toks, logp, k_cache, v_cache
 
         return watched_jit(
-            "runner.prefill_step", jax.jit(step, donate_argnums=(2, 3))
+            "runner.prefill_step", jax.jit(prefill_step, donate_argnums=(2, 3))
         )
 
     def _build_decode_fn(self, want_logprobs: bool = False,
@@ -745,8 +750,9 @@ class DeviceRunner:
         num_top = self.args.top_logprobs_cap if want_logprobs else 0
 
         if not want_procs:
-            def step(params, lora, k_cache, v_cache, tokens, pos, active,
-                     block_tables, salts, rng, temp, topk, topp, adapter_ids):
+            def decode_burst(params, lora, k_cache, v_cache, tokens, pos,
+                             active, block_tables, salts, rng, temp, topk,
+                             topp, adapter_ids):
                 out = llama.decode_multi(
                     params, cfg, tokens, pos, active, block_tables,
                     k_cache, v_cache, rng, temp, topk, topp,
@@ -766,15 +772,16 @@ class DeviceRunner:
 
             return watched_jit(
                 "runner.decode_state",
-                jax.jit(step, donate_argnums=(2, 3, 4, 5)),
+                jax.jit(decode_burst, donate_argnums=(2, 3, 4, 5)),
                 budget=self._decode_sig_budget,
             )
 
         from dynamo_tpu.ops import logits_process as lp
 
-        def step_p(params, lora, k_cache, v_cache, tokens, pos, active,
-                   block_tables, salts, rng, temp, topk, topp, adapter_ids,
-                   minp, rep, pres, freq, bias_ids, bias_vals, counts, pmask):
+        def decode_burst_procs(params, lora, k_cache, v_cache, tokens, pos,
+                               active, block_tables, salts, rng, temp, topk,
+                               topp, adapter_ids, minp, rep, pres, freq,
+                               bias_ids, bias_vals, counts, pmask):
             pp = lp.ProcParams(rep=rep, pres=pres, freq=freq,
                                bias_ids=bias_ids, bias_vals=bias_vals)
             st = lp.ProcState(out_counts=counts, prompt_mask=pmask)
@@ -800,7 +807,7 @@ class DeviceRunner:
         # donate caches + tokens/pos carry + the token-count array.
         return watched_jit(
             "runner.decode_state",
-            jax.jit(step_p, donate_argnums=(2, 3, 4, 5, 20)),
+            jax.jit(decode_burst_procs, donate_argnums=(2, 3, 4, 5, 20)),
             budget=self._decode_sig_budget,
         )
 
@@ -808,8 +815,9 @@ class DeviceRunner:
         cfg = self.config
         use_kernel = self.use_kernel
 
-        def step(params, lora, k_cache, v_cache, tokens, start_pos, chunk_lens,
-                 block_tables, adapter_ids, rng, rng_step, temp, topk, topp):
+        def spec_verify_step(params, lora, k_cache, v_cache, tokens, start_pos,
+                             chunk_lens, block_tables, adapter_ids, rng,
+                             rng_step, temp, topk, topp):
             from dynamo_tpu.ops.sampling import spec_verify_sample
 
             rng = jax.random.fold_in(rng, rng_step)
@@ -831,7 +839,7 @@ class DeviceRunner:
 
         return watched_jit(
             "runner.spec_verify",
-            jax.jit(step, donate_argnums=(2, 3)),
+            jax.jit(spec_verify_step, donate_argnums=(2, 3)),
             budget=self._decode_sig_budget,
         )
 

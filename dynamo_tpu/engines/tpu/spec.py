@@ -118,36 +118,42 @@ class NgramSpecDecoder:
             )
         nb_bucket = table_width_bucket(max_blocks, args.max_blocks_per_seq)
 
-        emitted_all, counts = await e._device(
-            e._run_spec,
-            tokens,
-            e._pos.copy(),
-            lens,
-            e._block_tables[:, :nb_bucket].copy(),
-            e._adapter_ids.copy(),
-            e._temp.copy(),
-            e._topk.copy(),
-            e._topp.copy(),
-        )
+        # The verify dispatch is synchronous: its await is this tick's
+        # device wait, and the emission below its host half.
+        with e.step_metrics.phase(
+            "tick.decode_wait", rows=len(active), nb=nb_bucket
+        ):
+            emitted_all, counts = await e._device(
+                e._run_spec,
+                tokens,
+                e._pos.copy(),
+                lens,
+                e._block_tables[:, :nb_bucket].copy(),
+                e._adapter_ids.copy(),
+                e._temp.copy(),
+                e._topk.copy(),
+                e._topp.copy(),
+            )
         e.steps += 1
         # The verify dispatch occupied the device: the window before the
         # next fused-decode dispatch is not host-injected gap.
         e._t_last_ready = None
-        for seq in list(active):
-            if seq.slot < 0:
-                continue  # finished by an earlier emit in this loop
-            slot = seq.slot
-            prop = proposals.get(slot, [])
-            n = int(counts[slot])
-            emitted = emitted_all[slot, :n].astype(np.int32)
-            e.spec_proposed += len(prop)
-            e.spec_accepted += n - 1
-            e._emit_burst(
-                seq, emitted, np.zeros(n, dtype=np.float32),
-            )
-            if seq.slot >= 0:
-                # The verify dispatch advanced this slot outside the
-                # decode carry — resync pos/tokens before the next fused
-                # decode burst reads the device-resident state.
-                e._dirty_state.add(slot)
+        with e.step_metrics.phase("tick.emit", rows=len(active)):
+            for seq in list(active):
+                if seq.slot < 0:
+                    continue  # finished by an earlier emit in this loop
+                slot = seq.slot
+                prop = proposals.get(slot, [])
+                n = int(counts[slot])
+                emitted = emitted_all[slot, :n].astype(np.int32)
+                e.spec_proposed += len(prop)
+                e.spec_accepted += n - 1
+                e._emit_burst(
+                    seq, emitted, np.zeros(n, dtype=np.float32),
+                )
+                if seq.slot >= 0:
+                    # The verify dispatch advanced this slot outside the
+                    # decode carry — resync pos/tokens before the next
+                    # fused decode burst reads the device-resident state.
+                    e._dirty_state.add(slot)
         return True

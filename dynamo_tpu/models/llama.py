@@ -321,78 +321,84 @@ def decoder_layer(
     sm_scale = c.query_scale**-0.5 if c.query_scale is not None else hd**-0.5
     cap = float(c.attn_logit_softcap or 0.0)
 
-    h = _rms_norm(x, lp["attn_norm"], c.rms_norm_eps, uo)
-    q = qeinsum("bcd,dh->bch", h, lp["wq"]) + lora_delta(ll, "wq", h, adapter_ids)
-    k = qeinsum("bcd,dh->bch", h, lp["wk"]) + lora_delta(ll, "wk", h, adapter_ids)
-    v = qeinsum("bcd,dh->bch", h, lp["wv"]) + lora_delta(ll, "wv", h, adapter_ids)
-    if c.qkv_bias:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(B, C, c.n_heads, hd)
-    k = k.reshape(B, C, c.n_kv_heads, hd)
-    v = v.reshape(B, C, c.n_kv_heads, hd)
-    if c.qk_norm:
-        # Qwen3/Gemma-3: per-head RMSNorm over head_dim on q and k, BEFORE
-        # RoPE (HF attention order: norm → rope). Gemma-family norms store
-        # (w - 1), hence the unit offset.
-        q = _rms_norm(q, lp["q_norm"], c.rms_norm_eps, uo)
-        k = _rms_norm(k, lp["k_norm"], c.rms_norm_eps, uo)
-    if cos_loc is not None:
-        # Gemma-3 dual-frequency RoPE: windowed (local) layers rotate with
-        # the local-base table; global layers with the (possibly
-        # position-scaled) global table. ``win`` is a traced scalar.
-        sel = (win > 0)
-        cos = jnp.where(sel, cos_loc, cos)
-        sin = jnp.where(sel, sin_loc, sin)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    # named_scope: metadata on the operations, so a device trace says which
+    # block an operation belongs to; the computation is unchanged.
+    with jax.named_scope("qkv"):
+        h = _rms_norm(x, lp["attn_norm"], c.rms_norm_eps, uo)
+        q = qeinsum("bcd,dh->bch", h, lp["wq"]) + lora_delta(ll, "wq", h, adapter_ids)
+        k = qeinsum("bcd,dh->bch", h, lp["wk"]) + lora_delta(ll, "wk", h, adapter_ids)
+        v = qeinsum("bcd,dh->bch", h, lp["wv"]) + lora_delta(ll, "wv", h, adapter_ids)
+        if c.qkv_bias:
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(B, C, c.n_heads, hd)
+        k = k.reshape(B, C, c.n_kv_heads, hd)
+        v = v.reshape(B, C, c.n_kv_heads, hd)
+        if c.qk_norm:
+            # Qwen3/Gemma-3: per-head RMSNorm over head_dim on q and k, BEFORE
+            # RoPE (HF attention order: norm → rope). Gemma-family norms store
+            # (w - 1), hence the unit offset.
+            q = _rms_norm(q, lp["q_norm"], c.rms_norm_eps, uo)
+            k = _rms_norm(k, lp["k_norm"], c.rms_norm_eps, uo)
+        if cos_loc is not None:
+            # Gemma-3 dual-frequency RoPE: windowed (local) layers rotate with
+            # the local-base table; global layers with the (possibly
+            # position-scaled) global table. ``win`` is a traced scalar.
+            sel = (win > 0)
+            cos = jnp.where(sel, cos_loc, cos)
+            sin = jnp.where(sel, sin_loc, sin)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    k_c = write_chunk_to_cache(k_c, k, block_tables, start_pos, chunk_lens)
-    v_c = write_chunk_to_cache(v_c, v, block_tables, start_pos, chunk_lens)
+    with jax.named_scope("kv_write"):
+        k_c = write_chunk_to_cache(k_c, k, block_tables, start_pos, chunk_lens)
+        v_c = write_chunk_to_cache(v_c, v, block_tables, start_pos, chunk_lens)
 
-    if first_chunk:
-        attn = dense_chunk_attention(
-            q, k, v, chunk_lens, sm_scale=sm_scale, window=win,
-            logit_cap=cap,
-        ).reshape(B, C, -1)
-    else:
-        attn = paged_attention(
-            q, k_c, v_c, block_tables, start_pos, chunk_lens,
-            use_kernel=use_kernel, sm_scale=sm_scale, window=win,
-            logit_cap=cap,
-        ).reshape(B, C, -1)
-    attn_out = qeinsum("bch,hd->bcd", attn, lp["wo"]) + lora_delta(
-        ll, "wo", attn, adapter_ids
-    )
-    if c.post_norms:
-        attn_out = _rms_norm(attn_out, lp["attn_post_norm"], c.rms_norm_eps, uo)
-    x = x + attn_out
+    with jax.named_scope("attn"):
+        if first_chunk:
+            attn = dense_chunk_attention(
+                q, k, v, chunk_lens, sm_scale=sm_scale, window=win,
+                logit_cap=cap,
+            ).reshape(B, C, -1)
+        else:
+            attn = paged_attention(
+                q, k_c, v_c, block_tables, start_pos, chunk_lens,
+                use_kernel=use_kernel, sm_scale=sm_scale, window=win,
+                logit_cap=cap,
+            ).reshape(B, C, -1)
+        attn_out = qeinsum("bch,hd->bcd", attn, lp["wo"]) + lora_delta(
+            ll, "wo", attn, adapter_ids
+        )
+        if c.post_norms:
+            attn_out = _rms_norm(attn_out, lp["attn_post_norm"], c.rms_norm_eps, uo)
+        x = x + attn_out
 
-    h = _rms_norm(x, lp["mlp_norm"], c.rms_norm_eps, uo)
-    if c.is_moe:
-        mlp_out = moe_ffn(
-            h, lp["router_w"], lp["we_gate"], lp["we_up"], lp["we_down"],
-            top_k=c.n_experts_per_tok,
-            capacity_factor=c.moe_capacity_factor,
-            norm_topk_prob=c.norm_topk_prob,
-        )
-    else:
-        gate = _act(
-            qeinsum("bcd,df->bcf", h, lp["w_gate"])
-            + lora_delta(ll, "w_gate", h, adapter_ids),
-            c.act_fn,
-        )
-        up = qeinsum("bcd,df->bcf", h, lp["w_up"]) + lora_delta(
-            ll, "w_up", h, adapter_ids
-        )
-        gu = gate * up
-        mlp_out = qeinsum("bcf,fd->bcd", gu, lp["w_down"]) + lora_delta(
-            ll, "w_down", gu, adapter_ids
-        )
-    if c.post_norms:
-        mlp_out = _rms_norm(mlp_out, lp["mlp_post_norm"], c.rms_norm_eps, uo)
-    x = x + mlp_out
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, lp["mlp_norm"], c.rms_norm_eps, uo)
+        if c.is_moe:
+            mlp_out = moe_ffn(
+                h, lp["router_w"], lp["we_gate"], lp["we_up"], lp["we_down"],
+                top_k=c.n_experts_per_tok,
+                capacity_factor=c.moe_capacity_factor,
+                norm_topk_prob=c.norm_topk_prob,
+            )
+        else:
+            gate = _act(
+                qeinsum("bcd,df->bcf", h, lp["w_gate"])
+                + lora_delta(ll, "w_gate", h, adapter_ids),
+                c.act_fn,
+            )
+            up = qeinsum("bcd,df->bcf", h, lp["w_up"]) + lora_delta(
+                ll, "w_up", h, adapter_ids
+            )
+            gu = gate * up
+            mlp_out = qeinsum("bcf,fd->bcd", gu, lp["w_down"]) + lora_delta(
+                ll, "w_down", gu, adapter_ids
+            )
+        if c.post_norms:
+            mlp_out = _rms_norm(mlp_out, lp["mlp_post_norm"], c.rms_norm_eps, uo)
+        x = x + mlp_out
     return x, k_c, v_c
 
 
@@ -419,12 +425,15 @@ def lm_head_logits(
 ) -> jnp.ndarray:
     """Final norm → vocab projection → final softcap. x: [..., d]."""
     c = config
-    x = _rms_norm(x, params["final_norm"], c.rms_norm_eps, c.rmsnorm_unit_offset)
-    head = params["embed"] if c.tie_word_embeddings else params["lm_head"]
-    logits = q_lm_head(x, head, tied=c.tie_word_embeddings)
-    if c.final_logit_softcap:
-        fcap = float(c.final_logit_softcap)
-        logits = fcap * jnp.tanh(logits / fcap)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(
+            x, params["final_norm"], c.rms_norm_eps, c.rmsnorm_unit_offset
+        )
+        head = params["embed"] if c.tie_word_embeddings else params["lm_head"]
+        logits = q_lm_head(x, head, tied=c.tie_word_embeddings)
+        if c.final_logit_softcap:
+            fcap = float(c.final_logit_softcap)
+            logits = fcap * jnp.tanh(logits / fcap)
     return logits
 
 
@@ -773,24 +782,25 @@ def decode_multi(
             use_kernel=use_kernel, use_megakernel=use_megakernel, lora=lora,
             adapter_ids=adapter_ids,
         )
-        if proc_params is not None:
-            logits = lp.apply(logits, proc_params, st)
-        if salts is not None:
-            # The sampled token's index is pos + 1 (pos counts the tokens
-            # before the current input token; the input occupies index pos)
-            # — the same index the prefill program folds for the first
-            # generated token, so preemption-by-recompute redraws
-            # identical noise.
-            row_keys = fold_row_keys(rng, salts, pos + 1)
-            nxt = sample_tokens(
-                logits, None, temperature, top_k, top_p, min_p,
-                row_keys=row_keys,
-            )
-        else:
-            nxt = sample_tokens(
-                logits, step_rng, temperature, top_k, top_p, min_p
-            )
-        nxt = jnp.where(active > 0, nxt, toks)
+        with jax.named_scope("sample"):
+            if proc_params is not None:
+                logits = lp.apply(logits, proc_params, st)
+            if salts is not None:
+                # The sampled token's index is pos + 1 (pos counts the tokens
+                # before the current input token; the input occupies index
+                # pos) — the same index the prefill program folds for the
+                # first generated token, so preemption-by-recompute redraws
+                # identical noise.
+                row_keys = fold_row_keys(rng, salts, pos + 1)
+                nxt = sample_tokens(
+                    logits, None, temperature, top_k, top_p, min_p,
+                    row_keys=row_keys,
+                )
+            else:
+                nxt = sample_tokens(
+                    logits, step_rng, temperature, top_k, top_p, min_p
+                )
+            nxt = jnp.where(active > 0, nxt, toks)
         if want_logprobs:
             logp = compute_logprobs(logits, nxt)
         else:

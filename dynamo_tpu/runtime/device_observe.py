@@ -535,7 +535,9 @@ class ProfilerControl:
 
     Degrades to a structured no-op when the profiler is unavailable
     (missing backend support, already-active capture from another tool):
-    every path returns a JSON-able dict, never raises."""
+    every path returns a JSON-able dict, never raises. ``start`` and
+    ``stop`` block (``stop`` exports the trace: seconds), so a caller on
+    an event loop runs them in a thread, as the route does."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()  # admin path only
@@ -581,7 +583,14 @@ class ProfilerControl:
             try:
                 import jax.profiler
 
-                jax.profiler.start_trace(log_dir)
+                # TraceMe events only (host_tracer_level 2): the engine's
+                # tick.* / device.* annotations, on the device trace's
+                # clock. The Python tracer (on by default) made a 4 s
+                # capture a 215 MB file that took 51 s to export.
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                options.host_tracer_level = 2
+                jax.profiler.start_trace(log_dir, profiler_options=options)
             except Exception as exc:
                 logger.warning("profiler start degraded to no-op: %s", exc)
                 return {

@@ -83,6 +83,49 @@ ENGINE_STEP_DECODE_TOKENS = f"{ENGINE_PREFIX}_decode_tokens_per_step"
 # flight); inflight_depth = bursts in flight at each dispatch.
 ENGINE_HOST_GAP = f"{ENGINE_PREFIX}_host_gap_seconds"
 ENGINE_INFLIGHT_DEPTH = f"{ENGINE_PREFIX}_inflight_depth"
+# The scheduler tick seen from inside (EngineStepMetrics.phase): wall time of
+# the scheduler loop by phase — the phases are exclusive and partition the
+# loop's wall time — and one observation per loop iteration that did not go
+# idle. Each phase is also a jax.profiler.TraceAnnotation of the same name,
+# so a profiler capture carries it on the device trace's clock.
+ENGINE_TICK_PHASE = f"{ENGINE_PREFIX}_tick_phase_seconds"
+ENGINE_TICK = f"{ENGINE_PREFIX}_tick_seconds"
+# Request phases at the stamps _export_phase_spans already reads
+# (phase=queue|prefill|decode), one observation per finished stream, and
+# the decode phase's tokens (generated - 1) beside them: deltas of sum and
+# count over a window, which the rolling SLO gauges cannot give.
+ENGINE_REQUEST_PHASE = f"{ENGINE_PREFIX}_request_phase_seconds"
+ENGINE_REQUEST_DECODE_TOKENS_TOTAL = (
+    f"{ENGINE_PREFIX}_request_decode_tokens_total"
+)
+
+# The tick-phase vocabulary: every name EngineStepMetrics.phase accepts, in
+# exactly one class. ``device_wait``: the loop awaits the device thread
+# (a prefill step, a burst's readback, the pipeline drained ahead of
+# admission); ``idle``: nothing to do (the wake wait, error backoff);
+# ``host``: everything else the loop does on the CPU.
+TICK_PHASES_HOST = (
+    "tick.sched",  # loop bookkeeping between the phases below
+    "tick.admit",  # queue pop, prefix match, pool allocation, KVBM onboard
+    "tick.prefill_build",  # a chunk round's numpy arrays and its results
+    "tick.install",  # commit blocks, slot state, the first token out
+    "tick.decode_build",  # _prepare_decode, dirty-slot and table payloads
+    "tick.decode_dispatch",  # sync + enqueue of one burst on the device thread
+    "tick.emit",  # stop conditions, one output per row, stats, perf ledger
+)
+TICK_PHASES_DEVICE_WAIT = (
+    "tick.drain",  # readback of in-flight bursts ahead of admission
+    "tick.prefill_wait",  # await of one prefill step
+    "tick.decode_wait",  # await of the oldest burst's readback
+)
+TICK_PHASES_IDLE = ("tick.idle",)
+TICK_PHASES = TICK_PHASES_HOST + TICK_PHASES_DEVICE_WAIT + TICK_PHASES_IDLE
+# Plain annotations (no counter) on the device thread, where the device
+# calls really run: the spans a gap attribution reads.
+DEVICE_SPANS = (
+    "device.prefill_step", "device.decode_dispatch", "device.decode_read",
+)
+REQUEST_PHASES = ("queue", "prefill", "decode")
 
 # -- router (router/router.py KvRouter + router/scheduler.py) ----------------
 ROUTER_PREFIX = "dynamo_tpu_router"
@@ -581,6 +624,10 @@ ALL_ENGINE = (
     ENGINE_STEP_DECODE_TOKENS,
     ENGINE_HOST_GAP,
     ENGINE_INFLIGHT_DEPTH,
+    ENGINE_TICK_PHASE,
+    ENGINE_TICK,
+    ENGINE_REQUEST_PHASE,
+    ENGINE_REQUEST_DECODE_TOKENS_TOTAL,
 )
 
 ALL_PERF = (

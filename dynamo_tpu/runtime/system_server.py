@@ -563,7 +563,10 @@ class SystemStatusServer:
                                   "(need a positive finite number)"},
                         status=400,
                     )
-            result = profiler.start(body.get("dir"))
+            # start and stop run OFF the event loop: stop exports the trace
+            # (seconds), and a worker whose loop is held that long misses
+            # load reports and loses its lease.
+            result = await asyncio.to_thread(profiler.start, body.get("dir"))
             if result.get("ok") and seconds:
                 # Bounded capture: auto-stop keeps an operator's one-shot
                 # POST from tracing forever when the stop call never comes.
@@ -582,7 +585,8 @@ class SystemStatusServer:
                     ):
                         return
                     logger.info(
-                        "auto-stopped profiler capture: %s", profiler.stop()
+                        "auto-stopped profiler capture: %s",
+                        await asyncio.to_thread(profiler.stop),
                     )
 
                 # Hold a strong reference: the loop keeps only weak task
@@ -597,7 +601,7 @@ class SystemStatusServer:
             status = 200 if result.get("ok") or result.get("degraded") else 409
             return web.json_response(result, status=status)
         if action == "stop":
-            result = profiler.stop()
+            result = await asyncio.to_thread(profiler.stop)
             status = 200 if result.get("ok") or result.get("degraded") else 409
             return web.json_response(result, status=status)
         if action == "status":

@@ -236,7 +236,13 @@ def test_profiler_control_cycle(tmp_path, monkeypatch):
     import jax.profiler as jp
 
     calls = []
-    monkeypatch.setattr(jp, "start_trace", lambda d: calls.append(("start", d)))
+    options = []
+
+    def start_trace(d, profiler_options=None):
+        calls.append(("start", d))
+        options.append(profiler_options)
+
+    monkeypatch.setattr(jp, "start_trace", start_trace)
     monkeypatch.setattr(jp, "stop_trace", lambda: calls.append(("stop",)))
     ctl = ProfilerControl()
     assert ctl.stop() == {"ok": False, "error": "no active capture"}
@@ -249,6 +255,10 @@ def test_profiler_control_cycle(tmp_path, monkeypatch):
     assert stopped["ok"] and stopped["dir"] == str(tmp_path / "trace")
     assert ctl.captures == 1
     assert calls == [("start", str(tmp_path / "trace")), ("stop",)]
+    # The capture holds TraceMe events (the engine's annotations) and no
+    # Python tracer: the latter made a 4 s capture a 215 MB file.
+    assert options[0].python_tracer_level == 0
+    assert options[0].host_tracer_level == 2
 
     # degraded stop that may have left the session live keeps the capture
     # active (retryable); an "already ended" error clears it
@@ -277,7 +287,7 @@ def test_profiler_degraded_start(monkeypatch):
     no-op: nothing raised, nothing counted, nothing left active."""
     import jax.profiler as jp
 
-    def no_backend(d):
+    def no_backend(d, **kw):
         raise RuntimeError("profiler unavailable")
 
     monkeypatch.setattr(jp, "start_trace", no_backend)

@@ -108,3 +108,47 @@ def test_static_metric_closure_is_clean():
 
     findings = run_lint(os.path.abspath(PKG), rule_ids=["DYN004"])
     assert findings == [], "\n".join(f.render() for f in findings)
+
+
+# -- the tick-phase vocabulary (EngineStepMetrics.phase) ----------------------
+
+_PHASE_CALL_RE = re.compile(r"""["'](tick\.[a-z_]+|device\.[a-z_]+)["']""")
+
+
+def _phase_literals():
+    """Every ``tick.*`` / ``device.*`` string literal under engines/."""
+    found = {}
+    for path in _py_files():
+        rel = os.path.relpath(path, PKG)
+        if not rel.startswith("engines" + os.sep):
+            continue
+        with open(path, encoding="utf-8") as f:
+            for lineno, line in enumerate(f, 1):
+                for name in _PHASE_CALL_RE.findall(line):
+                    found.setdefault(name, []).append(f"{rel}:{lineno}")
+    return found
+
+
+def test_tick_phases_are_classed_exactly_once():
+    """Every phase name is host, device-wait or idle, and only one of them;
+    the three classes make up TICK_PHASES."""
+    from dynamo_tpu.runtime import metric_names as mn
+
+    classes = (mn.TICK_PHASES_HOST, mn.TICK_PHASES_DEVICE_WAIT, mn.TICK_PHASES_IDLE)
+    names = [n for cls in classes for n in cls]
+    assert len(names) == len(set(names)), "a phase is in two classes"
+    assert set(names) == set(mn.TICK_PHASES) and len(mn.TICK_PHASES) == len(names)
+    assert all(n.startswith("tick.") for n in names)
+    assert {mn.ENGINE_TICK_PHASE, mn.ENGINE_TICK, mn.ENGINE_REQUEST_PHASE,
+            mn.ENGINE_REQUEST_DECODE_TOKENS_TOTAL} <= set(mn.ALL_ENGINE)
+
+
+def test_every_phase_the_engine_opens_is_in_the_vocabulary_and_used():
+    from dynamo_tpu.runtime import metric_names as mn
+
+    found = _phase_literals()
+    known = set(mn.TICK_PHASES) | set(mn.DEVICE_SPANS)
+    unknown = {n: where for n, where in found.items() if n not in known}
+    assert not unknown, f"phase names outside metric_names.py: {unknown}"
+    unused = known - set(found)
+    assert not unused, f"names in the vocabulary that nothing opens: {unused}"

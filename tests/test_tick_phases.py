@@ -1,0 +1,421 @@
+"""The engine tick seen from inside (engines/metrics.py
+``EngineStepMetrics.phase``): phase spans that partition the scheduler
+loop's wall time, request phases as histograms, the spans in a profiler
+capture, a profiler route that exports off the event loop, and the
+benchmark reader that turns the phase counters into shares."""
+
+import asyncio
+import glob
+import os
+import sys
+import time
+
+import aiohttp
+import pytest
+
+from dynamo_tpu.engines import metrics as engine_metrics
+from dynamo_tpu.engines.metrics import EngineStepMetrics
+from dynamo_tpu.runtime import metric_names as mn
+from dynamo_tpu.runtime.device_observe import ProfilerControl, global_profiler
+from dynamo_tpu.runtime.system_server import SystemStatusServer
+
+from tests.test_jax_engine import make_engine, req, run_one
+
+BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+
+
+def _phase_seconds(sm: EngineStepMetrics) -> dict:
+    return {p: sm.tick_phase.snapshot_total(phase=p)[1] for p in mn.TICK_PHASES}
+
+
+# -- the helper, without an engine --------------------------------------------
+
+
+class _Clock:
+    def __init__(self) -> None:
+        self.t = 100.0
+
+    def __call__(self) -> float:
+        return self.t
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = _Clock()
+    monkeypatch.setattr(engine_metrics.time, "monotonic", c)
+    return c
+
+
+def test_nested_phases_are_exclusive(clock):
+    """Entering a phase suspends the enclosing one; leaving resumes it: the
+    seconds of a nested block are counted once, under the innermost name."""
+    sm = EngineStepMetrics()
+    with sm.tick():
+        clock.t += 1.0  # tick.sched
+        with sm.phase("tick.admit"):
+            clock.t += 2.0
+            with sm.phase("tick.prefill_wait", rows=2):
+                clock.t += 4.0
+            clock.t += 8.0  # tick.admit again
+        clock.t += 16.0  # tick.sched again
+    got = _phase_seconds(sm)
+    assert got["tick.sched"] == 17.0
+    assert got["tick.admit"] == 10.0
+    assert got["tick.prefill_wait"] == 4.0
+    assert sum(got.values()) == 31.0
+    assert sm.tick_duration.snapshot_total() == (1, 31.0)
+
+
+@pytest.mark.parametrize("idle_phase", mn.TICK_PHASES_IDLE)
+def test_an_iteration_that_went_idle_is_no_tick(clock, idle_phase):
+    sm = EngineStepMetrics()
+    with sm.tick():
+        clock.t += 0.5
+        with sm.phase(idle_phase):
+            clock.t += 3.0
+    assert sm.tick_duration.snapshot_total() == (0, 0.0)
+    assert _phase_seconds(sm)[idle_phase] == 3.0
+    with sm.tick():
+        clock.t += 0.25
+    assert sm.tick_duration.snapshot_total() == (1, 0.25)
+
+
+def test_phase_survives_an_exception_and_refuses_unknown_names(clock):
+    sm = EngineStepMetrics()
+    with pytest.raises(KeyError):
+        sm.phase("tick.nonsense")
+    with pytest.raises(RuntimeError):
+        with sm.tick():
+            with sm.phase("tick.emit"):
+                clock.t += 1.0
+                raise RuntimeError("boom")
+    assert sm._scope is None  # nothing left open
+    assert _phase_seconds(sm)["tick.emit"] == 1.0
+    assert sm.tick_duration.snapshot_total() == (1, 1.0)
+
+
+def test_annotations_degrade_without_jax(monkeypatch):
+    """engines/metrics.py imports without JAX, and the helper still counts."""
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    sm = EngineStepMetrics()
+    with sm.annotate("device.decode_read", rows=1):
+        pass
+    with sm.tick():
+        with sm.phase("tick.decode_wait", rows=1):
+            pass
+    assert sm._trace_annotation is False
+    assert sm.tick_phase.count(phase="tick.decode_wait") == 1
+
+
+@pytest.mark.parametrize(
+    "stamps, want",
+    [
+        # queue 2 s, prefill 3 s, decode 5 s over 11 tokens
+        (((0.0, 2.0), (2.0, 5.0), (5.0, 10.0), 10),
+         {"queue": 2.0, "prefill": 3.0, "decode": 5.0, "tokens": 10}),
+        # cancelled in the queue: never admitted, nothing generated
+        (((0.0, 4.0), None, None, -1),
+         {"queue": 4.0, "prefill": None, "decode": None, "tokens": 0}),
+        # preempted: the re-prefill restamped its start after the first
+        # output (clamped to 0); one token out, a decode phase of no tokens
+        (((1.0, 1.0), (9.0, 3.0), (3.0, 3.0), 0),
+         {"queue": 0.0, "prefill": 0.0, "decode": 0.0, "tokens": 0}),
+    ],
+)
+def test_observe_request_counts(stamps, want):
+    sm = EngineStepMetrics()
+    sm.observe_request(*stamps)
+    for phase in mn.REQUEST_PHASES:
+        count, total = sm.request_phase.snapshot_total(phase=phase)
+        assert (count, total) == ((0, 0.0) if want[phase] is None else (1, want[phase]))
+    # a counter nothing incremented renders no sample
+    line = [l for l in sm.render().splitlines()
+            if l.startswith(mn.ENGINE_REQUEST_DECODE_TOKENS_TOTAL)]
+    assert (float(line[0].split()[-1]) if line else 0) == want["tokens"]
+
+
+# -- over a short run of the CPU engine ---------------------------------------
+
+
+async def test_phases_partition_the_loop_and_show_in_a_capture(tmp_path):
+    """One engine, four assertions (shared to bound suite compile time):
+
+    1. the phases partition the loop: sum of phase seconds is within 2% of
+       sum of tick seconds + idle, and no two recorded segments overlap;
+    2. the request-phase histograms move by one count per finished stream
+       and the token counter by generated - 1;
+    3. a sub-second capture through ProfilerControl (Python tracer off)
+       holds tick.decode_wait and device.decode_read events with their
+       ``rows`` stat on lines of a /host:CPU plane;
+    4. every phase the run went through is in the vocabulary.
+    """
+    engine, _ = make_engine(pipeline_depth=2, decode_steps=4)
+    sm = engine.step_metrics
+    segments = []
+    real_end = sm._end_segment
+
+    def recording_end():
+        name, t0 = sm._scope.name, sm._t0
+        real_end()
+        segments.append((name, t0, time.monotonic()))
+
+    sm._end_segment = recording_end
+    try:
+        await run_one(engine, req(range(10, 22), max_tokens=6))  # compiles
+        before = {p: sm.request_phase.count(phase=p) for p in mn.REQUEST_PHASES}
+        ctl = ProfilerControl()
+        started = ctl.start(str(tmp_path / "trace"))
+        assert started["ok"], started
+        reqs = [req(range(5 + i, 15 + 4 * i), max_tokens=12 + i) for i in range(6)]
+        outs = await asyncio.gather(*(run_one(engine, r) for r in reqs))
+        stopped = ctl.stop()
+        assert stopped["ok"], stopped
+        await asyncio.sleep(0.12)  # let the loop go idle at least once
+    finally:
+        await engine.stop()
+
+    # 1. partition
+    phases = _phase_seconds(sm)
+    n_ticks, tick_s = sm.tick_duration.snapshot_total()
+    idle_s = phases["tick.idle"]
+    assert n_ticks > 0 and idle_s > 0
+    assert abs(sum(phases.values()) - (tick_s + idle_s)) <= 0.02 * (tick_s + idle_s)
+    segments.sort(key=lambda s: s[1])
+    for (_, _, end), (_, start, _) in zip(segments, segments[1:]):
+        assert start >= end - 1e-6, "two phases were open at once"
+    # the recorded segments cover the loop's life with no hole between them
+    covered = sum(e - s for _, s, e in segments)
+    assert covered >= 0.98 * (segments[-1][2] - segments[0][1])
+    # 4. vocabulary; the run met admission, prefill, decode and idle
+    seen = {name for name, _, _ in segments}
+    assert seen <= set(mn.TICK_PHASES)
+    assert {"tick.sched", "tick.admit", "tick.prefill_build", "tick.prefill_wait",
+            "tick.install", "tick.decode_build", "tick.decode_dispatch",
+            "tick.decode_wait", "tick.emit", "tick.idle"} <= seen
+
+    # 2. one count per finished stream, generated - 1 tokens each
+    for p in mn.REQUEST_PHASES:
+        assert sm.request_phase.count(phase=p) - before[p] == len(reqs)
+    generated = [sum(len(o.token_ids) for o in out) for out in outs]
+    assert generated == [12 + i for i in range(6)]
+    counter = [l for l in sm.render().splitlines()
+               if l.startswith(mn.ENGINE_REQUEST_DECODE_TOKENS_TOTAL)]
+    assert float(counter[0].split()[-1]) == (6 - 1) + sum(g - 1 for g in generated)
+
+    # 3. the capture
+    from jax.profiler import ProfileData
+
+    files = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert len(files) == 1
+    found = {}
+    python_tracer_events = 0
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                # the Python tracer names its events "$file:line function"
+                python_tracer_events += event.name.startswith("$")
+                if event.name in ("tick.decode_wait", "device.decode_read"):
+                    found.setdefault(event.name, []).append(
+                        (line.name, dict(event.stats)))
+    assert python_tracer_events == 0
+    assert set(found) == {"tick.decode_wait", "device.decode_read"}
+    for rows in found.values():
+        assert all(1 <= stats["rows"] <= 4 for _, stats in rows)
+
+
+def test_phase_helper_cost_is_microseconds():
+    """Always on, inside the decode loop: a phase costs microseconds, not
+    a log line or a lock wait. The best of five batches, so that a worker
+    preempted once does not fail it; it measured about 3 us, the bound is
+    50."""
+    sm = EngineStepMetrics()
+    n, best = 400, float("inf")
+    with sm.tick():
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with sm.phase("tick.decode_wait", rows=3, nb=8):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 50e-6
+
+
+# -- the profiler route --------------------------------------------------------
+
+
+async def test_profile_route_exports_off_the_event_loop(monkeypatch):
+    """``stop`` exports the trace (seconds on a chip): the route runs it in
+    a thread, so /health answers while a slow stop_trace sleeps."""
+    import jax.profiler as jp
+
+    monkeypatch.setattr(jp, "start_trace", lambda d, **kw: None)
+    monkeypatch.setattr(jp, "stop_trace", lambda: time.sleep(1.5))
+    profiler = global_profiler()
+    server = SystemStatusServer(host="127.0.0.1", port=0)
+    await server.start()
+    base = f"http://127.0.0.1:{server.port}"
+    try:
+        async with aiohttp.ClientSession() as http:
+            async with http.post(base + "/debug/profile",
+                                 json={"action": "start"}) as r:
+                assert r.status == 200 and (await r.json())["ok"]
+            t0 = time.monotonic()
+            stop = asyncio.ensure_future(
+                http.post(base + "/debug/profile", json={"action": "stop"}))
+            await asyncio.sleep(0.05)  # the stop is now sleeping in its thread
+            async with http.get(base + "/health") as r:
+                assert r.status in (200, 503)
+            answered = time.monotonic() - t0
+            reply = await stop
+            body = await reply.json()
+            took = time.monotonic() - t0
+        assert reply.status == 200 and body["ok"]
+        assert answered < 1.0 and took >= 1.4, (answered, took)
+        assert not profiler.status()["active"]
+    finally:
+        if profiler.status()["active"]:
+            profiler.stop()
+        await server.stop()
+
+
+# -- the benchmark's reader ----------------------------------------------------
+
+
+class _Ctx:
+    def __init__(self, before: str, after: str) -> None:
+        sys.path.insert(0, BENCH)
+        import prom
+
+        self.snapshots = {"window_start": {"worker0": prom.parse(before)},
+                          "drained": {"worker0": prom.parse(after)}}
+
+    def targets(self, which):
+        return ["worker0"]
+
+
+_BEFORE = """
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.admit"} 1.0
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.decode_wait"} 10.0
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.idle"} 100.0
+dynamo_tpu_engine_request_decode_tokens_total 50
+"""
+_AFTER = """
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.admit"} 2.0
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.decode_wait"} 13.0
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.idle"} 150.0
+dynamo_tpu_engine_tick_phase_seconds_sum{phase="tick.emit"} 0.5
+dynamo_tpu_engine_request_decode_tokens_total 150
+"""
+_FAMILY = "dynamo_tpu_engine_tick_phase_seconds_sum"
+
+
+def _terms(*phases):
+    return [{"metric": _FAMILY, "labels": {"phase": p}} for p in phases]
+
+
+@pytest.mark.parametrize(
+    "params, want",
+    [
+        # (1 + 0.5) host seconds of (1 + 3 + 0.5) non-idle: a term that only
+        # the later snapshot holds started from nothing
+        ({"num": _terms("tick.admit", "tick.emit"),
+          "den": _terms("tick.admit", "tick.emit", "tick.decode_wait"),
+          "scale": 100.0}, 100.0 * 1.5 / 4.5),
+        # a term nothing exports counts for nothing
+        ({"num": _terms("tick.admit", "tick.install"),
+          "den": _terms("tick.admit", "tick.decode_wait")}, 1.0 / 4.0),
+        # terms of two families: seconds over tokens
+        ({"num": _terms("tick.decode_wait"),
+          "den": [{"metric": "dynamo_tpu_engine_request_decode_tokens_total"}],
+          "scale": 1000.0}, 1000.0 * 3.0 / 100.0),
+        # a program from before the counters: left out, not zero, not raised
+        ({"num": _terms("tick.install"), "den": _terms("tick.admit")}, None),
+        ({"num": _terms("tick.admit"), "den": _terms("tick.install")}, None),
+    ],
+)
+def test_prometheus_ratio_reader(params, want):
+    ctx = _Ctx(_BEFORE, _AFTER)
+    from readers import prometheus_ratio
+
+    got = prometheus_ratio.read({"target": "workers", **params}, ctx)
+    assert got == pytest.approx(want) if want is not None else got is None
+
+
+def test_new_layer_metric_files_name_real_families():
+    """Every family and phase label the seven new metric files read is one
+    the program exports: a typo would read nothing, for ever, in silence."""
+    import json
+
+    new = ["engine.tick_ms", "engine.tick_prefill_share", "engine.tick_host_share",
+           "admission.queue_wait_ms", "engine.prefill_phase_ms",
+           "engine.tpot_mean_ms", "frontend.ttft_ms"]
+    families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND)
+    labels = set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
+    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in new:
+        with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        entry = listed[name]
+        assert {k: spec[k] for k in ("unit", "better", "source", "layer", "moves")} \
+            == {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}
+        p = spec["params"]
+        terms = p.get("num", []) + p.get("den", []) or [p]
+        for t in terms:
+            family = t["metric"]
+            for suffix in ("_sum", "_count"):
+                if family.endswith(suffix) and family not in families:
+                    family = family[: -len(suffix)]
+            assert family in families, (name, t)
+            for value in (t.get("labels") or {}).values():
+                assert value in labels, (name, t)
+    # host + device-wait phases of the shares are the whole non-idle set
+    with open(os.path.join(BENCH, "layer_metrics", "engine.tick_host_share.json")) as f:
+        p = json.load(f)["params"]
+    assert {t["labels"]["phase"] for t in p["num"]} == set(mn.TICK_PHASES_HOST)
+    assert {t["labels"]["phase"] for t in p["den"]} == \
+        set(mn.TICK_PHASES_HOST) | set(mn.TICK_PHASES_DEVICE_WAIT)
+
+
+def test_recorded_fixture_holds_the_spans_and_the_named_programs():
+    """``benchmark/fixtures/served_tick.xplane.pb`` (a chip capture through
+    /debug/profile, cut to 0.15 s): what the gap-attribution work will test
+    against. The host spans are where its expectation says, and the
+    benchmark's reducer tells the renamed programs apart with the merged
+    ``trace_names/*.json``."""
+    import collections
+    import json
+
+    from jax.profiler import ProfileData
+
+    sys.path.insert(0, BENCH)
+    import trace_reduce
+
+    with open(os.path.join(BENCH, "fixtures", "served_tick.expect.json")) as f:
+        expect = json.load(f)
+    pd = ProfileData.from_file(os.path.join(BENCH, "fixtures", "served_tick.xplane.pb"))
+    planes = {p.name: p for p in pd.planes}
+    spans = {}
+    for line in planes[expect["host_plane"]].lines:
+        counts = collections.Counter(
+            e.name for e in line.events if e.name.startswith(("tick.", "device.")))
+        role = "scheduler" if any(n.startswith("tick.") for n in counts) else "device_thread"
+        assert line.name == expect["lines"][role]["line"]
+        spans[role] = dict(counts)
+    assert spans["scheduler"] == expect["lines"]["scheduler"]["spans"]
+    assert spans["device_thread"] == expect["lines"]["device_thread"]["spans"]
+    assert set(spans["scheduler"]) <= set(mn.TICK_PHASES)
+    assert set(spans["device_thread"]) <= set(mn.DEVICE_SPANS)
+    assert sum(spans["scheduler"].values()) == expect["tick_events"]
+
+    got = trace_reduce.reduce_plane(
+        planes[expect["device_plane"]], trace_reduce.load_names(), 1e9)
+    kinds = {name.split("(")[0]: row["kind"] for name, row in got["modules"].items()}
+    assert kinds["jit_decode_burst"] == "decode"
+    assert kinds["jit_prefill_step"] == "prefill"
+    # whole executions inside the window (the first one began before its
+    # first recorded operation and is not counted)
+    assert got["programs"]["prefill"]["count"] >= 1
+    assert got["programs"]["decode"]["count"] == 1
