@@ -1,8 +1,7 @@
 """Profile the llama-3-8B int8 decode step on the real chip.
 
 Isolates: full fused decode step, weight-stream floor (attention patched to
-identity), XLA-attention variant, and decode-kernel batch_block sweep —
-all measured INSIDE decode_multi (isolated kernel timings don't transfer).
+identity) and XLA-attention variant — all measured INSIDE decode_multi (isolated kernel timings don't transfer).
 """
 import functools
 import os, sys, time
@@ -76,10 +75,10 @@ def bench(label, fn, n=3):
     return dt
 
 
-which = sys.argv[1:] if len(sys.argv) > 1 else ["full", "floor", "bq"]
+which = sys.argv[1:] if len(sys.argv) > 1 else ["full", "floor"]
 
 if "full" in which:
-    bench(f"decode kernel BQ=8 (B={B} bs={BS} P={P} ctx={CTX})", mkdec(True))
+    bench(f"decode kernel (B={B} bs={BS} P={P} ctx={CTX})", mkdec(True))
 
 if "floor" in which:
     real = llama.paged_attention
@@ -156,7 +155,7 @@ if "v2" in which:
     real = llama.paged_attention
 
     def patched_v2(q, k_c, v_c, bt, sp, cl, *, use_kernel, sm_scale, window,
-                   logit_cap):
+                   logit_cap, **_):
         return decode_packed(
             q, k_c, v_c, bt, sp, window, sm_scale=sm_scale,
             logit_cap=logit_cap,
@@ -172,7 +171,7 @@ if "bf" in which:
     real = llama.paged_attention
 
     def patched_bf(q, k_c, v_c, bt, sp, cl, *, use_kernel, sm_scale, window,
-                   logit_cap):
+                   logit_cap, **_):
         return decode_bf16(
             q, k_c, v_c, bt, sp, window, sm_scale=sm_scale,
             logit_cap=logit_cap,
@@ -180,21 +179,6 @@ if "bf" in which:
 
     llama.paged_attention = patched_bf
     bench("decode V1-BF16-OPERANDS kernel", mkdec(True))
-    llama.paged_attention = real
-
-if "kbq" in which:
-    from dynamo_tpu.ops.pallas.paged_attention import (
-        paged_attention_decode_kernel as pdk,
-    )
-
-    real = llama.paged_attention
-    for bq in (8, 16):
-        def patched(q, k_c, v_c, bt, sp, cl, *, use_kernel, sm_scale,
-                    window, logit_cap, _bq=bq):
-            return pdk(q, k_c, v_c, bt, sp, sm_scale=sm_scale, window=window,
-                       logit_cap=logit_cap, batch_block=_bq)
-        llama.paged_attention = patched
-        bench(f"decode kv={KVQ} BQ={bq}", mkdec(True))
     llama.paged_attention = real
 
 if "nosample" in which:
@@ -257,24 +241,3 @@ if "head" in which:
             _ = np.asarray(f(*a))
             ts.append(time.perf_counter() - t0)
         print(f"{label}: {min(ts)/STEPS*1000:.2f} ms/step", flush=True)
-
-if "bq" in which:
-    from dynamo_tpu.ops.pallas.paged_attention import (
-        paged_attention_decode_kernel,
-    )
-
-    real = llama.paged_attention
-
-    def patched(bq):
-        def f(q, k_c, v_c, bt, sp, cl, *, use_kernel, sm_scale, window,
-              logit_cap):
-            return paged_attention_decode_kernel(
-                q, k_c, v_c, bt, sp, sm_scale=sm_scale, window=window,
-                logit_cap=logit_cap, batch_block=bq,
-            )
-        return f
-
-    for bq in (16, 32, 64):
-        llama.paged_attention = patched(bq)
-        bench(f"decode kernel BQ={bq}", mkdec(True))
-    llama.paged_attention = real

@@ -173,14 +173,6 @@ KV_BLOCK_SIZE = env_int(
     "default)",
     subsystem="engine",
 )
-DECODE_BQ = env_int(
-    "DYN_TPU_DECODE_BQ", 0,
-    "Decode paged-attention kernel batch-block (BQ) override for shape "
-    "tuning; 0 = auto (measured v5e: 16 for int8-quantized KV pools, 8 "
-    "for bf16 — BQ bounded by the ~16 MB scoped VMEM the double-buffered "
-    "page pairs occupy)",
-    subsystem="ops",
-)
 LOG_LEVEL = env_str(
     "DYN_TPU_LOG", "info", "Log level (trace|debug|info|warn|error)",
     subsystem="logging",

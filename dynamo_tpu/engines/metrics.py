@@ -137,6 +137,16 @@ class EngineStepMetrics:
             "Tokens of the decode phase over finished streams "
             "(generated - 1 each)",
         )
+        self.decode_live_pages = self.registry.counter(
+            mn.ENGINE_DECODE_LIVE_PAGES_TOTAL,
+            "KV pages the active rows' contexts reach, summed over "
+            "dispatched decode bursts",
+        )
+        self.decode_table_slots = self.registry.counter(
+            mn.ENGINE_DECODE_TABLE_SLOTS_TOTAL,
+            "Slots of the dispatched block table (max_num_seqs x table "
+            "width bucket), summed over dispatched decode bursts",
+        )
         self._phases = frozenset(mn.TICK_PHASES)
         self._idle_phases = frozenset(mn.TICK_PHASES_IDLE)
         self._request_phases = mn.REQUEST_PHASES
@@ -222,6 +232,10 @@ class EngineStepMetrics:
 
     def observe_inflight(self, depth: int) -> None:
         self.inflight_depth.observe(depth)
+
+    def observe_decode_pages(self, live_pages: int, table_slots: int) -> None:
+        self.decode_live_pages.inc(live_pages)
+        self.decode_table_slots.inc(table_slots)
 
     def host_gap_stats(self) -> tuple:
         """(count, total_seconds) observed on the host-gap family — the

@@ -127,9 +127,8 @@ class JaxEngineArgs:
     # pipeline-parallel stages that slice the layer axis.
     layered_cache: bool = True
     # KV-cache quantization: "int8" = per-token-per-head dynamic int8 pools
-    # (ops/kv_quant.py) — halves the decode step's history-read bytes AND
-    # the decode kernel's page VMEM (batch_block 8 → 16), and doubles the
-    # sequences a fixed HBM budget can hold. The reference's
+    # (ops/kv_quant.py) — halves the decode step's history-read bytes and
+    # doubles the sequences a fixed HBM budget can hold. The reference's
     # kv_cache_dtype=fp8 engine lever, TPU-style. Requires layered_cache.
     kv_cache_dtype: Optional[str] = None
     # Fused-layer decode megakernel (ops/pallas/fused_layer.py): one pallas
@@ -1533,12 +1532,13 @@ class JaxEngine:
             inflight_off = K * len(self._inflight)
             max_blocks = 1
             sum_ctx = 0
+            live_pages = 0
             for seq in active:
                 ctx = int(self._pos[seq.slot]) + inflight_off + K
                 sum_ctx += ctx
-                max_blocks = max(
-                    max_blocks, (ctx - 1) // args.block_size + 1
-                )
+                blocks = (ctx - 1) // args.block_size + 1
+                live_pages += blocks
+                max_blocks = max(max_blocks, blocks)
             nb_bucket = table_width_bucket(max_blocks, args.max_blocks_per_seq)
             want_logprobs = any(
                 s.request.sampling.logprobs is not None for s in active
@@ -1570,6 +1570,9 @@ class JaxEngine:
             )
             self.step_metrics.observe_host_gap(gap)
         self.step_metrics.observe_inflight(len(self._inflight) + 1)
+        self.step_metrics.observe_decode_pages(
+            live_pages, args.max_num_seqs * nb_bucket
+        )
         self._inflight.append(
             _InflightBurst(
                 handles=handles,

@@ -25,6 +25,7 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     dense_chunk_attention,
     paged_attention,
+    paged_attention_plan,
     write_chunk_to_cache,
 )
 from dynamo_tpu.ops.lora import lora_delta
@@ -304,6 +305,7 @@ def decoder_layer(
     first_chunk: bool = False,
     cos_loc: Optional[jnp.ndarray] = None,  # Gemma-3 local-rope table
     sin_loc: Optional[jnp.ndarray] = None,
+    attn_plan=None,  # ops.attention.paged_attention_plan() for this window
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decoder layer (attention + FFN, all family knobs). Shared by the
     scan-over-layers forward and the pipeline-parallel stage executor
@@ -365,7 +367,7 @@ def decoder_layer(
             attn = paged_attention(
                 q, k_c, v_c, block_tables, start_pos, chunk_lens,
                 use_kernel=use_kernel, sm_scale=sm_scale, window=win,
-                logit_cap=cap,
+                logit_cap=cap, plan=attn_plan,
             ).reshape(B, C, -1)
         attn_out = qeinsum("bch,hd->bcd", attn, lp["wo"]) + lora_delta(
             ll, "wo", attn, adapter_ids
@@ -523,7 +525,7 @@ def forward_paged(
             # Per-row history page counts (the kernel's scalar-prefetch
             # loop bound): one derivation per STEP, shared by every layer,
             # instead of recomputing from start_pos inside each layer call.
-            from dynamo_tpu.ops.pallas.fused_layer import history_pcounts
+            from dynamo_tpu.ops.pallas.live_pages import history_pcounts
 
             pcounts = history_pcounts(
                 start_pos, k_cache[0].shape[1], block_tables.shape[1]
@@ -570,6 +572,16 @@ def forward_paged(
             return (
                 lm_head_logits(params, c, x[:, 0]), k_cache, v_cache
             )
+        # The paged-attention kernel's grid follows from positions, table
+        # and window alone: one derivation per STEP and distinct window,
+        # shared by the layers (as pcounts above for the megakernel).
+        attn_plans = {} if first_chunk else {
+            w: paged_attention_plan(
+                C, c.n_heads, k_cache[0], block_tables, start_pos,
+                chunk_lens, use_kernel=use_kernel, window=w,
+            )
+            for w in sorted({int(w) for w in win_list})
+        }
         k_out, v_out = [], []
         for l in range(c.n_layers):
             if layered_params:
@@ -582,6 +594,7 @@ def forward_paged(
                 k_cache[l], v_cache[l], block_tables, start_pos, chunk_lens,
                 use_kernel=use_kernel, adapter_ids=adapter_ids,
                 first_chunk=first_chunk, cos_loc=cos_loc, sin_loc=sin_loc,
+                attn_plan=attn_plans.get(int(win_list[l])),
             )
             k_out.append(k_l)
             v_out.append(v_l)

@@ -23,12 +23,35 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.ops.pallas.paged_attention import (
+    DecodePlan,
+    decode_plan,
     paged_attention_decode_kernel,
     paged_attention_kernel,
 )
 from dynamo_tpu.runtime.device_observe import watched_jit
 
 NEG_INF = -1e30
+
+
+def _takes_decode_kernel(C: int, n_heads: int, k_cache) -> bool:
+    """Decode (C=1) and short chunks (speculative verify, chunk tails) take
+    the live-span kernel; longer chunks the generic (B, pages) grid."""
+    k_values = k_cache["q8"] if isinstance(k_cache, dict) else k_cache
+    return C <= 8 and C * (n_heads // k_values.shape[2]) <= 64
+
+
+def paged_attention_plan(
+    C: int, n_heads: int, k_cache, block_tables, start_pos, chunk_lens,
+    *, use_kernel: bool, window: Any = 0,
+) -> Optional[DecodePlan]:
+    """What ``paged_attention`` would derive from positions, table and
+    window on every call — the live-span kernel's grid — for a caller that
+    attends layer after layer over the same step: derive it once per
+    distinct ``window`` and pass it as ``plan``. None where the call takes
+    another route (nothing to share). ``k_cache`` is one layer's pool."""
+    if not (use_kernel and _takes_decode_kernel(C, n_heads, k_cache)):
+        return None
+    return decode_plan(k_cache, block_tables, start_pos, chunk_lens, C, window)
 
 
 def paged_attention(
@@ -43,6 +66,7 @@ def paged_attention(
     use_kernel: bool = False,
     window: Any = 0,  # sliding window in tokens (int or traced scalar); 0 = full
     logit_cap: float = 0.0,  # cap·tanh(s/cap) score softcap; 0 = off
+    plan: Optional[DecodePlan] = None,  # paged_attention_plan() of this step
 ) -> jnp.ndarray:
     """Returns [B, C, n_heads, head_dim].
 
@@ -55,19 +79,16 @@ def paged_attention(
     compiled body; ``logit_cap`` applies the Gemma-2 score softcap.
     """
     if use_kernel:
-        B, C, n_heads, _ = q.shape
-        k_values = k_cache["q8"] if isinstance(k_cache, dict) else k_cache
-        n_kv_heads = k_values.shape[2]
-        G = n_heads // n_kv_heads
-        if C <= 8 and C * G <= 64:
-            # Decode (C=1) and short chunks (speculative verify, chunk
-            # tails): the batch-blocked kernel amortizes the sequential
-            # grid's per-step overhead over 8-16 sequences per iteration
-            # (the generic (B, pages) grid runs B×P tiny steps — measured
-            # 3.3× of an 8B verify dispatch before this route).
+        _, C, n_heads, _ = q.shape
+        if _takes_decode_kernel(C, n_heads, k_cache):
+            # The live-span kernel's grid is the live page groups of the
+            # rows with chunk_lens > 0, so an empty slot or a wide table
+            # costs nothing (the generic (B, pages) grid runs B×P tiny
+            # steps whatever is live).
             return paged_attention_decode_kernel(
                 q, k_cache, v_cache, block_tables, start_pos,
-                sm_scale=sm_scale, window=window, logit_cap=logit_cap,
+                window, chunk_lens, plan,
+                sm_scale=sm_scale, logit_cap=logit_cap,
             )
         return paged_attention_kernel(
             q, k_cache, v_cache, block_tables, start_pos, chunk_lens,

@@ -1,11 +1,8 @@
 """Int8 KV-cache quantization (per-token-per-head dynamic scales).
 
 The decode step is HBM-bound on two streams: weights and KV history. Int8
-weights halve the first (ops/quant.py); this halves the second — and, just
-as importantly on TPU, halves the decode kernel's per-page VMEM footprint,
-which doubles the sequences one sequential grid step can serve
-(ops/pallas/paged_attention.py batch_block 8 → 16 inside the ~16 MB scoped
-VMEM budget).
+weights halve the first (ops/quant.py); this halves the second, and halves
+the bytes of a page in HBM, so a fixed pool holds twice the tokens.
 
 Layout: a quantized pool is a dict
     {"q8": int8 [num_blocks, block_size, KH, D],

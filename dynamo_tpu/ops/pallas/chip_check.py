@@ -6,9 +6,18 @@ attention shape the worker's builtin presets produce it lowers
 ``paged_attention_decode_kernel`` and ``paged_attention_kernel`` with
 ``interpret=False`` over bf16 and int8-KV pools, and ``fused_decoder_layer``
 at the Qwen3-8B layer shape for every pow2 table width up to the worker's
-default model length; each compiled call is compared with
+default model length. The decode kernel gets four more rows: the tail of
+a prefix-hit prefill (one row, four tokens, eight pages), the
+benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
+contexts, the others empty with a stale position, 128 pages of table; bf16
+and int8) and the all-live control at 8B width (32 rows within 200 tokens
+of a 128-page table). Each compiled call is compared with
 ``_paged_attention_xla`` / ``decoder_layer`` under
 ``jax.default_matmul_precision("highest")``.
+
+Decode rows that compiled are then timed, one after another with the chip
+to themselves (``us/call``: 24 calls chained in one jitted loop, best of
+five); the interpreter rehearsal times nothing.
 
 A refusal is RECORDED here (kernel, shape, first line of the compiler's
 message), never served around: the table goes to CHANGES.md, a refused
@@ -42,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.models.config import qwen3_8b_config, tiny_config
+from dynamo_tpu.runtime.device_observe import watched_jit
 from dynamo_tpu.utils.jax_env import (
     configure_compile_cache,
     require_serving_platform,
@@ -102,7 +112,16 @@ def _attention_shapes() -> List[Dict[str, Any]]:
     return list(seen.values())
 
 
-def _attention_inputs(shape, C: int, quantized: bool, B: int, P: int):
+def _attention_inputs(
+    shape, C: int, quantized: bool, B: int, P: int, rows: str = "uniform",
+    block_size: int = BLOCK_SIZE,
+):
+    """``rows``: "uniform" — every row live, ``start`` uniform over the
+    table; "ragged" — a third of the slots live, contexts lognormal around
+    300 tokens as the benchmark's chat traffic gives, the others EMPTY
+    (chunk_lens 0) with a stale position; "full" — every row live and
+    within 200 tokens of the table's end (nothing for a live-span kernel
+    to skip)."""
     from dynamo_tpu.ops.kv_quant import quantize_kv_chunk
 
     H, KH, D = shape["H"], shape["KH"], shape["D"]
@@ -112,7 +131,7 @@ def _attention_inputs(shape, C: int, quantized: bool, B: int, P: int):
     pools = []
     for _ in range(2):
         dense = jnp.asarray(
-            rng.standard_normal((NB, BLOCK_SIZE, KH, D)), jnp.bfloat16
+            rng.standard_normal((NB, block_size, KH, D)), jnp.bfloat16
         )
         if quantized:
             q8, s = quantize_kv_chunk(dense)
@@ -121,11 +140,19 @@ def _attention_inputs(shape, C: int, quantized: bool, B: int, P: int):
     tables = jnp.asarray(
         rng.permutation(NB)[: B * P].reshape(B, P).astype(np.int32)
     )
-    start = jnp.asarray(
-        rng.integers(0, P * BLOCK_SIZE - C, B).astype(np.int32)
-    )
-    lens = jnp.full((B,), C, jnp.int32)
-    return q, pools[0], pools[1], tables, start, lens
+    T = P * block_size
+    lens = np.full((B,), C, np.int32)
+    if rows == "ragged":
+        live = np.zeros(B, bool)
+        live[rng.permutation(B)[: (B + 2) // 3]] = True
+        ctx = np.clip(rng.lognormal(np.log(300), 0.6, B), 1, T - C)
+        start = np.where(live, ctx, 4 * T).astype(np.int32)
+        lens = np.where(live, C, 0).astype(np.int32)
+    elif rows == "full":
+        start = rng.integers(max(T - 200, 0), T - C, B).astype(np.int32)
+    else:
+        start = rng.integers(0, T - C, B).astype(np.int32)
+    return q, pools[0], pools[1], tables, jnp.asarray(start), jnp.asarray(lens)
 
 
 def _timed(row: Dict[str, Any], run, reference, ulps: float) -> Dict[str, Any]:
@@ -146,6 +173,34 @@ def _timed(row: Dict[str, Any], run, reference, ulps: float) -> Dict[str, Any]:
     return row
 
 
+TIMED_CALLS = 24
+
+
+def _build_timed_calls(call):
+    """``TIMED_CALLS`` calls of one kernel chained through q in one jitted
+    loop, so dispatch is paid once."""
+
+    def chained(q, *rest):
+        def body(_, qq):
+            return qq + (call(qq, *rest) * 1e-6).astype(qq.dtype)
+
+        return jax.lax.fori_loop(0, TIMED_CALLS, body, q)
+
+    return watched_jit("chip_check.timed_calls", jax.jit(chained))
+
+
+def _us_per_call(call, q, *rest) -> float:
+    """Device time of one kernel call: best of five timed loops."""
+    many = _build_timed_calls(call)
+    jax.block_until_ready(many(q, *rest))
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(many(q, *rest))
+        best = min(best, time.perf_counter() - t0)
+    return round(best / TIMED_CALLS * 1e6, 1)
+
+
 def attention_jobs(interpret: bool, B: int, P: int):
     from dynamo_tpu.ops.attention import _paged_attention_xla
     from dynamo_tpu.ops.pallas.paged_attention import (
@@ -153,10 +208,12 @@ def attention_jobs(interpret: bool, B: int, P: int):
         paged_attention_kernel,
     )
 
-    def job(shape, kernel_name, C, quantized):
+    def job(shape, kernel_name, C, quantized, B=B, P=P, rows="uniform",
+            block_size=BLOCK_SIZE):
         q, k, v, tables, start, lens = _attention_inputs(
-            shape, C, quantized, B, P
+            shape, C, quantized, B, P, rows, block_size
         )
+        live = np.asarray(lens) > 0
         row = {
             "kernel": kernel_name,
             "shape": (
@@ -164,38 +221,87 @@ def attention_jobs(interpret: bool, B: int, P: int):
                 f"B{B} P{P} {'int8' if quantized else 'bf16'}-KV"
                 + (f" window{shape['window']}" if shape["window"] else "")
                 + (f" softcap{shape['softcap']:g}" if shape["softcap"] else "")
+                + (f" {rows} {int(live.sum())} live" if rows != "uniform" else "")
+                + (f" bs{block_size}" if block_size != BLOCK_SIZE else "")
             ),
             "presets": shape["presets"],
             # The smoke worker (qwen3-8b) serves a bf16 pool.
             "required": "qwen3-8b" in shape["presets"] and not quantized,
         }
-        kw = dict(
-            window=shape["window"], logit_cap=shape["softcap"],
-            interpret=interpret,
-        )
+        kw = dict(logit_cap=shape["softcap"], interpret=interpret)
+
+        def decode(q, k, v, tables, start, lens):
+            return paged_attention_decode_kernel(
+                q, k, v, tables, start, shape["window"], lens, **kw
+            )
 
         def run():
-            if C == 1:
-                return paged_attention_decode_kernel(
-                    q, k, v, tables, start, **kw
-                )
-            return paged_attention_kernel(q, k, v, tables, start, lens, **kw)
+            if kernel_name == "paged_attention_decode":
+                # empty slots return zeros, and the reference is not asked
+                # about them (their stale position is past its table)
+                return decode(q, k, v, tables, start, lens)[live]
+            return paged_attention_kernel(
+                q, k, v, tables, start, lens, shape["window"], **kw
+            )
 
         def reference():
             return _paged_attention_xla(
-                q, k, v, tables, start, lens, shape["window"],
-                logit_cap=shape["softcap"],
+                q[live], k, v, tables[live], start[live], lens[live],
+                shape["window"], logit_cap=shape["softcap"],
             )
 
-        return _timed(row, run, reference, ulps=4)
+        row = _timed(row, run, reference, ulps=4)
+        if C == 1 and row["status"] == "compiled" and not interpret:
+            # timed by main() AFTER the pool: rows compile side by side,
+            # and a timing must have the chip to itself
+            row["time"] = functools.partial(
+                _us_per_call, decode, q, k, v, tables, start, lens
+            )
+        return row
 
-    return [
+    shapes = _attention_shapes()
+    jobs = [
         functools.partial(job, shape, kernel_name, C, quantized)
-        for shape in _attention_shapes()
+        for shape in shapes
         for kernel_name, C in (("paged_attention_decode", 1),
                                ("paged_attention", 16))
         for quantized in (False, True)
     ]
+    # The benchmark cell's decode (64 slots, a third live, the widest table
+    # its traffic meets) and the all-live control at 8B width.
+    cell = dict(B=B, P=P) if interpret else dict(B=64, P=128)
+    full = dict(B=B, P=P) if interpret else dict(B=32, P=128)
+    for shape in shapes:
+        if "qwen2.5-0.5b" in shape["presets"]:
+            # the tail of a prefix-hit prefill: one row, four tokens, a
+            # table narrower than a page group (a work list of one entry
+            # halted the core: live_pages.live_work_list, PR 25)
+            jobs.append(functools.partial(
+                job, shape, "paged_attention_decode", 4, False, B=1, P=8,
+            ))
+            jobs += [
+                functools.partial(
+                    job, shape, "paged_attention_decode", 1, quantized,
+                    rows="ragged", **cell,
+                )
+                for quantized in (False, True)
+            ]
+        if "qwen3-8b" in shape["presets"]:
+            jobs.append(functools.partial(
+                job, shape, "paged_attention_decode", 1, False,
+                rows="full", **full,
+            ))
+            # The same rows and tokens under --block-size 128: a page is 8
+            # times as heavy, so a grid step holds fewer of them.
+            jobs += [
+                functools.partial(
+                    job, shape, "paged_attention_decode", 1, quantized,
+                    rows="full", B=full["B"], P=max(full["P"] // 8, 1),
+                    block_size=8 * BLOCK_SIZE,
+                )
+                for quantized in (False, True)
+            ]
+    return jobs
 
 
 def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
@@ -305,16 +411,19 @@ def main() -> int:
     with ThreadPoolExecutor(max_workers=max(1, (os.cpu_count() or 2) - 1)) as pool:
         rows = list(pool.map(lambda job: job(), jobs))
     wall = time.monotonic() - t0
+    for r in rows:
+        timer = r.pop("time", None)
+        r["us_per_call"] = timer() if timer else None
 
     dev = jax.devices()[0]
     print(f"kernel table on {dev.platform} / {dev.device_kind}"
           + (" (Pallas INTERPRETER — not Mosaic)" if args.interpret else ""))
-    print("| kernel | shape | presets | result | compile+check s |")
-    print("|---|---|---|---|---|")
+    print("| kernel | shape | presets | result | compile+check s | us/call |")
+    print("|---|---|---|---|---|---|")
     for r in rows:
         result = r["status"] + (f": {r['message']}" if r["message"] else "")
         print(f"| {r['kernel']} | {r['shape']} | {', '.join(r['presets'])} "
-              f"| {result} | {r['seconds']} |")
+              f"| {result} | {r['seconds']} | {r['us_per_call'] or ''} |")
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:

@@ -79,6 +79,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.pallas.live_pages import (
+    history_pcounts,
+    window_page_bounds,
+)
+
 NEG_INF = -1e30
 
 _SUPPORTED_ACTS = ("silu", "gelu_tanh")
@@ -678,35 +683,6 @@ def _fused_layer_kernel(
         attn4_ref=pltpu.VMEM((B, KH, G, D), jnp.bfloat16),
         wsem=pltpu.SemaphoreType.DMA((6,)),
     )
-
-
-def history_pcounts(
-    start_pos: jnp.ndarray, block_size: int, table_width: int
-) -> jnp.ndarray:
-    """Per-row history page count for the decode megakernel's dynamic page
-    loop, clamped to the table width so a row can never index past its
-    table (the causal mask already hides any positions beyond it). Exposed
-    so the per-step caller (models/llama.py forward_paged) derives it ONCE
-    and shares it across all layers instead of recomputing per layer."""
-    start32 = start_pos.astype(jnp.int32)
-    return jnp.minimum((start32 + block_size - 1) // block_size, table_width)
-
-
-def window_page_bounds(
-    start_pos: jnp.ndarray, window, block_size: int
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(wlo, poff) for a sliding-window layer: ``wlo[b]`` is the first
-    VISIBLE history key index (``max(0, pos − W + 1)``; 0 when the layer
-    is full-attention) and ``poff[b] = wlo // BS`` its page — where each
-    row's dynamic page loop STARTS, so a windowed row streams only pages
-    holding in-window keys. The boundary page (``pos − W`` mid-page) is
-    streamed and masked in-kernel via the same ``wlo``. ``window`` may be
-    a TRACED scalar (0 = full) so one compiled program serves Gemma-3's
-    local/global layer mix."""
-    start32 = start_pos.astype(jnp.int32)
-    w = jnp.asarray(window, jnp.int32)
-    wlo = jnp.where(w > 0, jnp.maximum(start32 - w + 1, 0), 0)
-    return wlo, wlo // block_size
 
 
 def _fused_decoder_layer_impl(

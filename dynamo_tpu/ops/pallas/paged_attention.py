@@ -2,24 +2,23 @@
 
 The performance-critical op of the native engine: attention of a C-token
 query chunk against a paged KV cache, serving decode (C=1), chunked prefill,
-and full prefill uniformly (same contract as ops/attention.py's XLA oracle).
+and full prefill (same contract as ops/attention.py's XLA oracle).
 
 Reference parity: plays the role of the paged-attention CUDA kernels inside
 the reference's engines (vLLM/TRT-LLM) that Dynamo orchestrates around; the
 reference's own in-tree kernel is lib/llm/src/kernels/block_copy.cu (block
 movement), covered here by ops/pallas/block_copy.py.
 
-TPU-first design (not a CUDA translation):
+Two kernels, one contract (the XLA oracle's):
+
+``paged_attention_kernel`` — chunks of more than 8 tokens (chunked
+prefill). TPU-first design (not a CUDA translation):
   - The grid is (batch, page-group). The per-sequence block table is a
     scalar-prefetch operand; each grid step DMAs ``pages_per_step`` K/V
     pages selected by BlockSpec index_maps reading the table, so the pallas
     pipeline double-buffers the scattered HBM→VMEM page streams
     automatically — pages never materialize as a dense [B, T, KH, D] gather
     in HBM (the XLA oracle's O(padded-context) HBM-traffic problem).
-  - Multiple pages per grid step matter on TPU: the grid is sequential, so
-    per-iteration overhead × (B × P) dominated decode at large batch; the
-    in-kernel concat builds one [S·bs, D] key block per head and runs ONE
-    MXU dot per head per step instead of S skinny ones.
   - Each page DMA carries ALL kv heads (one [bs, KH, D] transfer — Mosaic
     wants the last two block dims full anyway); the small static KH loop is
     unrolled in the kernel body.
@@ -31,19 +30,60 @@ TPU-first design (not a CUDA translation):
     pl.when; partially-valid groups are handled by the causal mask.
   - All dots run on the MXU in float32 via preferred_element_type; the cache
     stays bfloat16 in HBM.
+
+``paged_attention_decode_kernel`` — decode (C=1) and short chunks (C ≤ 8:
+speculative verify, chunk tails). Its cost is what is LIVE, not the
+dispatched shape:
+  - The grid is ONE axis whose length is a traced scalar: the number of
+    live page groups over the rows with ``chunk_lens > 0``. ``decode_plan``
+    derives per-row page bounds ``[poff, pcount)`` and from them the flat
+    work list, step t → (row, first page) (ops/pallas/live_pages.py), once
+    per forward step; the layers share it. An empty slot, or table width
+    past a row's context, is no grid step — the kernel is not told the
+    slot count or the table width at all
+    (docs/design_docs/megakernel_paged_streaming.md).
+  - A step visits up to ``DECODE_GROUP_PAGES`` consecutive pages of one
+    row (fewer where a page is large: ``_decode_group_pages`` keeps the
+    step's operands inside a VMEM budget), each a BlockSpec operand whose
+    index map reads work list and block table, so the pipeline prefetches
+    the next step's pages across row boundaries; the work list is one
+    entry longer than the grid's most steps (live_pages.live_work_list: a
+    one-entry list halts the core). The pages stay BlockSpec operands
+    (not manual DMAs from a whole-pool HBM ref as in the megakernel)
+    because Mosaic refuses to slice a pool whose minor dimension is
+    narrower than a 128-lane tile: head_dim 64, and the int8 pools'
+    [NB, KH, 16] scales.
+  - One online-softmax update per step over all its pages' score tiles;
+    the steps of a row are consecutive, so q, the output block and the
+    flash state stay resident from the row's first step to its last.
+  - Same mathematics as the other kernel: f32 dots, f32 softmax, NEG_INF
+    mask, softcap, sliding window (a windowed row starts at its first
+    in-window page).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.pallas.live_pages import (
+    live_page_bounds,
+    live_work_list,
+)
+
 NEG_INF = -1e30
+# Live-span decode kernel: the most consecutive pages of one row a grid
+# step visits, and the VMEM a step's K and V page operands may take with
+# the pipeline's two buffers each (a quarter of the 16 MiB the compiler
+# scopes a kernel to on the v5e: 16 pages of every preset at block size 16
+# fit; the body's f32 temporaries take their share of the rest).
+DECODE_GROUP_PAGES = 16
+DECODE_PAGES_VMEM_BYTES = 4 << 20
 
 
 def _kernel(
@@ -165,209 +205,263 @@ def _kernel(
 
 
 def _decode_kernel(
-    # scalar prefetch
-    block_tables_ref,  # [B, P] int32 (SMEM)
+    # scalar prefetch (SMEM)
+    block_tables_ref,  # [B, P] int32
     start_pos_ref,  # [B] int32
+    pcount_ref,  # [B] int32 — live pages end (exclusive); 0 = inactive row
+    poff_ref,  # [B] int32 — first live page (sliding window; else 0)
     window_ref,  # [1] int32 — sliding window (0 = full attention)
-    # VMEM blocks: q [BQ, KH, C*G, D], then BQ (k, v) page pairs — int8
-    # caches interleave a [1, KH, bs] scale ref after each page ref
+    step_row_ref,  # [T] int32 — the row grid step t works on
+    step_page_ref,  # [T] int32 — the first page of step t's group
+    # VMEM blocks: q [1, KH, C*G, D] of the step's row, then S (k, v) page
+    # pairs — int8 caches interleave a [1, KH, bs] scale ref after each
+    # page ref (k, ks, v, vs)
     q_ref,
     *refs,  # pages..., o_ref, m, l, acc
     sm_scale: float,
     block_size: int,
-    batch_block: int,
     n_groups: int,
+    group_pages: int,
     logit_cap: float = 0.0,
     quantized: bool = False,
 ):
-    """Batch-blocked kernel for decode (C=1) and SHORT chunks (C ≤ 8, the
-    speculative-verify shape): the grid is (B/BQ, pages) and each
-    sequential grid step visits ONE page of BQ different sequences. The
-    generic kernel's (B, pages) grid ran B×P tiny steps whose per-iteration
-    overhead dominated (measured ~10µs/step ≫ the 0.5µs of compute);
-    batch-blocking amortizes it BQ-fold while every page DMA stays a single
-    contiguous [bs, KH, D] transfer. Int8 caches halve both the DMA bytes
-    and the per-page VMEM, which doubles the default batch_block (8 → 16)
-    inside the same scoped-VMEM budget.
+    """Live-span kernel for decode (C=1) and SHORT chunks (C ≤ 8, the
+    speculative-verify shape). The grid is ONE axis of work steps whose
+    length is the number of LIVE page groups — a traced scalar, so dead
+    slots and dead table width cost no grid step at all. Step t visits
+    ``group_pages`` (S) consecutive pages of one row, ``step_row[t]``,
+    starting at ``step_page[t]``; the steps of a row are consecutive, so
+    the q and output blocks stay resident for the row and the online-
+    softmax state in VMEM scratch is initialised at the row's first group
+    and written out at its last. The BlockSpec index maps read the work
+    list and the block table, so the pallas pipeline prefetches step t+1's
+    pages — of the same row or the next live one — while step t computes.
 
-    Query rows per (j, h) are (c, g) pairs, c-major; causality masks key t
-    visible to row (c, g) iff t <= start_j + c (the chunk's own K/V are
-    already in the cache, as in the generic kernel)."""
-    BQ = batch_block
+    Pages of a group past the row's last live page are fetched as copies
+    of that last page (clamped index: always a valid block) and hidden by
+    the ``t < pcount·bs`` mask. The S pages of a step share ONE online-
+    softmax update: their score tiles are independent dots reduced
+    together, so the MXU pipelines them (measured on the v5e at the
+    qwen2.5-0.5b shape with 8 pages a step: 105 us a call against 136 /
+    198 / 228 us with updates of 4 / 2 / 1 pages; 16 pages a step: 90 us;
+    my chip runs, PR 25).
+
+    Query rows per head are (c, g) pairs, c-major; key t is visible to
+    row (c, g) iff t <= start + c (the chunk's own K/V are already in the
+    cache, as in the generic kernel)."""
+    S = group_pages
     stride = 4 if quantized else 2
-    kv_refs = refs[: stride * BQ]
-    o_ref = refs[stride * BQ]
-    m_ref, l_ref, acc_ref = refs[stride * BQ + 1 :]
+    kv_refs = refs[: stride * S]
+    o_ref = refs[stride * S]
+    m_ref, l_ref, acc_ref = refs[stride * S + 1 :]
 
-    bb = pl.program_id(0)
-    p = pl.program_id(1)
-    num_steps = pl.num_programs(1)
     KH = q_ref.shape[1]
     CG = q_ref.shape[2]
     G = n_groups
     C = CG // G
+    bs = block_size
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    t = pl.program_id(0)
+    b = step_row_ref[t]
+    pstart = step_page_ref[t]
+    start = start_pos_ref[b]
+    pcount = pcount_ref[b]
     win = window_ref[0]
-    for j in range(BQ):  # static unroll over the sequence block
-        start = start_pos_ref[bb * BQ + j]
-        # Highest key any row can see: start + C - 1 (last chunk row).
-        last_needed_page = (start + C - 1) // block_size
-        # With a sliding window, pages wholly before start-win+1 skip both
-        # their compute AND never affect the causal/window mask.
-        first_needed_page = jnp.where(
-            win > 0, jnp.maximum(start - win + 1, 0) // block_size, 0
-        )
 
-        @pl.when((p >= first_needed_page) & (p <= last_needed_page))
-        def _compute(j=j, start=start):
-            if C == 1:
-                # decode fast path: one shared [1, bs] mask row (broadcast)
-                t_idx = p * block_size + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, block_size), 1
-                )
-                limit = start
-            else:
-                t_idx = p * block_size + jax.lax.broadcasted_iota(
-                    jnp.int32, (CG, block_size), 1
-                )
-                c_idx = jax.lax.broadcasted_iota(
-                    jnp.int32, (CG, block_size), 0
-                ) // G
-                limit = start + c_idx
-            visible = t_idx <= limit
-            visible = visible & ((win <= 0) | (t_idx > limit - win))
-            for h in range(KH):
-                q = q_ref[j, h].astype(jnp.float32)  # [CG, D]
-                k = kv_refs[stride * j][0, :, h, :].astype(jnp.float32)
-                v = kv_refs[stride * j + stride // 2][0, :, h, :].astype(
-                    jnp.float32
-                )
-                s_mat = (
-                    jax.lax.dot_general(
-                        q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    * sm_scale
-                )  # [G, bs]
-                if quantized:
-                    s_mat = s_mat * kv_refs[stride * j + 1][0, h][None, :]
-                if logit_cap > 0.0:
-                    s_mat = logit_cap * jnp.tanh(s_mat / logit_cap)
-                s_mat = jnp.where(visible, s_mat, NEG_INF)
-                m_prev = m_ref[j, h]
-                m_new = jnp.maximum(
-                    m_prev, jnp.max(s_mat, axis=-1, keepdims=True)
-                )
-                alpha = jnp.exp(m_prev - m_new)
-                probs = jnp.exp(s_mat - m_new)
-                l_ref[j, h] = l_ref[j, h] * alpha + jnp.sum(
-                    probs, axis=-1, keepdims=True
-                )
-                if quantized:
-                    probs = probs * kv_refs[stride * j + 3][0, h][None, :]
-                acc_ref[j, h] = acc_ref[j, h] * alpha + jax.lax.dot_general(
-                    probs, v, (((1,), (0,)), ((), ())),
+    @pl.when(pstart == poff_ref[b])
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    rows = 1 if C == 1 else CG  # decode: one shared mask row (broadcast)
+    if C == 1:
+        limit = start
+    else:
+        limit = start + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // G
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+
+    visible = []
+    for s in range(S):
+        t_idx = (pstart + s) * bs + lane
+        vis = (t_idx <= limit) & (t_idx < pcount * bs)
+        visible.append(vis & ((win <= 0) | (t_idx > limit - win)))
+    for h in range(KH):  # static unroll; KH is small (2-8)
+        q = q_ref[0, h].astype(jnp.float32)  # [CG, D]
+        scores = []
+        for s in range(S):
+            k = kv_refs[stride * s][0, :, h, :].astype(jnp.float32)
+            s_mat = (
+                jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
-                m_ref[j, h] = m_new
+                * sm_scale
+            )  # [CG, bs]
+            if quantized:
+                # Per-token scales ride the score/prob rows instead of
+                # touching the [bs, D] pages (ops/kv_quant.py layout).
+                s_mat = s_mat * kv_refs[stride * s + 1][0, h][None, :]
+            if logit_cap > 0.0:
+                s_mat = logit_cap * jnp.tanh(s_mat / logit_cap)
+            scores.append(jnp.where(visible[s], s_mat, NEG_INF))
+        m_prev = m_ref[h]
+        m_new = m_prev
+        for s_mat in scores:
+            m_new = jnp.maximum(m_new, jnp.max(s_mat, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[h] * alpha
+        acc = acc_ref[h] * alpha
+        for s, s_mat in enumerate(scores):
+            probs = jnp.exp(s_mat - m_new)
+            l_new = l_new + jnp.sum(probs, axis=-1, keepdims=True)
+            if quantized:
+                probs = probs * kv_refs[stride * s + 3][0, h][None, :]
+            v = kv_refs[stride * s + stride // 2][0, :, h, :].astype(
+                jnp.float32
+            )
+            acc = acc + jax.lax.dot_general(
+                probs, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        l_ref[h] = l_new
+        acc_ref[h] = acc
+        m_ref[h] = m_new
 
-    @pl.when(p == num_steps - 1)
+    @pl.when(pstart + S >= pcount)
     def _finalize():
-        for j in range(BQ):
-            for h in range(KH):
-                out = acc_ref[j, h] / jnp.maximum(l_ref[j, h], 1e-30)
-                o_ref[j, h] = out.astype(o_ref.dtype)
+        # Every live query row sees at least one key, so l > 0.
+        for h in range(KH):
+            out = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = out.astype(o_ref.dtype)
+
+
+class DecodePlan(NamedTuple):
+    """What the live-span decode kernel's grid is made of. It depends on
+    the step's positions, the table and the layer's window only, so a
+    forward step derives it once (``decode_plan``) and every layer with
+    that window shares it."""
+
+    tables: jnp.ndarray  # [B, P] int32; an empty slot's row is zeroed
+    pcount: jnp.ndarray  # [B] int32 — live pages end; 0 = empty slot
+    poff: jnp.ndarray  # [B] int32 — first live page
+    step_row: jnp.ndarray  # [T] int32 (live_work_list)
+    step_page: jnp.ndarray  # [T] int32
+    total: jnp.ndarray  # [] int32 — live page groups: the grid's length
+
+
+def _decode_group_pages(k_cache, table_width: int) -> int:
+    """Pages of one row a grid step visits: ``DECODE_GROUP_PAGES``, fewer
+    where the step's K and V page operands, each double-buffered by the
+    pipeline, would pass ``DECODE_PAGES_VMEM_BYTES`` (large
+    ``--block-size``). An int8 page counts twice: its f32 scale page
+    rides along and the body widens it to f32 as it does a bf16 one."""
+    from dynamo_tpu.ops.kv_quant import is_quantized_pool
+
+    quantized = is_quantized_pool(k_cache)
+    values = k_cache["q8"] if quantized else k_cache
+    _, block_size, n_kv_heads, head_dim = values.shape
+    page_bytes = (
+        block_size * n_kv_heads * max(head_dim, 128) * values.dtype.itemsize
+    )
+    step_bytes = 4 * page_bytes * (2 if quantized else 1)
+    fits = DECODE_PAGES_VMEM_BYTES // step_bytes
+    return max(1, min(DECODE_GROUP_PAGES, table_width, fits))
+
+
+def decode_plan(
+    k_cache, block_tables, start_pos, chunk_lens, C: int, window=0
+) -> DecodePlan:
+    """The live-span kernel's grid for one forward step: per-row page
+    bounds, the flat work list over them and its length."""
+    values = k_cache["q8"] if isinstance(k_cache, dict) else k_cache
+    block_size = values.shape[1]
+    P = block_tables.shape[1]
+    pcount, poff = live_page_bounds(
+        start_pos, chunk_lens, C, window, block_size, P
+    )
+    total, step_row, step_page = live_work_list(
+        pcount, poff, _decode_group_pages(k_cache, P), P
+    )
+    # An empty slot's table may hold anything; with no live row at all the
+    # grid still runs its one step, on row 0: give it a block that exists.
+    tables = jnp.where(
+        (pcount > poff)[:, None], block_tables.astype(jnp.int32), 0
+    )
+    return DecodePlan(tables, pcount, poff, step_row, step_page, total)
 
 
 def _paged_attention_decode_kernel_impl(
-    q: jnp.ndarray,  # [B, 1, n_heads, head_dim]
+    q: jnp.ndarray,  # [B, C, n_heads, head_dim], C <= 8
     k_cache,  # [num_blocks, block_size, KH, D] — or {"q8", "s"} int8 pool
     v_cache,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     start_pos: jnp.ndarray,  # [B] int32
     window=0,  # sliding window (int or traced scalar); 0 = full
+    chunk_lens: Optional[jnp.ndarray] = None,  # [B] int32; 0 = empty slot
+    plan: Optional[DecodePlan] = None,  # decode_plan() of the same inputs
     *,
     sm_scale: Optional[float] = None,
     interpret: bool = False,
-    batch_block: Optional[int] = None,
     logit_cap: float = 0.0,
 ) -> jnp.ndarray:
-    """Decode-path (C=1) batch-blocked kernel. Same contract as the XLA
-    oracle at C=1; B is padded to a multiple of ``batch_block`` (padded
-    rows read page 0 at position 0 — one valid key, discarded output).
-    With a sliding ``window``, page-group steps wholly before the window
-    skip their compute (long-context decode on windowed layers gets
-    cheaper, the SWA point). Int8 pools (ops/kv_quant.py) stream half the
-    bytes and default to batch_block 16."""
+    """Decode / short-chunk (C ≤ 8) live-span kernel. Same contract as the
+    XLA oracle for rows with ``chunk_lens > 0``; a row with ``chunk_lens``
+    0 is never visited (its table and position may be stale) and returns
+    zeros. Cost follows the live (row, page) pairs, not B × table width:
+    the same rows under a wider table run the same grid steps. With a
+    sliding ``window`` a row starts at its first in-window page. ``plan``
+    is the caller's ``decode_plan`` of the same table, positions, lengths
+    and window (one per forward step, shared by the layers); derived here
+    when absent."""
     from dynamo_tpu.ops.kv_quant import is_quantized_pool
 
     quantized = is_quantized_pool(k_cache)
     B, C, n_heads, head_dim = q.shape
-    assert C <= 8, "batch-blocked kernel serves decode / short-chunk steps"
+    assert C <= 8, "live-span kernel serves decode / short-chunk steps"
     k_values = k_cache["q8"] if quantized else k_cache
     _, block_size, n_kv_heads, _ = k_values.shape
     G = n_heads // n_kv_heads
+    CG = C * G
     scale = sm_scale if sm_scale is not None else head_dim**-0.5
-    if batch_block is None:
-        from dynamo_tpu import config
-
-        env_bq = config.DECODE_BQ.get()
-        if env_bq > 0:
-            batch_block = env_bq
-        else:
-            # Measured on v5e: BQ bounded by the ~16 MB scoped VMEM the
-            # per-j double-buffered page pairs occupy; int8 pages are half
-            # the size. DYN_TPU_DECODE_BQ overrides for shape tuning.
-            batch_block = 16 if quantized else 8
-    # C>1 multiplies the q block and all three scratches by C: shrink BQ
-    # so the VMEM footprint stays at the C=1 budget.
-    batch_block = max(1, batch_block // C)
-    BQ = max(min(batch_block, B), 1)
-
-    B_pad = ((B + BQ - 1) // BQ) * BQ
-    if B_pad != B:
-        q = jnp.pad(q, ((0, B_pad - B), (0, 0), (0, 0), (0, 0)))
-        block_tables = jnp.pad(block_tables, ((0, B_pad - B), (0, 0)))
-        start_pos = jnp.pad(start_pos, (0, B_pad - B))
+    S = _decode_group_pages(k_cache, block_tables.shape[1])
+    if plan is None:
+        plan = decode_plan(
+            k_cache, block_tables, start_pos, chunk_lens, C, window
+        )
+    live = plan.pcount > plan.poff  # rows the grid visits
 
     # [B, C, H, D] → [B, KH, C*G, D]; rows (c, g) c-major, as the kernel's
     # causal mask expects.
     q4 = (
-        q.reshape(B_pad, C, n_kv_heads, G, head_dim)
+        q.reshape(B, C, n_kv_heads, G, head_dim)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(B_pad, n_kv_heads, C * G, head_dim)
+        .reshape(B, n_kv_heads, CG, head_dim)
     )
-    CG = C * G
-    P = block_tables.shape[1]
     win = jnp.asarray(window, jnp.int32).reshape(1)
 
-    def q_map(bb, p, bt, sp, w):
-        return (bb, 0, 0, 0)
+    def q_map(t, bt, sp, pc, po, w, srow, spage):
+        return (srow[t], 0, 0, 0)
 
-    def kv_map_for(j):
-        def kv_map(bb, p, bt, sp, w):
-            return (bt[bb * BQ + j, p], 0, 0, 0)
+    def page_map(s, ndim):
+        """Index map of an operand that holds page s of the step's group
+        (clamped to the row's live pages: always a block that exists)."""
 
-        return kv_map
+        def index_map(t, bt, sp, pc, po, w, srow, spage):
+            b = srow[t]
+            page = jnp.maximum(jnp.minimum(spage[t] + s, pc[b] - 1), 0)
+            return (bt[b, page],) + (0,) * (ndim - 1)
 
-    def s_map_for(j):
-        def s_map(bb, p, bt, sp, w):
-            return (bt[bb * BQ + j, p], 0, 0)
+        return index_map
 
-        return s_map
-
-    in_specs = [pl.BlockSpec((BQ, n_kv_heads, CG, head_dim), q_map)]
+    in_specs = [pl.BlockSpec((1, n_kv_heads, CG, head_dim), q_map)]
     kv_args = []
-    for j in range(BQ):
-        spec = pl.BlockSpec((1, block_size, n_kv_heads, head_dim), kv_map_for(j))
+    for s in range(S):
+        spec = pl.BlockSpec(
+            (1, block_size, n_kv_heads, head_dim), page_map(s, 4)
+        )
         if quantized:
-            s_spec = pl.BlockSpec((1, n_kv_heads, block_size), s_map_for(j))
+            s_spec = pl.BlockSpec((1, n_kv_heads, block_size), page_map(s, 3))
             in_specs.extend([spec, s_spec, spec, s_spec])
             kv_args.extend(
                 [k_cache["q8"], k_cache["s"], v_cache["q8"], v_cache["s"]]
@@ -377,39 +471,40 @@ def _paged_attention_decode_kernel_impl(
             kv_args.extend([k_cache, v_cache])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B_pad // BQ, P),
+        num_scalar_prefetch=7,
+        grid=(jnp.maximum(plan.total, 1),),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((BQ, n_kv_heads, CG, head_dim), q_map),
+        out_specs=pl.BlockSpec((1, n_kv_heads, CG, head_dim), q_map),
         scratch_shapes=[
-            pltpu.VMEM((BQ, n_kv_heads, CG, 1), jnp.float32),
-            pltpu.VMEM((BQ, n_kv_heads, CG, 1), jnp.float32),
-            pltpu.VMEM((BQ, n_kv_heads, CG, head_dim), jnp.float32),
+            pltpu.VMEM((n_kv_heads, CG, 1), jnp.float32),
+            pltpu.VMEM((n_kv_heads, CG, 1), jnp.float32),
+            pltpu.VMEM((n_kv_heads, CG, head_dim), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, sm_scale=scale, block_size=block_size, batch_block=BQ,
-        n_groups=G, logit_cap=logit_cap, quantized=quantized,
+        _decode_kernel, sm_scale=scale, block_size=block_size, n_groups=G,
+        group_pages=S, logit_cap=logit_cap, quantized=quantized,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (B_pad, n_kv_heads, CG, head_dim), q.dtype
-        ),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv_heads, CG, head_dim), q.dtype),
         interpret=interpret,
     )(
-        block_tables.astype(jnp.int32),
+        plan.tables,
         start_pos.astype(jnp.int32),
+        plan.pcount,
+        plan.poff,
         win,
+        plan.step_row,
+        plan.step_page,
         q4,
         *kv_args,
     )
-    out = (
-        out[:B]
-        .reshape(B, n_kv_heads, C, G, head_dim)
-        .transpose(0, 2, 1, 3, 4)
-    )
+    # Rows the grid never visited (empty slots) hold whatever the buffer
+    # held: zeros, by contract.
+    out = jnp.where(live[:, None, None, None], out, 0)
+    out = out.reshape(B, n_kv_heads, C, G, head_dim).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, n_heads, head_dim)
 
 
@@ -533,7 +628,7 @@ paged_attention_decode_kernel = watched_jit(
     "pallas.paged_attention_decode",
     functools.partial(
         jax.jit,
-        static_argnames=("sm_scale", "interpret", "batch_block", "logit_cap"),
+        static_argnames=("sm_scale", "interpret", "logit_cap"),
     )(_paged_attention_decode_kernel_impl),
 )
 

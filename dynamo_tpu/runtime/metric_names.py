@@ -98,6 +98,12 @@ ENGINE_REQUEST_PHASE = f"{ENGINE_PREFIX}_request_phase_seconds"
 ENGINE_REQUEST_DECODE_TOKENS_TOTAL = (
     f"{ENGINE_PREFIX}_request_decode_tokens_total"
 )
+# What the decode paged-attention kernel's grid is made of, per dispatched
+# burst: the pages the active rows' contexts reach (live) and the slots of
+# the dispatched table, max_num_seqs x width bucket (what a grid over the
+# table would visit). live / slots = the share of the table that is work.
+ENGINE_DECODE_LIVE_PAGES_TOTAL = f"{ENGINE_PREFIX}_decode_live_pages_total"
+ENGINE_DECODE_TABLE_SLOTS_TOTAL = f"{ENGINE_PREFIX}_decode_table_slots_total"
 
 # The tick-phase vocabulary: every name EngineStepMetrics.phase accepts, in
 # exactly one class. ``device_wait``: the loop awaits the device thread
@@ -628,6 +634,8 @@ ALL_ENGINE = (
     ENGINE_TICK,
     ENGINE_REQUEST_PHASE,
     ENGINE_REQUEST_DECODE_TOKENS_TOTAL,
+    ENGINE_DECODE_LIVE_PAGES_TOTAL,
+    ENGINE_DECODE_TABLE_SLOTS_TOTAL,
 )
 
 ALL_PERF = (

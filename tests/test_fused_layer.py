@@ -239,7 +239,7 @@ def test_window_page_bounds_semantics():
     """window_page_bounds: wlo is the first VISIBLE key (max(0, pos−W+1)),
     poff its page — including the straddle case where pos−W lands
     mid-page (the boundary page is streamed and masked in-kernel)."""
-    from dynamo_tpu.ops.pallas.fused_layer import window_page_bounds
+    from dynamo_tpu.ops.pallas.live_pages import window_page_bounds
 
     BS = 16
     start = jnp.asarray([0, 5, 100, 100, 64, 200], jnp.int32)

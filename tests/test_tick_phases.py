@@ -419,3 +419,30 @@ def test_recorded_fixture_holds_the_spans_and_the_named_programs():
     # first recorded operation and is not counted)
     assert got["programs"]["prefill"]["count"] >= 1
     assert got["programs"]["decode"]["count"] == 1
+
+
+async def test_dispatch_counts_live_pages_and_table_slots():
+    """Every dispatched decode burst adds the pages its active rows'
+    contexts reach to ``decode_live_pages_total`` and ``max_num_seqs x
+    width bucket`` to ``decode_table_slots_total``: one stream of 10 prompt
+    tokens, bursts of 4 steps, 4-token pages, one burst in flight."""
+    engine, _ = make_engine(pipeline_depth=1, decode_steps=4)
+    sm = engine.step_metrics
+    dispatched = []
+    real = sm.observe_decode_pages
+    sm.observe_decode_pages = lambda live, slots: (
+        dispatched.append((live, slots)), real(live, slots)
+    )
+    try:
+        out = await run_one(engine, req(range(10, 20), max_tokens=9))
+    finally:
+        await engine.stop()
+    assert sum(len(o.token_ids) for o in out) == 9
+    # the first token comes from the prefill; burst i then attends over
+    # 10 + 4 (i + 1) tokens: 14 -> 4 pages in a 4-wide table, 18 -> 5 in 8
+    assert dispatched[:2] == [(4, 4 * 4), (5, 4 * 8)]
+    assert sm.decode_live_pages.value() == sum(l for l, _ in dispatched)
+    assert sm.decode_table_slots.value() == sum(s for _, s in dispatched)
+    text = sm.render()
+    assert f"{mn.ENGINE_DECODE_LIVE_PAGES_TOTAL} " in text
+    assert f"{mn.ENGINE_DECODE_TABLE_SLOTS_TOTAL} " in text

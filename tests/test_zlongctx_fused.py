@@ -93,7 +93,7 @@ def test_windowed_rows_stream_only_live_pages():
     gathers the full table and masks, is shown to produce NaN on the same
     poisoned pool). The fused output must be bit-identical to the clean
     run."""
-    from dynamo_tpu.ops.pallas.fused_layer import (
+    from dynamo_tpu.ops.pallas.live_pages import (
         history_pcounts,
         window_page_bounds,
     )
