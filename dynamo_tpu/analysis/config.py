@@ -63,11 +63,6 @@ class HotPathConfig:
             # arithmetic only. The control law itself is fenced behind the
             # TickBudgeter.evaluate boundary below.
             "engines/tpu/tick_budget.py",
-            # Perf ledger (PR 19): observe_decode/observe_prefill run at
-            # every reap / prefill round — this scope entry makes the
-            # linter prove the feeds stay deque-and-arithmetic only. The
-            # sentinel is fenced behind the PerfLedger.evaluate boundary.
-            "runtime/perf_ledger.py",
         }
     )
     boundaries: FrozenSet[Tuple[str, str]] = frozenset(
@@ -84,11 +79,6 @@ class HotPathConfig:
             # events, so traversal stops here rather than whitelisting
             # those in the decode plane.
             ("engines/tpu/tick_budget.py", "TickBudgeter.evaluate"),
-            # Perf sentinel: time-gated to eval_interval_s (per-reap calls
-            # return on a subtraction); past the gate it compares windows
-            # against fingerprints, counts anomalies, and records flight
-            # events — fenced rather than whitelisted, like the budgeter.
-            ("runtime/perf_ledger.py", "PerfLedger.evaluate"),
         }
     )
     device_roots: FrozenSet[str] = frozenset(
@@ -170,11 +160,6 @@ class RingWriterConfig:
             # evictions, sketch replacements; single writer: the manager's
             # event loop (same loop as the kvbm ring).
             "kvcache": ("kvbm/manager.py", "TieredKvManager"),
-            # Perf ledger (PR 19): sentinel anomalies + fingerprint
-            # load/store outcomes; single writer: the engine tick loop
-            # (evaluate rides the reap path; load/store ride start/stop
-            # on the same loop).
-            "perf": ("runtime/perf_ledger.py", "PerfLedger"),
             # Crash plane (PR 10): worker suspect/dead/rejoin transitions
             # + stale-incarnation drops; single writer: the consuming
             # frontend's event loop (worker_monitor pump + evaluate task).
@@ -298,7 +283,6 @@ class ImportLayeringConfig:
         (
             "planes",
             (
-                "bench/",
                 "disagg/",
                 "discd/",
                 "engines/",

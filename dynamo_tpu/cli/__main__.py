@@ -75,25 +75,9 @@ def main(argv=None) -> None:
         "observe",
         help="snapshot a running worker's device plane "
         "(/debug/memory /debug/compiles /debug/flight); sub-views: "
-        "trajectory, kvcache, perf",
+        "trajectory, kvcache",
     )
     add_observe_args(observe_p)
-    # Lazy import: bench compare is jax-free stdlib (it judges JSON
-    # records), so it can't ride cli.run's imports either.
-    from dynamo_tpu.bench.compare import add_compare_args
-
-    bench_p = sub.add_parser(
-        "bench",
-        help="bench-record tooling (compare: typed per-leg regression "
-        "verdicts over BENCH_*.json records, nonzero exit on regression)",
-    )
-    bench_sub = bench_p.add_subparsers(dest="bench_command", required=True)
-    compare_p = bench_sub.add_parser(
-        "compare",
-        help="judge the newest bench record against the previous usable "
-        "one with noise bands",
-    )
-    add_compare_args(compare_p)
     drain_p = sub.add_parser(
         "drain",
         help="live-handoff drain a running worker (POST /drain; in-flight "
@@ -132,10 +116,6 @@ def main(argv=None) -> None:
         from dynamo_tpu.analysis.cli import main_lint
 
         raise SystemExit(main_lint(args))
-    elif args.command == "bench":
-        from dynamo_tpu.bench.compare import main_compare
-
-        raise SystemExit(main_compare(args))
 
 
 if __name__ == "__main__":

@@ -213,13 +213,10 @@ async def run_http(pipeline, card: ModelDeploymentCard, args) -> None:
 def add_observe_args(parser: argparse.ArgumentParser) -> None:
     parser.add_argument(
         "what", nargs="?", default=None,
-        choices=[None, "trajectory", "kvcache", "perf"],
+        choices=[None, "trajectory", "kvcache"],
         help="optional sub-view: 'trajectory' pretty-prints one stitched "
         "request trajectory (GET /debug/trajectory/{trace_id}); 'kvcache' "
-        "pretty-prints the KV-reuse plane (GET /debug/kvcache); 'perf' "
-        "pretty-prints the perf ledger — per-shape decode attribution, "
-        "roofline fractions, and the sentinel's verdicts "
-        "(GET /debug/perf)",
+        "pretty-prints the KV-reuse plane (GET /debug/kvcache)",
     )
     parser.add_argument(
         "trace_id", nargs="?", default=None,
@@ -496,95 +493,6 @@ async def main_observe_kvcache(args) -> None:
         )
 
 
-async def main_observe_perf(args) -> None:
-    """Pretty-print the perf ledger of a running worker: per-shape decode
-    attribution (step p50/p99, host gap, dispatch/reap split, tok/s,
-    roofline fraction), prefill tokens/s per chunk bucket, and the live
-    sentinel's fingerprint verdicts — 'did this engine get slower than it
-    used to be on this exact shape' in one command."""
-    import aiohttp
-
-    from dynamo_tpu import config
-
-    port = args.port if args.port is not None else config.SYSTEM_PORT.get()
-    base = f"http://{args.host}:{port}"
-    async with aiohttp.ClientSession() as session:
-        try:
-            async with session.get(f"{base}/debug/perf") as r:
-                if r.status != 200:
-                    raise SystemExit(
-                        f"GET {base}/debug/perf -> {r.status}: "
-                        f"{await r.text()}"
-                    )
-                doc = await r.json()
-        except aiohttp.ClientError as exc:
-            raise SystemExit(f"cannot reach system server at {base}: {exc}")
-
-    if args.json:
-        print(json.dumps(doc, indent=2))
-        return
-
-    ident = doc.get("identity") or {}
-    print(
-        f"== perf ledger ({base}/debug/perf)  "
-        f"preset={ident.get('preset', '?')} "
-        f"backend={ident.get('backend', '?')} host={ident.get('host', '?')}"
-    )
-    rows = doc.get("decode") or []
-    if not rows:
-        print("  (no decode samples yet)")
-    else:
-        print(
-            f"  {'shape':<28} {'n':>5} {'step p50':>10} {'p99':>10} "
-            f"{'gap p50':>9} {'disp':>8} {'reap':>8} {'tok/s':>9} "
-            f"{'roofline':>8}"
-        )
-        for row in rows:
-            shape = (
-                f"w{row.get('width')}/{row.get('variant')}/"
-                f"{row.get('path')}"
-            )
-            frac = row.get("roofline_fraction")
-            print(
-                f"  {shape:<28} {row.get('samples', 0):>5} "
-                f"{row.get('step_p50_s', 0.0) * 1e3:>8.2f}ms "
-                f"{row.get('step_p99_s', 0.0) * 1e3:>8.2f}ms "
-                f"{row.get('host_gap_p50_s', 0.0) * 1e3:>7.2f}ms "
-                f"{row.get('dispatch_p50_s', 0.0) * 1e3:>6.2f}ms "
-                f"{row.get('reap_p50_s', 0.0) * 1e3:>6.2f}ms "
-                f"{row.get('toks_per_sec', 0.0):>9.1f} "
-                f"{'' if frac is None else f'{frac:>7.1%}':>8}"
-            )
-    prefill = doc.get("prefill") or {}
-    if prefill:
-        print("  prefill tok/s by chunk bucket: " + "  ".join(
-            f"{b}={v.get('toks_per_sec_p50', 0.0):.0f}"
-            for b, v in prefill.items()
-        ))
-    print(
-        f"\n== sentinel  fingerprints_loaded="
-        f"{doc.get('fingerprints_loaded', 0)}  "
-        f"anomalies_total={doc.get('anomalies_total', 0)}"
-    )
-    verdicts = doc.get("verdicts") or {}
-    if not verdicts:
-        print("  (no verdicts yet — sentinel has not evaluated)")
-    for key, v in sorted(verdicts.items()):
-        line = (
-            f"  {key:<40} {v.get('verdict', '?'):<12} "
-            f"n={v.get('samples', 0)} "
-            f"step_p50={v.get('step_p50_s', 0.0) * 1e3:.2f}ms "
-            f"tok/s={v.get('toks_per_sec', 0.0):.1f}"
-        )
-        print(line)
-        for anom in v.get("anomalies") or []:
-            print(
-                f"    ! {anom.get('kind')}  x{anom.get('ratio', 0.0):.3f} "
-                f"(live {anom.get('live'):.6g} vs baseline "
-                f"{anom.get('baseline'):.6g}, streak {anom.get('streak')})"
-            )
-
-
 async def main_observe(args) -> None:
     """One-shot pretty snapshot of /debug/memory, /debug/compiles and
     /debug/flight from a running worker's system server — the operator's
@@ -598,9 +506,6 @@ async def main_observe(args) -> None:
         return
     if getattr(args, "what", None) == "kvcache":
         await main_observe_kvcache(args)
-        return
-    if getattr(args, "what", None) == "perf":
-        await main_observe_perf(args)
         return
 
     port = args.port if args.port is not None else config.SYSTEM_PORT.get()

@@ -388,43 +388,6 @@ DRAIN_HANDOFF_CONCURRENCY = env_int(
     subsystem="liveness",
 )
 
-# -- perf ledger (runtime/perf_ledger.py)
-PERF_WINDOW = env_int(
-    "DYN_TPU_PERF_WINDOW", 256,
-    "Perf-ledger rolling window (samples per decode shape; bounds both "
-    "memory and quantile cost)",
-    subsystem="perf",
-)
-PERF_SAMPLE_TTL_S = env_float(
-    "DYN_TPU_PERF_SAMPLE_TTL_S", 120.0,
-    "Perf-ledger sample TTL in seconds (stale samples age out so the "
-    "windows describe the CURRENT regime, not history)",
-    subsystem="perf",
-)
-PERF_EVAL_INTERVAL_S = env_float(
-    "DYN_TPU_PERF_EVAL_INTERVAL_S", 5.0,
-    "Seconds between perf-sentinel evaluations (the fingerprint "
-    "comparison runs at this cadence, not per tick)",
-    subsystem="perf",
-)
-PERF_NOISE_BAND = env_float(
-    "DYN_TPU_PERF_NOISE_BAND", 0.10,
-    "Fractional noise band around a fingerprint before the sentinel "
-    "calls regression (0.10 = ±5%% run-to-run noise stays silent, a "
-    "20%% slowdown is flagged)",
-    subsystem="perf",
-)
-PERF_MIN_SAMPLES = env_int(
-    "DYN_TPU_PERF_MIN_SAMPLES", 16,
-    "Samples a window needs before the sentinel issues a verdict for it",
-    subsystem="perf",
-)
-PERF_FINGERPRINT_PATH = env_str(
-    "DYN_TPU_PERF_FINGERPRINT_PATH", "",
-    "Where steady-state perf fingerprints persist across restarts "
-    "(JSON; empty = in-memory only, every start is a cold start)",
-    subsystem="perf",
-)
 # -- request lifecycle plane (runtime/lifecycle.py)
 SLOW_REQUEST_S = env_float(
     "DYN_TPU_SLOW_REQUEST_S", 30.0,

@@ -237,11 +237,6 @@ class EngineStepMetrics:
         self.decode_live_pages.inc(live_pages)
         self.decode_table_slots.inc(table_slots)
 
-    def host_gap_stats(self) -> tuple:
-        """(count, total_seconds) observed on the host-gap family — the
-        aggregate bench.py records as host_gap_ms."""
-        return self.host_gap.snapshot_total()
-
     def render(self, openmetrics: bool = False) -> str:
         return self.registry.render(openmetrics=openmetrics)
 

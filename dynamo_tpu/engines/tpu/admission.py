@@ -507,9 +507,6 @@ class Admitter:
                 # Per-token prefill cost EWMA — the basis for the plane's
                 # prefill-seconds-saved estimate.
                 kv_reuse_plane().note_prefill_cost(dt, int(lens.sum()))
-                # Perf ledger: prefill tokens/s per pow2 chunk bucket (the
-                # attribution sibling of the decode-shape windows).
-                e._perf.observe_prefill(c_bucket, dt, int(lens.sum()))
                 if e._tick_budget_left is not None:
                     e._tick_budget_left -= int(lens.sum())
                 for r in range(rows):

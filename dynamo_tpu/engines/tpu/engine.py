@@ -24,7 +24,6 @@ import asyncio
 import collections
 import functools
 import math
-import socket
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -234,16 +233,7 @@ class _InflightBurst:
     seqs: List[Tuple[int, _Sequence]]
     t_dispatch: float
     occupancy: int
-    # Perf-ledger attribution stamps (runtime/perf_ledger.py), taken at
-    # dispatch so the reap can feed the ledger without recomputing shape:
-    # width bucket + program variant key the fingerprint sentinel judges
-    # on; dispatch host cost, mean context, and the host gap this burst
-    # paid before its dispatch.
-    nb_bucket: int = 0
-    variant: str = ""
-    dispatch_s: float = 0.0
-    avg_ctx: float = 0.0
-    gap_s: float = 0.0
+    nb_bucket: int  # table width the burst ran at (the reap's span names it)
 
 
 # Block-table lookahead reserved by every decode dispatch, in bursts of
@@ -456,26 +446,6 @@ class JaxEngine:
         from dynamo_tpu.engines.metrics import EngineStepMetrics
 
         self.step_metrics = EngineStepMetrics()
-        # Perf ledger (runtime/perf_ledger.py): always-on per-shape decode
-        # attribution + the live regression sentinel. Process-global — the
-        # status server renders/serves the same instance — with this
-        # engine's identity (fingerprint key) and a roofline closure over
-        # its model config installed here. configure() also loads any
-        # persisted fingerprints (corrupt file → counted cold start).
-        from dynamo_tpu.runtime.perf_ledger import global_perf_ledger
-        from dynamo_tpu.runtime.roofline import make_roofline_fn
-
-        self._perf = global_perf_ledger()
-        self._perf.configure(
-            preset=self.config.name,
-            backend=jax.default_backend(),
-            host=socket.gethostname(),
-            roofline_fn=make_roofline_fn(
-                self.config, args.quantization,
-                jax.devices()[0].device_kind,
-            ),
-        )
-
         # Device-plane observability (runtime/device_observe.py):
         # - flight: the tick loop's single-writer event ring (admit,
         #   preempt, dispatch, reap, spec tick, KV transfers, abort). The
@@ -633,11 +603,6 @@ class JaxEngine:
             self._loop_task = None
         self._executor.shutdown(wait=False)
         self._transfer_executor.shutdown(wait=False)
-        # Clean shutdown persists the perf fingerprints this run earned;
-        # after a terminal tick failure the windows describe a degraded
-        # engine, and a degraded baseline is worse than none.
-        if self._failure is None:
-            self._perf.store_fingerprints()
 
     def stats(self) -> Dict[str, Any]:
         """Engine stats for /engine/stats and metric scrapes. While the
@@ -1531,11 +1496,9 @@ class JaxEngine:
             # engine computes for the same burst index.
             inflight_off = K * len(self._inflight)
             max_blocks = 1
-            sum_ctx = 0
             live_pages = 0
             for seq in active:
                 ctx = int(self._pos[seq.slot]) + inflight_off + K
-                sum_ctx += ctx
                 blocks = (ctx - 1) // args.block_size + 1
                 live_pages += blocks
                 max_blocks = max(max_blocks, blocks)
@@ -1559,16 +1522,13 @@ class JaxEngine:
                 self._dispatch_on_device, nb_bucket, want_logprobs,
                 want_procs, state_sync, table_sync,
             )
-        t_dispatched = time.monotonic()
         # Host-gap: how long the device sat idle on host work between the
         # previous burst's readback and this dispatch. When another burst
         # was already in flight the device never waited — observe 0.
-        gap = 0.0
         if self._t_last_ready is not None:
-            gap = 0.0 if had_inflight else max(
-                0.0, t0 - self._t_last_ready
+            self.step_metrics.observe_host_gap(
+                0.0 if had_inflight else max(0.0, t0 - self._t_last_ready)
             )
-            self.step_metrics.observe_host_gap(gap)
         self.step_metrics.observe_inflight(len(self._inflight) + 1)
         self.step_metrics.observe_decode_pages(
             live_pages, args.max_num_seqs * nb_bucket
@@ -1580,12 +1540,6 @@ class JaxEngine:
                 t_dispatch=t0,
                 occupancy=len(active),
                 nb_bucket=nb_bucket,
-                variant=self.runner._variant_label(
-                    nb_bucket, want_logprobs, want_procs
-                ),
-                dispatch_s=t_dispatched - t0,
-                avg_ctx=sum_ctx / len(active),
-                gap_s=gap,
             )
         )
         self.flight.record(
@@ -1655,7 +1609,7 @@ class JaxEngine:
 
     def _emit_reaped(self, rec, toks, logps, topv, topi) -> None:
         """Host half of a reap: stop conditions and one output per row,
-        then the step, budgeter, flight and perf-ledger accounting."""
+        then the step, budgeter and flight accounting."""
         self.steps += 1
         gen0 = self.generated_tokens
         for slot, seq in rec.seqs:
@@ -1687,24 +1641,6 @@ class JaxEngine:
             tokens=self.generated_tokens - gen0,
             dur_ms=round(1000 * (self._t_last_ready - rec.t_dispatch), 3),
         )
-        # Perf ledger: the same burst accounting, decomposed per shape
-        # (width bucket, program variant, fused/fallback path) with the
-        # dispatch/reap host split the stamps above already paid for. The
-        # sentinel comparison itself is time-gated inside evaluate().
-        self._perf.observe_decode(
-            rec.nb_bucket,
-            rec.variant,
-            "fused" if rec.handles.fused else "fallback",
-            self._t_last_ready - rec.t_dispatch,
-            self.generated_tokens - gen0,
-            rec.occupancy,
-            rec.avg_ctx,
-            rec.gap_s,
-            rec.dispatch_s,
-            time.monotonic() - self._t_last_ready,
-            now=self._t_last_ready,
-        )
-        self._perf.evaluate(now=self._t_last_ready)
         self._publish_stats()
 
     async def _drain_inflight(self) -> None:

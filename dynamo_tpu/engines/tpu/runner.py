@@ -81,15 +81,12 @@ _scatter_table_rows = watched_jit(
 @dataclass
 class _DecodeHandles:
     """Un-materialized device results of one dispatched decode burst.
-    Returned by decode_dispatch; decode_read blocks on them. ``fused`` says
-    whether the burst ran the fused-layer megakernel or the XLA decode
-    program."""
+    Returned by decode_dispatch; decode_read blocks on them."""
 
     toks: Any
     logp: Any
     topv: Optional[Any] = None
     topi: Optional[Any] = None
-    fused: bool = False
 
 
 def _scatter_blocks_impl(cache, idx, blocks):
@@ -1085,10 +1082,7 @@ class DeviceRunner:
             )
         else:
             self.mk_fallback_bursts += 1
-        return _DecodeHandles(
-            toks=toks, logp=logp, topv=topv, topi=topi,
-            fused=self.use_megakernel,
-        )
+        return _DecodeHandles(toks=toks, logp=logp, topv=topv, topi=topi)
 
     @staticmethod
     def _variant_label(nb, want_logprobs, use_procs) -> str:

@@ -408,8 +408,7 @@ def all_presets() -> Dict[str, "ModelConfig"]:
     supports-matrix test iterates THIS registry (a new preset is
     automatically checked against the fused path's supports() gate or
     the documented-exclusion table — it can never silently drift to the
-    slow decode path), and bench.py's BENCH_MODEL knob resolves from the
-    same names."""
+    slow decode path)."""
     presets = [
         tiny_config(), tiny_moe_config(), mixtral_8x7b_config(),
         qwen2_500m_config(), llama3_8b_config(), llama3_3b_config(),

@@ -45,12 +45,11 @@ decode on the fused path:
     switch (tanh-gelu vs SiLU), ``(1 + w)`` norm weights, and per-column
     bias adds on the qkv tiles.
 
-Why this exists (r5): the per-layer XLA decode structure leaves the chip at
-~1/3 of its HBM roofline at the 8B shape — a device trace showed ~490
-fusions + ~390 copies per step of inter-op glue, a DMA-issue-bound
-standalone attention kernel (190µs/layer vs ~80µs of page bytes), and
-weight matmuls at 663 GB/s that a pallas mixed int8 dot beats at 726 GB/s
-(measured, `_prof_fused_ffn.py`). Fusing the whole layer removes the glue,
+Why this exists (r5): the per-layer XLA decode structure leaves inter-op
+glue (fusions and copies between every matmul), a standalone attention
+kernel bound by DMA issue and not by page bytes, and weight matmuls a
+pallas mixed int8 dot can beat (PERF.md holds what has been measured).
+Fusing the whole layer removes the glue,
 overlaps attention page fetches with weight streaming, and keeps the
 residual in VMEM across phases.
 

@@ -86,7 +86,7 @@ class ParserPlane:
         self.flight = FlightRecorder("parser", capacity=1024)
         self.metrics = ParserMetrics()
         self.peak_buffered = 0
-        # Lifetime counters (bench legs + /debug snapshots read these;
+        # Lifetime counters (tests + /debug snapshots read these;
         # the metric families are their scrapeable form).
         self.calls = 0
         self.degrades: Dict[str, int] = {}

@@ -345,8 +345,7 @@ class HbmLedger:
     Samplers run at snapshot/scrape time only (never on the tick thread)
     and read live object references — a category whose sampler throws
     reports -1 (visible as "unknown" rather than silently zero). The
-    ledger also tracks the peak total it has ever observed, which
-    bench.py records per leg."""
+    ledger also tracks the peak total it has ever observed."""
 
     def __init__(self) -> None:
         self._sources: Dict[str, Callable[[], int]] = {}

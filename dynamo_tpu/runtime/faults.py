@@ -29,9 +29,9 @@ Design rules:
     same registry.
 
 The module also aggregates process-wide *recovery activity* counters
-(``note_activity``): retries, breaker transitions, migrations. bench.py
-records them in every leg so a chaos-free run proves zero spurious
-activations of the self-healing paths.
+(``note_activity``): retries, breaker transitions, migrations. The soak
+tests read them so a chaos-free run proves zero spurious activations of
+the self-healing paths.
 """
 
 from __future__ import annotations
@@ -285,8 +285,8 @@ class FaultPlane:
 _PLANE: Optional[FaultPlane] = None
 
 # Process-wide recovery-activity counters (retry/breaker/migration events),
-# counted whether or not a plane is armed: bench legs record them so a
-# chaos-free run PROVES the self-healing paths sat idle.
+# counted whether or not a plane is armed: the chaos-free tests read them
+# to prove the self-healing paths sat idle.
 _ACTIVITY: Dict[str, int] = {}
 
 
@@ -352,7 +352,7 @@ def reset_activity() -> None:
 
 
 def plane_snapshot() -> Dict[str, Any]:
-    """Fault-plane state for bench legs / debug surfaces: armed flag,
+    """Fault-plane state for tests / debug surfaces: armed flag,
     per-point injections, and the recovery-activity counters."""
     plane = _PLANE
     return {

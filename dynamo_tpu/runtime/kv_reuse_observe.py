@@ -21,9 +21,7 @@ trajectory plane's sibling, design: docs/design_docs/kv_reuse_observability.md):
 
 Hot-path budget: every feed is O(1) amortized (dict lookup + heap push)
 and rides admission / stream-end paths — OUTSIDE the DYN002 decode tick
-scope — so the plane stays under the 1%/burst observe-overhead bar
-(``_prof_gap.py``). Feeds never raise: observability must not take down
-serving.
+scope. Feeds never raise: observability must not take down serving.
 """
 
 from __future__ import annotations

@@ -397,48 +397,6 @@ PARSER_JAIL_BUFFERED_PEAK_CHARS = (
     f"{PARSER_PREFIX}_jail_buffered_peak_chars"
 )
 
-# -- perf ledger (runtime/perf_ledger.py PerfLedger) --------------------------
-PERF_PREFIX = "dynamo_tpu_perf"
-# Rolling-window median step wall time per (width, variant, path) decode
-# shape — the always-on attribution the regression sentinel judges.
-PERF_STEP_P50_SECONDS = f"{PERF_PREFIX}_step_p50_seconds"
-# Rolling-window p99 step wall time per shape — tail drift shows here
-# before the median moves.
-PERF_STEP_P99_SECONDS = f"{PERF_PREFIX}_step_p99_seconds"
-# Rolling-window median host gap (CPU time the device sat idle between
-# reap and the next dispatch) per shape.
-PERF_HOST_GAP_P50_SECONDS = f"{PERF_PREFIX}_host_gap_p50_seconds"
-# Rolling-window median dispatch-side host cost per shape (the portion of
-# the step spent building + launching the burst).
-PERF_DISPATCH_P50_SECONDS = f"{PERF_PREFIX}_dispatch_p50_seconds"
-# Rolling-window median reap-side host cost per shape (device_get + state
-# update after the burst completed).
-PERF_REAP_P50_SECONDS = f"{PERF_PREFIX}_reap_p50_seconds"
-# Rolling-window decode throughput (tokens/s) per shape.
-PERF_TOKENS_PER_SEC = f"{PERF_PREFIX}_tokens_per_sec"
-# Measured tok/s divided by the pure-arithmetic bandwidth roofline
-# (runtime/roofline.py, the same model bench's 70B projection leg uses)
-# at the window's median occupancy and context — 1.0 is the HBM wall.
-PERF_ROOFLINE_FRACTION = f"{PERF_PREFIX}_roofline_fraction"
-# Rolling-window prefill throughput (tokens/s) per pow2 chunk bucket,
-# from the admission loop's per-round stamps.
-PERF_PREFILL_TOKENS_PER_SEC = f"{PERF_PREFIX}_prefill_tokens_per_sec"
-# Live samples currently inside each shape's rolling window (TTL-pruned);
-# verdicts are withheld below the min-sample floor.
-PERF_WINDOW_SAMPLES = f"{PERF_PREFIX}_window_samples"
-# Typed perf anomalies raised by the sentinel, labeled by kind
-# (step_regression | toks_regression) — the lint-pinned counter ISSUE 19
-# pages on.
-PERF_ANOMALIES_TOTAL = f"{PERF_PREFIX}_anomalies_total"
-# Steady-state fingerprints loaded from the persisted ledger at startup
-# (0 on cold start).
-PERF_FINGERPRINT_LOADED = f"{PERF_PREFIX}_fingerprints_loaded"
-# Fingerprint persistence failures by op (load | store) — a corrupt or
-# vanished file degrades to cold start and counts here, never crashes.
-PERF_FINGERPRINT_FAILURES_TOTAL = (
-    f"{PERF_PREFIX}_fingerprint_failures_total"
-)
-
 # -- SLO plane (runtime/trajectory.py SloTracker) -----------------------------
 SLO_PREFIX = "dynamo_tpu_slo"
 # Rolling-window fraction of finished streams that met BOTH the TTFT and
@@ -636,19 +594,4 @@ ALL_ENGINE = (
     ENGINE_REQUEST_DECODE_TOKENS_TOTAL,
     ENGINE_DECODE_LIVE_PAGES_TOTAL,
     ENGINE_DECODE_TABLE_SLOTS_TOTAL,
-)
-
-ALL_PERF = (
-    PERF_STEP_P50_SECONDS,
-    PERF_STEP_P99_SECONDS,
-    PERF_HOST_GAP_P50_SECONDS,
-    PERF_DISPATCH_P50_SECONDS,
-    PERF_REAP_P50_SECONDS,
-    PERF_TOKENS_PER_SEC,
-    PERF_ROOFLINE_FRACTION,
-    PERF_PREFILL_TOKENS_PER_SEC,
-    PERF_WINDOW_SAMPLES,
-    PERF_ANOMALIES_TOTAL,
-    PERF_FINGERPRINT_LOADED,
-    PERF_FINGERPRINT_FAILURES_TOTAL,
 )

@@ -198,8 +198,8 @@ class Histogram(_Metric):
 
     def snapshot_total(self, **labels: object) -> Tuple[int, float]:
         """(observation count, value sum) for one label key — the cheap
-        aggregate programmatic consumers (bench.py host-gap reporting)
-        read without parsing the rendered exposition."""
+        aggregate programmatic consumers read without parsing the
+        rendered exposition."""
         key = self._key(labels)
         with self._lock:
             return (

@@ -43,8 +43,8 @@ saturation tests replay bit-identically instead of racing wall clocks.
 
 Process-wide ``note_activity`` counters (``sheds``,
 ``brownout_transitions``, ``deadline_expired``) extend the PR 7
-zero-spurious-activation contract: bench legs record them, so a chaos-free
-under-capacity run PROVES the overload plane sat idle.
+zero-spurious-activation contract: a chaos-free under-capacity run leaves
+them unchanged, which is how the tests prove the overload plane sat idle.
 """
 
 from __future__ import annotations
@@ -322,7 +322,7 @@ class OverloadController:
         server.register_flight(self.flight.name, self.flight.snapshot)
 
     def snapshot(self) -> Dict[str, Any]:
-        """Controller state for bench legs / debug surfaces."""
+        """Controller state for tests / debug surfaces."""
         return {
             "state": STATE_NAMES[self._state],
             "active": self._active,

@@ -229,18 +229,6 @@ class SystemStatusServer:
             # KV-reuse plane (ALL_KVCACHE hit-rate/ROI/sketch gauges):
             # process-global, one sketch per process.
             self.register_metrics(render_kv_reuse_metrics)
-            # Perf ledger (ALL_PERF attribution gauges + anomaly counter,
-            # plus its "perf" flight ring): process-global, one ledger per
-            # process — the engine feeds it, every server exposes it.
-            from dynamo_tpu.runtime.perf_ledger import (
-                global_perf_ledger,
-                render_perf_metrics,
-            )
-
-            self.register_metrics(render_perf_metrics)
-            self.register_flight(
-                "perf", global_perf_ledger().flight.snapshot
-            )
             self._runtime_metrics_registered = True
         app = web.Application()
         app.router.add_get("/health", self._health)
@@ -263,7 +251,6 @@ class SystemStatusServer:
         app.router.add_get(
             "/debug/kvcache/prefixes", self._debug_kvcache_prefixes
         )
-        app.router.add_get("/debug/perf", self._debug_perf)
         app.router.add_get("/debug/memory", self._debug_memory)
         app.router.add_get("/debug/compiles", self._debug_compiles)
         app.router.add_get("/debug/flight", self._debug_flight)
@@ -441,13 +428,6 @@ class SystemStatusServer:
         except ValueError:
             k = 50
         return web.json_response(kvcache_prefixes(k=k))
-
-    # -- perf ledger (runtime/perf_ledger.py) ------------------------------
-
-    async def _debug_perf(self, request: web.Request) -> web.Response:
-        from dynamo_tpu.runtime.perf_ledger import perf_index
-
-        return web.json_response(perf_index())
 
     # -- device-plane debug surface (runtime/device_observe.py) ------------
 

@@ -475,7 +475,7 @@ class SloTracker:
             self.phase_p99.set(round(p99, 3), phase=phase)
 
     def snapshot(self) -> Dict[str, Any]:
-        """SLO state for bench legs / debug surfaces."""
+        """SLO state for tests / debug surfaces."""
         self._refresh()
         labels = [_window_label(w) for w in self.windows]
         return {

@@ -2,16 +2,15 @@
 platform a serving process may run on.
 
 Every process entry that compiles (worker, cli run, encode worker,
-bench.py, the bench children, tests/conftest.py, the root probe scripts)
-calls :func:`configure_compile_cache` once, before its first compile. No
-other code sets ``jax_compilation_cache_dir``
+tests/conftest.py) calls :func:`configure_compile_cache` once, before its
+first compile. No other code sets ``jax_compilation_cache_dir``
 (tests/test_jax_env.py greps for it). The path is fixed because a
 cache that moves never hits: a second start of the same worker is served
 from the first one's compiles only if both name the same directory.
 
 jax is imported inside the functions so parents that must stay off the
-chip (chip_smoke.py, bench/restart.py) can resolve the directory for their
-children without initialising a backend.
+chip (chip_smoke.py) can resolve the directory for their children without
+initialising a backend.
 """
 
 from __future__ import annotations

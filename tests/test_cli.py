@@ -229,59 +229,6 @@ async def test_observe_kvcache_against_live_worker(capsys):
         await engine.stop()
 
 
-async def test_observe_perf_against_live_worker(capsys):
-    """`dynamo-tpu observe perf` pretty-prints the perf ledger (per-shape
-    decode attribution + the live sentinel's verdicts) from a live
-    in-process worker's /debug/perf endpoint."""
-    import argparse
-
-    from dynamo_tpu.cli.run import add_observe_args, main_observe
-    from dynamo_tpu.runtime.system_server import (
-        SystemStatusServer,
-        attach_engine,
-    )
-    from tests.test_jax_engine import make_engine, req, run_one
-
-    engine, _ = make_engine()
-    server = SystemStatusServer(host="127.0.0.1", port=0)
-    attach_engine(server, engine)
-    await server.start()
-    try:
-        await run_one(engine, req(range(10, 26), max_tokens=6))
-        parser = argparse.ArgumentParser()
-        add_observe_args(parser)
-        args = parser.parse_args(["perf", "--port", str(server.port)])
-        await main_observe(args)
-        out = capsys.readouterr().out
-        assert "perf ledger" in out and "sentinel" in out
-        assert "step p50" in out and "tok/s" in out
-        assert "fingerprints_loaded=" in out
-
-        args = parser.parse_args(
-            ["perf", "--port", str(server.port), "--json"]
-        )
-        await main_observe(args)
-        doc = json.loads(capsys.readouterr().out)
-        assert doc["identity"]["preset"] == engine.config.name
-        # The engine's real decode bursts fed the ledger: at least one
-        # attributed shape row with samples and a step median.
-        assert doc["decode"] and doc["decode"][0]["samples"] >= 1
-        assert doc["decode"][0]["step_p50_s"] > 0.0
-        assert doc["decode"][0]["path"] in ("fused", "fallback")
-        # /metrics carries the lint-pinned ALL_PERF family.
-        import aiohttp
-
-        async with aiohttp.ClientSession() as s:
-            url = f"http://127.0.0.1:{server.port}/metrics"
-            async with s.get(url) as r:
-                body = await r.text()
-        assert "dynamo_tpu_perf_step_p50_seconds" in body
-        assert "dynamo_tpu_perf_tokens_per_sec" in body
-    finally:
-        await server.stop()
-        await engine.stop()
-
-
 async def test_debug_kvcache_200_without_engine():
     """/debug/kvcache serves 200 on a bare system server (mock attach /
     partial engine): the plane is process-global, never engine-owned."""

@@ -388,3 +388,141 @@ def test_kill9_single_death_recovers_quickly():
             w.stop()
         for p in reversed(procs):
             p.stop()
+
+
+async def test_silent_death_of_a_real_engine_loses_no_stream():
+    """The same death with real engines in one process, which the mocker
+    cluster above cannot show: a worker that goes SILENT mid-decode (no
+    FIN, no error: what a frontend sees of kill -9) is declared dead from
+    its missed load reports alone, evicted BEFORE its streams are
+    aborted, and every stream finishes full-length and token-exact on the
+    peer through migration's re-prefill. Nothing is re-dispatched onto
+    the corpse."""
+    from dynamo_tpu.engines.tpu import JaxEngine, JaxEngineArgs
+    from dynamo_tpu.llm.migration import Migration
+    from dynamo_tpu.llm.protocols.common import (
+        PreprocessedRequest,
+        SamplingOptions,
+        StopConditions,
+    )
+    from dynamo_tpu.models.config import tiny_config
+    from dynamo_tpu.runtime.context import Context
+    from dynamo_tpu.runtime.distributed import DistributedRuntime
+    from dynamo_tpu.runtime.engine import collect
+    from dynamo_tpu.runtime.liveness import (
+        DEAD,
+        LivenessConfig,
+        LivenessTracker,
+        WorkerLostError,
+    )
+    from dynamo_tpu.runtime.tasks import reap_task
+
+    def mk_engine():
+        return JaxEngine(JaxEngineArgs(
+            config=tiny_config(), block_size=4, num_kv_blocks=128,
+            max_num_seqs=4, max_model_len=256, prefill_chunk=32,
+            decode_steps=4, seed=9,
+        ))
+
+    def mk_req(i):
+        return PreprocessedRequest(
+            token_ids=list(range(10 + 7 * i, 26 + 7 * i)),
+            request_id=f"crash-{i}",
+            sampling=SamplingOptions(temperature=0.0),
+            stop=StopConditions(max_tokens=32, ignore_eos=True),
+        )
+
+    def toks_of(outs):
+        return [
+            t for o in outs
+            for t in ((o.get("token_ids") if isinstance(o, dict) else o.token_ids) or [])
+        ]
+
+    oracle, source, peer = mk_engine(), mk_engine(), mk_engine()
+
+    class Crashable:
+        """Serves until its third output, then never says anything again."""
+
+        def __init__(self, engine):
+            self.engine = engine
+            self.dead = asyncio.Event()
+            self.outputs = 0
+            self.calls_after_death = 0
+
+        async def generate(self, request, context):
+            if self.dead.is_set():
+                self.calls_after_death += 1
+            async for out in self.engine.generate(request, context):
+                if self.outputs >= 3:
+                    self.dead.set()
+                if self.dead.is_set():
+                    await asyncio.Event().wait()
+                self.outputs += 1
+                yield out
+
+    rt = DistributedRuntime.detached()
+    crash_src = Crashable(source)
+    ep = rt.namespace("crash").component("backend").endpoint("generate")
+    served = [
+        await ep.serve_endpoint(crash_src.generate, instance_id=1),
+        await ep.serve_endpoint(peer.generate, instance_id=2),
+    ]
+    client = await ep.client()
+    await client.wait_for_instances()
+    client.enable_stream_aborts()
+    order = []
+
+    def on_dead(wid, _inc):
+        order.append(("evicted", client.evict_instance(wid)))
+        order.append(("aborted", client.abort_instance(
+            wid, WorkerLostError(f"worker {wid} dead (missed reports)"))))
+
+    tracker = LivenessTracker(
+        LivenessConfig(interval_s=0.05, suspect_after=2, dead_after=4),
+        on_dead=on_dead,
+    )
+
+    async def liveness_loop():
+        while True:
+            if not crash_src.dead.is_set():
+                tracker.observe_report(1, 1001)
+            tracker.observe_report(2, 1002)
+            tracker.evaluate()
+            await asyncio.sleep(0.02)
+
+    liveness_task = asyncio.ensure_future(liveness_loop())
+    mig = Migration(migration_limit=3)
+    try:
+        want = [
+            toks_of(await collect(oracle.generate(mk_req(i), Context())))
+            for i in range(6)
+        ]
+        got = await asyncio.wait_for(
+            asyncio.gather(*(
+                collect(mig.generate(mk_req(i), Context(), client))
+                for i in range(6)
+            )),
+            timeout=120,
+        )
+        for i, outs in enumerate(got):
+            errs = [
+                o.get("error") if isinstance(o, dict) else o.error for o in outs
+            ]
+            assert not any(errs), (i, errs)
+            assert toks_of(outs) == want[i], f"stream {i} diverged"
+        assert crash_src.dead.is_set(), "the source never died; scenario dead"
+        assert tracker.state_of(1) == DEAD
+        assert order[0] == ("evicted", True)
+        assert order[1][0] == "aborted" and order[1][1] >= 1
+        assert crash_src.calls_after_death == 0
+        # the unplanned path pays re-prefill (the drain's handoff pays none)
+        assert mig.metrics.reprefill_tokens.value() > 0
+        assert mig.metrics.migrations.value(reason="worker_lost") == order[1][1]
+    finally:
+        liveness_task.cancel()
+        await reap_task(liveness_task, "liveness loop")
+        for s in served:
+            await s.shutdown(grace_period=1)
+        await rt.shutdown(grace_period=1)
+        for e in (oracle, source, peer):
+            await e.stop()

@@ -52,6 +52,42 @@ def req(tokens, max_tokens=8):
     )
 
 
+async def serve_disagg(rt, namespace, prefill_engine, decode_engine, **exporter_kw):
+    """A prefill worker (generate + kv endpoints, instance 1) and a decode
+    worker (instance 2) on ``rt``, behind a PrefillRouter. Returns the
+    served endpoints, the decode handler and the pipeline."""
+    ns = rt.namespace(namespace)
+    pc = ns.component("prefill")
+    served = [
+        await pc.endpoint("generate").serve_endpoint(
+            PrefillHandler(prefill_engine, worker_id=1).generate, instance_id=1
+        ),
+        await pc.endpoint("kv").serve_endpoint(
+            KvTransferHandler(prefill_engine, **exporter_kw).generate, instance_id=1
+        ),
+    ]
+
+    async def kv_client():
+        return await pc.endpoint("kv").client()
+
+    dc = ns.component("backend")
+    decode_handler = DecodeHandler(decode_engine, kv_client_factory=kv_client)
+    served.append(
+        await dc.endpoint("generate").serve_endpoint(
+            decode_handler.generate, instance_id=2
+        )
+    )
+    decode_client = await dc.endpoint("generate").client()
+
+    async def prefill_client():
+        return await pc.endpoint("generate").client()
+
+    pipeline = build_pipeline(
+        [PrefillRouter(prefill_client, threshold_tokens=8)], decode_client
+    )
+    return served, decode_handler, pipeline
+
+
 async def test_export_import_roundtrip():
     """Blocks exported from one engine and imported into another must make
     the second engine's prefix cache hit (and produce identical logits —
@@ -110,38 +146,10 @@ async def test_disaggregated_equals_aggregated():
     prefill_engine = make_engine(seed=3)
     decode_engine = make_engine(seed=3)
     oracle_engine = make_engine(seed=3)
-    ns = rt.namespace("t")
     served = []
     try:
-        pc = ns.component("prefill")
-        served.append(
-            await pc.endpoint("generate").serve_endpoint(
-                PrefillHandler(prefill_engine, worker_id=1).generate, instance_id=1
-            )
-        )
-        served.append(
-            await pc.endpoint("kv").serve_endpoint(
-                KvTransferHandler(prefill_engine).generate, instance_id=1
-            )
-        )
-
-        async def kv_client():
-            return await pc.endpoint("kv").client()
-
-        dc = ns.component("backend")
-        decode_handler = DecodeHandler(decode_engine, kv_client_factory=kv_client)
-        served.append(
-            await dc.endpoint("generate").serve_endpoint(
-                decode_handler.generate, instance_id=2
-            )
-        )
-        decode_client = await dc.endpoint("generate").client()
-
-        async def prefill_client():
-            return await pc.endpoint("generate").client()
-
-        pipeline = build_pipeline(
-            [PrefillRouter(prefill_client, threshold_tokens=8)], decode_client
+        served, _decode_handler, pipeline = await serve_disagg(
+            rt, "t", prefill_engine, decode_engine
         )
 
         prompt = list(range(60, 78))  # 18 tokens: 4 full blocks + tail
@@ -319,3 +327,58 @@ async def test_export_readback_overlaps_decode():
     finally:
         engine.runner.gather_blocks_readback = real_readback
         await engine.stop()
+
+
+async def test_concurrent_disagg_wave_is_token_exact_and_heals_nothing():
+    """More streams than decode slots through the whole disagg path at
+    once: every stream equals the aggregated engine's, every one pulled
+    its prefix, the bytes are accounted per wire dtype, the link's
+    bandwidth is learned, and with no fault armed the self-healing paths
+    (pull retries, breaker, aggregated fallback, migration) never ran."""
+    from dynamo_tpu.runtime import faults
+
+    rt = DistributedRuntime.detached()
+    prefill_engine = make_engine(seed=3)
+    decode_engine = make_engine(seed=3, max_num_seqs=2)
+    oracle_engine = make_engine(seed=3)
+    served = []
+    try:
+        served, decode_handler, pipeline = await serve_disagg(
+            rt, "twave", prefill_engine, decode_engine
+        )
+
+        prompts = [list(range(20 + 19 * i, 38 + 19 * i)) for i in range(5)]
+        want = []
+        for p in prompts:
+            out = await collect(oracle_engine.generate(req(p, max_tokens=10), Context()))
+            want.append([t for o in out for t in o.token_ids])
+
+        activity0 = faults.activity_snapshot()
+
+        async def one(p):
+            toks = []
+            async for o in pipeline.generate(req(p, max_tokens=10).to_dict(), Context()):
+                toks.extend(
+                    (o.token_ids if hasattr(o, "token_ids") else o.get("token_ids"))
+                    or []
+                )
+            return toks
+
+        got = await asyncio.gather(*(one(p) for p in prompts))
+        assert got == want
+        assert decode_handler.transfers == len(prompts)
+        assert decode_handler.blocks_pulled >= 4 * len(prompts)
+        assert sum(decode_handler.wire_bytes_by_dtype.values()) == \
+            decode_handler.bytes_pulled > 0
+        assert decode_handler.link_bandwidth()[1] > 0
+        assert (
+            decode_handler.transfer_failures, decode_handler.pull_retries,
+            decode_handler.breaker_opens, decode_handler.pull_fallbacks,
+        ) == (0, 0, 0, 0)
+        assert faults.activity_snapshot() == activity0
+    finally:
+        for s in served:
+            await s.shutdown(grace_period=1)
+        for e in (prefill_engine, decode_engine, oracle_engine):
+            await e.stop()
+        await rt.shutdown(grace_period=1)

@@ -260,6 +260,57 @@ async def test_drain_under_concurrent_load_drops_nothing():
             await e.stop()
 
 
+async def test_every_trigger_joins_the_one_drain():
+    """The signal handler, POST /drain and a preStop hook may all fire.
+    The first trigger starts the drain; every later one gets the same
+    task, an awaiter that gives up does not abort it, the live stream is
+    handed off once and completes token-exact."""
+    oracle = make_engine(seed=5)
+    source = make_engine(seed=5)
+    peer = make_engine(seed=5)
+    try:
+        prompt = list(range(40, 56))
+        want = toks_of(await collect(oracle.generate(req(prompt, 40), Context())))
+
+        ctrl = make_controller(source, {2: HandoffHandler(peer)})
+        mig = Migration(migration_limit=3)
+        got = []
+        got_some = asyncio.Event()
+
+        async def consume():
+            async for out in mig.generate(req(prompt, 40), Context(), source):
+                assert not out.error, out.error
+                got.extend(out.token_ids or [])
+                if len(got) >= 3:
+                    got_some.set()
+
+        task = asyncio.create_task(consume())
+        await got_some.wait()
+        first = ctrl.trigger()  # what loop.add_signal_handler(SIGTERM, ...) calls
+        assert ctrl.trigger() is first
+        assert ctrl.trigger(deadline_s=1.0) is first
+        assert ctrl.deadline_s == 30.0  # a late override is refused, not applied
+        impatient = asyncio.create_task(ctrl.drain())
+        await asyncio.sleep(0)
+        impatient.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await impatient
+        assert not first.cancelled()
+        status = await ctrl.drain()
+        await task
+
+        assert first.done() and ctrl.state == DRAINED
+        assert got == want
+        assert status["handoffs"] == 1
+        assert peer.handoffs_adopted == 1
+        assert source.handoffs_exported == 1
+        assert mig.metrics.reprefill_tokens.value() == 0
+        assert (await ctrl.drain()) == status  # a trigger after the end changes nothing
+    finally:
+        for e in (oracle, source, peer):
+            await e.stop()
+
+
 # ---------------------------------------------------------------------------
 # The ladder under seeded chaos
 # ---------------------------------------------------------------------------

@@ -215,6 +215,56 @@ async def test_sustained_shift_does_actuate_after_streak():
     assert fleet.verify_streams() == []
 
 
+async def test_chaos_free_ramp_converges_both_ways_and_heals_nothing():
+    """Open-loop load 1x -> 4x -> 1x with nothing injected: after each
+    shift the plan and the ready fleet meet and stay met (within the
+    window, both directions), the fleet grew and shrank, scaling down
+    re-prefilled nothing, no stream was lost, and no worker that kept
+    reporting was ever declared dead."""
+    cfg = sim_config(seed=29)
+    shifts = (15.0, 35.0)
+
+    def rate(t):
+        if t < shifts[0] or shifts[1] <= t < 55.0:
+            return 30.0  # about five SLA-sized workers
+        return 120.0 if t < shifts[1] else 0.0
+
+    fleet, planner, ctl = build_loop(
+        cfg, 5, rate,
+        planner_over=dict(max_replicas=64, total_chip_budget=128),
+        elastic_over=dict(actuation_deadline_s=20.0),
+    )
+    timeline = []
+    for _ in range(58):
+        fleet.run(1.0)
+        plan = await planner.step()
+        timeline.append(
+            (fleet.now, plan.decode if plan else None, fleet.ready_count("decode"))
+        )
+    fleet.settle(180.0)
+
+    def converged_after(shift_t):
+        """Intervals from the shift until desired == ready for three in a row."""
+        after = [i for i, (t, _w, _h) in enumerate(timeline) if t > shift_t]
+        for n, i in enumerate(after):
+            if all(w is not None and w == h for _t, w, h in timeline[i:i + 3]):
+                return n + 1
+        return None
+
+    up, down = converged_after(shifts[0]), converged_after(shifts[1])
+    assert up is not None and up <= shifts[1] - shifts[0], timeline
+    assert down is not None and down <= 58 - shifts[1], timeline
+    start = timeline[0][2]
+    assert max(h for _t, _w, h in timeline) >= 2 * start
+    assert timeline[-1][2] < max(h for _t, _w, h in timeline)
+    assert ctl.scale_ups >= 1 and ctl.scale_downs >= 1
+    assert len(ctl.drained_workers) >= 1
+    assert fleet.verify_streams() == []
+    assert fleet.drain_reprefill_tokens == 0
+    assert fleet.false_positive_deaths == []
+    assert fleet.migrated_streams == 0  # nothing died: nothing migrated
+
+
 async def test_state_machine_transitions_and_gauge():
     cfg = sim_config()
     fleet, planner, ctl = build_loop(cfg, 2, lambda t: 4.0 if t < 3 else 26.0)

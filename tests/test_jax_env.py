@@ -17,8 +17,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _sources():
-    """Every Python file the repo ships: the package, the tests, bench.py,
-    chip_smoke.py and the root probe scripts."""
+    """Every Python file the repo ships: the package, the tests,
+    chip_smoke.py and the driver's entry at the root."""
     files = glob.glob(os.path.join(REPO, "*.py"))
     for top in ("dynamo_tpu", "tests"):
         files += glob.glob(os.path.join(REPO, top, "**", "*.py"), recursive=True)

@@ -68,7 +68,7 @@ def test_all_family_tuples_are_canonical_and_exported():
     families = ("ALL_FRONTEND", "ALL_ROUTER", "ALL_KVBM", "ALL_KVCACHE",
                 "ALL_DISAGG", "ALL_ENGINE", "ALL_RUNTIME", "ALL_MIGRATION",
                 "ALL_FAULTS", "ALL_OVERLOAD", "ALL_DRAIN", "ALL_LIVENESS",
-                "ALL_PLANNER", "ALL_SLO", "ALL_PARSER", "ALL_PERF")
+                "ALL_PLANNER", "ALL_SLO", "ALL_PARSER")
     for family in families:
         tup = getattr(rt, family)
         assert tup and isinstance(tup, tuple)

@@ -642,6 +642,69 @@ async def test_admission_holds_at_high_watermark_instead_of_preempting():
         await engine.stop()
 
 
+
+async def test_armor_in_front_of_a_real_engine_sheds_before_any_prefill():
+    """The controller in front of a JaxEngine, ten arrivals at once against
+    two slots and a queue of two: the excess is shed typed before it costs
+    the engine a token, a budget that dies in the queue (the admission
+    seam's injected timeout) never reaches the engine either, and every
+    admitted stream is the engine's own full-length greedy stream."""
+    engine = _engine()
+    ctrl = OverloadController(
+        OverloadConfig(max_concurrency=2, max_queue_depth=2,
+                       max_queue_delay_s=60.0)
+    )
+    prompts = [list(range(10 + 9 * i, 26 + 9 * i)) for i in range(10)]
+
+    async def one(i):
+        ctx = Context()
+        try:
+            ticket = await ctrl.admit(ctx, request_id=f"r{i}")
+        except OverloadShedError as exc:
+            return ("shed", exc.reason)
+        try:
+            outs = await collect(
+                engine.generate(_req(prompts[i], max_tokens=12, rid=f"r{i}"), ctx)
+            )
+            assert not any(o.error for o in outs), outs
+            return ("ok", [t for o in outs for t in (o.token_ids or [])])
+        finally:
+            ctrl.release(ticket)
+
+    try:
+        want = []
+        for i in range(5):
+            outs = await collect(
+                engine.generate(_req(prompts[i], max_tokens=12), Context())
+            )
+            want.append([t for o in outs for t in (o.token_ids or [])])
+        prefill0 = engine.prefill_tokens
+
+        # 0,1 take the slots; the seam expires 2 as it joins the queue;
+        # 3 and 4 queue; 5..9 find the queue full.
+        plan = faults.FaultPlan(seed=3, rules=(
+            faults.FaultRule(point=fn.OVERLOAD_ADMIT, at=(1,), kind="timeout"),
+        ))
+        with faults.armed(plan):
+            got = await asyncio.gather(*(one(i) for i in range(10)))
+        assert got[:2] == [("ok", want[0]), ("ok", want[1])]
+        assert got[2] == ("shed", "deadline_expired")
+        assert got[3:5] == [("ok", want[3]), ("ok", want[4])]
+        assert got[5:] == [("shed", "queue_full")] * 5
+        # four streams reached the engine; prefix hits may spare tokens,
+        # a shed request can add none
+        assert engine.prefill_tokens - prefill0 <= 4 * len(prompts[0])
+        assert engine.deadline_sheds == 0
+        assert {e["request_id"] for e in engine.flight.snapshot()
+                if e["kind"] == "admit"} <= {"r", "r0", "r1", "r3", "r4"}
+        snap = ctrl.snapshot()
+        assert snap["sheds"] == {"queue_full": 5, "deadline_expired": 1}
+        assert snap["admitted"] == 4
+        assert snap["queue_depth"] == 0
+        assert ctrl.peak_queue_depth <= 2
+    finally:
+        await engine.stop()
+
 # -- HTTP frontend: saturation acceptance + error taxonomy --------------------
 
 

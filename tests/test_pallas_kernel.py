@@ -1,7 +1,8 @@
 """Pallas paged-attention kernel vs the XLA oracle.
 
 Runs the kernel in interpret mode (CPU CI); the same kernel compiles via
-Mosaic on real TPU (exercised by bench.py and the driver's bench run).
+Mosaic on real TPU (exercised by chip_smoke.py's kernels stage and the
+driver's benchmark run).
 """
 
 import numpy as np

@@ -198,24 +198,3 @@ async def test_spec_concurrent_batch_equivalence():
     finally:
         await plain.stop()
         await spec.stop()
-
-
-def test_spec_breakeven_harness_smoke():
-    """The break-even bench marshals DeviceRunner's private program
-    signatures directly — this smoke run breaks loudly if that contract
-    drifts (review finding: no other coverage ties them together)."""
-    from dynamo_tpu.bench.spec_breakeven import measure
-
-    out = measure(model="tiny", quant=None, batch=2, ctx=12, spec_k=2,
-                  block_size=8, iters=2)
-    assert out["t_decode_ms_per_token_step"] > 0
-    assert out["t_verify_ms"] > 0
-    # The rate is a RATIO of two wall-time measurements (iters=2): under
-    # full-suite contention on the 1-core host it can legitimately exceed
-    # spec_k (= "spec cannot win at this measured shape"), so the smoke
-    # gate is finite-and-nonnegative — the marshalling contract — not a
-    # bound derived from timing.
-    import math
-
-    rate = out["break_even_acceptance_rate"]
-    assert rate >= 0 and math.isfinite(rate), out

@@ -284,13 +284,8 @@ async def test_every_debug_route_returns_json_against_mock_engine():
         assert set(debug_paths) == {
             "/debug/requests", "/debug/traces", "/debug/memory",
             "/debug/compiles", "/debug/flight", "/debug/trajectory",
-            "/debug/kvcache", "/debug/kvcache/prefixes", "/debug/perf",
+            "/debug/kvcache", "/debug/kvcache/prefixes",
         }
-        # /debug/perf on a mock attach: the ledger is process-global, so
-        # the verdict body serves even with no decode samples yet.
-        status, body = await _get(server.port, "/debug/perf")
-        assert status == 200
-        assert "decode" in body and "verdicts" in body
         for path in debug_paths:
             status, body = await _get(server.port, path)
             assert status == 200, (path, body)
@@ -366,7 +361,7 @@ async def test_debug_device_routes_reflect_live_engine():
 
         status, body = await _get(server.port, "/debug/flight")
         assert status == 200
-        assert set(body["rings"]) == {"engine", "runner", "perf"}
+        assert set(body["rings"]) == {"engine", "runner"}
         kinds = {e["kind"] for e in body["events"]}
         assert {"admit", "dispatch", "reap", "finish", "decode"} <= kinds
         ts = [e["t_mono"] for e in body["events"]]

@@ -644,3 +644,66 @@ class TestWatermarkRollover:
             assert engine.stats()["budget_rollovers"] > 0
         finally:
             await engine.stop()
+
+
+# -- interleaving: what the budget is for ------------------------------------
+
+
+class TestInterleaving:
+    async def _wave_on_a_decoding_stream(self, **budget):
+        """Stream a decodes; long-prompt b (80 tokens, chunk 32) arrives
+        once a has emitted. Returns the tick loop's event kinds from b's
+        arrival to b's install, and both streams' lengths."""
+        engine = JaxEngine(_eng_args(max_num_seqs=2, **budget))
+        try:
+            a_outs = []
+
+            async def consume_a():
+                async for o in engine.generate(
+                    _req(range(10, 20), max_tokens=40, rid="a"), Context()
+                ):
+                    a_outs.append(o)
+
+            async def submit_b_after_two():
+                while sum(1 for o in a_outs if o.token_ids) < 2:
+                    await asyncio.sleep(0.002)
+                return await collect(
+                    engine.generate(
+                        _req(range(100, 180), max_tokens=6, rid="b"), Context()
+                    )
+                )
+
+            _, b_outs = await asyncio.gather(consume_a(), submit_b_after_two())
+            events = engine.flight.snapshot()
+            admit_b = next(
+                i for i, e in enumerate(events)
+                if e["kind"] == "admit" and e.get("request_id") == "b"
+            )
+            return (
+                [e["kind"] for e in events[:admit_b]],
+                sum(len(o.token_ids or []) for o in a_outs),
+                sum(len(o.token_ids or []) for o in b_outs),
+            )
+        finally:
+            await engine.stop()
+
+    async def test_a_decode_burst_runs_behind_every_parked_chunk(self):
+        """Budgeted, the long prefill parks at chunk boundaries and each
+        resume waits behind a decode burst: between any two parks the
+        running stream was reaped. Unbudgeted, the same prefill runs to
+        completion inside its tick and never parks. Neither mode drops
+        work."""
+        kinds, a_n, b_n = await self._wave_on_a_decoding_stream(
+            tick_budget_enabled=True, tick_budget_floor_tokens=16,
+            tick_budget_ceiling_tokens=64, tick_budget_policy=0.0,
+        )
+        parks = [i for i, k in enumerate(kinds) if k == "prefill_pause"]
+        assert len(parks) >= 2, kinds
+        for lo, hi in zip(parks, parks[1:]):
+            assert "reap" in kinds[lo:hi], kinds[lo:hi + 1]
+        assert "reap" in kinds[parks[-1]:], "b installed with no burst behind its last park"
+        assert (a_n, b_n) == (40, 6)
+
+        kinds, a_n, b_n = await self._wave_on_a_decoding_stream()
+        assert "prefill_pause" not in kinds
+        assert (a_n, b_n) == (40, 6)

@@ -6,6 +6,7 @@ benchmark reader that turns the phase counters into shares."""
 
 import asyncio
 import glob
+import json
 import os
 import sys
 import time
@@ -343,37 +344,163 @@ def test_prometheus_ratio_reader(params, want):
     assert got == pytest.approx(want) if want is not None else got is None
 
 
-def test_new_layer_metric_files_name_real_families():
-    """Every family and phase label the seven new metric files read is one
-    the program exports: a typo would read nothing, for ever, in silence."""
-    import json
+# -- what the benchmark reads of the program -----------------------------------
+#
+# One case per ``benchmark/layer_metrics/*.json`` whose reader touches the
+# program (the ``client`` readers time the load generator's own clock and
+# name nothing here). The files are read, never edited: a family, label,
+# route, flag or program name they hold that the program stopped exporting
+# would read nothing, for ever, in silence.
 
-    new = ["engine.tick_ms", "engine.tick_prefill_share", "engine.tick_host_share",
-           "admission.queue_wait_ms", "engine.prefill_phase_ms",
-           "engine.tpot_mean_ms", "frontend.ttft_ms"]
+
+def _layer_metric(name: str) -> dict:
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
+        return json.load(f)
+
+
+PROGRAM_READ = sorted(
+    os.path.basename(path)[: -len(".json")]
+    for path in glob.glob(os.path.join(BENCH, "layer_metrics", "*.json"))
+    if _layer_metric(os.path.basename(path)[: -len(".json")])["reader"] != "client"
+)
+
+
+def _terms(params: dict) -> list:
+    return params.get("num", []) + params.get("den", []) or [params]
+
+
+def _series(body: str, family: str, labels: dict) -> list:
+    return [
+        line for line in body.splitlines()
+        if line.split("{")[0].split(" ")[0] in
+        (family, family + "_sum", family + "_count", family + "_bucket")
+        and all(f'{k}="{v}"' in line for k, v in labels.items())
+    ]
+
+
+@pytest.fixture(scope="module")
+def served():
+    """What a worker and a frontend expose once they have served: a tiny
+    engine behind a system server with two overlapping streams (the second
+    is admitted mid-decode, so the pipeline is drained once), and an HTTP
+    service that streamed one completion. Scraped once for all cases."""
+    from dynamo_tpu.http import HttpService, ModelManager
+    from dynamo_tpu.llm import ModelDeploymentCard
+    from dynamo_tpu.llm.protocols.common import FinishReason, PostprocessedOutput
+    from dynamo_tpu.runtime.system_server import attach_engine
+
+    class Scripted:
+        async def generate(self, request, context):
+            yield {"annotation": "_prompt_tokens", "value": 3}
+            yield PostprocessedOutput(text="a", token_ids=[1], cumulative_tokens=1)
+            yield PostprocessedOutput(
+                finish_reason=FinishReason.LENGTH, cumulative_tokens=1)
+
+    async def scrape(session, port, path):
+        async with session.get(f"http://127.0.0.1:{port}{path}") as r:
+            assert r.status == 200, path
+            return await (r.json() if path.startswith("/debug/") else r.text())
+
+    async def run():
+        engine, _ = make_engine(decode_steps=4)
+        server = SystemStatusServer(host="127.0.0.1", port=0)
+        attach_engine(server, engine)
+        manager = ModelManager()
+        manager.register(
+            "scripted", Scripted(), ModelDeploymentCard(name="scripted", context_length=64))
+        service = HttpService(manager, host="127.0.0.1", port=0)
+        await server.start()
+        http_port = await service.start()
+        try:
+            first = asyncio.ensure_future(
+                run_one(engine, req(range(10, 26), max_tokens=24)))
+            while engine.generated_tokens < 4:
+                await asyncio.sleep(0.005)
+            await run_one(engine, req(range(40, 56), max_tokens=4))
+            await first
+            routes = {
+                (r.method, r.resource.canonical)
+                for r in server._runner.app.router.routes()  # noqa: SLF001
+            }
+            async with aiohttp.ClientSession() as s:
+                async with s.post(
+                    f"http://127.0.0.1:{http_port}/v1/completions",
+                    json={"model": "scripted", "prompt": "x", "stream": True},
+                ) as r:
+                    assert r.status == 200
+                    await r.read()
+                return {
+                    "workers": await scrape(s, server.port, "/metrics"),
+                    "frontend": await scrape(s, http_port, "/metrics"),
+                    "routes": routes,
+                    "/debug/memory": await scrape(s, server.port, "/debug/memory"),
+                    "/debug/compiles": await scrape(s, server.port, "/debug/compiles"),
+                    "programs": {
+                        "decode": engine.runner._build_decode_fn()._fn.__name__,  # noqa: SLF001
+                        "prefill": engine.runner._build_step_fn()._fn.__name__,  # noqa: SLF001
+                    },
+                }
+        finally:
+            await service.stop(grace_period=1)
+            await server.stop()
+            await engine.stop()
+
+    return asyncio.run(run())
+
+
+@pytest.mark.parametrize("name", PROGRAM_READ)
+def test_layer_metric_file_reads_what_the_program_exports(name, served):
+    from dynamo_tpu.worker.__main__ import build_parser
+
+    spec = _layer_metric(name)
+    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
+    keys = ("unit", "better", "source", "layer", "moves")
+    assert {k: spec[k] for k in keys} == {k: entry[k] for k in keys}
+
+    params = spec["params"]
     families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND)
     labels = set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
-    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
-        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
-    for name in new:
-        with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
-        entry = listed[name]
-        assert {k: spec[k] for k in ("unit", "better", "source", "layer", "moves")} \
-            == {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}
-        p = spec["params"]
-        terms = p.get("num", []) + p.get("den", []) or [p]
-        for t in terms:
-            family = t["metric"]
+    flags = {o for a in build_parser()._actions for o in a.option_strings}  # noqa: SLF001
+    for key in ("per_flag", "percent_of_worker_flag"):
+        assert params.get(key) in flags | {None}, (name, key)
+
+    if spec["reader"].startswith("prometheus_"):
+        body = served["frontend" if params["target"] == "frontend" else "workers"]
+        for term in _terms(params):
+            family = term["metric"]
             for suffix in ("_sum", "_count"):
                 if family.endswith(suffix) and family not in families:
                     family = family[: -len(suffix)]
-            assert family in families, (name, t)
-            for value in (t.get("labels") or {}).values():
-                assert value in labels, (name, t)
-    # host + device-wait phases of the shares are the whole non-idle set
-    with open(os.path.join(BENCH, "layer_metrics", "engine.tick_host_share.json")) as f:
-        p = json.load(f)["params"]
+            assert family in families, (name, term)
+            assert set((term.get("labels") or {}).values()) <= labels, (name, term)
+            assert _series(body, family, term.get("labels") or {}), (name, term)
+    elif spec["reader"] == "route_json":
+        assert ("GET", params["path"]) in served["routes"], name
+        at = [served[params["path"]]]
+        for key in params["pointer"]:
+            # the CPU backend keeps no memory statistics: there the walk
+            # ends at the null the route serves in their place
+            at = [x for x in at if x is not None]
+            assert key == "*" or all(key in x for x in at), (name, key)
+            at = [v for x in at for v in (x if key == "*" else [x[key]])]
+    else:
+        assert spec["reader"] == "trace"
+        assert ("POST", "/debug/profile") in served["routes"]
+        for key, family in params.items():
+            if key.endswith("_metric"):
+                assert family in families, (name, key)
+                assert _series(served["workers"], family, {}), (name, key)
+        if "program" in params:
+            sys.path.insert(0, BENCH)
+            import trace_reduce
+
+            rule = trace_reduce.load_names()["programs"][params["program"]]
+            assert "jit_" + served["programs"][params["program"]] in rule["module"]
+
+
+def test_host_share_phases_are_the_whole_non_idle_set():
+    p = _layer_metric("engine.tick_host_share")["params"]
     assert {t["labels"]["phase"] for t in p["num"]} == set(mn.TICK_PHASES_HOST)
     assert {t["labels"]["phase"] for t in p["den"]} == \
         set(mn.TICK_PHASES_HOST) | set(mn.TICK_PHASES_DEVICE_WAIT)
@@ -386,7 +513,6 @@ def test_recorded_fixture_holds_the_spans_and_the_named_programs():
     benchmark's reducer tells the renamed programs apart with the merged
     ``trace_names/*.json``."""
     import collections
-    import json
 
     from jax.profiler import ProfileData
 

@@ -530,3 +530,27 @@ def test_degrades_counted_per_dialect_and_reason():
         dialect="hermes", reason="truncated"
     )
     assert after == before + 1
+
+
+def test_clean_streams_leave_the_recovery_counters_alone():
+    """Zero spurious activations: well-formed calls of every dialect, split
+    a character at a time, move neither the process-wide degrade counters
+    nor the exception counter, and the ring sees commits and calls only."""
+    from dynamo_tpu.parsers.observe import parser_plane
+
+    plane = parser_plane()
+    degrades0, exceptions0 = dict(plane.degrades), plane.exceptions
+    events0 = plane.flight.total
+    calls = 0
+    for dialect, texts in sorted(CORPUS.items()):
+        for text in texts:
+            got, _content, jail = stream(
+                list(text), dialect if dialect in PINNED_ONLY else None
+            )
+            assert jail.outcome() == "clean", (dialect, jail.degrade_reasons)
+            calls += len(got)
+    assert calls >= len(CORPUS)
+    assert dict(plane.degrades) == degrades0
+    assert plane.exceptions == exceptions0
+    new = plane.flight.snapshot()[-(plane.flight.total - events0):]
+    assert new and {e["kind"] for e in new} <= {"jail_commit", "call"}

@@ -118,26 +118,3 @@ def test_killed_worker_recovers_without_reingest(tmp_path):
         f"ttft {served2['ttft_ms']:.0f}ms; cold load was "
         f"{served1['load_ms']:.0f}ms)"
     )
-
-
-def test_restart_bench_warm_beats_cold_3x(tmp_path):
-    """The chrek-role recovery number: a SIGKILLed worker's replacement
-    reaches its first token from the durable tiers (tmpfs weights +
-    persistent compile cache) at least 3x faster than a cold spawn
-    (ref: deploy/chrek/pkg/checkpoint/criu.go:1 — same metric, process
-    image replaced by tier re-attach)."""
-    pytest.importorskip("transformers")
-    from dynamo_tpu.bench.restart import run
-
-    model_dir = _model_dir(tmp_path)
-    out = run(model_dir, str(tmp_path / "caches"))
-    # Under full-suite contention on the host the compile/jit legs
-    # jitter by multiples (a loaded host reproducibly measured the old
-    # 1.5x end-to-end gate at 1.38x), so the hard gates are the
-    # contention-robust STRUCTURAL invariants: the warm worker actually
-    # skipped the cold safetensors ingest (weights_hit, asserted inside
-    # run()), the weight tier itself is >=5x faster warm (mmap vs ingest
-    # is CPU-light and jitter-immune), and warm beats cold end-to-end at
-    # all — with a 10% noise allowance rather than a ratio target.
-    assert out["warm_weight_load_s"] < out["cold_weight_load_s"] / 5, out
-    assert out["warm_s"] < out["cold_s"] * 1.1, out
