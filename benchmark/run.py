@@ -101,6 +101,7 @@ class Ctx:
         self.n_workers = 1
         self.chips = 1
         self.notes: List[str] = []
+        self.why_nothing = ""  # a reader's reason for leaving its metric out
 
     def targets(self, which: str) -> List[str]:
         if which == "frontend":
@@ -307,6 +308,14 @@ class Run:
         for p in problems:
             self.failures.append(f"{what} #{rec.rid} (prompt {rec.prompt_len}, due {rec.due:.2f}s): {p}")
 
+    async def wait_listed(self) -> None:
+        """Up to a minute for the frontend to list the model again."""
+        for _ in range(120):
+            listed = await self.get_json(self.cluster.base + "/v1/models")
+            if self.model in [m["id"] for m in listed["data"]]:
+                return
+            await asyncio.sleep(0.5)
+
     async def probes(self, traffic: Traffic) -> None:
         """One request at a time against an idle engine, so that batch
         composition is the same in every run; then the first again, which
@@ -328,11 +337,7 @@ class Run:
                 if rec.ok:
                     return rec
                 say(f"probe {i} (prompt {len(prompt)}), attempt {attempt + 1}, failed: {rec.error}")
-                for _ in range(120):
-                    listed = await self.get_json(self.cluster.base + "/v1/models")
-                    if self.model in [m["id"] for m in listed["data"]]:
-                        break
-                    await asyncio.sleep(0.5)
+                await self.wait_listed()
             raise BenchFailure(f"probe {i} (prompt {len(prompt)}) failed three times: {rec.error}")
 
         for i, prompt in enumerate(prompts):
@@ -362,10 +367,23 @@ class Run:
         rng = np.random.default_rng([self.args.seed, 32452843])
         bursts = self.traffic.get("warmup", {}).get("bursts", [])
         for rows, n_prompt, n_out in bursts:
-            now = traffic.now()
-            recs = await asyncio.gather(*(
-                traffic.client.request(now, laws.token_ids(rng, n_prompt, traffic.vocab), n_out, "burst")
-                for _ in range(rows)))
+            # As with the probes: a compile can hold the worker's event loop
+            # past the frontend's liveness budget, and the burst then ends in
+            # 404s within milliseconds, its program never compiled (1 run of
+            # 11 at PR 30 and 1 of 19 at PR 32 opened their window on 26 and
+            # 18 of 42 programs). Wait for the model and send the burst again, with
+            # fresh prompts (a repeated one would be a prefix hit).
+            for attempt in range(3):
+                now = traffic.now()
+                recs = await asyncio.gather(*(
+                    traffic.client.request(now, laws.token_ids(rng, n_prompt, traffic.vocab), n_out, "burst")
+                    for _ in range(rows)))
+                lost = [rec for rec in recs if not rec.ok]
+                if not lost:
+                    break
+                say(f"warm-up burst {rows} x {n_prompt}, attempt {attempt + 1}: "
+                    f"{len(lost)} failed: {lost[0].error}")
+                await self.wait_listed()
             for rec in recs:
                 self.check_record(rec, "warm-up burst")
         if bursts:
@@ -549,9 +567,13 @@ class Run:
                 if args.rehearse_cpu and spec["source"] == "device_trace":
                     continue  # a CPU run carries no device metric
                 reader = importlib.import_module("readers." + spec["reader"])
+                ctx.why_nothing = ""
                 value = reader.read(spec.get("params", {}), ctx)
                 if value is not None:
                     metrics[name] = {"value": value, "unit": spec["unit"]}
+                else:
+                    say(f"LEFT OUT of the line: {name}: the {spec['reader']} reader found "
+                        f"nothing to read" + (f" ({ctx.why_nothing})" if ctx.why_nothing else ""))
             if ctx.trace:
                 device["busy_s"] = ctx.trace["busy_s"]
                 device["window_s"] = ctx.trace["window_s"]

@@ -11,7 +11,10 @@
    on paper, and on ``fixtures/served_decode.xplane.pb``, a few hundred KB
    cut from this PR's first traced run on the chip;
 3. the roofline function against hand-worked numbers, for the benchmark's
-   configuration and for Qwen3-8B in int8 (whose cell is put off: PERF.md).
+   configuration and for Qwen3-8B in int8 (whose cell is put off: PERF.md),
+   and the reader that feeds it rows and context from the program's per-burst
+   counters, on a made-up capture in which every 2 Hz poll read 0 rows
+   (``tests/test_decode_roofline_reader.py`` holds the other cases).
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ HERE = os.path.dirname(os.path.realpath(__file__))
 sys.path.insert(0, HERE)
 
 import laws  # noqa: E402
+import prom  # noqa: E402
 import roofline  # noqa: E402
 import stats  # noqa: E402
 import trace_reduce  # noqa: E402
@@ -206,9 +210,85 @@ def check_roofline() -> None:
     print("roofline: ok")
 
 
+ROWS_FAMILY = "dynamo_tpu_engine_batch_occupancy"
+PAGES_FAMILY = "dynamo_tpu_engine_decode_live_pages_total"
+
+
+def scrape_text(bursts: int, rows: int, pages: int) -> str:
+    """A worker's /metrics after ``bursts`` decode bursts of ``rows`` rows in
+    all, over ``pages`` live pages; its gauges say the engine is idle NOW."""
+    return (
+        f'{ROWS_FAMILY}_sum{{phase="decode"}} {rows}\n'
+        f'{ROWS_FAMILY}_count{{phase="decode"}} {bursts}\n'
+        f'{ROWS_FAMILY}_sum{{phase="prefill"}} 900\n'
+        f'{ROWS_FAMILY}_count{{phase="prefill"}} 300\n'
+        f"{PAGES_FAMILY} {pages}\n"
+        "dynamo_tpu_engine_active_seqs 0\ndynamo_tpu_engine_total_blocks 16384\n"
+        "dynamo_tpu_engine_free_blocks 16384\n")
+
+
+class MadeUpCapture:
+    """What ``readers/trace.py`` is given, without a chip or a trace file:
+    the snapshots at the capture's start, the window's end and after the
+    drain (one text per worker), the 2 Hz polls inside the capture, and the
+    reduced trace's median decode program."""
+
+    def __init__(self, before, after, drained=None, burst_s=0.0544, polls=()):
+        with open(os.path.join(HERE, "configs", "qwen2.5-0.5b.json")) as f:
+            self.config = json.load(f)
+        self.n = len(before)
+        self.snapshots = {
+            when: {f"worker{i}": prom.parse(t) for i, t in enumerate(texts)}
+            for when, texts in (("capture_start", before), ("window_end", after),
+                                ("drained", drained or after))}
+        self.polls = [(t, {f"worker{i}": prom.parse(text) for i in range(self.n)})
+                      for t, text in polls]
+        self.capture_t0, self.w1 = 36.06, 40.0
+        self.trace = {"programs": {"decode": {"count": 12, "median_s": burst_s}} if burst_s else {}}
+        self.device_kind = "TPU v5 lite"
+        self.notes = []
+        self.why_nothing = ""
+
+    def targets(self, which):
+        return [f"worker{i}" for i in range(self.n)]
+
+    def worker_flag(self, flag):
+        return {"--decode-steps": "8", "--block-size": "16"}[flag]
+
+
+def roofline_params():
+    with open(os.path.join(HERE, "layer_metrics", "kernel.decode_roofline.json")) as f:
+        return json.load(f)["params"]
+
+
+# The capture of PR 31's refused runs: 12 decode bursts of 4 rows were
+# dispatched and reaped inside it, each row ending its burst at 330 tokens
+# (ceil(330 / 16) = 21 pages: 48 rows, 1,008 pages), and every poll of the
+# gauges fell between two requests and read an idle engine.
+PR31_BEFORE = scrape_text(bursts=1000, rows=11500, pages=250000)
+PR31_AFTER = scrape_text(bursts=1012, rows=11548, pages=251008)
+PR31_POLLS = [(t, PR31_AFTER) for t in (36.51, 37.02, 37.53, 38.04, 38.55, 39.06, 39.57)]
+# Rows 48 / 12 = 4 a burst; context (1008 - 48) x 16 / 48 = 320 tokens a row
+# (the last page counted empty: 10 under the true 330). Bytes of a step:
+# 987,922,432 of weights + 4 x 320 x 12,288 of KV = 1,003,651,072, at 819
+# GB/s 1.22546 ms; a 54.4 ms burst of 8 steps is 6.8 ms a step: 18.02%.
+PR31_SHARE = 100.0 * (987_922_432 + 4 * 320 * 12_288) / 819e9 / (0.0544 / 8)
+
+
+def check_roofline_reader() -> None:
+    from readers import trace
+
+    ctx = MadeUpCapture([PR31_BEFORE], [PR31_AFTER], polls=PR31_POLLS)
+    got = trace.read(roofline_params(), ctx)
+    assert close(got, PR31_SHARE) and abs(got - 18.0215) < 0.0005, got
+    assert "4.0 rows x 320 tokens per dispatched burst" in ctx.notes[0], ctx.notes
+    print(f"roofline reader: ok ({ctx.notes[0]})")
+
+
 if __name__ == "__main__":
     check_stats()
     check_laws()
     check_roofline()
+    check_roofline_reader()
     check_trace()
     print("selfcheck passed")
