@@ -856,7 +856,9 @@ def main() -> int:
         "minutes are budgeted: debug one stage at a time). A subset's last "
         "line says it was partial; only the full run prints ok=true.",
     )
-    ap.add_argument("--start-timeout", type=float, default=600.0)
+    # spawn -> "worker serving" includes the worker's own prefill ladder
+    # (12 programs at the default --prefill-chunk 512: ~4 min cold at 8B)
+    ap.add_argument("--start-timeout", type=float, default=900.0)
     ap.add_argument("--request-timeout", type=float, default=900.0)
     ap.add_argument("--kernel-timeout", type=float, default=600.0)
     args = ap.parse_args()

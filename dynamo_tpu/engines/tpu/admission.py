@@ -36,6 +36,32 @@ from dynamo_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
+# The shortest chunk a prefill program is compiled for: one lane tile of
+# tokens. A shorter chunk pads up to it, so the programs a fresh prompt
+# can reach are a finite ladder (rows bucket × chunk bucket, the table
+# following the chunk) that the engine compiles before it serves
+# (Admitter.compile_prefill_ladder).
+PREFILL_CHUNK_FLOOR = 128
+
+
+def prefill_chunk_bucket(n_tokens: int, prefill_chunk: int) -> int:
+    """Chunk width a round of at most ``n_tokens`` a row dispatches at:
+    its power of two, no shorter than the floor, no longer than the
+    engine's ``prefill_chunk``."""
+    return min(max(_next_pow2(n_tokens), PREFILL_CHUNK_FLOOR), prefill_chunk)
+
+
+def prefill_table_bucket(nb_needed: int, c_bucket: int, args: Any) -> int:
+    """Table width of a prefill batch whose longest row holds
+    ``nb_needed`` blocks and whose first round runs at ``c_bucket``: the
+    blocks' power of two, no narrower than the chunk's own blocks — so a
+    fresh prompt that fits one chunk is a function of the chunk bucket
+    alone (a 10-token prompt meets the (floor, floor / block) program)."""
+    return min(
+        max(_next_pow2(nb_needed), c_bucket // args.block_size),
+        args.max_blocks_per_seq,
+    )
+
 
 @dataclass
 class PendingPrefill:
@@ -352,6 +378,55 @@ class Admitter:
             e._tick_budget_left = saved
         return pending.first  # type: ignore[return-value]
 
+    def prefill_ladder(self) -> List[Tuple[int, int, int]]:
+        """Every (rows bucket, chunk bucket, table width) a batch of FRESH
+        prompts that each fit one chunk can dispatch at — the shapes
+        ``_begin_prefill`` / ``_prefill_rounds`` derive, enumerated."""
+        args = self.e.args
+        row_buckets = sorted(
+            {_next_pow2(r) for r in range(1, args.prefill_batch + 1)}
+        )
+        chunks = []
+        c = prefill_chunk_bucket(1, args.prefill_chunk)
+        while c < args.prefill_chunk:
+            chunks.append(c)
+            c *= 2
+        chunks.append(args.prefill_chunk)
+        return [
+            (
+                Bp, c,
+                prefill_table_bucket(math.ceil(c / args.block_size), c, args),
+            )
+            for Bp in row_buckets
+            for c in chunks
+        ]
+
+    async def compile_prefill_ladder(self) -> int:
+        """Run every ladder program once, THROUGH the callable the chunk
+        rounds call (``engine._run_step`` with the arrays a round builds:
+        an ahead-of-time ``lower().compile()`` beside it would not fill
+        that callable's cache, and its first real call would still trace
+        and load). Every row has length 0, so nothing is written to the
+        pool and nothing is emitted. Returns how many programs ran.
+
+        Not in the ladder, compiled at first use as before: rounds after
+        a prompt's first (prefix-hit tails, prompts longer than the
+        chunk), top-logprobs and logits-processor variants, multimodal
+        rows, and the decode and scatter programs."""
+        e = self.e
+        ladder = self.prefill_ladder()
+        for Bp, c, nb in ladder:
+            zeros = np.zeros(Bp, dtype=np.int32)
+            await e._device(
+                e._run_step,
+                np.zeros((Bp, c), dtype=np.int32), zeros, zeros,
+                np.zeros((Bp, nb), dtype=np.int32),
+                np.ones(Bp, dtype=np.float32), zeros,
+                np.ones(Bp, dtype=np.float32), zeros,
+                None, None, None, False, True, zeros,
+            )
+        return len(ladder)
+
     def _begin_prefill(self, batch: "List[Tuple[Any, Any]]") -> PendingPrefill:
         """Per-batch prefill preamble: lifecycle/ROI stamps plus every
         loop-invariant device array, captured as a PendingPrefill so the
@@ -387,7 +462,13 @@ class Admitter:
         )
 
         nb_needed = max(len(prep.ids) for _, prep in batch)
-        nb_bucket = min(_next_pow2(nb_needed), args.max_blocks_per_seq)
+        first_round = max(
+            min(len(p) - at, args.prefill_chunk) for p, at in zip(prompts, pos)
+        )
+        nb_bucket = prefill_table_bucket(
+            nb_needed, prefill_chunk_bucket(first_round, args.prefill_chunk),
+            args,
+        )
         Bp = _next_pow2(rows)
         tables = np.zeros((Bp, nb_bucket), dtype=np.int32)
         temp = np.ones(Bp, dtype=np.float32)
@@ -464,8 +545,8 @@ class Admitter:
                 chunks = [
                     prompts[r][pos[r] : pos[r] + args.prefill_chunk] for r in range(rows)
                 ]
-                c_bucket = min(
-                    _next_pow2(max(len(c) for c in chunks)), args.prefill_chunk
+                c_bucket = prefill_chunk_bucket(
+                    max(len(c) for c in chunks), args.prefill_chunk
                 )
                 tok_arr = np.zeros((Bp, c_bucket), dtype=np.int32)
                 start = np.zeros(Bp, dtype=np.int32)

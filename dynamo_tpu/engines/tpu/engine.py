@@ -59,6 +59,7 @@ from dynamo_tpu.runtime.device_observe import (
     FlightRecorder,
     HbmLedger,
     dump_flight,
+    global_compile_watcher,
     tree_device_bytes,
 )
 from dynamo_tpu.tokens.blocks import adapter_salt, compute_block_hashes
@@ -484,6 +485,12 @@ class JaxEngine:
         # after). The loop REPLACES this dict wholesale at reap/admission/
         # idle boundaries; readers get one consistent generation.
         self._stats_cache: Optional[Dict[str, Any]] = None
+        self._startup_compile: Dict[str, Any] = {
+            "prefill_ladder_programs": 0,
+            "prefill_ladder_seconds": 0.0,
+            "startup_compiles": 0,
+            "startup_compile_seconds": 0.0,
+        }
 
     # -- device-state delegates (DeviceRunner owns the mechanism) ---------
 
@@ -595,6 +602,26 @@ class JaxEngine:
                 self._scheduler_loop(), name="jax-engine-scheduler"
             )
 
+    async def compile_prefill_ladder(self) -> Dict[str, Any]:
+        """Compile, before the worker says it serves, the prefill programs
+        a batch of fresh prompts can reach (admission.prefill_ladder: rows
+        bucket × chunk bucket), each run once through the callable the
+        scheduler calls. A worker's start-up calls this between starting
+        the engine and registering; an engine built directly (tests,
+        tools) compiles at first use as before. Returns, and keeps for
+        ``stats()``, what the process had compiled when it ended."""
+        t0 = time.monotonic()
+        programs = await self._admitter.compile_prefill_ladder()
+        totals = global_compile_watcher().totals()
+        self._startup_compile = {
+            "prefill_ladder_programs": programs,
+            "prefill_ladder_seconds": round(time.monotonic() - t0, 3),
+            "startup_compiles": totals["compiles"],
+            "startup_compile_seconds": totals["compile_seconds"],
+        }
+        self._publish_stats()
+        return dict(self._startup_compile)
+
     async def stop(self) -> None:
         self._stopped.set()
         self._wake.set()
@@ -673,6 +700,10 @@ class JaxEngine:
             "mk_fused_bursts": self.runner.mk_fused_bursts,
             "mk_fallback_bursts": self.runner.mk_fallback_bursts,
             "mk_bursts_by_variant": dict(self.runner.mk_bursts_by_variant),
+            # What was compiled before this engine served a request
+            # (compile_prefill_ladder; zeros where it was never called):
+            # against /debug/compiles, what serving has compiled since.
+            **self._startup_compile,
         }
         if self.args.spec_mode:
             out["spec_proposed"] = self.spec_proposed

@@ -37,6 +37,7 @@ from dynamo_tpu.parallel.sharding import (
 from dynamo_tpu.runtime.device_observe import (
     FlightRecorder,
     global_compile_watcher,
+    tree_device_bytes,
     watched_jit,
 )
 from dynamo_tpu.utils.jax_env import require_serving_platform
@@ -91,10 +92,13 @@ class _DecodeHandles:
 
 def _scatter_blocks_impl(cache, idx, blocks):
     """cache ← blocks [L, n, BS, KH, D] at idx [n]. Works on all layouts:
-    stacked [L, NB, BS, KH, D], per-layer tuple of [NB, BS, KH, D], or
-    per-layer int8 {"q8", "s"} pools (blocks arrive in the dequantized
-    wire format and are re-quantized here — so bf16 and int8 engines
-    interoperate over disagg/checkpoint transfers)."""
+    stacked [L, NB, BS, KH, D], per-layer tuple of [NB, BS, KH, >= D]
+    (blocks arrive at the logical head size and are widened to the pool's,
+    ops/attention.pool_head_dim), or per-layer int8 {"q8", "s"} pools
+    (blocks arrive in the dequantized wire format and are re-quantized
+    here — so bf16 and int8 engines interoperate over disagg/checkpoint
+    transfers)."""
+    from dynamo_tpu.ops.attention import pad_head
     from dynamo_tpu.ops.kv_quant import quantize_kv_chunk
 
     def one(c, blk):
@@ -104,7 +108,7 @@ def _scatter_blocks_impl(cache, idx, blocks):
                 "q8": c["q8"].at[idx].set(q8),
                 "s": c["s"].at[idx].set(s.transpose(0, 2, 1)),
             }
-        return c.at[idx].set(blk.astype(c.dtype))
+        return c.at[idx].set(pad_head(blk.astype(c.dtype), c.shape[-1]))
 
     if isinstance(cache, (tuple, list)):
         return tuple(one(c, blocks[l]) for l, c in enumerate(cache))
@@ -128,11 +132,13 @@ _scatter_blocks = watched_jit(
 KV_QUANT_WIRE_DTYPE = jnp.bfloat16
 
 
-def _gather_blocks_impl(cache, idx):
+def _gather_blocks_impl(cache, idx, head_dim=None):
     """[L, n, BS, KH, D] of blocks idx [n], from any cache layout, as ONE
     device program (a per-layer host gather would pay L dispatch RTTs).
     Int8 pools are dequantized to KV_QUANT_WIRE_DTYPE — the wire/checkpoint
-    format is always dense [L, n, BS, KH, D]."""
+    format is always dense [L, n, BS, KH, D], D the LOGICAL head size:
+    ``head_dim`` (static) cuts a pool held wider (pool_head_dim) back to
+    it, so a block leaves as it would from a pool of any width."""
     from dynamo_tpu.ops.kv_quant import dequantize_pages
 
     def one(c):
@@ -140,14 +146,19 @@ def _gather_blocks_impl(cache, idx):
             return dequantize_pages(
                 c["q8"][idx], c["s"][idx], KV_QUANT_WIRE_DTYPE
             )
-        return c[idx]
+        return c[idx][..., :head_dim]
 
     if isinstance(cache, (tuple, list)):
         return jnp.stack([one(c) for c in cache])
     return cache[:, idx]
 
 
-_gather_blocks = watched_jit("runner.gather_blocks", jax.jit(_gather_blocks_impl))
+_gather_blocks = watched_jit(
+    "runner.gather_blocks",
+    functools.partial(jax.jit, static_argnames=("head_dim",))(
+        _gather_blocks_impl
+    ),
+)
 
 
 def _gather_blocks_q8_impl(cache, idx):
@@ -170,12 +181,15 @@ def _scatter_blocks_q8_impl(cache, idx, q8, s):
     at idx. Quantized pools take them VERBATIM (an int8→int8 transfer is
     bit-exact); dense pools dequantize on device — either way the int8
     payload rides H2D at half the dense width."""
+    from dynamo_tpu.ops.attention import pad_head
     from dynamo_tpu.ops.kv_quant import dequantize_pages
 
     def one(c, q8_l, s_l):
         if isinstance(c, dict):
             return {"q8": c["q8"].at[idx].set(q8_l), "s": c["s"].at[idx].set(s_l)}
-        return c.at[idx].set(dequantize_pages(q8_l, s_l, c.dtype))
+        return c.at[idx].set(
+            pad_head(dequantize_pages(q8_l, s_l, c.dtype), c.shape[-1])
+        )
 
     if isinstance(cache, (tuple, list)):
         return tuple(one(c, q8[l], s[l]) for l, c in enumerate(cache))
@@ -428,6 +442,12 @@ class DeviceRunner:
             dict(mesh.shape) if mesh is not None else None,
             self.decode_path, self.decode_path_reason,
             self.attention_impl, self.attention_reason,
+        )
+        values = jax.tree.leaves(self.k_cache)[0]  # int8 pools: "q8" sorts first
+        logger.info(
+            "kv pool: %.2f GB resident | %s%s per layer for a head of %d",
+            tree_device_bytes((self.k_cache, self.v_cache)) / 1e9,
+            values.dtype.name, list(values.shape), self.config.head_dim_,
         )
 
     @property
@@ -1218,8 +1238,9 @@ class DeviceRunner:
         any later decode step, so donated cache updates cannot outrun it."""
         self._mirror("gather", ids=np.asarray(ids, dtype=np.int32))
         idx = self._dev(np.asarray(ids, dtype=np.int32))
-        k = _gather_blocks(self.k_cache, idx)
-        v = _gather_blocks(self.v_cache, idx)
+        hd = self.config.head_dim_
+        k = _gather_blocks(self.k_cache, idx, head_dim=hd)
+        v = _gather_blocks(self.v_cache, idx, head_dim=hd)
         if self.multihost:
             # Followers also compute the gather (they must join the
             # collective); only the leader reads it back, replicated.

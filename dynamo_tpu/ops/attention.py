@@ -32,6 +32,31 @@ from dynamo_tpu.runtime.device_observe import watched_jit
 
 NEG_INF = -1e30
 
+# The TPU's vector lane count. A bf16 pool whose minor (head) dimension is
+# narrower than this is tiled by XLA with the BLOCK index minor-most, and
+# every program that hands it to a Pallas call or carries it through a
+# ``while`` re-lays the whole pool on the way in and on the way out (96
+# whole-pool copies a decode burst at head size 64, ~40 of 54.5 ms: my chip
+# runs, PR 25). Held at a full lane tile the default layout is the
+# row-major one the kernels read, so a pool is resident as
+# [blocks, block, kv_heads, pool_head_dim(D)] and only lanes [:D] carry
+# keys or values; the rest stay the zeros they were allocated as.
+KV_LANE_TILE = 128
+
+
+def pool_head_dim(head_dim: int) -> int:
+    """The minor dimension a dense per-layer KV pool is held at."""
+    return max(head_dim, KV_LANE_TILE)
+
+
+def pad_head(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` [..., D] zero-padded on its last axis to a pool's ``width``
+    (K/V entering a pool: a chunk, wire blocks); as is where they agree."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
 
 def _takes_decode_kernel(C: int, n_heads: int, k_cache) -> bool:
     """Decode (C=1) and short chunks (speculative verify, chunk tails) take
@@ -113,7 +138,11 @@ def _paged_attention_xla_impl(
             return dequantize_pages(pages, scales).reshape(
                 B, T, n_kv_heads, head_dim
             )
-        return cache[block_tables].reshape(B, T, n_kv_heads, head_dim)
+        # [..., :head_dim]: a pool held wider than the head (pool_head_dim)
+        # never shows its padding lanes to the scores or the output.
+        return cache[block_tables][..., :head_dim].reshape(
+            B, T, n_kv_heads, head_dim
+        )
 
     B, C, n_heads, head_dim = q.shape
     values = k_cache["q8"] if is_quantized_pool(k_cache) else k_cache
@@ -208,7 +237,7 @@ def dense_chunk_attention(
 
 
 def write_chunk_to_cache(
-    cache: jnp.ndarray,  # [num_blocks, block_size, KH, D]
+    cache: jnp.ndarray,  # [num_blocks, block_size, KH, pool_head_dim(D)]
     chunk: jnp.ndarray,  # [B, C, KH, D]
     block_tables: jnp.ndarray,  # [B, max_blocks]
     start_pos: jnp.ndarray,  # [B]
@@ -233,7 +262,9 @@ def write_chunk_to_cache(
     block_idx = jnp.where(valid, block_idx, num_blocks)  # OOB → dropped
     slot = pos % block_size
     if not quantized:
-        return cache.at[block_idx, slot].set(chunk, mode="drop")
+        return cache.at[block_idx, slot].set(
+            pad_head(chunk, cache.shape[-1]), mode="drop"
+        )
     q8, s = quantize_kv_chunk(chunk)  # [B, C, KH, D], [B, C, KH]
     # scales live [NB, KH, bs]: the two advanced indices surround the KH
     # slice, so the indexed result is [B, C, KH] — exactly s's shape.

@@ -11,7 +11,8 @@ a prefix-hit prefill (one row, four tokens, eight pages), the
 benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
 contexts, the others empty with a stale position, 128 pages of table; bf16
 and int8) and the all-live control at 8B width (32 rows within 200 tokens
-of a 128-page table). Each compiled call is compared with
+of a 128-page table). One last row, ``kv_pool_layout``, is not a kernel's:
+the serving pool's resident layout (``pool_layout_job``). Each compiled call is compared with
 ``_paged_attention_xla`` / ``decoder_layer`` under
 ``jax.default_matmul_precision("highest")``.
 
@@ -369,6 +370,146 @@ def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
     return [functools.partial(job, P) for P in widths]
 
 
+def whole_pool_copies(hlo_text: str, pool) -> int:
+    """``copy`` instructions of a compiled program's optimised HLO whose
+    result is a whole per-layer KV pool (``pool``: its shape and dtype):
+    what re-laying a resident pool for a kernel's operand looks like."""
+    import re
+
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(pool.dtype).name]
+    dims = ",".join(str(d) for d in pool.shape)
+    return len(re.findall(
+        rf"= {dtype}\[{re.escape(dims)}\][^ ]* copy\(", hlo_text
+    ))
+
+
+def pool_layout_job(interpret: bool):
+    """``kv_pool_layout``: the serving pool of a head-size-64 model is
+    resident in the layout the kernels read. On the chip, at the shape of
+    qwen2.5-0.5b with 2,048 blocks and 64 slots: the optimised HLO of the
+    runner's decode burst and prefill step holds no copy of a whole
+    per-layer pool, the donated pools alias in and out (aliased bytes =
+    resident bytes, plus the few small carries), and the logits of a
+    ragged two-row prefill and 16 greedy steps are equal, bit for bit,
+    with the pool held at the lane tile and at the logical head size. The
+    interpreter rehearsal runs the same control flow at the tiny shape on
+    the XLA path and says nothing about layouts."""
+    from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import qwen2_500m_config
+    from dynamo_tpu.runtime.device_observe import tree_device_bytes
+
+    cfg = tiny_config() if interpret else qwen2_500m_config()
+    NB, S, P, steps = (64, 4, 4, 4) if interpret else (2048, 64, 64, 16)
+    lens = np.array([40, 23] if interpret else [200, 137], np.int32)
+    C = 64 if interpret else 256
+
+    def check() -> str:
+        runner = DeviceRunner(JaxEngineArgs(
+            config=cfg, num_kv_blocks=NB, max_num_seqs=S,
+            max_model_len=P * BLOCK_SIZE, prefill_chunk=C,
+        ))
+        uk = runner.use_kernel
+        pool = runner.k_cache[0]
+        resident = tree_device_bytes((runner.k_cache, runner.v_cache))
+        st, i32 = runner.slot_state, np.int32
+        decode = runner._build_decode_fn().lower(
+            runner.params, runner.lora, runner.k_cache, runner.v_cache,
+            st["tokens"], st["pos"], st["active"], runner.slot_tables[:, :P],
+            st["salts"], runner.rng, st["temp"], st["topk"], st["topp"],
+            st["adapter_ids"],
+        ).compile()
+        B = len(lens)
+        prefill = runner._build_step_fn(first_chunk=True).lower(
+            runner.params, runner.lora, runner.k_cache, runner.v_cache,
+            np.zeros((B, C), i32), np.zeros(B, i32), lens,
+            np.zeros((B, C // BLOCK_SIZE), i32), np.zeros(B, i32), runner.rng,
+            np.ones(B, np.float32), np.zeros(B, i32), np.ones(B, np.float32),
+            np.zeros(B, i32), None, None,
+        ).compile()
+        copies = [whole_pool_copies(c.as_text(), pool) for c in (decode, prefill)]
+        aliased = decode.memory_analysis().alias_size_in_bytes
+
+        def _build_forward(first_chunk):
+            return watched_jit("chip_check.pool_layout", jax.jit(
+                lambda p, t, s, l, bt, k, v: llama.forward_paged(
+                    p, cfg, t, s, l, bt, k, v, use_kernel=uk,
+                    first_chunk=first_chunk,
+                ),
+                donate_argnums=(5, 6),
+            ))
+
+        first, step = _build_forward(True), _build_forward(False)
+        rng = np.random.default_rng(31)
+        toks = rng.integers(0, cfg.vocab_size, (B, C)).astype(i32)
+        tables = np.arange(1, 1 + B * P, dtype=i32).reshape(B, P)
+
+        def serve(k, v):
+            out = []
+            logits, k, v = first(
+                runner.params, toks, np.zeros(B, i32), lens, tables, k, v
+            )
+            out.append(np.asarray(logits, np.float32))
+            pos = lens.copy()
+            for _ in range(steps):
+                tok = out[-1].argmax(-1).astype(i32)[:, None]
+                logits, k, v = step(
+                    runner.params, tok, pos, np.ones(B, i32), tables, k, v
+                )
+                out.append(np.asarray(logits, np.float32))
+                pos = pos + 1
+            return np.stack(out)
+
+        shape = pool.shape[:-1] + (cfg.head_dim_,)
+        logical = lambda: tuple(  # noqa: E731
+            jnp.zeros(shape, pool.dtype) for _ in range(cfg.n_layers)
+        )
+        served = serve(runner.k_cache, runner.v_cache)  # donated: last use
+        reference = serve(logical(), logical())
+        differ = int((served != reference).sum())
+        message = (
+            f"{pool.dtype.name}{list(pool.shape)} for a head of "
+            f"{cfg.head_dim_}; whole-pool copies decode/prefill "
+            f"{copies[0]}/{copies[1]}; aliased {aliased} B of {resident} B "
+            f"resident; {differ} of {served.size} logits differ from the "
+            f"logical pool's, max |difference| "
+            f"{float(np.abs(served - reference).max()):g} "
+            f"(max |logit| {float(np.abs(reference).max()):.4g})"
+        )
+        bad = differ or not np.isfinite(served).all()
+        if not interpret:  # layouts and aliasing are the chip's
+            bad = bad or any(copies) or not (
+                resident <= aliased < resident + (1 << 20)
+            )
+        if bad:
+            raise AssertionError(message)
+        return message
+
+    def job():
+        row = {
+            "kernel": "kv_pool_layout",
+            "shape": (
+                f"{cfg.name} NB{NB} S{S} P{P}: decode burst, prefill step, "
+                f"{int(lens[0])}+{int(lens[1])}-token prefill + {steps} "
+                "greedy steps"
+            ),
+            "presets": [cfg.name],
+            "required": True,
+        }
+        t0 = time.monotonic()
+        try:
+            row.update(status="compiled", message=check())
+        except AssertionError as exc:
+            row.update(status="disagrees", message=str(exc))
+        except Exception as exc:  # recorded like a kernel's refusal
+            row.update(status="refused", message=_first_line(exc))
+        row["seconds"] = round(time.monotonic() - t0, 1)
+        return row
+
+    return job
+
+
 def main() -> int:
     ap = argparse.ArgumentParser("pallas kernels: compile on the chip, check vs XLA")
     ap.add_argument(
@@ -414,6 +555,8 @@ def main() -> int:
     for r in rows:
         timer = r.pop("time", None)
         r["us_per_call"] = timer() if timer else None
+    # After the timings: it holds a model and three sets of pools.
+    rows.append({"us_per_call": None, **pool_layout_job(args.interpret)()})
 
     dev = jax.devices()[0]
     print(f"kernel table on {dev.platform} / {dev.device_kind}"

@@ -115,6 +115,9 @@ def _kernel(
 
     KH = q_ref.shape[1]
     CG = q_ref.shape[2]
+    # A page may be held wider than the head (ops/attention.pool_head_dim):
+    # lanes [:D] are the keys and values, the rest are never loaded.
+    D = q_ref.shape[3]
     G = n_groups
     W = S * block_size  # keys visited per grid step
 
@@ -151,10 +154,10 @@ def _kernel(
             q = q_ref[0, h].astype(jnp.float32)  # [CG, D]
             st = stride
             k = jnp.concatenate(
-                [kv_refs[st * s][0, :, h, :] for s in range(S)], axis=0
+                [kv_refs[st * s][0, :, h, :D] for s in range(S)], axis=0
             ).astype(jnp.float32)  # [W, D]
             v = jnp.concatenate(
-                [kv_refs[st * s + st // 2][0, :, h, :] for s in range(S)],
+                [kv_refs[st * s + st // 2][0, :, h, :D] for s in range(S)],
                 axis=0,
             ).astype(jnp.float32)  # [W, D]
             if quantized:
@@ -257,6 +260,7 @@ def _decode_kernel(
 
     KH = q_ref.shape[1]
     CG = q_ref.shape[2]
+    D = q_ref.shape[3]  # the head; a page's lanes past it are padding
     G = n_groups
     C = CG // G
     bs = block_size
@@ -290,7 +294,7 @@ def _decode_kernel(
         q = q_ref[0, h].astype(jnp.float32)  # [CG, D]
         scores = []
         for s in range(S):
-            k = kv_refs[stride * s][0, :, h, :].astype(jnp.float32)
+            k = kv_refs[stride * s][0, :, h, :D].astype(jnp.float32)
             s_mat = (
                 jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
@@ -317,7 +321,7 @@ def _decode_kernel(
             l_new = l_new + jnp.sum(probs, axis=-1, keepdims=True)
             if quantized:
                 probs = probs * kv_refs[stride * s + 3][0, h][None, :]
-            v = kv_refs[stride * s + stride // 2][0, :, h, :].astype(
+            v = kv_refs[stride * s + stride // 2][0, :, h, :D].astype(
                 jnp.float32
             )
             acc = acc + jax.lax.dot_general(
@@ -393,7 +397,7 @@ def decode_plan(
 
 def _paged_attention_decode_kernel_impl(
     q: jnp.ndarray,  # [B, C, n_heads, head_dim], C <= 8
-    k_cache,  # [num_blocks, block_size, KH, D] — or {"q8", "s"} int8 pool
+    k_cache,  # [num_blocks, block_size, KH, >= D] — or {"q8", "s"} int8 pool
     v_cache,
     block_tables: jnp.ndarray,  # [B, max_blocks] int32
     start_pos: jnp.ndarray,  # [B] int32
@@ -420,7 +424,7 @@ def _paged_attention_decode_kernel_impl(
     B, C, n_heads, head_dim = q.shape
     assert C <= 8, "live-span kernel serves decode / short-chunk steps"
     k_values = k_cache["q8"] if quantized else k_cache
-    _, block_size, n_kv_heads, _ = k_values.shape
+    _, block_size, n_kv_heads, page_dim = k_values.shape  # >= head_dim
     G = n_heads // n_kv_heads
     CG = C * G
     scale = sm_scale if sm_scale is not None else head_dim**-0.5
@@ -458,7 +462,7 @@ def _paged_attention_decode_kernel_impl(
     kv_args = []
     for s in range(S):
         spec = pl.BlockSpec(
-            (1, block_size, n_kv_heads, head_dim), page_map(s, 4)
+            (1, block_size, n_kv_heads, page_dim), page_map(s, 4)
         )
         if quantized:
             s_spec = pl.BlockSpec((1, n_kv_heads, block_size), page_map(s, 3))
@@ -532,7 +536,7 @@ def _paged_attention_kernel_impl(
     quantized = is_quantized_pool(k_cache)
     B, C, n_heads, head_dim = q.shape
     k_values = k_cache["q8"] if quantized else k_cache
-    num_blocks, block_size, n_kv_heads, _ = k_values.shape
+    num_blocks, block_size, n_kv_heads, page_dim = k_values.shape
     P = block_tables.shape[1]
     G = n_heads // n_kv_heads
     scale = sm_scale if sm_scale is not None else head_dim**-0.5
@@ -568,7 +572,7 @@ def _paged_attention_kernel_impl(
         return s_map
 
     kv_spec = lambda s: pl.BlockSpec(  # noqa: E731
-        (1, block_size, n_kv_heads, head_dim), kv_map_for(s)
+        (1, block_size, n_kv_heads, page_dim), kv_map_for(s)
     )
     in_specs = [pl.BlockSpec((1, n_kv_heads, C * G, head_dim), q_map)]
     kv_args = []

@@ -425,6 +425,19 @@ async def main() -> None:
 
     ready_state["detail"] = "starting engine"
     await engine.start()
+    # Before anything registers: the prefill programs a fresh prompt can
+    # reach compile here, where no load report is due and no request waits
+    # (tracing and lowering hold the GIL: a registered worker that compiles
+    # misses load reports, docs/design_docs/engine.md).
+    ready_state["detail"] = "compiling the prefill ladder"
+    warm = await engine.compile_prefill_ladder()
+    print(
+        f"prefill ladder: {warm['prefill_ladder_programs']} programs in "
+        f"{warm['prefill_ladder_seconds']:.1f}s; compiled before serving: "
+        f"{warm['startup_compiles']} programs in "
+        f"{warm['startup_compile_seconds']:.1f}s",
+        flush=True,
+    )
     if args.kv_checkpoint_dir:
         # Restore BEFORE registering: the model card and the first load
         # report must describe a worker whose warm cache is already

@@ -56,6 +56,14 @@ DECODE_SHAPES = {
     "gemma-2-9b-d256-bs128": (16, 1, 16, 8, 256, 16, False, 50.0, 128),
 }
 
+# Shapes whose bf16 page is held at a 128-lane tile for a head of 64
+# (ops/attention.pool_head_dim: the pool the worker serves since PR 33):
+# the kernel's page operand is [1, bs, KH, 128] and it loads lanes [:64].
+WIDE_PAGE = {"qwen2.5-0.5b-bf16-page128": "qwen2.5-0.5b-bf16",
+             "qwen2.5-0.5b-prefix-hit-tail-page128": "qwen2.5-0.5b-prefix-hit-tail"}
+for _wide, _logical in WIDE_PAGE.items():
+    DECODE_SHAPES[_wide] = DECODE_SHAPES[_logical]
+
 
 @pytest.mark.parametrize("name", sorted(DECODE_SHAPES))
 def test_decode_kernel_compiles_for_v5e(one_chip, name):
@@ -73,7 +81,7 @@ def test_decode_kernel_compiles_for_v5e(one_chip, name):
         pool = {"q8": sds((NB, bs, KH, D), jnp.int8),
                 "s": sds((NB, KH, bs), jnp.float32)}
     else:
-        pool = sds((NB, bs, KH, D), jnp.bfloat16)
+        pool = sds((NB, bs, KH, 128 if name in WIDE_PAGE else D), jnp.bfloat16)
     fn = jax.jit(functools.partial(
         _paged_attention_decode_kernel_impl, logit_cap=cap
     ))
@@ -82,3 +90,99 @@ def test_decode_kernel_compiles_for_v5e(one_chip, name):
         sds((B,), jnp.int32), sds((), jnp.int32), sds((B,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("page", [64, 128])
+def test_chunk_kernel_compiles_for_v5e_at_head_64(one_chip, page):
+    """The generic (B, pages) kernel at the shape a prefix-hit tail of the
+    benchmark cell now takes (one row, a 128-token chunk: chunks pad up to
+    admission.PREFILL_CHUNK_FLOOR), over the logical and the wide page."""
+    from dynamo_tpu.ops.pallas.paged_attention import (
+        _paged_attention_kernel_impl,
+    )
+
+    B, C, H, KH, D, P, bs = 1, 128, 14, 2, 64, 32, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((4096, bs, KH, page), jnp.bfloat16)
+    compiled = jax.jit(_paged_attention_kernel_impl).lower(
+        sds((B, C, H, D), jnp.bfloat16), pool, pool, sds((B, P), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
+    """The decode burst and the prefill step of a two-layer qwen2.5-0.5b,
+    compiled for the v5e as the runner builds them: the optimised HLO holds
+    no copy of a whole per-layer pool (96 a program with the pool at its
+    logical head size of 64: the resident layout was not the kernels'),
+    and the donated pools alias in and out."""
+    import dataclasses
+    import types
+
+    import numpy as np
+
+    from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import qwen2_500m_config
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+
+    cfg = dataclasses.replace(qwen2_500m_config(), n_layers=2, vocab_size=8192)
+    NB, S = 2048, 64
+    args = JaxEngineArgs(
+        config=cfg, num_kv_blocks=NB, max_num_seqs=S, max_model_len=2048,
+        prefill_chunk=1024, use_kernel=True,
+    )
+    runner = types.SimpleNamespace(  # what the program builders read
+        config=cfg, args=args, use_kernel=True, use_megakernel=False,
+        multihost=False, _decode_sig_budget=None,
+        _constrain_out=lambda *a: a if len(a) > 1 else a[0],
+    )
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = dict(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
+    ))
+    params["layers"] = jax.eval_shape(
+        lambda p: [jax.tree.map(lambda a: a[l], p) for l in range(cfg.n_layers)],
+        params["layers"],
+    )
+    params = jax.tree.map(on_chip, params)
+    k, v = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, NB, 16, layered=True)
+    ))
+    resident = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in k + v)
+    i32, f32 = jnp.int32, jnp.float32
+
+    def rows(B):
+        return [arr((B,), i32), arr((2,), jnp.uint32), arr((B,), f32),
+                arr((B,), i32), arr((B,), f32), arr((B,), i32)]
+
+    if program == "decode_burst":
+        fn = DeviceRunner._build_decode_fn(runner)
+        lowered = fn.lower(
+            params, None, k, v, arr((S,), i32), arr((S,), i32),
+            arr((S,), i32), arr((S, 32), i32), *rows(S),
+        )
+    else:
+        fresh = program == "prefill_fresh"
+        B, C, P = (4, 256, 16) if fresh else (1, 128, 32)
+        fn = DeviceRunner._build_step_fn(runner, first_chunk=fresh)
+        lowered = fn.lower(
+            params, None, k, v, arr((B, C), i32), arr((B,), i32),
+            arr((B,), i32), arr((B, P), i32), *rows(B), None, None,
+        )
+    compiled = lowered.compile()
+    assert whole_pool_copies(compiled.as_text(), k[0]) == 0
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert resident <= aliased < resident + (1 << 20)

@@ -757,7 +757,11 @@ async def test_http_saturation_bounded_queue_typed_sheds_token_exact():
     queue stays bounded, excess sheds 429 + Retry-After, deadline-carrying
     requests whose budget dies mid-queue shed 504 BEFORE reaching the
     engine, and every admitted stream completes token-exact."""
-    stub = StubPipeline(tokens=6, itl_s=0.03)  # ≥ 180ms service time
+    # ≥ 600 ms of service, 250 ms deadlines: the burst, posted at 70 ms,
+    # has 180 ms to reach the server before the deadlines free two queue
+    # slots (at 30 ms / 60 ms that margin was 40 ms, and a loaded CI host
+    # once let 4 of the burst queue: 6 x 200 for 4)
+    stub = StubPipeline(tokens=6, itl_s=0.1)
     ctrl = OverloadController(
         OverloadConfig(max_concurrency=2, max_queue_depth=4,
                        max_queue_delay_s=30.0)
@@ -779,7 +783,7 @@ async def test_http_saturation_bounded_queue_typed_sheds_token_exact():
             # far smaller than the fillers' remaining service time.
             dead = [
                 asyncio.ensure_future(
-                    post(s, headers={"x-dynamo-deadline-ms": "60"})
+                    post(s, headers={"x-dynamo-deadline-ms": "250"})
                 )
                 for _ in range(2)
             ]

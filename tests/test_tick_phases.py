@@ -489,8 +489,11 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
         assert ("POST", "/debug/profile") in served["routes"]
         for key, family in params.items():
             if key.endswith("_metric"):
+                # ``rows_metric`` is read under ``rows_labels``: the served
+                # scrape must hold that very series, label values included
+                series_labels = params.get(key[: -len("metric")] + "labels") or {}
                 assert family in families, (name, key)
-                assert _series(served["workers"], family, {}), (name, key)
+                assert _series(served["workers"], family, series_labels), (name, key)
         if "program" in params:
             sys.path.insert(0, BENCH)
             import trace_reduce
@@ -504,6 +507,39 @@ def test_host_share_phases_are_the_whole_non_idle_set():
     assert {t["labels"]["phase"] for t in p["num"]} == set(mn.TICK_PHASES_HOST)
     assert {t["labels"]["phase"] for t in p["den"]} == \
         set(mn.TICK_PHASES_HOST) | set(mn.TICK_PHASES_DEVICE_WAIT)
+
+
+def _roofline_reader_cases():
+    """``benchmark/tests/test_decode_roofline_reader.py``, loaded by path:
+    the benchmark keeps the cases beside its reader (tier-1 is not its to
+    edit); here they count. The file imports no JAX and reads no chip."""
+    import importlib.util
+
+    path = os.path.join(BENCH, "tests", "test_decode_roofline_reader.py")
+    spec = importlib.util.spec_from_file_location("benchmark_roofline_reader_cases", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ROOFLINE_READER_CASES = (
+    "bursts_in_the_drain", "burst_not_counted", "no_counters", "no_decode_burst",
+    "one_of_two_idle", "pages_before_capture", "polls_all_idle", "two_workers",
+    "the_share_is_a_lower_estimate",
+)
+
+
+@pytest.mark.parametrize("case", ROOFLINE_READER_CASES)
+def test_decode_roofline_reader_on_made_up_captures(case):
+    """What ``kernel.decode_roofline`` prints from the capture's own
+    counters (PR 32), on captures made up without a chip: the benchmark's
+    own nine cases, none missing and none new."""
+    cases = _roofline_reader_cases()
+    assert set(ROOFLINE_READER_CASES[:-1]) == set(cases.CASES)
+    if case in cases.CASES:
+        cases.test_decode_roofline_share(case)
+    else:
+        getattr(cases, "test_" + case)()
 
 
 def test_recorded_fixture_holds_the_spans_and_the_named_programs():
