@@ -732,6 +732,7 @@ class JaxEngine:
             "decode_path_reason": self.runner.decode_path_reason,
             "attention_impl": self.runner.attention_impl,
             "attention_reason": self.runner.attention_reason,
+            "expert_ffn": self.runner.expert_ffn,
             "mk_fused_bursts": self.runner.mk_fused_bursts,
             "mk_fallback_bursts": self.runner.mk_fallback_bursts,
             "mk_bursts_by_variant": dict(self.runner.mk_bursts_by_variant),

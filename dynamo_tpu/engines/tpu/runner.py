@@ -498,13 +498,14 @@ class DeviceRunner:
         self._spec_fn: Optional[Any] = None  # speculative verify program
         self.sleep_level = 0
         self.host_params: Optional[Any] = None
+        self.expert_ffn = self._describe_expert_ffn()
         logger.info(
             "device runner: platform=%s device_kind=%s devices=%d mesh=%s | "
-            "decode path: %s (%s) | attention: %s (%s)",
+            "decode path: %s (%s) | attention: %s (%s) | expert_ffn: %s",
             backend, jax.devices()[0].device_kind, len(jax.devices()),
             dict(mesh.shape) if mesh is not None else None,
             self.decode_path, self.decode_path_reason,
-            self.attention_impl, self.attention_reason,
+            self.attention_impl, self.attention_reason, self.expert_ffn,
         )
         values = jax.tree.leaves(self.k_cache)[0]  # int8 pools: "q8" sorts first
         logger.info(
@@ -536,6 +537,23 @@ class DeviceRunner:
     @property
     def attention_impl(self) -> str:
         return "pallas" if self.use_kernel else "xla"
+
+    def _describe_expert_ffn(self) -> Optional[str]:
+        """The form a decode step's expert layers take and why
+        (ops/moe.form_in_use at ``max_num_seqs`` tokens); None for a model
+        without expert layers. Not a choice: ``moe_ffn`` makes it, from the
+        same arguments, every time it is traced."""
+        from dynamo_tpu.ops.moe import form_in_use
+
+        c = self.config
+        if not self.hybrid:
+            return "xla, the stacked layer loop takes no kernel" if c.is_moe else None
+        forms = {
+            form_in_use(self.use_kernel, self.args.max_num_seqs, lp, spec)
+            for spec, lp in zip(c.layer_specs, self.params["layers"])
+            if spec.kind == "experts"
+        }
+        return "; ".join(sorted(forms)) or None
 
     # -- path selection ----------------------------------------------------
 

@@ -301,10 +301,13 @@ def forward(
                 im += 1
             else:
                 if want_moe_stats:
-                    out, st = moe_ffn(h, lp, spec, row_mask=real, want_stats=True)
+                    out, st = moe_ffn(
+                        h, lp, spec, row_mask=real, want_stats=True,
+                        use_kernel=use_kernel,
+                    )
                     stats = stats + st
                 else:
-                    out = moe_ffn(h, lp, spec)
+                    out = moe_ffn(h, lp, spec, row_mask=real, use_kernel=use_kernel)
         x = x + out.astype(x.dtype)
     ssm_new = {"conv": tuple(conv_out), "S": tuple(s_out)}
     store_new = None if store is None else {

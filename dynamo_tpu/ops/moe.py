@@ -12,16 +12,25 @@ expert matrices are sharded on their first axis and GSPMD partitions the
 dense form's einsums by itself: no exchange of tokens, every shard sees the
 whole batch, as the two chips of the served deployment do.
 
-Two ways to compute the held experts' part, chosen from the static token
-count alone:
+Three ways to compute the held experts' part. ``hit_list_reason`` chooses
+the first from what it is given (the caller's ``use_kernel``, the static
+token count, the matrices, the spec); the static token count alone chooses
+between the other two:
 
-* few tokens (a decode step): every token through every held expert with a
-  [T, E_held] weight matrix that is zero off the routing. The weights of all
-  held experts stream once, which is what bounds a decode step anyway, and
-  the einsums are static shapes that GSPMD partitions over an ``experts``
+* hit list (a decode step on one TPU chip): the dense form's own products,
+  float32 accumulation, over the experts that got a LIVE token and no
+  others, by a Pallas kernel whose grid is that list
+  (ops/pallas/expert_ffn.py). A step streams from HBM the matrices of the
+  experts hit, which is what ``want_stats`` counts: a dead slot (``row_mask``
+  false) routes to no expert.
+* dense (few tokens, everywhere else): every token through every held
+  expert with a [T, E_held] weight matrix that is zero off the routing and
+  on dead rows. The weights of all held experts stream once, and the
+  einsums are static shapes that GSPMD partitions over an ``experts``
   sharded axis by itself.
-* many tokens (a prefill chunk): assignments sorted by expert, one grouped
-  matmul per matrix (``jax.lax.ragged_dot``), scattered back weighted.
+* grouped (many tokens, a prefill chunk): assignments sorted by expert, one
+  grouped matmul per matrix (``jax.lax.ragged_dot``), scattered back
+  weighted.
 
 Shapes: T = B*C tokens, E router width, Eh experts held, K experts a token,
 f expert width. ``lp`` holds ``router_w`` [d, E], optionally ``router_bias``
@@ -38,14 +47,21 @@ from typing import TYPE_CHECKING, Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.ops.pallas.expert_ffn import ACTIVATIONS, expert_ffn, hit_list
 from dynamo_tpu.ops.quant import qeinsum
 
 if TYPE_CHECKING:  # models/ imports this module: the spec is data, named only
     from dynamo_tpu.models.config import ExpertsSpec
 
-# Token count up to which every token goes through every held expert. At the
-# widths served here (d 2688, f 1856, 64 held) the dense form's FLOPs pass
-# the time the weights take to stream at about 256 tokens on a v5e.
+# Token count up to which every token goes through every expert hit (the
+# hit-list kernel, where ``hit_list_reason`` finds none against it) or
+# through every held expert (the dense form); above it the grouped form
+# serves. At the widths served here (d 2688, f 1856, 64 held) the dense
+# form's FLOPs pass the time the weights take to stream at about 256 tokens
+# on a v5e, and up to there the kernel with all 64 experts hit is no slower
+# than the dense form (chip_check's expert_ffn rows at 64, 128 and 256
+# tokens: 1,724 / 1,725 / 1,931 us against 1,751 / 1,786 / 2,188, my chip
+# run, PR 37), so one bound serves both.
 DENSE_TOKENS_MAX = 256
 
 _HI = jax.lax.Precision.HIGHEST
@@ -84,6 +100,46 @@ def _activate(up: jnp.ndarray, gate: Optional[jnp.ndarray], spec: ExpertsSpec):
     raise ValueError(f"unknown expert activation {spec.activation!r}")
 
 
+def hit_list_reason(
+    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+) -> Optional[str]:
+    """None where the held experts' part goes through the hit-list kernel;
+    otherwise why it keeps the XLA forms."""
+    if not use_kernel:
+        return "no Pallas kernels here (use_kernel is false)"
+    if T > DENSE_TOKENS_MAX:
+        return f"{T} tokens a step is over {DENSE_TOKENS_MAX}"
+    if isinstance(lp["we_up"], dict):
+        return "quantized expert matrices"
+    if spec.activation not in ACTIVATIONS:
+        return f"activation {spec.activation} is not in the kernel"
+    n_held, d, f = lp["we_up"].shape
+    if not n_held:
+        return "no expert held"
+    if d % 128 or not f % 128:
+        # The kernel reads we_up through its resident layout, which XLA
+        # makes d-minor exactly when f would need lane padding and d not.
+        return f"widths d {d}, f {f}: we_up is not resident with d minor"
+    return None
+
+
+def form_in_use(
+    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+) -> str:
+    """Which form a step of ``T`` tokens takes, for the log: ``pallas hit
+    list``, or ``xla dense|grouped, <why not the kernel>``."""
+    why = hit_list_reason(use_kernel, T, lp, spec)
+    if why is None:
+        return "pallas hit list"
+    return f"xla {'dense' if _dense_serves(T, lp) else 'grouped'}, {why}"
+
+
+def _dense_serves(T: int, lp: Dict[str, Any]) -> bool:
+    """Off the kernel: the dense form (few tokens, or quantized matrices,
+    which ``ragged_dot`` does not take) rather than the grouped one."""
+    return T <= DENSE_TOKENS_MAX or isinstance(lp["we_up"], dict)
+
+
 def _experts_dense(xs, comb, lp, spec):
     """Every token through every held expert; ``comb`` [T, Eh] weighs."""
     up = qeinsum("td,edf->etf", xs, lp["we_up"])
@@ -112,6 +168,13 @@ def _experts_grouped(xs, top_w, local, valid, lp, spec, n_held):
     return jnp.zeros((T, xs.shape[-1]), jnp.float32).at[token_of].add(out)
 
 
+def _combine(top_w, local, valid, n_held):
+    """[T, Eh] float32: the weight of each held expert in each token's
+    result, zero off the routing."""
+    hot = jax.nn.one_hot(jnp.where(valid, local, n_held), n_held, dtype=jnp.float32)
+    return (top_w[..., None] * hot).sum(1)
+
+
 def _shared_expert(xs, lp, spec):
     up = qeinsum("td,df->tf", xs, lp["ws_up"])
     gate = qeinsum("td,df->tf", xs, lp["ws_gate"]) if "ws_gate" in lp else None
@@ -125,13 +188,16 @@ def moe_ffn(
     lp: Dict[str, Any],
     spec: ExpertsSpec,
     *,
-    row_mask: Optional[jnp.ndarray] = None,  # [B, C] bool: real tokens (stats only)
+    row_mask: Optional[jnp.ndarray] = None,  # [B, C] bool: live tokens
     want_stats: bool = False,
+    use_kernel: bool = False,
 ):
     """Held experts' part of the routed result plus the shared expert.
-    Returns [B, C, d], and with ``want_stats`` also a float32 [3]: held
-    experts that got a token, most tokens on one held expert, tokens on
-    held experts (the mean is that over the experts held)."""
+    A token ``row_mask`` calls dead is routed to no expert: its row of the
+    result holds the shared expert's part alone and it counts on no
+    expert. Returns [B, C, d], and with ``want_stats`` also a float32 [3]:
+    held experts that got a live token, most tokens on one held expert,
+    tokens on held experts (the mean is that over the experts held)."""
     B, C, d = x.shape
     T = B * C
     xs = x.reshape(T, d)
@@ -140,9 +206,20 @@ def moe_ffn(
     top_w, top_i = route(xs, lp, spec)
     local = top_i - lo
     valid = (local >= 0) & (local < n_held)
-    if T <= DENSE_TOKENS_MAX or isinstance(lp["we_up"], dict):
-        hot = jax.nn.one_hot(jnp.where(valid, local, n_held), n_held, dtype=jnp.float32)
-        comb = (top_w[..., None] * hot).sum(1)  # [T, Eh]
+    if row_mask is not None:
+        valid = valid & row_mask.reshape(T, 1)
+    hit_listed = hit_list_reason(use_kernel, T, lp, spec) is None
+    if hit_listed or want_stats:  # tokens on each held expert, [Eh] float32
+        load = jnp.zeros((n_held + 1,), jnp.float32).at[
+            jnp.where(valid, local, n_held).reshape(-1)
+        ].add(1.0)[:n_held]
+    if hit_listed:
+        y = expert_ffn(
+            xs, _combine(top_w, local, valid, n_held), lp["we_up"],
+            lp["we_down"], *hit_list(load),
+        )
+    elif _dense_serves(T, lp):
+        comb = _combine(top_w, local, valid, n_held)
         y = _experts_dense(xs, comb, lp, spec).astype(jnp.float32)
     else:
         y = _experts_grouped(xs, top_w, local, valid, lp, spec, n_held)
@@ -151,8 +228,4 @@ def moe_ffn(
     y = y.astype(x.dtype).reshape(B, C, d)
     if not want_stats:
         return y
-    live = valid if row_mask is None else valid & row_mask.reshape(T, 1)
-    load = jnp.zeros((n_held + 1,), jnp.float32).at[
-        jnp.where(live, local, n_held).reshape(-1)
-    ].add(1.0)[:n_held]
     return y, jnp.stack([(load > 0).sum().astype(jnp.float32), load.max(), load.sum()])

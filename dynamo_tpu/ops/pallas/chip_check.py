@@ -6,7 +6,11 @@ attention shape the worker's builtin presets produce it lowers
 ``paged_attention_decode_kernel`` and ``paged_attention_kernel`` with
 ``interpret=False`` over bf16 and int8-KV pools, and ``fused_decoder_layer``
 at the Qwen3-8B layer shape for every pow2 table width up to the worker's
-default model length. The decode kernel gets four more rows: the tail of
+default model length, and ``expert_ffn`` (the hit-list expert kernel) at the
+hybrid configuration's served widths against ops/moe.py's dense form: 64
+tokens over 1, 8, 19, 29 and all 64 held experts hit, and 128 and 256
+tokens with all hit (the rows that decide how many tokens the kernel serves),
+each with both forms' ``us/call``. The decode kernel gets four more rows: the tail of
 a prefix-hit prefill (one row, four tokens, eight pages), the
 benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
 contexts, the others empty with a stale position, 128 pages of table; bf16
@@ -370,6 +374,76 @@ def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
     return [functools.partial(job, P) for P in widths]
 
 
+def expert_ffn_jobs(interpret: bool):
+    """The hit-list expert kernel against the XLA dense form
+    (ops/moe._experts_dense, what it replaces in a decode step), at the
+    served widths: d 2688, f 1856, 64 held, bf16. ``hit`` experts get the
+    tokens' top-6 choices; the others are never read. The one-expert row
+    is ``required``: a one-entry work list halted the core once (PR 25)."""
+    from dynamo_tpu.models.config import ExpertsSpec
+    from dynamo_tpu.ops import moe
+    from dynamo_tpu.ops.pallas.expert_ffn import expert_ffn, hit_list
+
+    d, f, n_held, K = (128, 48, 8, 2) if interpret else (2688, 1856, 64, 6)
+    dtype = jnp.float32 if interpret else jnp.bfloat16
+    spec = ExpertsSpec(n_experts=n_held, top_k=K, d_ff=f, activation="relu2")
+
+    @functools.cache
+    def weights():
+        k1, k2 = jax.random.split(jax.random.PRNGKey(37))
+        return {
+            "we_up": jax.random.normal(k1, (n_held, d, f), dtype) * d**-0.5,
+            "we_down": jax.random.normal(k2, (n_held, f, d), dtype) * f**-0.5,
+        }
+
+    def job(T, hit):
+        rng = np.random.default_rng(T * 100 + hit)
+        xs = jnp.asarray(rng.standard_normal((T, d)), dtype)
+        comb = np.zeros((T, n_held), np.float32)
+        chosen = rng.permutation(n_held)[:hit]
+        for t in range(T):
+            mine = rng.permutation(chosen)[:K]
+            comb[t, mine] = rng.random(len(mine)) + 0.1
+        comb[:, chosen] += (comb[:, chosen].sum(0) == 0) * 0.5  # each one hit
+        comb = jnp.asarray(comb)
+        ids, count = hit_list(comb.sum(0))
+        row = {
+            "kernel": "expert_ffn",
+            "shape": f"T{T} d{d} f{f} held{n_held} hit{hit} {dtype.__name__}",
+            "presets": ["nemotron-3-nano-30b-a3b-ep2"],
+            "required": hit == 1,
+        }
+
+        def kernel(xs, comb, lp, ids, count):
+            return expert_ffn(
+                xs, comb, lp["we_up"], lp["we_down"], ids, count,
+                interpret=interpret,
+            ).astype(xs.dtype)
+
+        def dense(xs, comb, lp, ids, count):
+            return moe._experts_dense(xs, comb, lp, spec)
+
+        row = _timed(
+            row, lambda: kernel(xs, comb, weights(), ids, count),
+            lambda: dense(xs, comb, weights(), ids, count), ulps=4,
+        )
+        if row["status"] == "compiled" and not interpret:
+
+            def both():
+                args = (xs, comb, weights(), ids, count)
+                row["message"] = f"xla dense {_us_per_call(dense, *args)} us/call"
+                return _us_per_call(kernel, *args)
+
+            row["time"] = both
+        return row
+
+    hits = [1, 3, n_held] if interpret else [1, 8, 19, 29, 64]
+    jobs = [functools.partial(job, 16 if interpret else 64, h) for h in hits]
+    jobs += [functools.partial(job, T, n_held) for T in
+             ((32,) if interpret else (128, 256))]
+    return jobs
+
+
 def whole_pool_copies(hlo_text: str, pool) -> int:
     """``copy`` instructions of a compiled program's optimised HLO whose
     result is a whole per-layer KV pool (``pool``: its shape and dtype):
@@ -518,6 +592,10 @@ def main() -> int:
         "interpreter, small batch and widths. Says nothing about Mosaic.",
     )
     ap.add_argument("--out", default=None, help="also write the table as JSON here")
+    ap.add_argument(
+        "--only", default=None, metavar="FAMILY",
+        help="only this family's rows: a builder's partial table",
+    )
     args = ap.parse_args()
 
     configure_compile_cache()
@@ -533,18 +611,30 @@ def main() -> int:
             d_model=256, head_dim=128, n_heads=4, n_kv_heads=2, d_ff=512,
             qk_norm=True, dtype=jnp.bfloat16, name="tiny-fused",
         )
-        jobs = attention_jobs(True, B=4, P=4)
-        jobs += fused_layer_jobs(True, fused_cfg, B=4, widths=[1, 4])
+        families = {
+            "paged_attention": lambda: attention_jobs(True, B=4, P=4),
+            "fused_decoder_layer": lambda: fused_layer_jobs(
+                True, fused_cfg, B=4, widths=[1, 4]),
+            "expert_ffn": lambda: expert_ffn_jobs(True),
+        }
     else:
         from dynamo_tpu.worker.__main__ import build_parser
 
         worker = build_parser().parse_args([])
         top = worker.max_model_len // BLOCK_SIZE
         widths = [1 << i for i in range(top.bit_length())]
-        jobs = attention_jobs(False, B=worker.max_num_seqs, P=16)
-        jobs += fused_layer_jobs(
-            False, qwen3_8b_config(), B=worker.max_num_seqs, widths=widths
-        )
+        families = {
+            "paged_attention": lambda: attention_jobs(
+                False, B=worker.max_num_seqs, P=16),
+            "fused_decoder_layer": lambda: fused_layer_jobs(
+                False, qwen3_8b_config(), B=worker.max_num_seqs, widths=widths),
+            "expert_ffn": lambda: expert_ffn_jobs(False),
+        }
+    families["kv_pool_layout"] = lambda: []  # one row, after the timings
+    if args.only is not None and args.only not in families:
+        ap.error(f"--only takes one of {', '.join(families)}")
+    jobs = [job for name, make in families.items()
+            if args.only in (None, name) for job in make()]
     # Mosaic and XLA compile on the host with the GIL released: rows
     # compile side by side instead of one after another (32 rows took
     # 673 s in sequence on the chip machine, my chip run, PR 21).
@@ -556,7 +646,8 @@ def main() -> int:
         timer = r.pop("time", None)
         r["us_per_call"] = timer() if timer else None
     # After the timings: it holds a model and three sets of pools.
-    rows.append({"us_per_call": None, **pool_layout_job(args.interpret)()})
+    if args.only in (None, "kv_pool_layout"):
+        rows.append({"us_per_call": None, **pool_layout_job(args.interpret)()})
 
     dev = jax.devices()[0]
     print(f"kernel table on {dev.platform} / {dev.device_kind}"

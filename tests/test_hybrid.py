@@ -9,6 +9,7 @@ benchmark's copy of the reference; (h) refusals by mechanism.
 
 import asyncio
 import dataclasses
+import functools
 import importlib.util
 import os
 import subprocess
@@ -174,13 +175,32 @@ def _loop_moe(x, lp, spec):
     return out
 
 
-@pytest.mark.parametrize("form", ["dense", "grouped"])
-def test_dropless_when_every_token_chooses_one_expert(form, monkeypatch):
+@pytest.fixture
+def hit_list_calls(monkeypatch):
+    """``moe_ffn(use_kernel=True)`` on the CPU: the hit-list kernel under
+    the Pallas interpreter. Yields the (ids, count) of each call made."""
+    from dynamo_tpu.ops.pallas.expert_ffn import expert_ffn
+
+    calls = []
+
+    def interpreted(xs, comb, up, down, ids, count):
+        calls.append((np.asarray(ids), int(count[0])))
+        return expert_ffn(xs, comb, up, down, ids, count, interpret=True)
+
+    monkeypatch.setattr(moe, "expert_ffn", interpreted)
+    return calls
+
+
+@pytest.mark.parametrize("form", ["dense", "grouped", "hit_list"])
+def test_dropless_when_every_token_chooses_one_expert(form, monkeypatch, hit_list_calls):
     """All 24 tokens tie on expert 0 (a zero router): the capacity-factor op
     this replaced computed one and dropped the rest. Every one is computed."""
     rng = np.random.default_rng(1)
-    d, E, f, T = 8, 4, 16, 24
-    spec = ExpertsSpec(n_experts=E, top_k=1, d_ff=f)
+    # the kernel reads relu2 experts of a model width that fills the lanes
+    kernel = form == "hit_list"
+    d, E, f, T = 128 if kernel else 8, 4, 16, 24
+    spec = ExpertsSpec(n_experts=E, top_k=1, d_ff=f,
+                       activation="relu2" if kernel else "silu_gated")
     lp = dict(
         router_w=jnp.zeros((d, E), jnp.float32),
         we_gate=jnp.asarray(rng.standard_normal((E, d, f)) * 0.2, jnp.float32),
@@ -188,12 +208,80 @@ def test_dropless_when_every_token_chooses_one_expert(form, monkeypatch):
         we_down=jnp.asarray(rng.standard_normal((E, f, d)) * 0.2, jnp.float32),
     )
     x = jnp.asarray(rng.standard_normal((1, T, d)), jnp.float32)
-    monkeypatch.setattr(moe, "DENSE_TOKENS_MAX", 256 if form == "dense" else 0)
-    y, stats = moe.moe_ffn(x, lp, spec, want_stats=True)
+    monkeypatch.setattr(moe, "DENSE_TOKENS_MAX", 0 if form == "grouped" else 256)
+    y, stats = moe.moe_ffn(x, lp, spec, want_stats=True, use_kernel=kernel)
+    assert [n for _, n in hit_list_calls] == ([1] if kernel else [])
     want = _loop_moe(x[0], lp, spec)
     _close(y[0], want, 1e-4)
     assert (np.abs(want).max(-1) > 1e-6).all()  # no token's output is zero
     assert [float(s) for s in stats] == [1.0, T, T]  # one expert hit, by all
+
+
+HIT_CASES = {
+    # name: (experts the router may choose, of 8; [2, 6) are held)
+    "one_expert_hit": (0, 3),
+    "a_few_hit": (1, 2, 4, 7),
+    "all_held_hit": tuple(range(8)),
+}
+
+
+@pytest.mark.parametrize("dead_rows", [False, True], ids=["all_live", "dead_rows"])
+@pytest.mark.parametrize("case", sorted(HIT_CASES))
+def test_hit_list_kernel_is_the_dense_form_over_the_experts_hit(
+    case, dead_rows, hit_list_calls
+):
+    """The hit-list form against the per-token loop and against the dense
+    form, holding experts [2, 6) of a router 8 wide: its list is the held
+    experts that a LIVE token chose, ids first, the last repeated; a dead
+    row, which alone chooses held expert 5, adds nothing to it and counts
+    on no expert."""
+    rng = np.random.default_rng(7)
+    d, E, f, T, K, lo, hi = 128, 8, 48, 12, 2, 2, 6
+    allowed = [e for e in HIT_CASES[case] if not (dead_rows and e == 5)]
+    spec = ExpertsSpec(n_experts=E, top_k=K, d_ff=f, routing="sigmoid_bias",
+                       activation="relu2", held=(lo, hi))
+    live = np.ones(T, bool)
+    x = rng.standard_normal((T, d)).astype(np.float32)
+    router_w = rng.standard_normal((d, E)).astype(np.float32) * 0.1
+    bias = np.full(E, -100.0, np.float32)
+    bias[allowed] = 0.0
+    if dead_rows:
+        # feature 0 sends the dead rows, and only them, to expert 5
+        live[[1, 6, 11]] = False
+        x[:, 0] = np.where(live, -8.0, 8.0)
+        router_w[0], router_w[1:, 5], bias[5] = 0.0, 0.0, 0.0
+        router_w[0, 5] = 4.0
+    lp = dict(
+        router_w=jnp.asarray(router_w), router_bias=jnp.asarray(bias),
+        we_up=jnp.asarray(rng.standard_normal((E, d, f)) * 0.1, jnp.float32),
+        we_down=jnp.asarray(rng.standard_normal((E, f, d)) * 0.2, jnp.float32),
+    )
+    held = {k: (v[lo:hi] if k.startswith("we_") else v) for k, v in lp.items()}
+    x = jnp.asarray(x)
+    _, top_i = moe.route(x, lp, spec)
+    top_i = np.asarray(top_i)
+    if dead_rows:
+        assert (top_i[~live] == 5).any(1).all() and not (top_i[live] == 5).any()
+    want_hit = sorted({int(e) - lo for e in top_i[live].ravel() if lo <= e < hi})
+
+    mask = jnp.asarray(live).reshape(1, T)
+    y, stats = moe.moe_ffn(x[None], held, spec, row_mask=mask, want_stats=True,
+                           use_kernel=True)
+    (ids, count), = hit_list_calls
+    assert count == len(want_hit) == {"one_expert_hit": 1}.get(case, count)
+    assert case != "all_held_hit" or count == hi - lo - dead_rows
+    assert list(ids[:count]) == want_hit and (ids[count:] == ids[count - 1]).all()
+    assert len(ids) == hi - lo + 1  # never a list of one entry
+
+    dense, dense_stats = moe.moe_ffn(x[None], held, spec, row_mask=mask, want_stats=True)
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(dense_stats))
+    assert float(stats[0]) == count
+    _close(y[0], dense[0], 1e-5)
+    assert float(jnp.abs(y[0][~live]).max(initial=0.0)) == 0.0  # routed nowhere
+    # the loop oracle computes every expert of the router: the held share of it
+    absent = dict(lp, we_down=lp["we_down"].at[lo:hi].set(0.0))
+    want = _loop_moe(x, lp, spec) - _loop_moe(x, absent, spec)
+    _close(y[0][live], want[live], 1e-4)
 
 
 # -- (d) the share test -------------------------------------------------------------
@@ -211,18 +299,21 @@ def _share_setup():
     return spec, lp, x, want
 
 
-def test_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("form", ["xla", "hit_list"])
+def test_shares_add_up_to_the_uncut_layer(form, hit_list_calls):
     """Share 0 holds experts [0, 4), share 1 [4, 8); each returns its own
     experts' part plus the shared expert, so the shared expert is in the sum
     twice: counted once, the two parts are the uncut reference's output."""
     spec, lp, x, want = _share_setup()
+    kernel = form == "hit_list"
     parts = []
     for lo, hi in ((0, 4), (4, 8)):
         held = {k: (v[lo:hi] if k.startswith("we_") else v) for k, v in lp.items()}
-        parts.append(moe.moe_ffn(x, held, spec.holding(lo, hi)))
+        parts.append(moe.moe_ffn(x, held, spec.holding(lo, hi), use_kernel=kernel))
     shared_only = moe.moe_ffn(
         x, {k: (v[:0] if k.startswith("we_") else v) for k, v in lp.items()},
-        spec.holding(0, 0))
+        spec.holding(0, 0), use_kernel=kernel)
+    assert len(hit_list_calls) == (2 if kernel else 0)  # no expert held: no kernel
     _close(parts[0] + parts[1] - shared_only, want, 1e-4)
     assert float(jnp.abs(parts[0] - parts[1]).max()) > 1e-3  # the shares differ
 
@@ -474,3 +565,60 @@ def test_mechanisms_that_carry_only_kv_refuse_a_hybrid_configuration(mechanism):
     with pytest.raises(ValueError, match=f"{mechanism}.*recurrent state"):
         check(c)
     check(dataclasses.replace(c, layer_specs=None))  # a dense model passes
+
+
+# -- (i) which expert form a step takes, and what says so ---------------------------------
+
+
+FORM_CASES = {
+    # name: (use_kernel, tokens, d, f, held, activation, quantized) -> the form's first words
+    "decode_slots_on_the_chip": ((True, 64, 128, 48, 4, "relu2", False), "pallas hit list"),
+    "small_prefill_on_the_chip": ((True, 256, 128, 48, 4, "relu2", False), "pallas hit list"),
+    "no_kernels_here": ((False, 64, 128, 48, 4, "relu2", False), "xla dense, no Pallas"),
+    "a_prefill_chunk": ((True, 512, 128, 48, 4, "relu2", False), "xla grouped, 512 tokens"),
+    "quantized_matrices": ((True, 64, 128, 48, 4, "relu2", True), "xla dense, quantized"),
+    "gated_experts": ((True, 64, 128, 48, 4, "silu_gated", False), "xla dense, activation"),
+    "no_expert_held": ((True, 64, 128, 48, 0, "relu2", False), "xla dense, no expert held"),
+    "narrow_model_width": ((True, 64, 64, 48, 4, "relu2", False), "xla dense, widths d 64"),
+    "expert_width_fills_lanes": ((True, 64, 128, 256, 4, "relu2", False), "xla dense, widths"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FORM_CASES))
+def test_expert_form_follows_what_moe_ffn_is_given(case):
+    """``form_in_use`` (the runner's log line) and ``hit_list_reason`` (what
+    ``moe_ffn`` branches on) from the arguments alone: no flag, no name."""
+    (use_kernel, T, d, f, held, act, quantized), want = FORM_CASES[case]
+    spec = ExpertsSpec(n_experts=8, top_k=2, d_ff=f, activation=act, held=(0, held))
+    up = jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16)
+    lp = {"we_up": {"q8": up, "s": None} if quantized else up}
+    form = moe.form_in_use(use_kernel, T, lp, spec)
+    assert form.startswith(want)
+    assert (moe.hit_list_reason(use_kernel, T, lp, spec) is None) == (
+        form == "pallas hit list")
+
+
+@pytest.mark.parametrize("model", ["tiny-hybrid", "tiny-moe", "tiny"])
+def test_engine_stats_name_the_expert_form(model):
+    """``/engine/stats`` says beside ``attention_reason`` which form the
+    decode step's expert layers take; on the CPU none is the kernel."""
+    from dynamo_tpu.models.config import tiny_config, tiny_moe_config
+
+    config = {"tiny-hybrid": tiny_hybrid_config, "tiny-moe": tiny_moe_config,
+              "tiny": tiny_config}[model]()
+
+    async def run():
+        engine = JaxEngine(JaxEngineArgs(
+            config=config, block_size=16, num_kv_blocks=32, max_num_seqs=4,
+            max_model_len=128, prefill_chunk=32,
+        ))
+        try:
+            return engine.stats()
+        finally:
+            await engine.stop()
+
+    stats = asyncio.run(run())
+    assert "cpu" in stats["attention_reason"]
+    want = {"tiny-hybrid": "xla dense, no Pallas kernels here",
+            "tiny-moe": "xla, the stacked layer loop takes no kernel", "tiny": None}[model]
+    assert stats["expert_ffn"] == want or stats["expert_ffn"].startswith(want)

@@ -1,5 +1,5 @@
-"""The decode paged-attention kernel compiled by Mosaic for a described
-TPU v5e, at the served shapes: the chip's compiler is installed here and
+"""The decode paged-attention kernel and the hit-list expert kernel compiled
+by Mosaic for a described TPU v5e, at the served shapes: the chip's compiler is installed here and
 compiles for a chip that is not attached, so a lowering the interpreter
 accepts and Mosaic refuses (a slice not aligned to the tiling, too much
 VMEM) fails here, at no chip time. Nothing runs: this says nothing about
@@ -116,6 +116,33 @@ def test_chunk_kernel_compiles_for_v5e_at_head_64(one_chip, page):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tokens", [64, 128, 256])
+def test_expert_kernel_compiles_for_v5e_at_the_served_widths(one_chip, tokens):
+    """ops/pallas/expert_ffn.py at the hybrid configuration's widths (d 2688,
+    f 1856: 14.5 lane tiles, 64 held, bf16) for a decode burst's 64 slots
+    and the two small prefill shapes: Mosaic takes the [464, 2688] tiles of
+    both matrices, and XLA hands ``we_up`` over as it is resident (d minor:
+    the kernel's transpose is a bitcast), so the program holds no copy of a
+    matrix stack and no temporary the size of one."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+    from dynamo_tpu.ops.pallas.expert_ffn import _expert_ffn_impl
+
+    d, f, held = 2688, 1856, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up, down = sds((held, d, f), jnp.bfloat16), sds((held, f, d), jnp.bfloat16)
+    compiled = jax.jit(_expert_ffn_impl).lower(
+        sds((tokens, d), jnp.bfloat16), sds((tokens, held), jnp.float32), up, down,
+        sds((held + 1,), jnp.int32), sds((1,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert whole_pool_copies(text, up) == whole_pool_copies(text, down) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 @pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
 def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
     """The decode burst and the prefill step of a two-layer qwen2.5-0.5b,
@@ -206,6 +233,7 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     from dynamo_tpu.models.config import (
         NEMOTRON_3_NANO_30B_A3B_HF, ModelConfig, cut_hybrid,
     )
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
     full = ModelConfig.from_hf_config(NEMOTRON_3_NANO_30B_A3B_HF)
     cfg = cut_hybrid(full, n_layers=6, experts_held=(0, 8), vocab_rows=8192, name="cut")
@@ -258,6 +286,15 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     # benchmark/trace_names tells the two programs apart by a ``while``: the
     # burst is one, and a prefill step (scan, sort, grouped matmul) holds none
     assert (" while(" in text) == (program == "decode_burst")
+    # The burst's 64 slots and the tail's 128 tokens go through the hit-list
+    # expert kernel (2,048 fresh tokens through the grouped form), and no
+    # program re-lays a stack of expert matrices on the way to either.
+    assert ("expert_ffn_hit_list" in text) == (program != "prefill_fresh")
+    experts = params["layers"][1]
+    copies = [whole_pool_copies(text, experts[k]) for k in ("we_up", "we_down")]
+    # (the grouped form's ``ragged_dot`` does re-lay ``we_up``, resident
+    # with d minor, once a fresh prefill step: PERF.md §7)
+    assert copies == ([1, 0] if program == "prefill_fresh" else [0, 0])
     resident = sum(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(donated))
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
