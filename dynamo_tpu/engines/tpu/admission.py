@@ -344,7 +344,7 @@ class Admitter:
                     except Exception:
                         logger.exception("KV onboard failed; prefilling locally")
             matched, ids = e.pool.pin_prefix(hashes)
-            if e.config.is_hybrid:
+            if e.config.has_recurrent_state:
                 # A prefix is a hit only as far as K/V pages AND a snapshot
                 # of the recurrent state both reach, and short of the last
                 # token (whose logits the first sample needs).
@@ -475,8 +475,10 @@ class Admitter:
         """Snapshot destinations of one chunk round, nothing kept yet: every
         entry out of the store's range."""
         e = self.e
+        stride = e._ssm_stride  # 0: no layer keeps recurrent state
         return np.full(
-            (Bp, c_bucket // e._ssm_stride), e.runner.snap_entries, dtype=np.int32,
+            (Bp, c_bucket // stride if stride else 0), e.runner.snap_entries,
+            dtype=np.int32,
         )
 
     def _begin_prefill(self, batch: "List[Tuple[Any, Any]]") -> PendingPrefill:

@@ -314,13 +314,14 @@ class JaxEngine:
         # snapshots (their index here, the arrays in the runner). The router
         # hears of a block only once a snapshot covers it.
         hybrid = self.config.is_hybrid
+        recurrent = self.config.has_recurrent_state
         self.pool = BlockPool(
             args.num_kv_blocks, args.block_size, on_event=on_kv_event,
-            announce_commits=not hybrid,
+            announce_commits=not recurrent,
         )
         self.snapshots: Optional[StateSnapshots] = None
         self._ssm_stride = 0  # tokens between snapshot boundaries
-        if hybrid:
+        if recurrent:
             self._ssm_stride = self.config.specs_of("mamba2")[0].scan_block
             if args.prefill_chunk % self._ssm_stride:
                 raise ValueError(
@@ -741,7 +742,10 @@ class JaxEngine:
             # against /debug/compiles, what serving has compiled since.
             **self._startup_compile,
         }
-        if self.config.is_hybrid:
+        if self.config.has_latent_cache:
+            out["latent_pool"] = dict(self.runner.kv_pool)
+            out["mla_attention"] = self.runner.mla_attention
+        if self.config.has_recurrent_state:
             snaps = self.snapshots
             out["ssm_state_slots"] = {
                 "used": out["active_seqs"], "total": self.args.max_num_seqs,
@@ -1786,7 +1790,7 @@ class JaxEngine:
                 self.runner.proc_reset_slot(
                     slot, seq.request.token_ids, seq.generated
                 )
-        if self.config.is_hybrid:
+        if self.config.has_recurrent_state:
             # The recurrent state advanced with the dropped bursts and has
             # no host mirror to roll back to: every live sequence recomputes.
             for seq in [s for s in self._slots if s is not None]:

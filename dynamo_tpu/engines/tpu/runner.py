@@ -308,10 +308,11 @@ class DeviceRunner:
                 ) if on
             ]
             if unsupported:
+                what = ", ".join(unsupported)
                 raise ValueError(
-                    f"{self.config.name} is a hybrid model (recurrent state "
-                    f"beside paged K/V); not implemented for it: "
-                    + ", ".join(unsupported)
+                    self.config.hybrid_refusal(what)
+                    or f"{self.config.name} is served by the per-layer-spec "
+                    f"loop (models/hybrid.py); not implemented for it: {what}"
                 )
         self.use_kernel, self.attention_reason = self._choose_attention(
             args, backend, mesh
@@ -508,12 +509,30 @@ class DeviceRunner:
             self.attention_impl, self.attention_reason, self.expert_ffn,
         )
         values = jax.tree.leaves(self.k_cache)[0]  # int8 pools: "q8" sorts first
-        logger.info(
-            "kv pool: %.2f GB resident | %s%s per layer for a head of %d",
-            tree_device_bytes((self.k_cache, self.v_cache)) / 1e9,
-            values.dtype.name, list(values.shape), self.config.head_dim_,
-        )
-        if self.hybrid:
+        self.kv_pool = {
+            "gb": round(tree_device_bytes((self.k_cache, self.v_cache)) / 1e9, 3),
+            "dtype": values.dtype.name, "shape": list(values.shape),
+        }
+        if self.config.has_latent_cache:
+            spec = self.config.specs_of("mla")[0]
+            self.mla_attention = (
+                "kernel" if self.use_kernel else f"xla: {self.attention_reason}"
+            )
+            logger.info(
+                "latent pool: %.2f GB resident | %s%s per layer, %d layers: a row "
+                "is c_kv %d + rotary key %d of %d lanes, no V pool | "
+                "mla_attention: %s",
+                self.kv_pool["gb"], values.dtype.name, list(values.shape),
+                len(self.k_cache), spec.kv_rank, spec.rope_dim, values.shape[-1],
+                self.mla_attention,
+            )
+        else:
+            logger.info(
+                "kv pool: %.2f GB resident | %s%s per layer for a head of %d",
+                self.kv_pool["gb"], values.dtype.name, list(values.shape),
+                self.config.head_dim_,
+            )
+        if self.config.has_recurrent_state:
             logger.info(
                 "recurrent state: %.2f GB in %d slots, %.2f GB in %d snapshots "
                 "| %d attention, %d mamba2, %d expert layers",
@@ -527,7 +546,9 @@ class DeviceRunner:
 
     @property
     def hybrid(self) -> bool:
-        """One mixer per layer and recurrent state beside the pools."""
+        """The per-layer-spec loop serves (models/hybrid.py): its programs
+        carry the recurrent state beside the pools, empty where no layer
+        has any."""
         return self.config.is_hybrid
 
     @property

@@ -22,6 +22,9 @@ import jax.numpy as jnp
 # (``ModelConfig.layer_specs``); the layer loop (models/hybrid.py) dispatches
 # on the spec's ``kind``. The llama-family decoder layer (attention + FFN)
 # builds an ExpertsSpec from its MoE knobs, so one expert op serves both.
+# A published layer of two sublayers (a mixer, then an FFN or experts) is two
+# entries; where the family norms a sublayer's OUTPUT too, before the residual
+# is added (``sandwich_norm``), the entry says so: ``post_norm``.
 
 
 @dataclass(frozen=True)
@@ -31,6 +34,41 @@ class AttentionSpec:
     head_dim: int
     positions: str = "rope"  # "rope" | "none" (no positional rotation)
     kind: str = "attention"
+
+
+@dataclass(frozen=True)
+class LatentAttentionSpec:
+    """Multi-head latent attention (MLA): queries through a low-rank
+    bottleneck, keys and values expanded from one cached latent a token,
+    ``c_kv`` [kv_rank] beside one rotary key ``k_r`` [rope_dim] that all
+    heads share. The cache row is ``cache_width`` values, whatever the
+    number of heads."""
+
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int  # a head's query/key lanes without positions
+    rope_dim: int  # its rotary lanes; the key's are shared by every head
+    v_dim: int
+    post_norm: bool = False
+    kind: str = "mla"
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_rank + self.rope_dim
+
+
+@dataclass(frozen=True)
+class DenseFFNSpec:
+    """A gated-silu FFN as a sublayer of its own."""
+
+    d_ff: int
+    post_norm: bool = False
+    kind: str = "dense_ffn"
 
 
 @dataclass(frozen=True)
@@ -65,12 +103,14 @@ class ExpertsSpec:
     n_experts: int  # the router's width: every expert of the model
     top_k: int
     d_ff: int
-    routing: str = "softmax"  # "softmax" | "sigmoid_bias" (correction bias chooses)
+    # "softmax" | "sigmoid" | "sigmoid_bias" (a correction bias chooses)
+    routing: str = "softmax"
     norm_topk: bool = True
     scale: float = 1.0
     activation: str = "silu_gated"  # "silu_gated" | "relu2" (no gate matrix)
     shared_d_ff: int = 0  # one shared expert of this width (0 = none)
     held: Optional[Tuple[int, int]] = None  # [lo, hi) experts held here; None = all
+    post_norm: bool = False
     kind: str = "experts"
 
     @property
@@ -86,12 +126,16 @@ class ExpertsSpec:
         return dataclasses.replace(self, held=(lo, hi))
 
 
-LayerSpec = Union[AttentionSpec, Mamba2Spec, ExpertsSpec]
+LayerSpec = Union[
+    AttentionSpec, LatentAttentionSpec, Mamba2Spec, ExpertsSpec, DenseFFNSpec
+]
 
 
 def refuse_hybrid(config: Any, mechanism: str) -> None:
     """Raise, naming ``mechanism``, for a configuration whose sequences carry
-    recurrent state: the mechanisms that call this move paged K/V only."""
+    recurrent state or whose cache is one latent pool a layer: the mechanisms
+    that call this move a (K, V) pair of paged blocks per layer and nothing
+    else."""
     why = getattr(config, "hybrid_refusal", lambda m: None)(mechanism)
     if why:
         raise ValueError(why)
@@ -186,11 +230,31 @@ class ModelConfig:
     def specs_of(self, kind: str) -> List[LayerSpec]:
         return [s for s in (self.layer_specs or ()) if s.kind == kind]
 
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Sequences carry state beside the paged pools (Mamba-2 layers)."""
+        return bool(self.specs_of("mamba2"))
+
+    @property
+    def has_latent_cache(self) -> bool:
+        """The paged pools hold one latent row a token a layer, not K and V."""
+        return bool(self.specs_of("mla"))
+
     def hybrid_refusal(self, mechanism: str) -> Optional[str]:
         """Why ``mechanism`` cannot serve this configuration, or None. The
         mechanisms that spell out the per-layer K/V tuple (disaggregation
-        wire, KVBM tiers, KV checkpoints, the fused-layer megakernel) know
-        nothing of per-sequence recurrent state."""
+        wire, KVBM tiers, KV checkpoints, int8 KV, the fused-layer
+        megakernel) know nothing of per-sequence recurrent state, nor of a
+        pool that is one latent tile a layer."""
+        mla = self.specs_of("mla")
+        if mla:
+            return (
+                f"{mechanism} carries a (K, V) pair of paged blocks per layer, "
+                f"and {self.name} keeps ONE latent pool per layer (c_kv beside "
+                f"the shared rotary key, {mla[0].cache_width} values a token) "
+                f"in {len(mla)} latent-attention layers: there is no K and no "
+                f"V block for {mechanism} to move"
+            )
         if not self.specs_of("mamba2"):
             return None
         return (
@@ -222,6 +286,8 @@ class ModelConfig:
     def from_hf_config(cls, cfg: Dict[str, Any], name: str = "") -> "ModelConfig":
         if str(cfg.get("model_type", "")) == "nemotron_h":
             return _nemotron_h_from_hf(cfg, name)
+        if str(cfg.get("model_type", "")) == "pangu_ultra_moe":
+            return _pangu_ultra_moe_from_hf(cfg, name)
         archs = cfg.get("architectures") or [""]
         arch = archs[0].lower()
         eos = cfg.get("eos_token_id")
@@ -439,6 +505,114 @@ def cut_hybrid(
     )
 
 
+def _pangu_ultra_moe_from_hf(cfg: Dict[str, Any], name: str = "") -> ModelConfig:
+    """``pangu_ultra_moe``: every published layer is latent attention, then
+    a dense FFN (the first ``first_k_dense_replace`` layers) or the experts;
+    two entries of ``layer_specs`` a layer. ``sandwich_norm`` puts a norm on
+    each sublayer's output too. Sigmoid scores choose and weigh the experts
+    (no ``scoring_func``, ``n_group`` or ``topk_method`` key: no correction
+    bias, no groups). The multi-token-prediction module
+    (``num_nextn_predict_layers``) is a draft head and is not built."""
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"pangu_ultra_moe: hidden_act {cfg['hidden_act']!r} is not implemented")
+    if cfg.get("rope_scaling"):
+        raise ValueError("pangu_ultra_moe: rope_scaling is not implemented")
+    if cfg.get("attention_bias"):
+        raise ValueError("pangu_ultra_moe: attention_bias is not implemented")
+    post = bool(cfg.get("sandwich_norm", False))
+    mla = LatentAttentionSpec(
+        n_heads=int(cfg["num_attention_heads"]), q_rank=int(cfg["q_lora_rank"]),
+        kv_rank=int(cfg["kv_lora_rank"]), nope_dim=int(cfg["qk_nope_head_dim"]),
+        rope_dim=int(cfg["qk_rope_head_dim"]), v_dim=int(cfg["v_head_dim"]),
+        post_norm=post,
+    )
+    dense = DenseFFNSpec(d_ff=int(cfg["intermediate_size"]), post_norm=post)
+    experts = ExpertsSpec(
+        n_experts=int(cfg["n_routed_experts"]), top_k=int(cfg["num_experts_per_tok"]),
+        d_ff=int(cfg["moe_intermediate_size"]), routing="sigmoid",
+        norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        shared_d_ff=int(cfg["moe_intermediate_size"]) * int(cfg.get("n_shared_experts", 0)),
+        post_norm=post,
+    )
+    n, k = int(cfg["num_hidden_layers"]), int(cfg.get("first_k_dense_replace", 0))
+    specs: List[LayerSpec] = []
+    for i in range(n):
+        specs += [mla, dense if i < k else experts]
+    eos = cfg.get("eos_token_id")
+    return ModelConfig(
+        vocab_size=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        n_layers=len(specs), n_heads=mla.n_heads,
+        n_kv_heads=int(cfg.get("num_key_value_heads", mla.n_heads)),
+        head_dim=mla.qk_dim, d_ff=dense.d_ff,
+        rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        max_position_embeddings=int(cfg.get("max_position_embeddings", 8192)),
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        eos_token_ids=[] if eos is None else [int(e) for e in (eos if isinstance(eos, list) else [eos])],
+        bos_token_id=cfg.get("bos_token_id"),
+        name=name or "pangu_ultra_moe",
+        layer_specs=tuple(specs),
+    )
+
+
+# openPangu-Ultra-MoE-718B, the keys of its public config.json that say
+# something about its shape
+# (https://huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B/blob/main/config.json).
+OPENPANGU_ULTRA_MOE_718B_HF: Dict[str, Any] = {
+    "model_type": "pangu_ultra_moe", "hidden_size": 7680, "num_hidden_layers": 61,
+    "first_k_dense_replace": 3, "intermediate_size": 18432, "hidden_act": "silu",
+    "num_attention_heads": 128, "num_key_value_heads": 128, "attention_bias": False,
+    "q_lora_rank": 1536, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "sandwich_norm": True,
+    "n_routed_experts": 256, "num_experts_per_tok": 8, "moe_intermediate_size": 2048,
+    "n_shared_experts": 1, "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+    "num_nextn_predict_layers": 1, "rms_norm_eps": 1e-05, "rope_theta": 25600000,
+    "max_position_embeddings": 131072, "tie_word_embeddings": False,
+    "vocab_size": 153600,
+}
+
+
+def openpangu_ultra_moe_ep16_config() -> ModelConfig:
+    """openPangu-Ultra-MoE-718B at its published widths, as share 0 of the
+    first stage of its served deployment: sixteen chips share each layer by
+    expert parallelism (16 of the 256 routed experts each; latent attention,
+    dense FFN, router and shared expert whole on every chip, each attending
+    over its own batch), the vocabulary sliced eight ways, the 61 layers on
+    thirteen such groups as pipeline stages. This chip: one leading dense
+    layer and four expert layers, ten sublayers."""
+    hf = dict(OPENPANGU_ULTRA_MOE_718B_HF, num_hidden_layers=5, first_k_dense_replace=1)
+    return cut_hybrid(
+        ModelConfig.from_hf_config(hf), n_layers=10, experts_held=(0, 16),
+        vocab_rows=19200, name="openpangu-ultra-moe-718b-ep16",
+    )
+
+
+def tiny_mla_config(**overrides) -> ModelConfig:
+    """The openPangu layer at toy widths (tests, the CPU rehearsal): one
+    leading dense layer and two expert layers, sandwich norms, latent
+    attention with a cache row of 32 + 16 values, 16 experts routed over of
+    which the first 4 are held, plain sigmoid routing, gated-silu experts
+    and a shared one."""
+    mla = LatentAttentionSpec(
+        n_heads=4, q_rank=48, kv_rank=32, nope_dim=16, rope_dim=16, v_dim=16,
+        post_norm=True,
+    )
+    dense = DenseFFNSpec(d_ff=192, post_norm=True)
+    experts = ExpertsSpec(
+        n_experts=16, top_k=4, d_ff=64, routing="sigmoid", scale=2.5,
+        shared_d_ff=64, held=(0, 4), post_norm=True,
+    )
+    base = dict(
+        vocab_size=512, d_model=128, n_layers=6, n_heads=4, n_kv_heads=4,
+        head_dim=32, d_ff=192, max_position_embeddings=2048, eos_token_ids=[2],
+        rope_theta=25600000.0, dtype=jnp.float32, name="tiny-mla",
+        layer_specs=(mla, dense, mla, experts, mla, experts),
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
 def nemotron3_nano_ep2_config() -> ModelConfig:
     """NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, as share 0 of
     the first stage of its served deployment: two chips share each layer by
@@ -651,6 +825,7 @@ def all_presets() -> Dict[str, "ModelConfig"]:
         qwen2_500m_config(), llama3_8b_config(), llama3_3b_config(),
         llama3_70b_config(), qwen3_8b_config(), gemma3_1b_config(),
         gemma2_2b_config(), tiny_hybrid_config(), nemotron3_nano_ep2_config(),
+        tiny_mla_config(), openpangu_ultra_moe_ep16_config(),
     ]
     return {c.name: c for c in presets}
 

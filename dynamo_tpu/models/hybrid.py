@@ -8,6 +8,14 @@ mixture of experts (``ops/moe.py``). ``ModelConfig.layer_specs`` says which;
 ``llama.forward_paged`` / ``llama.decode_multi`` hand over to this module
 when it is set, so the dense decoder layer stays as it was.
 
+A family whose published layer is two sublayers (``pangu_ultra_moe``: latent
+attention, then a dense FFN or the experts) is two entries a layer; a spec
+with ``post_norm`` norms the sublayer's output too, before the residual is
+added (``h <- h + rmsnorm(mixer(rmsnorm(h)))``). Latent attention (MLA)
+caches ONE row a token a layer, ``c_kv`` beside the rotary key all heads
+share: ``k_cache[i]`` is then the i-th latent layer's pool
+``[blocks, block, width]`` and ``v_cache`` is empty.
+
 Two kinds of state travel with a sequence. K/V pages exist only for the
 attention layers: ``k_cache[i]`` belongs to the i-th ATTENTION layer. The
 recurrent state of the Mamba-2 layers is ``{"conv": (...), "S": (...)}``,
@@ -33,6 +41,11 @@ from dynamo_tpu.models.llama import _rms_norm as _rms
 from dynamo_tpu.ops import mamba2 as m2
 from dynamo_tpu.ops.attention import (
     dense_chunk_attention,
+    latent_pool_width,
+    pad_head,
+    mla_attention_plan,
+    mla_chunk_attention,
+    mla_paged_attention,
     paged_attention,
     paged_attention_plan,
     pool_head_dim,
@@ -58,13 +71,40 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     d = c.d_model
 
     def norm(k, shape, scale, dtype=None):
-        return (jax.random.normal(k, shape, dtype=_F32) * scale).astype(dtype or c.dtype)
+        # One matrix at a time: dispatched ahead, the float32 draws of a whole
+        # layer's expert stacks are alive at once, beside the weights already
+        # held (15.70 of 15.75 GB at 4.9 B parameters: my chip run, PR 39).
+        return jax.block_until_ready(
+            (jax.random.normal(k, shape, dtype=_F32) * scale).astype(dtype or c.dtype)
+        )
 
     layers = []
     for i, spec in enumerate(c.layer_specs):
         k = jax.random.split(jax.random.fold_in(key, i), 8)
         lp: Params = {"norm": jnp.ones((d,), c.dtype)}
-        if spec.kind == "attention":
+        if getattr(spec, "post_norm", False):
+            lp["post_norm"] = jnp.ones((d,), c.dtype)
+        if spec.kind == "mla":
+            H, qr, kr = spec.n_heads, spec.q_rank, spec.kv_rank
+            # The published ``kv_b_proj`` is held as its key half and its
+            # value half: the absorbed form uses them apart.
+            lp.update(
+                w_qa=norm(k[0], (d, qr), d**-0.5),
+                q_norm=jnp.ones((qr,), c.dtype),
+                w_qb=norm(k[1], (qr, H, spec.qk_dim), qr**-0.5),
+                w_kva=norm(k[2], (d, spec.cache_width), d**-0.5),
+                kv_norm=jnp.ones((kr,), c.dtype),
+                w_kb=norm(k[3], (kr, H, spec.nope_dim), kr**-0.5),
+                w_vb=norm(k[4], (kr, H, spec.v_dim), kr**-0.5),
+                wo=norm(k[5], (H * spec.v_dim, d), (H * spec.v_dim) ** -0.5),
+            )
+        elif spec.kind == "dense_ffn":
+            f = spec.d_ff
+            lp.update(
+                w_gate=norm(k[0], (d, f), d**-0.5), w_up=norm(k[1], (d, f), d**-0.5),
+                w_down=norm(k[2], (f, d), f**-0.5),
+            )
+        elif spec.kind == "attention":
             hq, hk = spec.n_heads * spec.head_dim, spec.n_kv_heads * spec.head_dim
             lp.update(
                 wq=norm(k[0], (d, hq), d**-0.5), wk=norm(k[1], (d, hk), d**-0.5),
@@ -124,7 +164,23 @@ def param_logical_axes(config: ModelConfig) -> Params:
 
 def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int):
     """One layered pool per ATTENTION layer, in the layout the kernels read
-    (llama.init_kv_cache's layered form)."""
+    (llama.init_kv_cache's layered form). A latent-attention model has one
+    LATENT pool per such layer and no V pool: logically
+    [blocks, block, 1, cache_width], held as [blocks, block, width] with the
+    row at whole lane tiles (ops/attention.latent_pool_width)."""
+    latent = config.specs_of("mla")
+    if latent:
+        if config.specs_of("attention"):
+            raise ValueError(
+                f"{config.name}: latent and K/V attention layers in one model "
+                "are not implemented (one block table, two pool shapes)"
+            )
+        return tuple(
+            jnp.zeros(
+                (num_blocks, block_size, latent_pool_width(s.cache_width)), config.dtype
+            )
+            for s in latent
+        ), ()
     k, v = [], []
     for spec in config.specs_of("attention"):
         shape = (num_blocks, block_size, spec.n_kv_heads, pool_head_dim(spec.head_dim))
@@ -233,6 +289,54 @@ def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_le
     return jnp.einsum("bch,hd->bcd", attn.reshape(B, C, -1), lp["wo"]), k_c, v_c
 
 
+def _mla_mixer(c, spec, lp, h, pool, block_tables, start_pos, chunk_lens, rope,
+               *, use_kernel, first_chunk, plan):
+    """Latent attention. The cache row ``c_kv | k_r`` is written first;
+    a fresh chunk then attends in the expanded form over its own latents,
+    every other step in the absorbed form over the pool's rows."""
+    B, C, _ = h.shape
+    H, R, dn = spec.n_heads, spec.kv_rank, spec.nope_dim
+    scale = spec.qk_dim**-0.5
+    cq = _rms(jnp.einsum("bcd,dr->bcr", h, lp["w_qa"]), lp["q_norm"], c.rms_norm_eps)
+    q = jnp.einsum("bcr,rhk->bchk", cq, lp["w_qb"])  # [B, C, H, nope + rope]
+    q_n, q_r = q[..., :dn], apply_rope(q[..., dn:], *rope)
+    ckr = jnp.einsum("bcd,dr->bcr", h, lp["w_kva"])
+    c_kv = _rms(ckr[..., :R], lp["kv_norm"], c.rms_norm_eps)
+    k_r = apply_rope(ckr[..., None, R:], *rope)[:, :, 0]  # one key, every head's
+    pool = write_chunk_to_cache(
+        pool, jnp.concatenate([c_kv, k_r], axis=-1), block_tables, start_pos, chunk_lens
+    )
+    if first_chunk:
+        k_n = jnp.einsum("bcr,rhk->bchk", c_kv, lp["w_kb"])
+        v = jnp.einsum("bcr,rhk->bchk", c_kv, lp["w_vb"])
+        k = jnp.concatenate(
+            [k_n, jnp.broadcast_to(k_r[:, :, None], (B, C, H, spec.rope_dim))], axis=-1
+        )
+        attn = mla_chunk_attention(
+            jnp.concatenate([q_n, q_r], axis=-1), k, v, chunk_lens, sm_scale=scale
+        )
+    else:
+        q_abs = jnp.concatenate(
+            [jnp.einsum("bchk,rhk->bchr", q_n, lp["w_kb"]), q_r], axis=-1
+        )
+        o_lat = mla_paged_attention(
+            pad_head(q_abs, pool.shape[-1]), pool, block_tables, start_pos, chunk_lens,
+            v_width=R, sm_scale=scale, use_kernel=use_kernel, plan=plan,
+        )
+        attn = jnp.einsum("bchr,rhk->bchk", o_lat, lp["w_vb"])
+    return jnp.einsum("bch,hd->bcd", attn.reshape(B, C, -1), lp["wo"]), pool
+
+
+def _dense_ffn(lp, h):
+    gate = jnp.einsum("bcd,df->bcf", h, lp["w_gate"])
+    up = jnp.einsum("bcd,df->bcf", h, lp["w_up"])
+    return jnp.einsum("bcf,fd->bcd", jax.nn.silu(gate) * up, lp["w_down"])
+
+
+# A sublayer's scope in a device trace: ``mixer_<kind>`` but for these.
+_SCOPES = {"mla": "mixer_mla", "dense_ffn": "ffn_dense"}
+
+
 # -- forward -------------------------------------------------------------------
 
 
@@ -261,11 +365,18 @@ def forward(
     B, C = tokens.shape
     x = params["embed"][tokens].astype(c.dtype)
     rope = None
-    if any(s.positions == "rope" for s in c.specs_of("attention")):
+    latent = c.specs_of("mla")
+    if latent or any(s.positions == "rope" for s in c.specs_of("attention")):
         pos = start_pos[:, None] + jax.lax.broadcasted_iota(jnp.int32, (B, C), 1)
-        rope = rope_table(pos, c.head_dim_, c.rope_theta)
+        rope = rope_table(
+            pos, latent[0].rope_dim if latent else c.head_dim_, c.rope_theta
+        )
     plan = None
-    if not first_chunk and c.specs_of("attention"):
+    if not first_chunk and latent:
+        plan = mla_attention_plan(
+            C, k_cache[0], block_tables, start_pos, chunk_lens, use_kernel=use_kernel
+        )
+    elif not first_chunk and c.specs_of("attention"):
         plan = paged_attention_plan(
             C, c.n_heads, k_cache[0], block_tables, start_pos, chunk_lens,
             use_kernel=use_kernel, window=0,
@@ -279,8 +390,16 @@ def forward(
     ia = im = 0
     for spec, lp in zip(c.layer_specs, params["layers"]):
         h = _rms(x, lp["norm"], c.rms_norm_eps)
-        with jax.named_scope(f"mixer_{spec.kind}"):
-            if spec.kind == "attention":
+        with jax.named_scope(_SCOPES.get(spec.kind, f"mixer_{spec.kind}")):
+            if spec.kind == "mla":
+                out, k_out[ia] = _mla_mixer(
+                    c, spec, lp, h, k_cache[ia], block_tables, start_pos, chunk_lens,
+                    rope, use_kernel=use_kernel, first_chunk=first_chunk, plan=plan,
+                )
+                ia += 1
+            elif spec.kind == "dense_ffn":
+                out = _dense_ffn(lp, h)
+            elif spec.kind == "attention":
                 out, k_out[ia], v_out[ia] = _attention_mixer(
                     c, spec, lp, h, k_cache[ia], v_cache[ia], block_tables, start_pos,
                     chunk_lens, rope, use_kernel=use_kernel, first_chunk=first_chunk,
@@ -308,6 +427,8 @@ def forward(
                     stats = stats + st
                 else:
                     out = moe_ffn(h, lp, spec, row_mask=real, use_kernel=use_kernel)
+            if "post_norm" in lp:
+                out = _rms(out.astype(x.dtype), lp["post_norm"], c.rms_norm_eps)
         x = x + out.astype(x.dtype)
     ssm_new = {"conv": tuple(conv_out), "S": tuple(s_out)}
     store_new = None if store is None else {

@@ -28,6 +28,12 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     paged_attention_decode_kernel,
     paged_attention_kernel,
 )
+from dynamo_tpu.ops.pallas.mla_paged import (
+    LatentPlan,
+    latent_plan,
+    mla_paged_decode,
+    query_block,
+)
 from dynamo_tpu.runtime.device_observe import watched_jit
 
 NEG_INF = -1e30
@@ -56,6 +62,16 @@ def pad_head(x: jnp.ndarray, width: int) -> jnp.ndarray:
     if pad == 0:
         return x
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def latent_pool_width(cache_width: int) -> int:
+    """The minor dimension a latent (MLA) pool is held at: the cache row
+    (c_kv beside the shared rotary key) at a whole number of lane tiles.
+    XLA tiles the minor dimension in 128 lanes whatever is asked for, so
+    576 values a token occupy 640 either way; held at 640 the kernel's
+    page tile is the resident one and its contraction runs over whole
+    tiles (the lanes past the row are zeros in the pool and in q~)."""
+    return -(-cache_width // KV_LANE_TILE) * KV_LANE_TILE
 
 
 def _takes_decode_kernel(C: int, n_heads: int, k_cache) -> bool:
@@ -272,3 +288,112 @@ def write_chunk_to_cache(
         "q8": cache["q8"].at[block_idx, slot].set(q8, mode="drop"),
         "s": cache["s"].at[block_idx, :, slot].set(s, mode="drop"),
     }
+
+
+# -- latent attention (MLA) ----------------------------------------------------
+# Two forms of one attention. EXPANDED: per-head keys and values are made
+# from the latents (a fresh chunk, whose latents are in registers). ABSORBED:
+# the key's up-projection is folded into the query and the value's applied
+# after the sum, so the H heads attend over the cached rows themselves
+# (decode, and a chunk that has context): one key/value head whose key is the
+# whole row and whose value is its first ``v_width`` lanes.
+
+# Query positions per block of the XLA forms: bounds the float32 scores at
+# [B, block, H, keys].
+_MLA_XLA_QUERY_BLOCK = 64
+
+
+def mla_chunk_attention(
+    q: jnp.ndarray,  # [B, C, H, Dqk]
+    k: jnp.ndarray,  # [B, C, H, Dqk] — the chunk's own expanded keys
+    v: jnp.ndarray,  # [B, C, H, Dv]
+    chunk_lens: jnp.ndarray,  # [B]
+    *,
+    sm_scale: float,
+) -> jnp.ndarray:
+    """Expanded form over a fresh chunk (every row starts at 0): causal
+    attention within the chunk, in blocks of query positions so that the
+    scores of 128 heads stay [B, H, block, keys]. Returns [B, C, H, Dv]."""
+    B, C, H, _ = q.shape
+    qb = query_block(C, _MLA_XLA_QUERY_BLOCK)
+    out = []
+    for r0 in range(0, C, qb):  # unrolled: a prefill program holds no ``while``
+        keys = r0 + qb  # causal: this block sees no key past its last query
+        kf = k[:, :keys].astype(jnp.float32)
+        vf = v[:, :keys].astype(jnp.float32)
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q[:, r0:keys].astype(jnp.float32), kf
+        ) * sm_scale
+        rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (qb, keys), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (qb, keys), 1)
+        valid = (cols[None] < chunk_lens[:, None, None])[:, None]  # [B, 1, qb, keys]
+        s = jnp.where((cols <= rows)[None, None] & valid, s, NEG_INF)
+        out.append(jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), vf))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
+def _mla_paged_xla_impl(
+    q, pool, block_tables, start_pos, chunk_lens, *, v_width: int, sm_scale: float
+):
+    """Absorbed form, XLA: gather the rows' pages, then blocks of query
+    positions against all of them. The oracle for the kernel, and what the
+    CPU and a mesh serve from."""
+    B, C, H, W = q.shape
+    T = block_tables.shape[1] * pool.shape[1]
+    rows = pool[block_tables].reshape(B, T, W).astype(jnp.float32)
+    qb = query_block(C, _MLA_XLA_QUERY_BLOCK)
+    t_pos = jax.lax.broadcasted_iota(jnp.int32, (qb, T), 1)
+    out = []
+    for r0 in range(0, C, qb):  # unrolled, as above
+        s = jnp.einsum(
+            "bqhw,btw->bqht", q[:, r0 : r0 + qb].astype(jnp.float32), rows
+        ) * sm_scale
+        limit = (
+            start_pos[:, None, None] + r0
+            + jax.lax.broadcasted_iota(jnp.int32, (qb, T), 0)[None]
+        )
+        s = jnp.where((t_pos[None] <= limit)[:, :, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        out.append(jnp.einsum("bqht,btw->bqhw", p, rows[..., :v_width]))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
+_mla_paged_xla = watched_jit(
+    "ops.mla_paged_xla",
+    partial(jax.jit, static_argnames=("v_width", "sm_scale"))(_mla_paged_xla_impl),
+)
+
+
+def mla_attention_plan(
+    C: int, pool, block_tables, start_pos, chunk_lens, *, use_kernel: bool
+) -> Optional[LatentPlan]:
+    """The absorbed kernel's grid for one forward step, shared by its
+    latent-attention layers; None where the XLA form serves."""
+    if not use_kernel:
+        return None
+    return latent_plan(pool, block_tables, start_pos, chunk_lens, C)
+
+
+def mla_paged_attention(
+    q: jnp.ndarray,  # [B, C, H, W] absorbed queries (zeros past the cache row)
+    pool: jnp.ndarray,  # [num_blocks, block_size, W] latent rows
+    block_tables: jnp.ndarray,
+    start_pos: jnp.ndarray,
+    chunk_lens: jnp.ndarray,
+    *,
+    v_width: int,
+    sm_scale: float,
+    use_kernel: bool = False,
+    plan: Optional[LatentPlan] = None,
+) -> jnp.ndarray:
+    """Absorbed latent attention over the paged latent pool; the chunk's
+    own rows must already be written. Returns [B, C, H, v_width]."""
+    if use_kernel:
+        return mla_paged_decode(
+            q, pool, block_tables, start_pos, chunk_lens, plan,
+            v_width=v_width, sm_scale=sm_scale,
+        )
+    return _mla_paged_xla(
+        q, pool, block_tables, start_pos, chunk_lens,
+        v_width=v_width, sm_scale=sm_scale,
+    )

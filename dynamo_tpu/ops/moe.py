@@ -79,6 +79,9 @@ def route(
     if spec.routing == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
         choose = scores
+    elif spec.routing == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choose = scores
     elif spec.routing == "sigmoid_bias":
         scores = jax.nn.sigmoid(logits)
         choose = scores + lp["router_bias"].astype(jnp.float32)

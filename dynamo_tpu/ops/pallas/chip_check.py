@@ -444,6 +444,77 @@ def expert_ffn_jobs(interpret: bool):
     return jobs
 
 
+def mla_jobs(interpret: bool):
+    """The absorbed latent-attention kernel (``mla_paged_decode``) against
+    its XLA oracle at the served widths: 128 heads over a latent pool of
+    640-lane rows (c_kv 512 + rotary key 64, lanes past 576 zero) in
+    128-token pages. Decode rows at the cell's context, a ONE-row decode
+    (``required``: a one-entry work list halted the core once, PR 25), and
+    a question chunk over a cached document. The timed rows print the
+    kernel's own roofline: bytes of the live pages at the HBM peak against
+    the FLOPs at the bf16 peak."""
+    from dynamo_tpu.ops.attention import _mla_paged_xla
+    from dynamo_tpu.ops.pallas.mla_paged import mla_paged_decode
+
+    H, R, W, bs = (4, 128, 256, 16) if interpret else (128, 512, 640, 128)
+    dtype = jnp.float32 if interpret else jnp.bfloat16
+    scale = 192**-0.5
+
+    def job(B, C, context, P, required=False):
+        rng = np.random.default_rng(B * 1000 + C)
+        NB = B * P + 1
+        pool = jnp.zeros((NB, bs, W), dtype).at[..., : R + 64].set(
+            jnp.asarray(rng.standard_normal((NB, bs, R + 64)), dtype))
+        tables = jnp.asarray(1 + rng.permutation(B * P).reshape(B, P), jnp.int32)
+        q = jnp.zeros((B, C, H, W), dtype).at[..., : R + 64].set(
+            jnp.asarray(rng.standard_normal((B, C, H, R + 64)), dtype))
+        start = jnp.asarray(context - rng.integers(0, bs, B), jnp.int32)
+        lens = jnp.full((B,), C, jnp.int32)
+        row = {
+            "kernel": "mla_paged_decode",
+            "shape": f"B{B} C{C} H{H} ctx{context} P{P} bs{bs} W{W} {dtype.__name__}",
+            "presets": ["openpangu-ultra-moe-718b-ep16"],
+            "required": required,
+        }
+
+        def kernel(q, pool, tables, start, lens):
+            return mla_paged_decode(q, pool, tables, start, lens, v_width=R,
+                                    sm_scale=scale, interpret=interpret)
+
+        def xla(q, pool, tables, start, lens):
+            return _mla_paged_xla(q, pool, tables, start, lens, v_width=R, sm_scale=scale)
+
+        args = (q, pool, tables, start, lens)
+        row = _timed(row, lambda: kernel(*args), lambda: xla(*args), ulps=8)
+        if row["status"] == "compiled" and not interpret:
+
+            def timed():
+                # (the timed loop feeds each call's result back into q)
+                us = _us_per_call(
+                    lambda *a: jnp.pad(kernel(*a), [(0, 0)] * 3 + [(0, W - R)]), *args)
+                keys = float(np.asarray(start).sum() + B * C)
+                bytes_, flops = keys * W * 2, 2.0 * keys * C * H * (W + R)
+                least = max(bytes_ / 819e9, flops / 197e12) * 1e6
+                row["message"] = (
+                    f"least {least:.0f} us ({bytes_ / 819e9 * 1e6:.0f} bytes, "
+                    f"{flops / 197e12 * 1e6:.0f} flops): {100 * least / us:.1f}% of roofline")
+                return us
+
+            row["time"] = timed
+        return row
+
+    if interpret:
+        return [functools.partial(job, 3, 1, 40, 4), functools.partial(job, 1, 1, 40, 4, True),
+                functools.partial(job, 2, 16, 40, 4)]
+    return [
+        functools.partial(job, 24, 1, 16500, 136),
+        functools.partial(job, 32, 1, 16500, 136),
+        functools.partial(job, 1, 1, 16500, 136, True),
+        functools.partial(job, 1, 128, 16384, 136),
+        functools.partial(job, 2, 256, 16384, 136),
+    ]
+
+
 def whole_pool_copies(hlo_text: str, pool) -> int:
     """``copy`` instructions of a compiled program's optimised HLO whose
     result is a whole per-layer KV pool (``pool``: its shape and dtype):
@@ -616,6 +687,7 @@ def main() -> int:
             "fused_decoder_layer": lambda: fused_layer_jobs(
                 True, fused_cfg, B=4, widths=[1, 4]),
             "expert_ffn": lambda: expert_ffn_jobs(True),
+            "mla_paged_decode": lambda: mla_jobs(True),
         }
     else:
         from dynamo_tpu.worker.__main__ import build_parser
@@ -629,6 +701,7 @@ def main() -> int:
             "fused_decoder_layer": lambda: fused_layer_jobs(
                 False, qwen3_8b_config(), B=worker.max_num_seqs, widths=widths),
             "expert_ffn": lambda: expert_ffn_jobs(False),
+            "mla_paged_decode": lambda: mla_jobs(False),
         }
     families["kv_pool_layout"] = lambda: []  # one row, after the timings
     if args.only is not None and args.only not in families:

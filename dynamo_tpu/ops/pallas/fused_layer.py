@@ -131,6 +131,12 @@ def supports_reason(
     logit softcap, post-norms, unit-offset RMSNorm, qkv-bias and GeGLU
     are in-kernel epilogues since r11 and are deliberately absent."""
     c = config
+    if getattr(c, "has_latent_cache", False):
+        return (
+            "latent (MLA) cache: the kernel streams a K and a V page of one "
+            "head width per layer, and this model keeps one latent pool a "
+            "layer (c_kv beside the shared rotary key) and no V pool"
+        )
     if getattr(c, "is_hybrid", False):
         return (
             "hybrid model (one mixer per layer: the kernel fuses attention "

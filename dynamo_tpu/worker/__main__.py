@@ -21,10 +21,12 @@ from dynamo_tpu.models.config import (
     llama3_70b_config,
     mixtral_8x7b_config,
     nemotron3_nano_ep2_config,
+    openpangu_ultra_moe_ep16_config,
     qwen2_500m_config,
     qwen3_8b_config,
     tiny_config,
     tiny_hybrid_config,
+    tiny_mla_config,
 )
 from dynamo_tpu.parallel import MeshConfig, make_mesh
 from dynamo_tpu.router import KvEventPublisher, LoadPublisher
@@ -49,6 +51,8 @@ BUILTIN_CONFIGS = {
     "mixtral-8x7b": mixtral_8x7b_config,
     "tiny-hybrid": tiny_hybrid_config,
     "nemotron-3-nano-30b-a3b-ep2": nemotron3_nano_ep2_config,
+    "tiny-mla": tiny_mla_config,
+    "openpangu-ultra-moe-718b-ep16": openpangu_ultra_moe_ep16_config,
 }
 
 

@@ -210,6 +210,9 @@ DOCUMENTED_PRESET_EXCLUSIONS = {
     # one mixer per layer, recurrent state beside paged K/V
     "tiny-hybrid": "hybrid",
     "nemotron-3-nano-30b-a3b-ep2": "hybrid",
+    # one latent pool a layer, no K and no V page
+    "tiny-mla": "latent",
+    "openpangu-ultra-moe-718b-ep16": "latent",
 }
 
 

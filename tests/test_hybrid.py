@@ -287,34 +287,46 @@ def test_hit_list_kernel_is_the_dense_form_over_the_experts_hit(
 # -- (d) the share test -------------------------------------------------------------
 
 
-def _share_setup():
-    c = tiny_hybrid_config()
-    spec = dataclasses.replace(c.layer_specs[1], held=None)  # all 8 experts
+def _share_setup(model="nemotron_h"):
+    """(uncut spec, its weights, x, the uncut reference's output, the
+    shares' held ranges) of one expert layer: ``nemotron_h`` (8 experts in
+    2 shares, relu2, a correction bias) or ``pangu_ultra_moe`` (16 experts
+    in 16 shares of one, gated silu, plain sigmoid scores)."""
+    if model == "nemotron_h":
+        c, at, shares, reference = tiny_hybrid_config(), 1, ((0, 4), (4, 8)), ref
+    else:
+        from dynamo_tpu.models import pangu_ultra_moe_reference as reference
+        from dynamo_tpu.models.config import tiny_mla_config
+
+        c, at, shares = tiny_mla_config(), 3, tuple((e, e + 1) for e in range(16))
+    spec = dataclasses.replace(c.layer_specs[at], held=None, post_norm=False)  # every expert
     full = dataclasses.replace(c, layer_specs=(spec,), n_layers=1)
     lp = llama.init_params(full, jax.random.PRNGKey(3))["layers"][0]
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 12, c.d_model))
     L = dict(kind="experts", top_k=spec.top_k, scale=spec.scale, held=(0, spec.n_experts))
     with jax.default_matmul_precision("highest"):
-        want = ref.ref_experts(x.reshape(-1, c.d_model), lp, L).reshape(x.shape)
-    return spec, lp, x, want
+        want = reference.ref_experts(x.reshape(-1, c.d_model), lp, L).reshape(x.shape)
+    return spec, lp, x, want, shares
 
 
-@pytest.mark.parametrize("form", ["xla", "hit_list"])
-def test_shares_add_up_to_the_uncut_layer(form, hit_list_calls):
-    """Share 0 holds experts [0, 4), share 1 [4, 8); each returns its own
-    experts' part plus the shared expert, so the shared expert is in the sum
-    twice: counted once, the two parts are the uncut reference's output."""
-    spec, lp, x, want = _share_setup()
+@pytest.mark.parametrize("model,form", [("nemotron_h", "xla"), ("nemotron_h", "hit_list"),
+                                        ("pangu_ultra_moe", "xla")])
+def test_shares_add_up_to_the_uncut_layer(model, form, hit_list_calls):
+    """Each share returns its own experts' part plus the shared expert, so
+    the shared expert is in the sum once a share: counted once, the parts are
+    the uncut reference's output (two shares of four experts; the sixteen
+    shares of one expert of an expert-parallel group of sixteen)."""
+    spec, lp, x, want, shares = _share_setup(model)
     kernel = form == "hit_list"
     parts = []
-    for lo, hi in ((0, 4), (4, 8)):
+    for lo, hi in shares:
         held = {k: (v[lo:hi] if k.startswith("we_") else v) for k, v in lp.items()}
         parts.append(moe.moe_ffn(x, held, spec.holding(lo, hi), use_kernel=kernel))
     shared_only = moe.moe_ffn(
         x, {k: (v[:0] if k.startswith("we_") else v) for k, v in lp.items()},
         spec.holding(0, 0), use_kernel=kernel)
-    assert len(hit_list_calls) == (2 if kernel else 0)  # no expert held: no kernel
-    _close(parts[0] + parts[1] - shared_only, want, 1e-4)
+    assert len(hit_list_calls) == (len(shares) if kernel else 0)  # no expert held: no kernel
+    _close(sum(parts) - (len(shares) - 1) * shared_only, want, 1e-4)
     assert float(jnp.abs(parts[0] - parts[1]).max()) > 1e-3  # the shares differ
 
 
@@ -326,7 +338,7 @@ def test_expert_parallel_shards_sum_to_the_uncut_layer():
 
     from dynamo_tpu.parallel import MeshConfig, make_mesh
 
-    spec, lp, x, want = _share_setup()
+    spec, lp, x, want, _ = _share_setup()
     mesh = make_mesh(MeshConfig(ep=2), jax.devices()[:2])
     put = lambda k, v: jax.device_put(
         v, NamedSharding(mesh, P("ep") if k.startswith("we_") else P()))

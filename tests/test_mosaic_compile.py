@@ -305,3 +305,108 @@ def dataclasses_replace_layers(cfg, keep):
 
     return dataclasses.replace(
         cfg, n_layers=len(keep), layer_specs=tuple(cfg.layer_specs[i] for i in keep))
+
+
+MLA_SHAPES = {
+    # name: (B, C, P): the absorbed kernel at the served widths (128 heads over
+    # rows of 640 lanes in pages of 128 tokens, a table of 136 pages)
+    "decode_32_rows": (32, 1, 136),
+    "decode_one_row": (1, 1, 136),
+    "question_chunk_128": (1, 128, 136),
+    "question_chunks_256_x8": (8, 256, 136),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MLA_SHAPES))
+def test_mla_kernel_compiles_for_v5e_at_the_served_widths(one_chip, name):
+    """ops/pallas/mla_paged.py: the page tile read once for scores (640
+    lanes) and values (its first 512), M = query block x 128 heads rows a
+    virtual row, the work list in SMEM."""
+    from dynamo_tpu.ops.pallas.mla_paged import _mla_paged_decode_impl
+
+    B, C, P = MLA_SHAPES[name]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(functools.partial(_mla_paged_decode_impl, v_width=512, sm_scale=192**-0.5))
+    compiled = fn.lower(
+        sds((B, C, 128, 640), jnp.bfloat16), sds((2560, 128, 640), jnp.bfloat16),
+        sds((B, P), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32),
+    ).compile()
+    assert "mla_paged_decode" in compiled.as_text()
+
+
+def _mla_program(one_chip, program, depth):
+    """(compiled, donated shapes) of one served program of the openPangu
+    configuration at its published widths, ``depth`` expert layers after the
+    leading dense one, as the runner builds it."""
+    import dataclasses
+    import types
+
+    from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import hybrid, llama
+    from dynamo_tpu.models.config import openpangu_ultra_moe_ep16_config
+
+    cfg = openpangu_ultra_moe_ep16_config()
+    keep = 2 * (1 + depth)
+    cfg = dataclasses.replace(cfg, n_layers=keep, layer_specs=cfg.layer_specs[:keep])
+    NB, S, P, bs = 2560, 32, 136, 128
+    args = JaxEngineArgs(
+        config=cfg, block_size=bs, num_kv_blocks=NB, max_num_seqs=S, max_model_len=P * bs,
+        prefill_chunk=256, use_kernel=True,
+    )
+    runner = types.SimpleNamespace(config=cfg, args=args, use_kernel=True,
+                                   _decode_sig_budget=None)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = lambda f: jax.tree.map(on_chip, jax.eval_shape(f))
+    params = shapes(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    k, v = shapes(lambda: llama.init_kv_cache(cfg, NB, bs, layered=True))
+    store = shapes(lambda: hybrid.init_ssm_state(cfg, 16))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def rows(B):
+        return [arr((B,), i32), arr((2,), jnp.uint32), arr((B,), f32),
+                arr((B,), i32), arr((B,), f32)]
+
+    if program == "decode_burst":
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, S))
+        lowered = DeviceRunner._build_decode_fn_hybrid(runner, False, False).lower(
+            params, k, v, state, arr((S,), i32), arr((S,), i32), arr((S,), i32),
+            arr((S, P), i32), *rows(S),
+        )
+    else:
+        fresh = program == "prefill_fresh"
+        B, C, width = (8, 256, 2) if fresh else (8, 256, P)
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
+        lowered = DeviceRunner._build_step_fn_hybrid(runner, False, 0, fresh).lower(
+            params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
+            arr((B,), i32), arr((B, width), i32), arr((B, 0), i32), *rows(B),
+        )
+    return lowered.compile(), (params, k)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+def test_mla_served_programs_compile_for_the_chip(one_chip, program):
+    """The decode burst, the fresh prefill step and a batch of question
+    chunks over a full table, of the openPangu configuration at its published
+    widths (the dense layer and one expert layer), compiled for the v5e as
+    the runner builds them: the absorbed kernel lowers in the burst and the
+    chunk-with-context step, the fresh step holds none (expanded form), the
+    latent pools alias in and out, and no program copies a whole pool."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+
+    compiled, (params, k) = _mla_program(one_chip, program, depth=1)
+    text = compiled.as_text()
+    assert ("mla_paged_decode" in text) == (program != "prefill_fresh")
+    assert (" while(" in text) == (program == "decode_burst")
+    assert whole_pool_copies(text, k[0]) == 0
+    resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k)
+    assert compiled.memory_analysis().alias_size_in_bytes >= resident

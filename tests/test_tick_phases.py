@@ -401,14 +401,13 @@ def served():
             assert r.status == 200, path
             return await (r.json() if path.startswith("/debug/") else r.text())
 
-    async def hybrid_scrape():
-        # The metrics that belong to a hybrid configuration's cells read
-        # families only such an engine moves: a tiny one, one stream twice
-        # (the repeat resumes from a recurrent-state snapshot).
-        from dynamo_tpu.models.config import tiny_hybrid_config
-
+    async def hybrid_scrape(config):
+        # The metrics that belong to a hybrid or a latent-attention
+        # configuration's cells read families only such an engine moves: a
+        # tiny one, one stream twice (the repeat resumes from a
+        # recurrent-state snapshot, or is a prefix hit over cached latents).
         engine, _ = make_engine(
-            config=tiny_hybrid_config(), block_size=16, prefill_chunk=64, decode_steps=4)
+            config=config, block_size=16, prefill_chunk=64, decode_steps=4)
         server = SystemStatusServer(host="127.0.0.1", port=0)
         attach_engine(server, engine)
         await server.start()
@@ -422,7 +421,10 @@ def served():
             await engine.stop()
 
     async def run():
-        hybrid_body = await hybrid_scrape()
+        from dynamo_tpu.models.config import tiny_hybrid_config, tiny_mla_config
+
+        hybrid_body = await hybrid_scrape(tiny_hybrid_config())
+        mla_body = await hybrid_scrape(tiny_mla_config())
         engine, _ = make_engine(decode_steps=4)
         server = SystemStatusServer(host="127.0.0.1", port=0)
         attach_engine(server, engine)
@@ -452,6 +454,7 @@ def served():
                     await r.read()
                 return {
                     "workers_hybrid": hybrid_body,
+                    "workers_mla": mla_body,
                     "workers": await scrape(s, server.port, "/metrics"),
                     "frontend": await scrape(s, http_port, "/metrics"),
                     "routes": routes,
@@ -481,13 +484,16 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
     assert {k: spec[k] for k in keys} == {k: entry[k] for k in keys}
 
     params = spec["params"]
-    families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND)
+    families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND) | {
+        mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
     labels = set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES) | {"used", "total"}
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
     workers = "workers"
     if entry.get("workloads") and all("nemotron" in w for w in entry["workloads"]):
         workers = "workers_hybrid"
+    if entry.get("workloads") and all("openpangu" in w for w in entry["workloads"]):
+        workers = "workers_mla"
     flags = {o for a in build_parser()._actions for o in a.option_strings}  # noqa: SLF001
     for key in ("per_flag", "percent_of_worker_flag"):
         assert params.get(key) in flags | {None}, (name, key)
@@ -512,7 +518,7 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
             assert key == "*" or all(key in x for x in at), (name, key)
             at = [v for x in at for v in (x if key == "*" else [x[key]])]
     else:
-        assert spec["reader"] in ("trace", "hybrid_roofline")
+        assert spec["reader"] in ("trace", "hybrid_roofline", "mla_roofline")
         assert ("POST", "/debug/profile") in served["routes"]
         for key, family in params.items():
             if key.endswith("_metric"):
