@@ -17,7 +17,11 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Any, Optional
+from typing import Any, Callable, Dict, Optional
+
+# An interval between two frames of a decoding row longer than this is a
+# stall: counted, and put on the record by the engine. A constant, not a knob.
+FRAME_STALL_SECONDS = 0.5
 
 
 class _PhaseScope:
@@ -25,12 +29,14 @@ class _PhaseScope:
     enclosing scope's segment and leaving resumes it, so at any instant the
     time belongs to exactly one phase: the innermost one open."""
 
-    __slots__ = ("_m", "name", "attrs", "_outer")
+    __slots__ = ("_m", "name", "attrs", "waits", "_outer")
 
-    def __init__(self, metrics: "EngineStepMetrics", name: str, attrs: dict) -> None:
+    def __init__(self, metrics: "EngineStepMetrics", name: str, attrs: dict,
+                 waits: bool = False) -> None:
         self._m = metrics
         self.name = name
         self.attrs = attrs
+        self.waits = waits  # a device-wait phase: never starved
         self._outer: Optional[_PhaseScope] = None
 
     def __enter__(self) -> "_PhaseScope":
@@ -72,8 +78,14 @@ class _TickScope:
 
 
 class EngineStepMetrics:
-    def __init__(self) -> None:
+    def __init__(self, inflight: Callable[[], int] = lambda: 0) -> None:
+        """``inflight``: how many decode bursts the engine has handed the
+        device and not yet read back (the engine's ``len`` of them)."""
         from dynamo_tpu.runtime import metric_names as mn
+        from dynamo_tpu.runtime.device_observe import (
+            global_compile_watcher,
+            global_gc_watcher,
+        )
         from dynamo_tpu.runtime.metrics_core import COUNT_BUCKETS, MetricsRegistry
 
         self.registry = MetricsRegistry()
@@ -98,16 +110,6 @@ class EngineStepMetrics:
             "Tokens emitted per decode step (fused multi-iteration burst)",
             buckets=COUNT_BUCKETS,
         )
-        # Decode-tick pipelining (dispatch/reap split): host_gap is the
-        # device wait the host injected between the previous burst's
-        # readback completing and the next dispatch being enqueued — 0
-        # whenever another burst was already queued on the device. The
-        # depth-1 vs depth-2 comparison of this family IS the overlap win.
-        self.host_gap = self.registry.histogram(
-            mn.ENGINE_HOST_GAP,
-            "Host-injected device wait between decode bursts "
-            "(0 = the next burst was already in flight)",
-        )
         self.inflight_depth = self.registry.histogram(
             mn.ENGINE_INFLIGHT_DEPTH,
             "Decode bursts in flight on the device at each dispatch "
@@ -125,6 +127,35 @@ class EngineStepMetrics:
         self.tick_duration = self.registry.histogram(
             mn.ENGINE_TICK,
             "Wall time of one scheduler-loop iteration that did not go idle",
+        )
+        # What the device waits for and what a decoding row waits for: the
+        # same segments, counted twice more (see _begin_segment and
+        # observe_frame). The counters mirror plain dicts at render.
+        self.device_starved = self.registry.counter(
+            mn.ENGINE_DEVICE_STARVED_SECONDS_TOTAL,
+            "Scheduler-loop wall time in segments that are no device wait "
+            "and began with no decode burst in flight, by phase: a lower "
+            "bound on the time the device held no program",
+            ["phase"],
+        )
+        self.frame_interval = self.registry.histogram(
+            mn.ENGINE_FRAME_INTERVAL,
+            "Interval between two reaped bursts that each gave a live row "
+            "a frame; kind=prefill when the loop awaited a prefill step in "
+            "between, else decode",
+            ["kind"],
+        )
+        self.frame_row_seconds = self.registry.counter(
+            mn.ENGINE_FRAME_ROW_SECONDS_TOTAL,
+            "Rows that got a frame x the seconds of the interval before it "
+            "in each tick phase: what live decode rows waited for",
+            ["phase"],
+        )
+        self.frame_stalls = self.registry.counter(
+            mn.ENGINE_FRAME_STALLS_TOTAL,
+            f"Frame intervals over {FRAME_STALL_SECONDS} s, each also a "
+            "'stall' flight record and a WARNING line of the worker",
+            ["kind"],
         )
         self.request_phase = self.registry.histogram(
             mn.ENGINE_REQUEST_PHASE,
@@ -183,9 +214,32 @@ class EngineStepMetrics:
             mn.ENGINE_SSM_SNAPSHOT_EVICTIONS_TOTAL,
             "Snapshots evicted to make room (least recently used first)",
         )
-        self._phases = frozenset(mn.TICK_PHASES)
+        # phase name -> is it a device wait (the vocabulary and its one
+        # class the counts below ask about, in one lookup)
+        self._phases = {
+            name: name in mn.TICK_PHASES_DEVICE_WAIT for name in mn.TICK_PHASES
+        }
         self._idle_phases = frozenset(mn.TICK_PHASES_IDLE)
         self._request_phases = mn.REQUEST_PHASES
+        self._inflight = inflight
+        self._compile_watcher = global_compile_watcher()
+        self._gc_watcher = global_gc_watcher()
+        self._starved = False  # the open segment began with the device empty
+        # Seconds of closed segments by phase, and the two counts made of
+        # them; plain floats on the loop's path, mirrored at render.
+        self._phase_s: Dict[str, float] = dict.fromkeys(mn.TICK_PHASES, 0.0)
+        self._starved_s: Dict[str, float] = dict.fromkeys(mn.TICK_PHASES, 0.0)
+        self._frame_row_s: Dict[str, float] = dict.fromkeys(mn.TICK_PHASES, 0.0)
+        self._stalls: Dict[str, int] = dict.fromkeys(mn.FRAME_KINDS, 0)
+        # (seconds by phase, compiles, collector seconds) at the last frame
+        self._frame_mark: Optional[tuple] = None
+        # Every series of the three from the first scrape: a share over
+        # phases must not read a phase not met yet as a family not there.
+        for name in mn.TICK_PHASES:
+            self.tick_phase.touch(phase=name)
+        for kind in mn.FRAME_KINDS:
+            self.frame_interval.touch(kind=kind)
+        self.registry.on_render(self._refresh)
         self._scope: Optional[_PhaseScope] = None  # the open phase, if any
         self._t0 = 0.0
         self._annotation: Any = None
@@ -215,15 +269,17 @@ class EngineStepMetrics:
         to ``tick_phase_seconds{phase}``. Only the scheduler task opens
         phases; a phase that awaits stays open across the await. The name
         must be one of metric_names.TICK_PHASES."""
-        if name not in self._phases:
+        waits = self._phases.get(name)
+        if waits is None:
             raise KeyError(f"unknown tick phase {name!r}")
-        return _PhaseScope(self, name, attrs)
+        return _PhaseScope(self, name, attrs, waits)
 
     def tick(self) -> _TickScope:
         return _TickScope(self)
 
     def _begin_segment(self, scope: _PhaseScope) -> None:
         self._scope = scope
+        self._starved = not scope.waits and not self._inflight()
         self._annotation = self.annotate(scope.name, **scope.attrs)
         self._annotation.__enter__()
         self._t0 = time.monotonic()
@@ -237,8 +293,60 @@ class EngineStepMetrics:
         name = scope.name
         self._scope = None
         self.tick_phase.observe(dt, phase=name)
+        self._phase_s[name] += dt
+        if self._starved:
+            self._starved_s[name] += dt
         if name in self._idle_phases:
             self._idle_segments += 1
+
+    def observe_frame(self, rows: int) -> Optional[Dict[str, Any]]:
+        """A reaped burst just gave ``rows`` live rows a frame. The interval
+        since the last such frame is the difference of the closed segments'
+        seconds by phase (so it runs from the start of that emission to the
+        start of this one, and its phases add up to it exactly). Returns
+        what the engine puts on the record when the interval is a stall."""
+        mark = (
+            tuple(self._phase_s.values()),
+            self._compile_watcher.compiles,
+            self._gc_watcher.seconds,
+        )
+        last, self._frame_mark = self._frame_mark, mark
+        if last is None:
+            return None
+        spent = {
+            name: now - then
+            for name, then, now in zip(self._phase_s, last[0], mark[0])
+            if now > then
+        }
+        interval = sum(spent.values())
+        kind = "prefill" if "tick.prefill_wait" in spent else "decode"
+        self.frame_interval.observe(interval, kind=kind)
+        for name, seconds in spent.items():
+            self._frame_row_s[name] += rows * seconds
+        if interval <= FRAME_STALL_SECONDS:
+            return None
+        self._stalls[kind] += 1
+        top = sorted(spent.items(), key=lambda item: -item[1])[:3]
+        return {
+            "interval_s": round(interval, 4),
+            "frame_kind": kind,
+            "rows": rows,
+            "phases": {name: round(seconds, 4) for name, seconds in top},
+            "compiles": mark[1] - last[1],
+            "gc_s": round(mark[2] - last[2], 4),
+        }
+
+    def forget_frame(self) -> None:
+        """The loop went idle with no live row: the next frame's interval
+        would span the wait for a request, which no row waited through."""
+        self._frame_mark = None
+
+    def _refresh(self) -> None:
+        for name in self._phase_s:
+            self.device_starved.set_total(self._starved_s[name], phase=name)
+            self.frame_row_seconds.set_total(self._frame_row_s[name], phase=name)
+        for kind, count in self._stalls.items():
+            self.frame_stalls.set_total(count, kind=kind)
 
     def observe_request(self, queue, prefill, decode, decode_tokens: int) -> None:
         """One finished stream: the (start, end) monotonic stamps of its
@@ -262,9 +370,6 @@ class EngineStepMetrics:
         self.step_duration.observe(duration_s, phase="decode")
         self.batch_occupancy.observe(occupancy, phase="decode")
         self.decode_tokens.observe(tokens)
-
-    def observe_host_gap(self, gap_s: float) -> None:
-        self.host_gap.observe(gap_s)
 
     def observe_inflight(self, depth: int) -> None:
         self.inflight_depth.observe(depth)

@@ -473,7 +473,7 @@ class JaxEngine:
         # attach_engine; dependency-free, so always on).
         from dynamo_tpu.engines.metrics import EngineStepMetrics
 
-        self.step_metrics = EngineStepMetrics()
+        self.step_metrics = EngineStepMetrics(inflight=self._inflight.__len__)
         # Device-plane observability (runtime/device_observe.py):
         # - flight: the tick loop's single-writer event ring (admit,
         #   preempt, dispatch, reap, spec tick, KV transfers, abort). The
@@ -648,7 +648,9 @@ class JaxEngine:
         ``stats()``, what the process had compiled when it ended."""
         t0 = time.monotonic()
         programs = await self._admitter.compile_prefill_ladder()
-        totals = global_compile_watcher().totals()
+        watcher = global_compile_watcher()
+        watcher.start_up_ended()
+        totals = watcher.totals()
         self._startup_compile = {
             "prefill_ladder_programs": programs,
             "prefill_ladder_seconds": round(time.monotonic() - t0, 3),
@@ -1098,10 +1100,6 @@ class JaxEngine:
                                     break
                                 admitted = True
                     if admitted:
-                        # Prefill just ran on the device: the wait before the
-                        # next decode dispatch is device-busy time, not
-                        # host-injected gap — don't observe it.
-                        self._t_last_ready = None
                         self._publish_stats()
                     active = (
                         any(s is not None for s in self._slots)
@@ -1114,11 +1112,11 @@ class JaxEngine:
                         else:
                             await self._decode_tick()
                     elif not admitted:
-                        # Idle: request inter-arrival time is not host gap.
-                        self._t_last_ready = None
+                        # Idle, no live row: the next reap's inter-reap gap
+                        # would span the wait for a request — don't let it
+                        # testify as ITL, nor as a frame interval.
+                        self.step_metrics.forget_frame()
                         if self._budgeter is not None:
-                            # The next reap's inter-reap gap would span the
-                            # idle period — don't let it testify as ITL.
                             self._budgeter.note_idle()
                         self._publish_stats()
                         self._wake.clear()
@@ -1418,6 +1416,7 @@ class JaxEngine:
         """Handle a pending sleep request / asleep state. Returns True when
         this tick is consumed (the main loop should ``continue``)."""
         if self._sleep_level > 0:  # asleep: idle until wake() or stop()
+            self.step_metrics.forget_frame()
             self._publish_stats()
             self._wake.clear()
             with self.step_metrics.phase("tick.idle"):
@@ -1596,7 +1595,6 @@ class JaxEngine:
                 s.request.sampling.logprobs is not None for s in active
             )
             want_procs = any(self._uses_procs[s.slot] for s in active)
-        had_inflight = bool(self._inflight)
         t0 = time.monotonic()
         # Chaos seam, deliberately AFTER the sync payloads were built (the
         # dirty sets are already cleared): recovery must resync every slot
@@ -1610,13 +1608,6 @@ class JaxEngine:
             handles = await self._device(
                 self._dispatch_on_device, nb_bucket, want_logprobs,
                 want_procs, state_sync, table_sync,
-            )
-        # Host-gap: how long the device sat idle on host work between the
-        # previous burst's readback and this dispatch. When another burst
-        # was already in flight the device never waited — observe 0.
-        if self._t_last_ready is not None:
-            self.step_metrics.observe_host_gap(
-                0.0 if had_inflight else max(0.0, t0 - self._t_last_ready)
             )
         self.step_metrics.observe_inflight(len(self._inflight) + 1)
         self.step_metrics.observe_decode_pages(
@@ -1709,14 +1700,18 @@ class JaxEngine:
                 float(moe[0]), self.args.decode_steps * len(experts) * held,
                 float(moe[1]), float(moe[2]) / held,
             )
+        rows = 0
         for slot, seq in rec.seqs:
             if self._slots[slot] is not seq or seq.slot != slot:
                 continue  # finished/preempted while this burst was in flight
+            rows += 1
             self._emit_burst(
                 seq, toks[slot], logps[slot],
                 None if topv is None else topv[slot],
                 None if topi is None else topi[slot],
             )
+        if rows:
+            self._note_frame(rows)
         # Emitted (post-stop-condition) tokens, not dispatched K×B — the
         # honest throughput number the planner divides by step time. The
         # duration is dispatch→readback of THIS burst (queue-inclusive at
@@ -1739,6 +1734,25 @@ class JaxEngine:
             dur_ms=round(1000 * (self._t_last_ready - rec.t_dispatch), 3),
         )
         self._publish_stats()
+
+    def _note_frame(self, rows: int) -> None:
+        """``rows`` live rows just got a frame: the interval since the last
+        one goes to the step metrics, and a stalled one on the record, with
+        what lay in it (the worker's log is kept beside a benchmark run)."""
+        stall = self.step_metrics.observe_frame(rows)
+        if stall is None:
+            return
+        stall["waiting"] = len(self._waiting)
+        self.flight.record("stall", **stall)
+        # dynlint: disable=DYN002 -- a stall is an anomaly, not a steady tick: one line per frame interval over half a second, and the line is the record an operator and a benchmark run keep
+        logger.warning(
+            "stall: %.3f s between two frames (%s) with %d rows waiting and "
+            "%d queued; most of it in %s; %d compiles and %.3f s of garbage "
+            "collection inside it",
+            stall["interval_s"], stall["frame_kind"], rows, stall["waiting"],
+            ", ".join(f"{p} {s:.3f} s" for p, s in stall["phases"].items()),
+            stall["compiles"], stall["gc_s"],
+        )
 
     async def _drain_inflight(self) -> None:
         """Barrier: reap every in-flight burst. Required before any event
@@ -1795,9 +1809,6 @@ class JaxEngine:
             # no host mirror to roll back to: every live sequence recomputes.
             for seq in [s for s in self._slots if s is not None]:
                 self._preempt(seq, why="in-flight bursts aborted")
-        # Don't let the failure + retry-backoff window masquerade as host
-        # gap on the next dispatch.
-        self._t_last_ready = None
         self._publish_stats()
 
     def _emit_burst(

@@ -135,13 +135,12 @@ class NgramSpecDecoder:
                 e._topp.copy(),
             )
         e.steps += 1
-        # The verify dispatch occupied the device: the window before the
-        # next fused-decode dispatch is not host-injected gap.
-        e._t_last_ready = None
         with e.step_metrics.phase("tick.emit", rows=len(active)):
+            rows = 0
             for seq in list(active):
                 if seq.slot < 0:
                     continue  # finished by an earlier emit in this loop
+                rows += 1
                 slot = seq.slot
                 prop = proposals.get(slot, [])
                 n = int(counts[slot])
@@ -156,4 +155,6 @@ class NgramSpecDecoder:
                     # decode carry — resync pos/tokens before the next
                     # fused decode burst reads the device-resident state.
                     e._dirty_state.add(slot)
+            if rows:
+                e._note_frame(rows)
         return True

@@ -10,7 +10,7 @@ fallback arming) created exactly the failure classes that are invisible
 without it: a silent recompile storm, HBM-accounting drift, or a tick
 pipeline wedging with no record of the events that led there.
 
-Four parts, all designed to stay OFF the tick thread's critical path:
+Five parts, all designed to stay OFF the tick thread's critical path:
 
   1. **Compile telemetry** (``watched_jit`` / ``CompileWatcher``): every
      ``jax.jit`` program site wraps its compiled callable; per program we
@@ -19,7 +19,11 @@ Four parts, all designed to stay OFF the tick thread's critical path:
      crosses its signature budget — the pow2 ``table_width_bucket``
      programs get an explicit expected-count budget from the runner).
      Steady-state cost per dispatch is two ``_cache_size()`` C++ calls and
-     two ``perf_counter()`` reads — no locks, no tree flattening.
+     two ``perf_counter()`` reads — no locks, no tree flattening. A call
+     that DID compile is named: program, the shapes it was called with and
+     its seconds go into the watcher's ``recent`` ring (``/debug/compiles``),
+     and once the worker's start-up ladder has ended
+     (``CompileWatcher.start_up_ended``) into a WARNING line as well.
   2. **HBM ledger** (``HbmLedger``): structural byte accounting per
      category (KV pools, params, decode slot state, slot tables, LoRA
      stacks, processor state), sampled at scrape/snapshot time and
@@ -34,6 +38,9 @@ Four parts, all designed to stay OFF the tick thread's critical path:
   4. **Profiler control** (``ProfilerControl``): ``POST /debug/profile``
      wraps ``jax.profiler.start_trace``/``stop_trace`` with graceful
      no-op degradation when the backend/profiler is unavailable.
+  5. **Garbage-collector pauses** (``GcWatcher``): the process's one
+     ``gc.callbacks`` hook, start to stop of each collection, by
+     generation. A stalled frame interval reads its seconds from here.
 
 Every Prometheus name comes from runtime/metric_names.py (``ALL_RUNTIME``)
 — the lint test rejects inline literals. Metric values mirror the plain
@@ -43,6 +50,8 @@ a metrics lock; render-time sampling pays it instead.
 
 from __future__ import annotations
 
+import collections
+import gc
 import json
 import os
 import threading
@@ -50,7 +59,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from dynamo_tpu.runtime import metric_names as mn
-from dynamo_tpu.runtime.metrics_core import Histogram, MetricsRegistry
+from dynamo_tpu.runtime.metrics_core import MetricsRegistry
 from dynamo_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -79,10 +88,10 @@ class _ProgramStats:
 
     __slots__ = (
         "name", "compiles", "signatures", "storms", "compile_seconds",
-        "last_compile_seconds", "budget", "_hist",
+        "last_compile_seconds", "budget", "_watcher",
     )
 
-    def __init__(self, name: str, hist: Histogram) -> None:
+    def __init__(self, name: str, watcher: "CompileWatcher") -> None:
         self.name = name
         self.compiles = 0
         self.signatures = 0
@@ -90,16 +99,17 @@ class _ProgramStats:
         self.compile_seconds = 0.0
         self.last_compile_seconds = 0.0
         self.budget: Optional[int] = None
-        self._hist = hist
+        self._watcher = watcher
 
-    def on_compile(self, n: int, dt: float) -> None:
+    def on_compile(self, n: int, dt: float, signature: str) -> None:
         self.compiles += n
         self.signatures += n
         self.compile_seconds += dt
         self.last_compile_seconds = dt
-        # Histogram takes its lock — fine: compiles are rare by definition
-        # (a program that compiles on the hot path is the storm we detect).
-        self._hist.observe(dt, program=self.name)
+        # Histogram and ring take their time — fine: compiles are rare by
+        # definition (a program that compiles on the hot path is the storm
+        # we detect).
+        self._watcher.on_compile(self.name, n, dt, signature)
 
     def to_dict(self) -> Dict[str, Any]:
         return {
@@ -150,20 +160,20 @@ class WatchedJit:
             out = fn(*args, **kwargs)
             grew = fn._cache_size() - before
             if grew > 0:
-                self._on_compile(grew, time.perf_counter() - t0)
+                self._on_compile(grew, time.perf_counter() - t0, args, kwargs)
             return out
         key = _abstract_signature(args, kwargs)
         t0 = time.perf_counter()
         out = fn(*args, **kwargs)
         if key not in self._seen:
             self._seen.add(key)
-            self._on_compile(1, time.perf_counter() - t0)
+            self._on_compile(1, time.perf_counter() - t0, args, kwargs)
         return out
 
-    def _on_compile(self, n: int, dt: float) -> None:
+    def _on_compile(self, n: int, dt: float, args, kwargs) -> None:
         self._sigs += n
         st = self._stats
-        st.on_compile(n, dt)
+        st.on_compile(n, dt, describe_call(args, kwargs))
         budget = self._budget if self._budget is not None else st.budget
         if budget is None:
             budget = DEFAULT_SIGNATURE_BUDGET
@@ -196,6 +206,37 @@ def _abstract_signature(args, kwargs) -> Tuple:
     return (str(treedef), tuple(leaf_key(l) for l in leaves))
 
 
+def describe_call(args, kwargs) -> str:
+    """The call a program compiled for, for a person to read, in at most
+    600 characters: each argument as ``dtype[shape]``, a scalar as its
+    value, a pytree of more than four arrays (parameters, pools) as its
+    type and leaf count. Shape and dtype are metadata, valid on a donated
+    buffer after the call."""
+    import jax
+
+    def one(x) -> str:
+        shape = getattr(x, "shape", None)
+        if shape is not None:
+            return f"{getattr(x, 'dtype', '?')}[{','.join(map(str, shape))}]"
+        if x is None or isinstance(x, (bool, int, float, str)):
+            return repr(x)
+        leaves = jax.tree_util.tree_leaves(x)
+        if not leaves or (len(leaves) == 1 and leaves[0] is x):
+            return type(x).__name__
+        if len(leaves) > 4:
+            return f"<{type(x).__name__}: {len(leaves)} arrays>"
+        return "(" + ", ".join(one(leaf) for leaf in leaves) + ")"
+
+    parts = [one(a) for a in args]
+    parts += [f"{k}={one(v)}" for k, v in kwargs.items()]
+    text = ", ".join(parts)
+    return text if len(text) <= 600 else text[:597] + "..."
+
+
+# How many compile events ``/debug/compiles`` keeps under ``recent``.
+RECENT_COMPILES = 64
+
+
 class CompileWatcher:
     """Per-process compile-telemetry registry (program name → stats).
 
@@ -207,6 +248,14 @@ class CompileWatcher:
         self.registry = registry or MetricsRegistry()
         self._lock = threading.Lock()  # program-creation only, never hot
         self._programs: Dict[str, _ProgramStats] = {}
+        # Every compile named, newest last; ``compiles`` is the running
+        # count behind ``totals()["compiles"]``, one attribute read for
+        # whoever wants the compiles inside an interval.
+        self.recent: "collections.deque[Dict[str, Any]]" = collections.deque(
+            maxlen=RECENT_COMPILES
+        )
+        self.compiles = 0
+        self._serving = False
         self._hist = self.registry.histogram(
             mn.RUNTIME_COMPILE_SECONDS,
             "Wall time of calls that compiled a new program signature "
@@ -244,9 +293,32 @@ class CompileWatcher:
             with self._lock:
                 st = self._programs.get(name)
                 if st is None:
-                    st = _ProgramStats(name, self._hist)
+                    st = _ProgramStats(name, self)
                     self._programs[name] = st
         return st
+
+    def start_up_ended(self) -> None:
+        """The worker compiled what it means to before serving (the
+        engine's ``compile_prefill_ladder`` ended): from here on a compile
+        stalls live streams, and is logged as one."""
+        self._serving = True
+
+    def on_compile(self, program: str, n: int, dt: float, signature: str) -> None:
+        self._hist.observe(dt, program=program)
+        self.compiles += n
+        self.recent.append({
+            "program": program,
+            "signature": signature,
+            "seconds": round(dt, 4),
+            "t_mono": round(time.monotonic(), 6),
+            "t_wall": round(time.time(), 3),
+            "serving": self._serving,
+        })
+        if self._serving:
+            logger.warning(
+                "compiled on the serving path: %s %s in %.3f s",
+                program, signature, dt,
+            )
 
     def set_budget(self, name: str, budget: Optional[int]) -> None:
         """Default per-instance signature budget for every WatchedJit that
@@ -260,7 +332,11 @@ class CompileWatcher:
             name: st.to_dict()
             for name, st in sorted(list(self._programs.items()))
         }
-        return {"programs": programs, "totals": self.totals()}
+        return {
+            "programs": programs,
+            "totals": self.totals(),
+            "recent": list(self.recent),
+        }
 
     def totals(self) -> Dict[str, Any]:
         stats = list(self._programs.values())
@@ -285,6 +361,59 @@ def watched_jit(
     the watcher's per-name default, which itself defaults to unbudgeted)."""
     w = watcher if watcher is not None else global_compile_watcher()
     return WatchedJit(w.program(name), fn, budget)
+
+
+# ---------------------------------------------------------------------------
+# Garbage-collector pauses
+# ---------------------------------------------------------------------------
+
+
+class GcWatcher:
+    """Start to stop of each collection of the garbage collector, by
+    generation. A collection stops every thread of the process (it runs
+    under the GIL), so its seconds are a pause of the scheduler loop and
+    of the device thread alike. The families mirror the plain counters at
+    render. Counts once ``on_gc`` is among ``gc.callbacks``."""
+
+    GENERATIONS = (0, 1, 2)
+
+    def __init__(self) -> None:
+        self.pause_seconds = [0.0 for _ in self.GENERATIONS]
+        self.collections = [0 for _ in self.GENERATIONS]
+        self._t0 = 0.0
+        self.registry = MetricsRegistry()
+        self._pause_metric = self.registry.counter(
+            mn.RUNTIME_GC_PAUSE_SECONDS_TOTAL,
+            "Seconds inside collections of the garbage collector "
+            "(every thread of the process stands still for them)",
+            ["generation"],
+        )
+        self._collections_metric = self.registry.counter(
+            mn.RUNTIME_GC_COLLECTIONS_TOTAL,
+            "Collections of the garbage collector", ["generation"],
+        )
+        self.registry.on_render(self._refresh)
+
+    @property
+    def seconds(self) -> float:
+        return sum(self.pause_seconds)
+
+    def on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        generation = info["generation"]
+        self.pause_seconds[generation] += time.perf_counter() - self._t0
+        self.collections[generation] += 1
+
+    def _refresh(self) -> None:
+        for generation in self.GENERATIONS:
+            self._pause_metric.set_total(
+                self.pause_seconds[generation], generation=generation
+            )
+            self._collections_metric.set_total(
+                self.collections[generation], generation=generation
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -647,14 +776,17 @@ class ProfilerControl:
 _LOCK = threading.Lock()
 _WATCHER: Optional[CompileWatcher] = None
 _PROFILER: Optional[ProfilerControl] = None
+_GC: Optional[GcWatcher] = None
 
 
 def _init_globals() -> None:
-    global _WATCHER, _PROFILER
+    global _WATCHER, _PROFILER, _GC
     with _LOCK:
         if _WATCHER is not None:
             return
         _PROFILER = ProfilerControl()
+        _GC = GcWatcher()
+        gc.callbacks.append(_GC.on_gc)
         _WATCHER = CompileWatcher()
 
 
@@ -672,12 +804,21 @@ def global_profiler() -> ProfilerControl:
     return _PROFILER  # type: ignore[return-value]
 
 
+def global_gc_watcher() -> GcWatcher:
+    """The process's one ``gc.callbacks`` hook, installed at first use."""
+    if _GC is None:
+        _init_globals()
+    return _GC  # type: ignore[return-value]
+
+
 def render_runtime_metrics(openmetrics: bool = False) -> str:
     """Prometheus text for the process-global runtime families (compile
-    watcher + profiler). Registered on every SystemStatusServer — the
-    device plane is per-process, like the lifecycle/tracer debug rings."""
+    watcher + profiler + garbage collector). Registered on every
+    SystemStatusServer — the device plane is per-process, like the
+    lifecycle/tracer debug rings."""
     parts = [
         global_compile_watcher().registry.render(openmetrics=openmetrics),
         global_profiler().registry.render(openmetrics=openmetrics),
+        global_gc_watcher().registry.render(openmetrics=openmetrics),
     ]
     return "\n".join(p for p in parts if p)

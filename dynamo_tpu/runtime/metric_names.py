@@ -78,10 +78,7 @@ ENGINE_BATCH_OCCUPANCY = f"{ENGINE_PREFIX}_batch_occupancy"
 ENGINE_STEP_PREFILL_TOKENS = f"{ENGINE_PREFIX}_prefill_tokens_per_step"
 ENGINE_STEP_DECODE_TOKENS = f"{ENGINE_PREFIX}_decode_tokens_per_step"
 # Decode-tick pipelining (engines/tpu/engine.py dispatch/reap split):
-# host_gap = device wait injected by the host between a burst's readback
-# completing and the next dispatch (0 when another burst was already in
-# flight); inflight_depth = bursts in flight at each dispatch.
-ENGINE_HOST_GAP = f"{ENGINE_PREFIX}_host_gap_seconds"
+# bursts in flight at each dispatch.
 ENGINE_INFLIGHT_DEPTH = f"{ENGINE_PREFIX}_inflight_depth"
 # The scheduler tick seen from inside (EngineStepMetrics.phase): wall time of
 # the scheduler loop by phase — the phases are exclusive and partition the
@@ -90,6 +87,24 @@ ENGINE_INFLIGHT_DEPTH = f"{ENGINE_PREFIX}_inflight_depth"
 # so a profiler capture carries it on the device trace's clock.
 ENGINE_TICK_PHASE = f"{ENGINE_PREFIX}_tick_phase_seconds"
 ENGINE_TICK = f"{ENGINE_PREFIX}_tick_seconds"
+# What the device waits for (label phase): wall time of the loop's segments
+# that are not a device wait and began with no decode burst in flight, the
+# device holding nothing the engine had not yet read back. A LOWER bound on
+# device idleness: a burst that ended before the host came to read it, and
+# gaps between operations inside a program, are invisible to the host.
+ENGINE_DEVICE_STARVED_SECONDS_TOTAL = (
+    f"{ENGINE_PREFIX}_device_starved_seconds_total"
+)
+# What a decoding row waits for. One observation per interval between two
+# reaped bursts that each gave a frame to a live row (kind=prefill when the
+# loop awaited a prefill step in between, else decode); rows that got the
+# frame x the interval's seconds in each tick phase (label phase: over a
+# window the series partition the time live rows spent waiting for
+# frames); intervals over engines/metrics.py FRAME_STALL_SECONDS (label
+# kind), each also a ``stall`` flight record and a WARNING line.
+ENGINE_FRAME_INTERVAL = f"{ENGINE_PREFIX}_frame_interval_seconds"
+ENGINE_FRAME_ROW_SECONDS_TOTAL = f"{ENGINE_PREFIX}_frame_row_seconds_total"
+ENGINE_FRAME_STALLS_TOTAL = f"{ENGINE_PREFIX}_frame_stalls_total"
 # Request phases at the stamps _export_phase_spans already reads
 # (phase=queue|prefill|decode), one observation per finished stream, and
 # the decode phase's tokens (generated - 1) beside them: deltas of sum and
@@ -148,6 +163,7 @@ DEVICE_SPANS = (
     "device.prefill_step", "device.decode_dispatch", "device.decode_read",
 )
 REQUEST_PHASES = ("queue", "prefill", "decode")
+FRAME_KINDS = ("decode", "prefill")
 
 # -- router (router/router.py KvRouter + router/scheduler.py) ----------------
 ROUTER_PREFIX = "dynamo_tpu_router"
@@ -252,6 +268,11 @@ RUNTIME_FLIGHT_EVENTS_TOTAL = f"{RUNTIME_PREFIX}_flight_events_total"
 RUNTIME_FLIGHT_OVERWRITTEN_TOTAL = f"{RUNTIME_PREFIX}_flight_overwritten_total"
 # On-demand jax.profiler captures (POST /debug/profile).
 RUNTIME_PROFILER_CAPTURES_TOTAL = f"{RUNTIME_PREFIX}_profiler_captures_total"
+# The garbage collector's pauses (one gc.callbacks hook, start to stop of
+# each collection; label generation): every thread of the process stands
+# still for them, the scheduler loop and the device thread included.
+RUNTIME_GC_PAUSE_SECONDS_TOTAL = f"{RUNTIME_PREFIX}_gc_pause_seconds_total"
+RUNTIME_GC_COLLECTIONS_TOTAL = f"{RUNTIME_PREFIX}_gc_collections_total"
 
 # -- disagg (disagg/handlers.py DecodeHandler) -------------------------------
 DISAGG_PREFIX = "dynamo_tpu_disagg"
@@ -572,6 +593,8 @@ ALL_RUNTIME = (
     RUNTIME_FLIGHT_EVENTS_TOTAL,
     RUNTIME_FLIGHT_OVERWRITTEN_TOTAL,
     RUNTIME_PROFILER_CAPTURES_TOTAL,
+    RUNTIME_GC_PAUSE_SECONDS_TOTAL,
+    RUNTIME_GC_COLLECTIONS_TOTAL,
 )
 
 ALL_ENGINE = (
@@ -602,10 +625,13 @@ ALL_ENGINE = (
     ENGINE_BATCH_OCCUPANCY,
     ENGINE_STEP_PREFILL_TOKENS,
     ENGINE_STEP_DECODE_TOKENS,
-    ENGINE_HOST_GAP,
     ENGINE_INFLIGHT_DEPTH,
     ENGINE_TICK_PHASE,
     ENGINE_TICK,
+    ENGINE_DEVICE_STARVED_SECONDS_TOTAL,
+    ENGINE_FRAME_INTERVAL,
+    ENGINE_FRAME_ROW_SECONDS_TOTAL,
+    ENGINE_FRAME_STALLS_TOTAL,
     ENGINE_REQUEST_PHASE,
     ENGINE_REQUEST_DECODE_TOKENS_TOTAL,
     ENGINE_DECODE_LIVE_PAGES_TOTAL,

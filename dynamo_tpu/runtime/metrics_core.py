@@ -193,6 +193,12 @@ class Histogram(_Metric):
             if trace_id:
                 self._exemplars[(key, idx)] = (v, str(trace_id), time.time())
 
+    def touch(self, **labels: object) -> None:
+        """Export the series from the first scrape, at no observation."""
+        key = self._key(labels)
+        with self._lock:
+            self._counts.setdefault(key, [0] * (len(self.buckets) + 1))
+
     def count(self, **labels: object) -> int:
         return sum(self._counts.get(self._key(labels), ()))
 

@@ -3,8 +3,11 @@ telemetry + recompile-storm detection, HBM ledger, flight recorder,
 profiler control, and the engine stats-snapshot consistency fix."""
 
 import asyncio
+import contextlib
 import json
+import logging
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -12,12 +15,16 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.runtime.device_observe import (
+    RECENT_COMPILES,
     CompileWatcher,
     FlightRecorder,
+    GcWatcher,
     HbmLedger,
     ProfilerControl,
+    describe_call,
     dump_flight,
     global_compile_watcher,
+    global_gc_watcher,
     tree_device_bytes,
     watched_jit,
 )
@@ -98,6 +105,172 @@ def test_per_instance_budget_not_shared_across_program_objects():
     st = watcher.snapshot()["programs"]["t.shared"]
     assert st["signatures"] == 4  # aggregated totals
     assert st["storms"] == 0  # but no instance crossed ITS budget
+
+
+@contextlib.contextmanager
+def warning_lines():
+    """WARNING lines of the dynamo_tpu logger (it does not propagate)."""
+    lines = []
+
+    class Capture(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    handler = Capture(level=logging.WARNING)
+    logging.getLogger("dynamo_tpu").addHandler(handler)
+    try:
+        yield lines
+    finally:
+        logging.getLogger("dynamo_tpu").removeHandler(handler)
+
+
+def test_a_compile_is_named_with_its_shapes_and_seconds():
+    watcher = CompileWatcher()
+    fn = watched_jit(
+        "t.named", jax.jit(lambda p, x, n: x * n + p["w"].sum(), static_argnums=2),
+        watcher=watcher,
+    )
+    before = watcher.compiles
+    fn({"w": jnp.zeros((3, 5), jnp.float32)}, jnp.zeros((2, 8), jnp.int32), 3)
+    fn({"w": jnp.zeros((3, 5), jnp.float32)}, jnp.ones((2, 8), jnp.int32), 3)  # a hit
+    recent = watcher.snapshot()["recent"]
+    assert len(recent) == 1 and watcher.compiles == before + 1
+    event = recent[0]
+    assert event["program"] == "t.named"
+    assert event["signature"] == "(float32[3,5]), int32[2,8], 3"
+    assert event["seconds"] > 0 and event["serving"] is False
+    assert event["t_mono"] > 0 and event["t_wall"] > 1e9
+    assert watcher.snapshot()["totals"]["compiles"] == watcher.compiles
+    json.dumps(watcher.snapshot())  # the route serves it as it is
+
+
+@pytest.mark.parametrize(
+    "args, kwargs, want",
+    [
+        ((np.zeros((4, 2), np.int8), 7, None, True), {}, "int8[4,2], 7, None, True"),
+        # parameters and pools are their type and size, not a page of shapes
+        (({k: np.zeros(2, np.float32) for k in "abcde"},), {},
+         "<dict: 5 arrays>"),
+        (([np.zeros(1, np.int32), np.zeros((), np.float32)],), {"nb": 8},
+         "(int32[1], float32[]), nb=8"),
+        ((object(), ()), {}, "object, tuple"),
+    ],
+)
+def test_describe_call(args, kwargs, want):
+    assert describe_call(args, kwargs) == want
+
+
+def test_describe_call_is_cut_to_600_characters():
+    text = describe_call(tuple(np.zeros((i + 1,), np.float32) for i in range(200)), {})
+    assert len(text) == 600 and text.endswith("...")
+
+
+def test_the_recent_ring_holds_the_last_64_compiles():
+    watcher = CompileWatcher()
+    fn = watched_jit(
+        "t.ring", jax.jit(lambda x: x + 1), budget=1000, watcher=watcher)
+    for n in range(1, RECENT_COMPILES + 7):
+        fn(np.zeros(n, np.float32))
+    recent = watcher.snapshot()["recent"]
+    assert RECENT_COMPILES == 64 and len(recent) == 64
+    assert recent[0]["signature"] == "float32[7]"
+    assert recent[-1]["signature"] == f"float32[{RECENT_COMPILES + 6}]"
+    assert watcher.compiles == RECENT_COMPILES + 6  # the count is not the ring's
+
+
+def test_a_compile_after_start_up_is_logged_and_one_before_is_not():
+    watcher = CompileWatcher()
+    fn = watched_jit("t.ladder", jax.jit(lambda x: x - 1), watcher=watcher)
+    with warning_lines() as lines:
+        fn(np.zeros(3, np.float32))
+        assert not [l for l in lines if "serving path" in l]
+        watcher.start_up_ended()
+        fn(np.zeros(3, np.float32))  # compiled before: a hit, no line
+        fn(np.zeros((5, 2), np.float32))
+    said = [l for l in lines if "serving path" in l]
+    assert len(said) == 1
+    assert said[0].startswith(
+        "compiled on the serving path: t.ladder float32[5,2] in ")
+    assert [e["serving"] for e in watcher.recent] == [False, True]
+
+
+async def test_a_program_the_ladder_does_not_hold_is_named_where_an_operator_looks():
+    """A worker that compiled its ladder, then met a program the ladder
+    does not hold (here: the decode burst, and a prefill over a prefix
+    hit): `/debug/compiles` names each under ``recent`` with its shapes,
+    and the worker's log has a line for each."""
+    import aiohttp
+
+    from dynamo_tpu.runtime.system_server import SystemStatusServer, attach_engine
+
+    engine, _ = make_engine(prefill_batch=2)
+    server = SystemStatusServer(host="127.0.0.1", port=0)
+    attach_engine(server, engine)
+    await server.start()
+    watcher = global_compile_watcher()
+    try:
+        report = await engine.compile_prefill_ladder()
+        at_start, t_start = watcher.compiles, time.monotonic()
+        assert report["startup_compiles"] == watcher.totals()["compiles"] == at_start
+        with warning_lines() as lines:
+            await run_one(engine, req(range(10, 30), max_tokens=6))
+            await run_one(engine, req(range(10, 30), max_tokens=6))  # a prefix hit
+        async with aiohttp.ClientSession() as http:
+            async with http.get(
+                    f"http://127.0.0.1:{server.port}/debug/compiles") as r:
+                body = await r.json()
+    finally:
+        await server.stop()
+        await engine.stop()
+    assert set(body) == {"programs", "totals", "recent"}
+    after = [e for e in body["recent"] if e["t_mono"] >= t_start]
+    assert len(after) == body["totals"]["compiles"] - at_start > 0
+    assert all(e["serving"] for e in after)
+    names = {e["program"] for e in after}
+    assert any("decode" in n for n in names), names
+    assert all(e["signature"] and e["seconds"] > 0 for e in after)
+    said = [l for l in lines if l.startswith("compiled on the serving path: ")]
+    assert len(said) == len(after)
+    for event in after:
+        assert any(f"{event['program']} {event['signature']} in " in l for l in said)
+
+
+def test_gc_watcher_times_each_collection_by_generation(monkeypatch):
+    import gc
+
+    from dynamo_tpu.runtime import device_observe
+
+    t = [10.0]
+    monkeypatch.setattr(device_observe.time, "perf_counter", lambda: t[0])
+    w = GcWatcher()
+    for generation, seconds in ((0, 0.001), (2, 0.25), (0, 0.002)):
+        w.on_gc("start", {"generation": generation})
+        t[0] += seconds
+        w.on_gc("stop", {"generation": generation, "collected": 0, "uncollectable": 0})
+        t[0] += 1.0  # between collections: nobody's pause
+    assert w.seconds == pytest.approx(0.253)
+    assert w.collections == [2, 0, 1]
+    body = w.registry.render()
+    from dynamo_tpu.runtime import metric_names as mn
+
+    assert f'{mn.RUNTIME_GC_COLLECTIONS_TOTAL}{{generation="0"}} 2' in body
+    assert f'{mn.RUNTIME_GC_COLLECTIONS_TOTAL}{{generation="1"}} 0' in body
+    assert f'{mn.RUNTIME_GC_PAUSE_SECONDS_TOTAL}{{generation="2"}} 0.25' in body
+    assert w.on_gc not in gc.callbacks  # the process's hook is another's
+
+
+def test_the_process_has_one_gc_hook_and_it_counts():
+    import gc
+
+    w = global_gc_watcher()
+    assert global_gc_watcher() is w and gc.callbacks.count(w.on_gc) == 1
+    before = w.collections[2], w.seconds
+    gc.collect()
+    assert w.collections[2] == before[0] + 1 and w.seconds > before[1]
+    from dynamo_tpu.runtime.device_observe import render_runtime_metrics
+    from dynamo_tpu.runtime import metric_names as mn
+
+    assert mn.RUNTIME_GC_PAUSE_SECONDS_TOTAL + '{generation="2"}' in render_runtime_metrics()
 
 
 async def test_engine_device_plane_lifecycle():

@@ -86,6 +86,7 @@ def test_runtime_family_covers_device_observe_emitters():
     from dynamo_tpu.runtime.device_observe import (
         CompileWatcher,
         FlightRecorder,
+        GcWatcher,
         HbmLedger,
         ProfilerControl,
     )
@@ -93,7 +94,7 @@ def test_runtime_family_covers_device_observe_emitters():
     emitted = set()
     for obj in (
         CompileWatcher(), HbmLedger(), FlightRecorder("lint"),
-        ProfilerControl(),
+        ProfilerControl(), GcWatcher(),
     ):
         emitted.update(m.name for m in obj.registry._metrics)
     assert emitted == set(mn.ALL_RUNTIME)

@@ -226,6 +226,213 @@ async def test_phases_partition_the_loop_and_show_in_a_capture(tmp_path):
         assert all(1 <= stats["rows"] <= 4 for _, stats in rows)
 
 
+# -- what the device waits for -------------------------------------------------
+
+
+def _starved_seconds(sm: EngineStepMetrics) -> dict:
+    sm.render()  # the counters mirror the loop's plain floats at a scrape
+    return {p: sm.device_starved.value(phase=p) for p in mn.TICK_PHASES}
+
+
+@pytest.mark.parametrize(
+    "inflight, phase, want",
+    [
+        # a burst in flight: the device has work whatever the host does
+        (1, "tick.emit", 0.0),
+        (2, "tick.decode_build", 0.0),
+        (1, "tick.idle", 0.0),
+        # a device wait is the device's time by definition, even when the
+        # burst it reads was popped off the in-flight window first
+        (0, "tick.decode_wait", 0.0),
+        (0, "tick.drain", 0.0),
+        (0, "tick.prefill_wait", 0.0),
+        # nothing handed over and not read back: idle, or the host's turn
+        (0, "tick.idle", 2.0),
+        (0, "tick.admit", 2.0),
+        (0, "tick.decode_dispatch", 2.0),
+    ],
+)
+def test_a_segment_is_starved_when_the_device_holds_nothing(
+        clock, inflight, phase, want):
+    sm = EngineStepMetrics(inflight=lambda: inflight)
+    with sm.phase(phase):
+        clock.t += 2.0
+    got = _starved_seconds(sm)
+    assert got.pop(phase) == want
+    assert set(got.values()) == {0.0}
+    assert _phase_seconds(sm)[phase] == 2.0
+
+
+def test_starved_seconds_follow_the_window_and_nested_phases_count_once(clock):
+    """In-flight depth is read where a segment begins: the outer phase's
+    two halves are two segments, and a nested block's seconds are counted
+    once, under the innermost name, like the phase seconds themselves."""
+    inflight = [0]
+    sm = EngineStepMetrics(inflight=lambda: inflight[0])
+    with sm.tick():
+        clock.t += 1.0  # tick.sched, empty
+        with sm.phase("tick.admit"):
+            clock.t += 2.0  # empty
+            with sm.phase("tick.prefill_wait"):
+                clock.t += 4.0  # a device wait: never
+            clock.t += 8.0  # tick.admit again, still empty
+        with sm.phase("tick.decode_dispatch"):
+            clock.t += 16.0  # began empty: the enqueue itself counts
+            inflight[0] = 1
+        clock.t += 32.0  # tick.sched again, a burst in flight
+        with sm.phase("tick.decode_wait"):
+            inflight[0] = 0  # popped, being read
+            clock.t += 64.0
+        with sm.phase("tick.emit"):
+            clock.t += 128.0  # the last burst is read: empty again
+    got = _starved_seconds(sm)
+    assert got["tick.sched"] == 1.0
+    assert got["tick.admit"] == 10.0
+    assert got["tick.decode_dispatch"] == 16.0
+    assert got["tick.emit"] == 128.0
+    assert sum(got.values()) == 155.0
+    assert sum(_phase_seconds(sm).values()) == 255.0
+
+
+# -- what a decoding row waits for ---------------------------------------------
+
+
+def _frames(sm: EngineStepMetrics) -> dict:
+    sm.render()
+    return {
+        "interval": {k: sm.frame_interval.snapshot_total(kind=k) for k in mn.FRAME_KINDS},
+        "row_s": {p: sm.frame_row_seconds.value(phase=p) for p in mn.TICK_PHASES},
+        "stalls": {k: sm.frame_stalls.value(kind=k) for k in mn.FRAME_KINDS},
+    }
+
+
+def _burst(sm, clock, rows, wait=0.008, emit=0.002):
+    """One reaped burst as the engine's loop times it: the readback wait,
+    then the emission, inside which the frame is noted."""
+    with sm.phase("tick.decode_wait"):
+        clock.t += wait
+    with sm.phase("tick.emit"):
+        clock.t += emit / 2
+        noted = sm.observe_frame(rows)
+        clock.t += emit / 2
+    return noted
+
+
+def test_frame_interval_kind_follows_the_prefill_wait(clock):
+    sm = EngineStepMetrics()
+    with sm.tick():
+        assert _burst(sm, clock, 3) is None  # the first frame: no interval yet
+        assert _burst(sm, clock, 3) is None
+        with sm.phase("tick.admit"):  # an admission lies between two frames
+            clock.t += 0.001
+            with sm.phase("tick.prefill_build"):
+                clock.t += 0.002
+                with sm.phase("tick.prefill_wait"):
+                    clock.t += 0.040
+            with sm.phase("tick.install"):
+                clock.t += 0.003
+        assert _burst(sm, clock, 4) is None
+        assert _burst(sm, clock, 4) is None
+    got = _frames(sm)
+    # an interval runs from one emission's start to the next one's
+    count, total = got["interval"]["decode"]
+    assert count == 2 and total == pytest.approx(0.020)
+    count, total = got["interval"]["prefill"]
+    assert count == 1 and total == pytest.approx(0.056)
+    # rows x seconds, by phase: the three rows of the first interval, the
+    # four that got the frame after the admission, the four of the last
+    assert got["row_s"]["tick.prefill_wait"] == pytest.approx(4 * 0.040)
+    assert got["row_s"]["tick.admit"] == pytest.approx(4 * 0.001)
+    assert got["row_s"]["tick.decode_wait"] == pytest.approx((3 + 4 + 4) * 0.008)
+    assert sum(got["row_s"].values()) == pytest.approx(
+        3 * 0.010 + 4 * 0.056 + 4 * 0.010)
+    assert got["stalls"] == {"decode": 0.0, "prefill": 0.0}
+
+
+def test_an_idle_stretch_drops_the_interval_and_an_admission_does_not(clock):
+    sm = EngineStepMetrics()
+    with sm.tick():
+        _burst(sm, clock, 1)
+        _burst(sm, clock, 1)
+    with sm.tick():  # the last row finished: the loop waits for a request
+        sm.forget_frame()
+        with sm.phase("tick.idle"):
+            clock.t += 30.0
+    with sm.tick():
+        with sm.phase("tick.admit"):
+            with sm.phase("tick.prefill_wait"):
+                clock.t += 0.050
+        assert _burst(sm, clock, 1) is None  # no frame before it: no interval
+        _burst(sm, clock, 1)
+    got = _frames(sm)
+    assert got["interval"]["decode"][0] == 2
+    assert got["interval"]["prefill"][0] == 0
+    assert got["row_s"]["tick.idle"] == 0.0
+    assert got["stalls"] == {"decode": 0.0, "prefill": 0.0}
+
+
+def test_a_stalled_interval_is_counted_and_described(clock):
+    sm = EngineStepMetrics()
+    with sm.tick():
+        _burst(sm, clock, 5)
+        with sm.phase("tick.install"):
+            clock.t += 0.6  # the forced sleep
+        noted = _burst(sm, clock, 5)
+    assert noted == {
+        "interval_s": 0.61, "frame_kind": "decode", "rows": 5,
+        "phases": {"tick.install": 0.6, "tick.decode_wait": 0.008, "tick.emit": 0.002},
+        "compiles": 0, "gc_s": pytest.approx(0.0, abs=0.05),
+    }
+    assert _frames(sm)["stalls"] == {"decode": 1.0, "prefill": 0.0}
+    assert engine_metrics.FRAME_STALL_SECONDS == 0.5
+
+
+def test_a_stall_leaves_one_flight_record_and_one_log_line(clock):
+    """The engine's half: a 0.6 s sleep forced into a phase between two
+    frames leaves a ``stall`` record on the flight ring that names the
+    phase, and a WARNING line (the worker's log is kept beside every
+    benchmark run)."""
+    from tests.test_device_observe import warning_lines
+
+    engine, _ = make_engine()
+    sm = engine.step_metrics
+    with warning_lines() as lines, sm.tick():
+        with sm.phase("tick.emit"):
+            engine._note_frame(2)
+        with sm.phase("tick.prefill_build"):
+            clock.t += 0.6
+        with sm.phase("tick.prefill_wait"):
+            clock.t += 0.05
+        with sm.phase("tick.emit"):
+            engine._note_frame(3)
+            engine._note_frame(3)  # the next frame, at once: no stall
+    stalls = [e for e in engine.flight.snapshot() if e["kind"] == "stall"]
+    assert len(stalls) == 1
+    assert stalls[0]["interval_s"] == 0.65 and stalls[0]["frame_kind"] == "prefill"
+    assert stalls[0]["rows"] == 3 and stalls[0]["waiting"] == 0
+    assert list(stalls[0]["phases"]) == ["tick.prefill_build", "tick.prefill_wait"]
+    assert {"compiles", "gc_s"} <= set(stalls[0])
+    said = [l for l in lines if l.startswith("stall: ")]
+    assert len(said) == 1
+    assert "0.650 s between two frames (prefill)" in said[0]
+    assert "tick.prefill_build 0.600 s" in said[0] and "3 rows waiting" in said[0]
+
+
+def test_every_new_series_is_in_the_first_scrape_at_zero():
+    body = EngineStepMetrics().render()
+    for phase in mn.TICK_PHASES:
+        for family in (mn.ENGINE_DEVICE_STARVED_SECONDS_TOTAL,
+                       mn.ENGINE_FRAME_ROW_SECONDS_TOTAL):
+            assert f'{family}{{phase="{phase}"}} 0' in body.splitlines()
+    for kind in mn.FRAME_KINDS:
+        lines = body.splitlines()
+        assert f'{mn.ENGINE_FRAME_STALLS_TOTAL}{{kind="{kind}"}} 0' in lines
+        assert f'{mn.ENGINE_FRAME_INTERVAL}_count{{kind="{kind}"}} 0' in lines
+        assert f'{mn.ENGINE_FRAME_INTERVAL}_sum{{kind="{kind}"}} 0.0' in lines
+        assert f'{mn.ENGINE_FRAME_INTERVAL}_bucket{{kind="{kind}",le="10"}} 0' in lines
+    assert "host_gap" not in body
+
+
 def test_phase_helper_cost_is_microseconds():
     """Always on, inside the decode loop: a phase costs microseconds, not
     a log line or a lock wait. The best of five batches, so that a worker
@@ -486,7 +693,8 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
     params = spec["params"]
     families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND) | {
         mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
-    labels = set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES) | {"used", "total"}
+    labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
+              | set(mn.FRAME_KINDS) | {"used", "total"})
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
     workers = "workers"
@@ -540,6 +748,27 @@ def test_host_share_phases_are_the_whole_non_idle_set():
     assert {t["labels"]["phase"] for t in p["num"]} == set(mn.TICK_PHASES_HOST)
     assert {t["labels"]["phase"] for t in p["den"]} == \
         set(mn.TICK_PHASES_HOST) | set(mn.TICK_PHASES_DEVICE_WAIT)
+
+
+def test_starved_shares_split_the_phases_that_can_starve():
+    """Each tick phase that can be starved is in exactly one of the three
+    starved shares, each share is over all eleven phases' seconds, and the
+    row-wait share is over all eleven too."""
+    shares = [_layer_metric("engine.device_starved_" + part + "_share")["params"]
+              for part in ("idle", "prefill", "decode")]
+    nums = [t["labels"]["phase"] for p in shares for t in p["num"]]
+    assert sorted(nums) == sorted(mn.TICK_PHASES_HOST + mn.TICK_PHASES_IDLE)
+    for p in shares:
+        assert {t["metric"] for t in p["num"]} == {mn.ENGINE_DEVICE_STARVED_SECONDS_TOTAL}
+        assert {t["metric"] for t in p["den"]} == {mn.ENGINE_TICK_PHASE + "_sum"}
+        assert sorted(t["labels"]["phase"] for t in p["den"]) == sorted(mn.TICK_PHASES)
+    rows = _layer_metric("engine.row_wait_prefill_share")["params"]
+    assert sorted(t["labels"]["phase"] for t in rows["den"]) == sorted(mn.TICK_PHASES)
+    assert {t["labels"]["phase"] for t in rows["num"]} == {
+        "tick.admit", "tick.prefill_build", "tick.prefill_wait", "tick.install"}
+    for name in PROGRAM_READ:
+        if _layer_metric(name)["params"].get("to") == "window_end":
+            assert _layer_metric(name)["params"]["from"] == "window_start"
 
 
 def _roofline_reader_cases():
