@@ -15,15 +15,17 @@ MXU; measured 16× rows for 2.4× cost on the v5e).
 from __future__ import annotations
 
 import asyncio
+import collections
 import math
 import time
-from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from dataclasses import dataclass, field
+from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
 from dynamo_tpu.engines.tpu.runner import _next_pow2
 from dynamo_tpu.runtime import lifecycle
+from dynamo_tpu.runtime.device_observe import global_compile_watcher
 from dynamo_tpu.runtime.kv_reuse_observe import global_plane as kv_reuse_plane
 from dynamo_tpu.tokens.blocks import adapter_salt, compute_block_hashes
 
@@ -98,11 +100,26 @@ class PendingPrefill:
     snap_keys: Optional[List[int]] = None
 
 
+@dataclass
+class _Family:
+    """One prefix-hit prefill shape: rounds that met it, rows buckets run."""
+
+    met: int = 0
+    ran: Set[int] = field(default_factory=set)
+
+
 class Admitter:
     """Engine-attached admission pipeline (state lives on the engine)."""
 
     def __init__(self, engine: Any) -> None:
         self.e = engine
+        # Prefix-hit prefill programs by (chunk bucket, table width,
+        # top-logprobs variant): how often a round met the shape and the
+        # rows buckets that ran; the sibling rows buckets still to compile
+        # (run_pending_family), and how many of them have run.
+        self._families: Dict[Tuple[int, int, bool], "_Family"] = {}
+        self.family_pending: Deque[Tuple[int, int, int, bool]] = collections.deque()
+        self.family_programs = 0
 
     async def _admit_batch(self) -> int:
         """Admit + prefill up to ``prefill_batch`` waiting sequences in ONE
@@ -412,14 +429,16 @@ class Admitter:
             e._tick_budget_left = saved
         return pending.first  # type: ignore[return-value]
 
+    def _row_buckets(self) -> List[int]:
+        return sorted(
+            {_next_pow2(r) for r in range(1, self.e.args.prefill_batch + 1)}
+        )
+
     def prefill_ladder(self) -> List[Tuple[int, int, int]]:
         """Every (rows bucket, chunk bucket, table width) a batch of FRESH
         prompts that each fit one chunk can dispatch at — the shapes
         ``_begin_prefill`` / ``_prefill_rounds`` derive, enumerated."""
         args = self.e.args
-        row_buckets = sorted(
-            {_next_pow2(r) for r in range(1, args.prefill_batch + 1)}
-        )
         chunks = []
         c = prefill_chunk_bucket(1, args.prefill_chunk)
         while c < args.prefill_chunk:
@@ -431,45 +450,107 @@ class Admitter:
                 Bp, c,
                 prefill_table_bucket(math.ceil(c / args.block_size), c, args),
             )
-            for Bp in row_buckets
+            for Bp in self._row_buckets()
             for c in chunks
         ]
 
-    async def compile_prefill_ladder(self) -> int:
-        """Run every ladder program once, THROUGH the callable the chunk
-        rounds call (``engine._run_step`` with the arrays a round builds:
-        an ahead-of-time ``lower().compile()`` beside it would not fill
-        that callable's cache, and its first real call would still trace
-        and load). Every row has length 0, so nothing is written to the
-        pool and nothing is emitted. Returns how many programs ran.
+    async def _run_empty_step(
+        self, Bp: int, c: int, nb: int, *, first_chunk: bool,
+        want_top: bool = False, step: Any = None,
+    ):
+        """One prefill program run THROUGH the callable the chunk rounds
+        call (``engine._run_step``, or ``step`` around it, with the arrays
+        a round builds: an ahead-of-time ``lower().compile()`` beside it
+        would not fill that callable's cache, and its first real call would
+        still trace and load), every row of length 0: nothing is written to
+        the pool and nothing is emitted. Returns the step's outputs."""
+        e = self.e
+        zeros = np.zeros(Bp, dtype=np.int32)
+        hybrid = ()
+        if e.config.is_hybrid:
+            # With the rows' recurrent state: started from zeros, no
+            # snapshot kept.
+            ssm = await e._device(e.runner.ssm_begin, zeros - 1)
+            hybrid = (ssm, self._snap_dst(Bp, c))
+        return await e._device(
+            step or e._run_step,
+            np.zeros((Bp, c), dtype=np.int32), zeros, zeros,
+            np.zeros((Bp, nb), dtype=np.int32),
+            np.ones(Bp, dtype=np.float32), zeros,
+            np.ones(Bp, dtype=np.float32), zeros,
+            None, None, None, want_top, first_chunk, zeros, *hybrid,
+        )
 
-        Not in the ladder, compiled at first use as before: rounds after
-        a prompt's first (prefix-hit tails, prompts longer than the
-        chunk), top-logprobs and logits-processor variants, multimodal
-        rows, and the decode and scatter programs."""
+    def _run_family_step(self, *step_args):
+        """On the device thread: what this call compiles is the family's
+        own warm-up, logged as that and not as a serving-path compile."""
+        with global_compile_watcher().family_warm_up():
+            return self.e._run_step(*step_args)
+
+    async def compile_prefill_ladder(self) -> int:
+        """Run every ladder program once (``_run_empty_step``). Returns
+        how many programs ran.
+
+        Not in the ladder: rounds after a prompt's first (prefix-hit
+        tails, prompts longer than the chunk), whose rows-bucket family is
+        compiled once such a shape recurs (``run_pending_family``); and,
+        compiled at first use as before, top-logprobs and logits-processor
+        variants, multimodal rows, and the decode and scatter programs."""
         e = self.e
         ladder = self.prefill_ladder()
         for Bp, c, nb in ladder:
-            zeros = np.zeros(Bp, dtype=np.int32)
-            hybrid = ()
-            if e.config.is_hybrid:
-                # The same ladder with the rows' recurrent state: started
-                # from zeros, no snapshot kept; the state programs beside
-                # the step (start, install) run once per rows bucket too.
-                ssm = await e._device(e.runner.ssm_begin, zeros - 1)
-                hybrid = (ssm, self._snap_dst(Bp, c))
-            out = await e._device(
-                e._run_step,
-                np.zeros((Bp, c), dtype=np.int32), zeros, zeros,
-                np.zeros((Bp, nb), dtype=np.int32),
-                np.ones(Bp, dtype=np.float32), zeros,
-                np.ones(Bp, dtype=np.float32), zeros,
-                None, None, None, False, True, zeros, *hybrid,
-            )
-            if hybrid and c == ladder[0][1]:
+            out = await self._run_empty_step(Bp, c, nb, first_chunk=True)
+            if e.config.is_hybrid and c == ladder[0][1]:
+                # The state programs beside the step (start, install) run
+                # once per rows bucket too.
                 rows = list(range(min(Bp, e.args.max_num_seqs)))
                 await e._device(e.runner.ssm_install, rows, out[4], rows)
         return len(ladder)
+
+    def _note_prefix_hit_round(self, Bp: int, c: int, nb: int, want_top: bool) -> None:
+        """A chunk round over cached context dispatched at this shape. Such
+        programs are in no start-up ladder (most workers never meet one,
+        and a ladder program costs seconds). One that RECURS says this
+        worker's traffic hits prefixes: at its second meeting the sibling
+        rows buckets, which the next burst of asks would each compile in
+        front of live streams, are put down to be compiled one a tick."""
+        family = self._families.setdefault((c, nb, want_top), _Family())
+        family.met += 1
+        family.ran.add(Bp)
+        if family.met == 2:
+            self.family_pending.extend(
+                (rows, c, nb, want_top)
+                for rows in self._row_buckets() if rows not in family.ran
+            )
+
+    async def run_pending_family(self) -> bool:
+        """Compile AT MOST ONE pending sibling of a recurring prefix-hit
+        prefill program: the scheduler calls this once a tick ahead of
+        admission, so no await of its loop is longer than one compile and
+        stats and load reports go out in between. True where one ran."""
+        e = self.e
+        while self.family_pending:
+            Bp, c, nb, want_top = self.family_pending.popleft()
+            family = self._families[(c, nb, want_top)]
+            if Bp in family.ran:  # a batch of that many rows came first
+                continue
+            family.ran.add(Bp)
+            try:
+                with e.step_metrics.phase("tick.prefill_wait", rows=0, chunk=c, nb=nb):
+                    await self._run_empty_step(
+                        Bp, c, nb, first_chunk=False, want_top=want_top,
+                        step=self._run_family_step,
+                    )
+            except Exception:
+                logger.exception(
+                    "family warm-up of the prefix-hit prefill program rows %d, "
+                    "chunk %d, table %d failed; it compiles at first use",
+                    Bp, c, nb,
+                )
+                continue
+            self.family_programs += 1
+            return True
+        return False
 
     def _snap_dst(self, Bp: int, c_bucket: int) -> np.ndarray:
         """Snapshot destinations of one chunk round, nothing kept yet: every
@@ -625,6 +706,10 @@ class Admitter:
                 # Fresh prefills (no prefix-cache hit, first chunk round) take
                 # the dense in-chunk attention program — zero paged reads.
                 first_chunk = bool(np.all(start[:rows] == 0))
+                if not first_chunk and procs is None and mm_embeds is None:
+                    self._note_prefix_hit_round(
+                        Bp, c_bucket, tables.shape[1], want_top
+                    )
                 hybrid_args = ()
                 if hybrid:
                     snap_dst = self._snap_dst(Bp, c_bucket)

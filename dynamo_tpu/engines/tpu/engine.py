@@ -743,6 +743,10 @@ class JaxEngine:
             # (compile_prefill_ladder; zeros where it was never called):
             # against /debug/compiles, what serving has compiled since.
             **self._startup_compile,
+            # Sibling rows buckets of recurring prefix-hit prefill programs
+            # (admission.run_pending_family): compiled so far, still to run.
+            "prefill_family_programs": self._admitter.family_programs,
+            "prefill_family_pending": len(self._admitter.family_pending),
         }
         if self.config.has_latent_cache:
             out["latent_pool"] = dict(self.runner.kv_pool)
@@ -1081,7 +1085,10 @@ class JaxEngine:
                         )
                     ):
                         await self._drain_inflight()
-                    admitted = False
+                    # A prefix-hit prefill program that recurred brings the
+                    # rows buckets beside it: at most one compile a tick
+                    # (admission.run_pending_family), and no idling on it.
+                    admitted = await self._admitter.run_pending_family()
                     with self.step_metrics.phase(
                         "tick.admit", waiting=len(self._waiting)
                     ):
@@ -1089,7 +1096,7 @@ class JaxEngine:
                             # Budgeted admission (tick_budget.py): the
                             # closed-loop prefill token grant replaces the
                             # static batch cap.
-                            admitted = await self._admit_tick_budgeted()
+                            admitted |= await self._admit_tick_budgeted()
                         else:
                             # Admit in batched prefill dispatches; a per-tick
                             # batch cap bounds how long running decodes stall

@@ -17,12 +17,17 @@ the first from what it is given (the caller's ``use_kernel``, the static
 token count, the matrices, the spec); the static token count alone chooses
 between the other two:
 
-* hit list (a decode step on one TPU chip): the dense form's own products,
-  float32 accumulation, over the experts that got a LIVE token and no
-  others, by a Pallas kernel whose grid is that list
-  (ops/pallas/expert_ffn.py). A step streams from HBM the matrices of the
-  experts hit, which is what ``want_stats`` counts: a dead slot (``row_mask``
-  false) routes to no expert.
+* hit list (a decode step or a small prefill step on one TPU chip): the
+  dense form's own products, float32 accumulation, over the experts that
+  got a LIVE token and no others, by a Pallas kernel whose grid is that
+  list (ops/pallas/expert_ffn.py). A step streams from HBM the matrices of
+  the experts hit, which is what ``want_stats`` counts: a dead slot
+  (``row_mask`` false) routes to no expert. The kernel reads ``relu2``
+  experts (two matrices) and ``silu_gated`` ones (three), with ``we_up`` /
+  ``we_gate`` in either layout XLA holds them in: d minor-most where f does
+  not fill the 128 lanes (the hybrid cell, 2688 x 1856), f minor-most where
+  it does (the latent cell, 7680 x 2048); a model width that does not fill
+  the lanes stays on the XLA forms.
 * dense (few tokens, everywhere else): every token through every held
   expert with a [T, E_held] weight matrix that is zero off the routing and
   on dead rows. The weights of all held experts stream once, and the
@@ -56,12 +61,17 @@ if TYPE_CHECKING:  # models/ imports this module: the spec is data, named only
 # Token count up to which every token goes through every expert hit (the
 # hit-list kernel, where ``hit_list_reason`` finds none against it) or
 # through every held expert (the dense form); above it the grouped form
-# serves. At the widths served here (d 2688, f 1856, 64 held) the dense
+# serves. At the hybrid cell's widths (d 2688, f 1856, 64 held) the dense
 # form's FLOPs pass the time the weights take to stream at about 256 tokens
 # on a v5e, and up to there the kernel with all 64 experts hit is no slower
 # than the dense form (chip_check's expert_ffn rows at 64, 128 and 256
 # tokens: 1,724 / 1,725 / 1,931 us against 1,751 / 1,786 / 2,188, my chip
-# run, PR 37), so one bound serves both.
+# run, PR 37). The ridge is a property of the chip, not of the widths (an
+# expert's FLOPs over its bytes is the token count, whatever d and f), and
+# at the latent cell's widths (7680 x 2048, 16 held, three matrices) the
+# kernel with all 16 hit reads 2,083 / 2,124 / 2,464 us at 64 / 128 / 256
+# tokens against 2,127 / 2,136 / 2,444 (a tie at 256, where a step with two
+# experts hit takes 347 us; my chip run, PR 43), so one bound serves both.
 DENSE_TOKENS_MAX = 256
 
 _HI = jax.lax.Precision.HIGHEST
@@ -119,10 +129,11 @@ def hit_list_reason(
     n_held, d, f = lp["we_up"].shape
     if not n_held:
         return "no expert held"
-    if d % 128 or not f % 128:
-        # The kernel reads we_up through its resident layout, which XLA
-        # makes d-minor exactly when f would need lane padding and d not.
-        return f"widths d {d}, f {f}: we_up is not resident with d minor"
+    if d % 128:
+        # The kernel reads we_up (and we_gate) through the resident layout:
+        # f minor-most where f fills the 128 lanes, d minor-most where it
+        # does not and d does (expert_ffn.f_minor). Neither: XLA pads.
+        return f"widths d {d}, f {f}: d does not fill the 128 lanes"
     return None
 
 
@@ -220,6 +231,7 @@ def moe_ffn(
         y = expert_ffn(
             xs, _combine(top_w, local, valid, n_held), lp["we_up"],
             lp["we_down"], *hit_list(load),
+            lp["we_gate"] if spec.activation == "silu_gated" else None,
         )
     elif _dense_serves(T, lp):
         comb = _combine(top_w, local, valid, n_held)

@@ -6,11 +6,13 @@ attention shape the worker's builtin presets produce it lowers
 ``paged_attention_decode_kernel`` and ``paged_attention_kernel`` with
 ``interpret=False`` over bf16 and int8-KV pools, and ``fused_decoder_layer``
 at the Qwen3-8B layer shape for every pow2 table width up to the worker's
-default model length, and ``expert_ffn`` (the hit-list expert kernel) at the
-hybrid configuration's served widths against ops/moe.py's dense form: 64
-tokens over 1, 8, 19, 29 and all 64 held experts hit, and 128 and 256
-tokens with all hit (the rows that decide how many tokens the kernel serves),
-each with both forms' ``us/call``. The decode kernel gets four more rows: the tail of
+default model length, and ``expert_ffn`` (the hit-list expert kernel)
+against ops/moe.py's dense form at the hybrid configuration's served widths
+(64 tokens over 1, 8, 19, 29 and all 64 held experts hit, and 128 and 256
+tokens with all hit) and at the latent configuration's (three matrices of
+7680 x 2048, 16 held: 8, 64, 128 and 256 tokens with 2 and all 16 hit, and
+the 32 decode slots on one expert): the rows that decide how many tokens
+the kernel serves, each with both forms' ``us/call``. The decode kernel gets four more rows: the tail of
 a prefix-hit prefill (one row, four tokens, eight pages), the
 benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
 contexts, the others empty with a stale position, 128 pages of table; bf16
@@ -47,6 +49,7 @@ import functools
 import json
 import os
 import sys
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
@@ -376,72 +379,97 @@ def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
 
 def expert_ffn_jobs(interpret: bool):
     """The hit-list expert kernel against the XLA dense form
-    (ops/moe._experts_dense, what it replaces in a decode step), at the
-    served widths: d 2688, f 1856, 64 held, bf16. ``hit`` experts get the
-    tokens' top-6 choices; the others are never read. The one-expert row
-    is ``required``: a one-entry work list halted the core once (PR 25)."""
+    (ops/moe._experts_dense, what it replaces in a decode step), bf16, at
+    the two served configurations' widths, which are the kernel's two
+    layouts and two activations: the hybrid cell's (d 2688, f 1856, 64
+    held, relu2, top-6: ``we_up`` resident with d minor) and the latent
+    cell's (d 7680, f 2048, 16 held, gated silu, top-8: three matrices, f
+    minor). ``hit`` experts get the tokens' choices; the others are never
+    read. The one-expert row is ``required``: a one-entry work list halted
+    the core once (PR 25)."""
     from dynamo_tpu.models.config import ExpertsSpec
     from dynamo_tpu.ops import moe
     from dynamo_tpu.ops.pallas.expert_ffn import expert_ffn, hit_list
 
-    d, f, n_held, K = (128, 48, 8, 2) if interpret else (2688, 1856, 64, 6)
     dtype = jnp.float32 if interpret else jnp.bfloat16
-    spec = ExpertsSpec(n_experts=n_held, top_k=K, d_ff=f, activation="relu2")
+    drawing = threading.Lock()  # rows run side by side: one draw of a stack
 
-    @functools.cache
-    def weights():
-        k1, k2 = jax.random.split(jax.random.PRNGKey(37))
-        return {
-            "we_up": jax.random.normal(k1, (n_held, d, f), dtype) * d**-0.5,
-            "we_down": jax.random.normal(k2, (n_held, f, d), dtype) * f**-0.5,
-        }
+    def family(preset, d, f, n_held, K, activation, rows):
+        spec = ExpertsSpec(n_experts=n_held, top_k=K, d_ff=f, activation=activation)
 
-    def job(T, hit):
-        rng = np.random.default_rng(T * 100 + hit)
-        xs = jnp.asarray(rng.standard_normal((T, d)), dtype)
-        comb = np.zeros((T, n_held), np.float32)
-        chosen = rng.permutation(n_held)[:hit]
-        for t in range(T):
-            mine = rng.permutation(chosen)[:K]
-            comb[t, mine] = rng.random(len(mine)) + 0.1
-        comb[:, chosen] += (comb[:, chosen].sum(0) == 0) * 0.5  # each one hit
-        comb = jnp.asarray(comb)
-        ids, count = hit_list(comb.sum(0))
-        row = {
-            "kernel": "expert_ffn",
-            "shape": f"T{T} d{d} f{f} held{n_held} hit{hit} {dtype.__name__}",
-            "presets": ["nemotron-3-nano-30b-a3b-ep2"],
-            "required": hit == 1,
-        }
+        def weights():
+            with drawing:
+                return drawn()
 
-        def kernel(xs, comb, lp, ids, count):
-            return expert_ffn(
-                xs, comb, lp["we_up"], lp["we_down"], ids, count,
-                interpret=interpret,
-            ).astype(xs.dtype)
+        @functools.cache
+        def drawn():
+            keys = jax.random.split(jax.random.PRNGKey(37), 3)
+            lp = {
+                "we_up": jax.random.normal(keys[0], (n_held, d, f), dtype) * d**-0.5,
+                "we_down": jax.random.normal(keys[1], (n_held, f, d), dtype) * f**-0.5,
+            }
+            if activation == "silu_gated":
+                lp["we_gate"] = (
+                    jax.random.normal(keys[2], (n_held, d, f), dtype) * d**-0.5)
+            return lp
 
-        def dense(xs, comb, lp, ids, count):
-            return moe._experts_dense(xs, comb, lp, spec)
+        def job(T, hit):
+            rng = np.random.default_rng(T * 100 + hit)
+            xs = jnp.asarray(rng.standard_normal((T, d)), dtype)
+            comb = np.zeros((T, n_held), np.float32)
+            chosen = rng.permutation(n_held)[:hit]
+            for t in range(T):
+                mine = rng.permutation(chosen)[:K]
+                comb[t, mine] = rng.random(len(mine)) + 0.1
+            comb[:, chosen] += (comb[:, chosen].sum(0) == 0) * 0.5  # each one hit
+            comb = jnp.asarray(comb)
+            ids, count = hit_list(comb.sum(0))
+            row = {
+                "kernel": "expert_ffn",
+                "shape": f"T{T} d{d} f{f} held{n_held} hit{hit} {activation} "
+                         f"{dtype.__name__}",
+                "presets": [preset],
+                "required": hit == 1,
+            }
 
-        row = _timed(
-            row, lambda: kernel(xs, comb, weights(), ids, count),
-            lambda: dense(xs, comb, weights(), ids, count), ulps=4,
+            def kernel(xs, comb, lp, ids, count):
+                return expert_ffn(
+                    xs, comb, lp["we_up"], lp["we_down"], ids, count,
+                    lp.get("we_gate"), interpret=interpret,
+                ).astype(xs.dtype)
+
+            def dense(xs, comb, lp, ids, count):
+                return moe._experts_dense(xs, comb, lp, spec)
+
+            row = _timed(
+                row, lambda: kernel(xs, comb, weights(), ids, count),
+                lambda: dense(xs, comb, weights(), ids, count), ulps=4,
+            )
+            if row["status"] == "compiled" and not interpret:
+
+                def both():
+                    args = (xs, comb, weights(), ids, count)
+                    row["message"] = f"xla dense {_us_per_call(dense, *args)} us/call"
+                    return _us_per_call(kernel, *args)
+
+                row["time"] = both
+            return row
+
+        return [functools.partial(job, T, hit) for T, hit in rows]
+
+    if interpret:
+        return (
+            family("tiny-hybrid", 128, 48, 8, 2, "relu2",
+                   [(16, 1), (16, 3), (16, 8), (32, 8)])
+            + family("tiny-mla", 128, 256, 4, 2, "silu_gated",
+                     [(16, 1), (16, 2), (32, 4)])
         )
-        if row["status"] == "compiled" and not interpret:
-
-            def both():
-                args = (xs, comb, weights(), ids, count)
-                row["message"] = f"xla dense {_us_per_call(dense, *args)} us/call"
-                return _us_per_call(kernel, *args)
-
-            row["time"] = both
-        return row
-
-    hits = [1, 3, n_held] if interpret else [1, 8, 19, 29, 64]
-    jobs = [functools.partial(job, 16 if interpret else 64, h) for h in hits]
-    jobs += [functools.partial(job, T, n_held) for T in
-             ((32,) if interpret else (128, 256))]
-    return jobs
+    return (
+        family("nemotron-3-nano-30b-a3b-ep2", 2688, 1856, 64, 6, "relu2",
+               [(64, 1), (64, 8), (64, 19), (64, 29), (64, 64), (128, 64), (256, 64)])
+        + family("openpangu-ultra-moe-718b-ep16", 7680, 2048, 16, 8, "silu_gated",
+                 [(32, 1)] + [(T, hit) for T in (8, 64, 128, 256) for hit in (2, 16)])
+    )
 
 
 def mla_jobs(interpret: bool):
@@ -449,8 +477,10 @@ def mla_jobs(interpret: bool):
     its XLA oracle at the served widths: 128 heads over a latent pool of
     640-lane rows (c_kv 512 + rotary key 64, lanes past 576 zero) in
     128-token pages. Decode rows at the cell's context, a ONE-row decode
-    (``required``: a one-entry work list halted the core once, PR 25), and
-    a question chunk over a cached document. The timed rows print the
+    (``required``: a one-entry work list halted the core once, PR 25), a
+    question chunk over a cached document, and two steps whose every row is
+    empty (``required``: what the engine runs to compile a prefix-hit prefill
+    program's sibling rows buckets, admission.run_pending_family). The timed rows print the
     kernel's own roofline: bytes of the live pages at the HBM peak against
     the FLOPs at the bf16 peak."""
     from dynamo_tpu.ops.attention import _mla_paged_xla
@@ -460,7 +490,7 @@ def mla_jobs(interpret: bool):
     dtype = jnp.float32 if interpret else jnp.bfloat16
     scale = 192**-0.5
 
-    def job(B, C, context, P, required=False):
+    def job(B, C, context, P, required=False, empty=False):
         rng = np.random.default_rng(B * 1000 + C)
         NB = B * P + 1
         pool = jnp.zeros((NB, bs, W), dtype).at[..., : R + 64].set(
@@ -470,23 +500,29 @@ def mla_jobs(interpret: bool):
             jnp.asarray(rng.standard_normal((B, C, H, R + 64)), dtype))
         start = jnp.asarray(context - rng.integers(0, bs, B), jnp.int32)
         lens = jnp.full((B,), C, jnp.int32)
+        if empty:  # a family warm-up's step: every row of length 0, no work
+            start, lens = jnp.zeros_like(start), jnp.zeros_like(lens)
+        live = (lens > 0)[:, None, None, None]  # an empty row's result is no result
         row = {
             "kernel": "mla_paged_decode",
-            "shape": f"B{B} C{C} H{H} ctx{context} P{P} bs{bs} W{W} {dtype.__name__}",
+            "shape": f"B{B} C{C} H{H} ctx{0 if empty else context} P{P} bs{bs} W{W} "
+                     f"{dtype.__name__}" + (" every row empty" if empty else ""),
             "presets": ["openpangu-ultra-moe-718b-ep16"],
             "required": required,
         }
 
         def kernel(q, pool, tables, start, lens):
-            return mla_paged_decode(q, pool, tables, start, lens, v_width=R,
-                                    sm_scale=scale, interpret=interpret)
+            out = mla_paged_decode(q, pool, tables, start, lens, v_width=R,
+                                   sm_scale=scale, interpret=interpret)
+            return jnp.where(live, out, 0)
 
         def xla(q, pool, tables, start, lens):
-            return _mla_paged_xla(q, pool, tables, start, lens, v_width=R, sm_scale=scale)
+            out = _mla_paged_xla(q, pool, tables, start, lens, v_width=R, sm_scale=scale)
+            return jnp.where(live, out, 0)
 
         args = (q, pool, tables, start, lens)
         row = _timed(row, lambda: kernel(*args), lambda: xla(*args), ulps=8)
-        if row["status"] == "compiled" and not interpret:
+        if row["status"] == "compiled" and not interpret and not empty:
 
             def timed():
                 # (the timed loop feeds each call's result back into q)
@@ -505,13 +541,16 @@ def mla_jobs(interpret: bool):
 
     if interpret:
         return [functools.partial(job, 3, 1, 40, 4), functools.partial(job, 1, 1, 40, 4, True),
-                functools.partial(job, 2, 16, 40, 4)]
+                functools.partial(job, 2, 16, 40, 4),
+                functools.partial(job, 2, 16, 40, 4, True, True)]
     return [
         functools.partial(job, 24, 1, 16500, 136),
         functools.partial(job, 32, 1, 16500, 136),
         functools.partial(job, 1, 1, 16500, 136, True),
         functools.partial(job, 1, 128, 16384, 136),
         functools.partial(job, 2, 256, 16384, 136),
+        functools.partial(job, 2, 128, 16384, 136, True, True),
+        functools.partial(job, 8, 256, 16384, 136, True, True),
     ]
 
 

@@ -51,6 +51,7 @@ a metrics lock; render-time sampling pays it instead.
 from __future__ import annotations
 
 import collections
+import contextlib
 import gc
 import json
 import os
@@ -256,6 +257,7 @@ class CompileWatcher:
         )
         self.compiles = 0
         self._serving = False
+        self._warming = threading.local()  # family_warm_up, per thread
         self._hist = self.registry.histogram(
             mn.RUNTIME_COMPILE_SECONDS,
             "Wall time of calls that compiled a new program signature "
@@ -303,18 +305,37 @@ class CompileWatcher:
         stalls live streams, and is logged as one."""
         self._serving = True
 
+    @contextlib.contextmanager
+    def family_warm_up(self):
+        """What this thread compiles inside is a warm-up the engine chose
+        to run (the sibling rows buckets of a recurring prefix-hit prefill
+        program, one a tick: engines/tpu/admission.py), not a program a
+        request met first: an INFO line, ``family`` in ``recent``."""
+        self._warming.family = True
+        try:
+            yield
+        finally:
+            self._warming.family = False
+
     def on_compile(self, program: str, n: int, dt: float, signature: str) -> None:
         self._hist.observe(dt, program=program)
         self.compiles += n
+        family = getattr(self._warming, "family", False)
         self.recent.append({
             "program": program,
             "signature": signature,
             "seconds": round(dt, 4),
             "t_mono": round(time.monotonic(), 6),
             "t_wall": round(time.time(), 3),
-            "serving": self._serving,
+            "serving": self._serving and not family,
+            "family": family,
         })
-        if self._serving:
+        if family:
+            logger.info(
+                "compiled for the family of %s %s in %.3f s",
+                program, signature, dt,
+            )
+        elif self._serving:
             logger.warning(
                 "compiled on the serving path: %s %s in %.3f s",
                 program, signature, dt,

@@ -45,15 +45,18 @@ def test_package_has_zero_non_baselined_findings_under_five_seconds():
 
     Measured wall with all nine passes (DYN001-DYN009) on the CI
     container: ~1.3s — the parse-once ``module.nodes`` flat-list
-    invariant keeps each added rule a linear scan, not a re-walk."""
-    t0 = time.monotonic()
+    invariant keeps each added rule a linear scan, not a re-walk. The
+    budget is CPU time of this process: tier-1 runs six workers on
+    shared cores, and the wall clock of one of them measures the others
+    (the driver's run of PR 42 failed here alone, at 5+ s of wall)."""
+    t0 = time.process_time()
     findings = run_lint(os.path.abspath(PKG))
-    elapsed = time.monotonic() - t0
+    elapsed = time.process_time() - t0
     new, _old = partition_new(findings, load_baseline(DEFAULT_BASELINE))
     assert not new, "new dynlint findings:\n" + "\n".join(
         f.render() for f in new
     )
-    assert elapsed < 5.0, f"analyzer took {elapsed:.2f}s (budget 5s)"
+    assert elapsed < 5.0, f"analyzer took {elapsed:.2f}s of CPU (budget 5s)"
 
 
 def test_finding_count_matches_checked_in_baseline():

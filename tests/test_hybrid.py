@@ -183,9 +183,9 @@ def hit_list_calls(monkeypatch):
 
     calls = []
 
-    def interpreted(xs, comb, up, down, ids, count):
+    def interpreted(xs, comb, up, down, ids, count, gate=None):
         calls.append((np.asarray(ids), int(count[0])))
-        return expert_ffn(xs, comb, up, down, ids, count, interpret=True)
+        return expert_ffn(xs, comb, up, down, ids, count, gate, interpret=True)
 
     monkeypatch.setattr(moe, "expert_ffn", interpreted)
     return calls
@@ -284,6 +284,78 @@ def test_hit_list_kernel_is_the_dense_form_over_the_experts_hit(
     _close(y[0][live], want[live], 1e-4)
 
 
+GATED_CASES = {
+    # name: (d, f, activation): the kernel's two layouts (``we_up`` resident f
+    # minor where f fills the 128 lanes, d minor where it does not) and its two
+    # activations, several tiles of each matrix an expert
+    "f_minor_gated": (256, 256, "silu_gated"),
+    "f_minor_relu2": (256, 384, "relu2"),
+    "d_minor_gated": (128, 48, "silu_gated"),
+    "d_minor_relu2": (128, 48, "relu2"),
+}
+
+
+@pytest.mark.parametrize("hit", ["none", "one", "two", "all"])
+@pytest.mark.parametrize("case", sorted(GATED_CASES))
+def test_expert_kernel_with_a_gate_matrix_is_the_dense_form(case, hit, monkeypatch):
+    """``expert_ffn`` under the Pallas interpreter against ``_experts_dense``:
+    silu(x @ gate) * (x @ up) or relu2(x @ up), then the down product, over
+    the experts on the list and no others (an expert off the list holds NaN: it
+    is never read), dead rows adding nothing, an empty list giving zeros."""
+    from dynamo_tpu.ops.pallas import expert_ffn as ef
+
+    d, f, activation = GATED_CASES[case]
+    E, T, K = 6, 12, 2
+    rng = np.random.default_rng(11)
+    chosen = {"none": [], "one": [3], "two": [1, 4], "all": list(range(E))}[hit]
+    spec = ExpertsSpec(n_experts=E, top_k=K, d_ff=f, activation=activation)
+    gated = activation == "silu_gated"
+    lp = {k: rng.standard_normal(shape).astype(np.float32) * 0.1
+          for k, shape in (("we_up", (E, d, f)), ("we_down", (E, f, d)))}
+    if gated:
+        lp["we_gate"] = rng.standard_normal((E, d, f)).astype(np.float32) * 0.1
+    dense_lp = {k: jnp.asarray(v.copy()) for k, v in lp.items()}
+    # What the kernel may not read (an empty list is one step over expert 0
+    # under a zero combine column: ``hit_list``).
+    unread = [e for e in range(E) if e not in (chosen or [0])]
+    for k in lp:
+        lp[k][unread] = np.nan
+    lp = {k: jnp.asarray(v) for k, v in lp.items()}
+    xs = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
+    comb = np.zeros((T, E), np.float32)
+    for t in range(T):
+        for e in rng.permutation(chosen)[:K]:
+            comb[t, e] = rng.random() + 0.1
+    comb[[2, 9]] = 0.0  # dead rows
+    if chosen:
+        comb[0, chosen] = 0.5  # every expert of the case is hit
+    comb = jnp.asarray(comb)
+    ids, count = ef.hit_list(comb.sum(0))
+    assert int(count[0]) == len(chosen)
+    # several tiles an expert: [128, f] row tiles, [128, d] / [16, d] down tiles
+    monkeypatch.setattr(ef, "F_TILE_BYTES_MAX", 128 * max(d, f) * 4 if ef.f_minor(f)
+                        else 16 * d * 4)
+    y = ef._expert_ffn_impl(xs, comb, lp["we_up"], lp["we_down"], ids, count,
+                            lp.get("we_gate"), interpret=True)
+    want = moe._experts_dense(xs, comb, dense_lp, spec)
+    assert y.dtype == jnp.float32 and bool(jnp.isfinite(y).all())
+    _close(y, want, 1e-4)
+    assert float(jnp.abs(y[jnp.asarray([2, 9])]).max()) == 0.0
+    assert (float(jnp.abs(y).max()) > 1e-3) == bool(chosen)
+
+
+@pytest.mark.parametrize("n,row_bytes,want", [
+    (7680, 2048 * 2, 640),  # the latent widths: [640, 2048] row tiles of up and gate
+    (2048, 7680 * 2, 128),  # and [128, 7680] tiles of down
+    (256, 1 << 30, 128),    # no tile fits: one lane tile
+    (256, 4, 256),          # everything fits: the whole axis
+])
+def test_lane_tile_divides_the_axis_in_lane_multiples(n, row_bytes, want):
+    from dynamo_tpu.ops.pallas.expert_ffn import lane_tile
+
+    assert lane_tile(n, row_bytes) == want and n % want == 0
+
+
 # -- (d) the share test -------------------------------------------------------------
 
 
@@ -310,7 +382,8 @@ def _share_setup(model="nemotron_h"):
 
 
 @pytest.mark.parametrize("model,form", [("nemotron_h", "xla"), ("nemotron_h", "hit_list"),
-                                        ("pangu_ultra_moe", "xla")])
+                                        ("pangu_ultra_moe", "xla"),
+                                        ("pangu_ultra_moe", "hit_list")])
 def test_shares_add_up_to_the_uncut_layer(model, form, hit_list_calls):
     """Each share returns its own experts' part plus the shared expert, so
     the shared expert is in the sum once a share: counted once, the parts are
@@ -589,10 +662,15 @@ FORM_CASES = {
     "no_kernels_here": ((False, 64, 128, 48, 4, "relu2", False), "xla dense, no Pallas"),
     "a_prefill_chunk": ((True, 512, 128, 48, 4, "relu2", False), "xla grouped, 512 tokens"),
     "quantized_matrices": ((True, 64, 128, 48, 4, "relu2", True), "xla dense, quantized"),
-    "gated_experts": ((True, 64, 128, 48, 4, "silu_gated", False), "xla dense, activation"),
+    "gated_experts": ((True, 64, 128, 48, 4, "silu_gated", False), "pallas hit list"),
+    "unknown_activation": ((True, 64, 128, 48, 4, "gelu", False), "xla dense, activation gelu"),
+    "latent_cell_widths": ((True, 32, 7680, 2048, 16, "silu_gated", False), "pallas hit list"),
+    "latent_cell_document_chunk": ((True, 256, 7680, 2048, 16, "silu_gated", False),
+                                   "pallas hit list"),
+    "hybrid_cell_widths": ((True, 64, 2688, 1856, 64, "relu2", False), "pallas hit list"),
     "no_expert_held": ((True, 64, 128, 48, 0, "relu2", False), "xla dense, no expert held"),
     "narrow_model_width": ((True, 64, 64, 48, 4, "relu2", False), "xla dense, widths d 64"),
-    "expert_width_fills_lanes": ((True, 64, 128, 256, 4, "relu2", False), "xla dense, widths"),
+    "expert_width_fills_lanes": ((True, 64, 128, 256, 4, "relu2", False), "pallas hit list"),
 }
 
 
@@ -608,6 +686,32 @@ def test_expert_form_follows_what_moe_ffn_is_given(case):
     assert form.startswith(want)
     assert (moe.hit_list_reason(use_kernel, T, lp, spec) is None) == (
         form == "pallas hit list")
+
+
+@pytest.mark.parametrize("preset,tokens,want", [
+    ("nemotron-3-nano-30b-a3b-ep2", 64, None),
+    ("nemotron-3-nano-30b-a3b-ep2", 256, None),
+    ("nemotron-3-nano-30b-a3b-ep2", 512, "512 tokens a step is over 256"),
+    ("openpangu-ultra-moe-718b-ep16", 32, None),
+    ("openpangu-ultra-moe-718b-ep16", 256, None),
+    ("openpangu-ultra-moe-718b-ep16", 2048, "2048 tokens a step is over 256"),
+])
+def test_both_served_expert_configurations_take_the_kernel(preset, tokens, want):
+    """``hit_list_reason`` at the two cells' published widths, from the
+    spec and the matrices' shapes alone: the hybrid cell's relu2 experts
+    (d-minor ``we_up``) and the latent cell's gated-silu ones (f-minor, three
+    matrices) go through the kernel up to 256 tokens a step."""
+    from dynamo_tpu.models.config import (
+        nemotron3_nano_ep2_config, openpangu_ultra_moe_ep16_config,
+    )
+
+    config = {"nemotron-3-nano-30b-a3b-ep2": nemotron3_nano_ep2_config,
+              "openpangu-ultra-moe-718b-ep16": openpangu_ultra_moe_ep16_config}[preset]()
+    spec = next(s for s in config.layer_specs if isinstance(s, ExpertsSpec))
+    lo, hi = spec.held_
+    lp = {"we_up": jax.ShapeDtypeStruct((hi - lo, config.d_model, spec.d_ff), jnp.bfloat16)}
+    assert moe.hit_list_reason(True, tokens, lp, spec) == want
+    assert moe.hit_list_reason(False, tokens, lp, spec).startswith("no Pallas kernels")
 
 
 @pytest.mark.parametrize("model", ["tiny-hybrid", "tiny-moe", "tiny"])

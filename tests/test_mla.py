@@ -55,10 +55,18 @@ def _close(got, want, tol=2e-5):
 
 @pytest.fixture
 def interpreted_kernel(monkeypatch):
-    """``use_kernel`` on the CPU: the Pallas kernel in interpret mode."""
+    """``use_kernel`` on the CPU: the Pallas kernels (the absorbed attention
+    and, since its experts are gated silu at a model width of 128, the
+    hit-list expert kernel) in interpret mode."""
+    from dynamo_tpu.ops import moe
+    from dynamo_tpu.ops.pallas import expert_ffn
+
     monkeypatch.setattr(
         attention, "mla_paged_decode",
         functools.partial(mla_paged._mla_paged_decode_impl, interpret=True))
+    monkeypatch.setattr(
+        moe, "expert_ffn",
+        functools.partial(expert_ffn._expert_ffn_impl, interpret=True))
 
 
 # -- (a) the system against the reference ---------------------------------------

@@ -116,18 +116,30 @@ def test_chunk_kernel_compiles_for_v5e_at_head_64(one_chip, page):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("tokens", [64, 128, 256])
-def test_expert_kernel_compiles_for_v5e_at_the_served_widths(one_chip, tokens):
-    """ops/pallas/expert_ffn.py at the hybrid configuration's widths (d 2688,
-    f 1856: 14.5 lane tiles, 64 held, bf16) for a decode burst's 64 slots
-    and the two small prefill shapes: Mosaic takes the [464, 2688] tiles of
-    both matrices, and XLA hands ``we_up`` over as it is resident (d minor:
-    the kernel's transpose is a bitcast), so the program holds no copy of a
-    matrix stack and no temporary the size of one."""
+EXPERT_WIDTHS = {
+    # name: (d, f, held, gated). Hybrid: f is 14.5 lane tiles, so XLA hands
+    # ``we_up`` over as it is resident, d minor (the kernel's transpose is
+    # a bitcast) and Mosaic takes [464, 2688] tiles of both matrices.
+    # Latent: f fills the lanes, three matrices f minor as written, [640,
+    # 2048] row tiles of up and gate, [128, 7680] tiles of down.
+    "hybrid": (2688, 1856, 64, False),
+    "latent": (7680, 2048, 16, True),
+}
+
+
+@pytest.mark.parametrize("widths,tokens", [
+    ("hybrid", 64), ("hybrid", 128), ("hybrid", 256),
+    ("latent", 32), ("latent", 128), ("latent", 256),
+])
+def test_expert_kernel_compiles_for_v5e_at_the_served_widths(one_chip, widths, tokens):
+    """ops/pallas/expert_ffn.py at the two served configurations' widths,
+    bf16, for a decode burst's slots and the small prefill shapes: the
+    program holds no copy of a matrix stack and no temporary the size of
+    one."""
     from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
     from dynamo_tpu.ops.pallas.expert_ffn import _expert_ffn_impl
 
-    d, f, held = 2688, 1856, 64
+    d, f, held, gated = EXPERT_WIDTHS[widths]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -135,12 +147,43 @@ def test_expert_kernel_compiles_for_v5e_at_the_served_widths(one_chip, tokens):
     up, down = sds((held, d, f), jnp.bfloat16), sds((held, f, d), jnp.bfloat16)
     compiled = jax.jit(_expert_ffn_impl).lower(
         sds((tokens, d), jnp.bfloat16), sds((tokens, held), jnp.float32), up, down,
-        sds((held + 1,), jnp.int32), sds((1,), jnp.int32),
+        sds((held + 1,), jnp.int32), sds((1,), jnp.int32), *([up] if gated else []),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert whole_pool_copies(text, up) == whole_pool_copies(text, down) == 0
-    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+    # (the f-minor form hands the tokens over as tiles of the model width)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20) + tokens * d * 2
+
+
+# sha256 (first 16 hex digits) of the d-minor kernel's jaxpr at the hybrid
+# cell's widths, by tokens a step: recorded at PR 42's tree (the kernel as PR
+# 37 measured it). The optimised HLO of that cell's served programs differed
+# between that tree and PR 43's only in the debug locations inside the
+# serialized kernel body; this pins the body itself (grid, index maps, block
+# shapes, compiler parameters, every operation in order). A deliberate change
+# to the d-minor form records new digests AND measures the hybrid cell.
+HYBRID_KERNEL_JAXPR = {64: "de2a61bcb891a99f", 128: "2055237fd83f27dd",
+                       256: "7c33da4c7d189412"}
+
+
+@pytest.mark.parametrize("tokens", sorted(HYBRID_KERNEL_JAXPR))
+def test_hybrid_expert_kernel_is_the_program_it_was(tokens):
+    import hashlib
+    import re
+
+    from dynamo_tpu.ops.pallas.expert_ffn import _expert_ffn_impl
+
+    d, f, held = 2688, 1856, 64
+    sds = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(_expert_ffn_impl)(
+        sds((tokens, d), jnp.bfloat16), sds((tokens, held), jnp.float32),
+        sds((held, d, f), jnp.bfloat16), sds((held, f, d), jnp.bfloat16),
+        sds((held + 1,), jnp.int32), sds((1,), jnp.int32),
+    ))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert "dynamo_tpu" not in text  # no path of a checkout in what is hashed
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == HYBRID_KERNEL_JAXPR[tokens]
 
 
 @pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
@@ -383,8 +426,9 @@ def _mla_program(one_chip, program, depth):
             arr((S, P), i32), *rows(S),
         )
     else:
-        fresh = program == "prefill_fresh"
-        B, C, width = (8, 256, 2) if fresh else (8, 256, P)
+        fresh = program.startswith("prefill_fresh")
+        B = 1 if program.endswith("_one_row") else 8
+        C, width = 256, (2 if fresh else P)
         state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
         lowered = DeviceRunner._build_step_fn_hybrid(runner, False, 0, fresh).lower(
             params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
@@ -393,20 +437,33 @@ def _mla_program(one_chip, program, depth):
     return lowered.compile(), (params, k)
 
 
-@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+@pytest.mark.parametrize("program", [
+    "decode_burst", "prefill_fresh", "prefill_tail",
+    "prefill_fresh_one_row", "prefill_tail_one_row",
+])
 def test_mla_served_programs_compile_for_the_chip(one_chip, program):
     """The decode burst, the fresh prefill step and a batch of question
     chunks over a full table, of the openPangu configuration at its published
     widths (the dense layer and one expert layer), compiled for the v5e as
     the runner builds them: the absorbed kernel lowers in the burst and the
     chunk-with-context step, the fresh step holds none (expanded form), the
-    latent pools alias in and out, and no program copies a whole pool."""
+    latent pools alias in and out, and no program copies a whole pool. The
+    burst's 32 slots and a one-row step's 256 tokens (a document's chunk, a
+    question over it) go through the hit-list expert kernel, its three
+    matrices read as they are resident: no copy of an expert stack; eight
+    rows of 256 go through the grouped form."""
     from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
     compiled, (params, k) = _mla_program(one_chip, program, depth=1)
     text = compiled.as_text()
-    assert ("mla_paged_decode" in text) == (program != "prefill_fresh")
+    assert ("mla_paged_decode" in text) == (not program.startswith("prefill_fresh"))
     assert (" while(" in text) == (program == "decode_burst")
     assert whole_pool_copies(text, k[0]) == 0
     resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k)
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
+    hit_listed = program == "decode_burst" or program.endswith("_one_row")
+    assert ("expert_ffn_hit_list" in text) == hit_listed
+    if hit_listed:
+        experts = params["layers"][3]
+        assert [whole_pool_copies(text, experts[m])
+                for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]
