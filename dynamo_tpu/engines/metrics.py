@@ -214,6 +214,36 @@ class EngineStepMetrics:
             mn.ENGINE_SSM_SNAPSHOT_EVICTIONS_TOTAL,
             "Snapshots evicted to make room (least recently used first)",
         )
+        # Two page groups (metric_names.py says what each counts); never
+        # touched by a model with one.
+        self.kv_group_blocks = self.registry.gauge(
+            mn.ENGINE_KV_GROUP_BLOCKS,
+            "Blocks of each page group of a model with two", ["group", "state"],
+        )
+        self.window_pages_released = self.registry.counter(
+            mn.ENGINE_WINDOW_PAGES_RELEASED_TOTAL,
+            "Window-group pages given back behind a running sequence's window",
+        )
+        self.window_pages_dead = self.registry.counter(
+            mn.ENGINE_WINDOW_PAGES_DEAD_TOTAL,
+            "Window-group pages live rows hold wholly behind their window, "
+            "summed over dispatched decode bursts",
+        )
+        self.window_pages_held = self.registry.counter(
+            mn.ENGINE_WINDOW_PAGES_HELD_TOTAL,
+            "Window-group pages live rows hold, summed over dispatched "
+            "decode bursts",
+        )
+        self.decode_window_live_pages = self.registry.counter(
+            mn.ENGINE_DECODE_WINDOW_LIVE_PAGES_TOTAL,
+            "Window-group pages the active rows attend over, summed over "
+            "dispatched decode bursts",
+        )
+        self.prefix_hits_cut_by_window = self.registry.counter(
+            mn.ENGINE_PREFIX_HITS_CUT_BY_WINDOW_TOTAL,
+            "Prefix hits shortened because the window group no longer held "
+            "the window in front of the resume position",
+        )
         # phase name -> is it a device wait (the vocabulary and its one
         # class the counts below ask about, in one lookup)
         self._phases = {
@@ -383,6 +413,19 @@ class EngineStepMetrics:
         self.moe_expert_slots.inc(slots)
         self.moe_max_expert_tokens.inc(most)
         self.moe_mean_expert_tokens.inc(mean)
+
+    def observe_kv_groups(self, groups: Dict[str, Dict[str, int]],
+                          released: int, cut: int) -> None:
+        for group, states in groups.items():
+            for state, n in states.items():
+                self.kv_group_blocks.set(n, group=group, state=state)
+        self.window_pages_released.set_total(released)
+        self.prefix_hits_cut_by_window.set_total(cut)
+
+    def observe_window_pages(self, live: int, held: int, dead: int) -> None:
+        self.decode_window_live_pages.inc(live)
+        self.window_pages_held.inc(held)
+        self.window_pages_dead.inc(dead)
 
     def observe_ssm(self, slots_used: int, slots_total: int,
                     snaps_used: int, snaps_total: int) -> None:

@@ -209,16 +209,14 @@ class Admitter:
             done = await self._prefill_rounds(pending)
         except asyncio.CancelledError:
             self._forget_snapshots(pending)
-            for seq, prep in pending.batch:
-                e.pool.release(prep.ids, prep.hashes[: prep.matched])
+            for seq, _ in pending.batch:
+                e._release_blocks(seq)
                 e._requeue(seq)
             raise
         except Exception as exc:
             self._forget_snapshots(pending)
-            for seq, prep in pending.batch:
-                e.pool.release(prep.ids, prep.hashes[: prep.matched])
-                seq.block_ids = []
-                seq.block_hashes = []
+            for seq, _ in pending.batch:
+                e._release_blocks(seq)
             e._contain_admission_failure([s for s, _ in pending.batch], exc)
             return 0
         if not done:
@@ -372,6 +370,14 @@ class Admitter:
                 )
                 e.pool.release(ids[keep:], hashes[keep:matched])
                 matched, ids = keep, ids[:keep]
+            if e.window is not None and matched:
+                # A prefix is a hit only as far as the full group holds the
+                # blocks AND the window group still holds the window in
+                # front of the resume position; otherwise the match is cut
+                # back to where both hold (or to nothing).
+                keep = e.window.cut_match(hashes, matched, len(prompt))
+                e.pool.release(ids[keep:], hashes[keep:matched])
+                matched, ids = keep, ids[:keep]
             if pf is not None:
                 # Claim AFTER our own pin: the lease's pins release with
                 # the blocks already re-held, so their refcounts never dip
@@ -387,7 +393,13 @@ class Admitter:
             else 0
         )
         need = n_blocks_prompt - len(ids) + 1 + headroom
-        if need > e.pool.free_blocks:
+        # One decision over both page groups: the window group must have a
+        # row's most pages to give (it is sized so that it has).
+        win_short = e.window is not None and e.window.pool.free_blocks < min(
+            e.window.row_bound(e.window.window, args.block_size, args.prefill_chunk),
+            n_blocks_prompt + 1,
+        )
+        if need > e.pool.free_blocks or win_short:
             e.pool.release(ids, hashes[:matched])
             e._requeue(seq)
             return None
@@ -400,6 +412,10 @@ class Admitter:
             ids.append(b)
         seq.block_ids = ids
         seq.block_hashes = hashes[:matched]
+        if e.window is not None:
+            seq.win_ids = e.window.pin_tail(hashes, matched, len(prompt))
+            seq.win_pinned = matched
+            seq.win_keep = e.window.prompt_tail(len(prompt))
         return _prep_cls()(
             ids=ids,
             hashes=hashes,
@@ -475,7 +491,7 @@ class Admitter:
         return await e._device(
             step or e._run_step,
             np.zeros((Bp, c), dtype=np.int32), zeros, zeros,
-            np.zeros((Bp, nb), dtype=np.int32),
+            np.zeros(e.tables_shape(Bp, nb), dtype=np.int32),
             np.ones(Bp, dtype=np.float32), zeros,
             np.ones(Bp, dtype=np.float32), zeros,
             None, None, None, want_top, first_chunk, zeros, *hybrid,
@@ -605,14 +621,15 @@ class Admitter:
             args,
         )
         Bp = _next_pow2(rows)
-        tables = np.zeros((Bp, nb_bucket), dtype=np.int32)
+        tables = np.zeros(e.tables_shape(Bp, nb_bucket), dtype=np.int32)
+        full_tables = tables if e.window is None else tables[:, 0]
         temp = np.ones(Bp, dtype=np.float32)
         topk = np.zeros(Bp, dtype=np.int32)
         topp = np.ones(Bp, dtype=np.float32)
         adapter = np.zeros(Bp, dtype=np.int32)
         salts = np.zeros(Bp, dtype=np.int32)
         for r, (seq_r, prep) in enumerate(batch):
-            tables[r, : len(prep.ids)] = prep.ids
+            full_tables[r, : len(prep.ids)] = prep.ids
             temp[r], topk[r], topp[r] = prep.sp
             adapter[r] = prep.adapter_id
             salts[r] = seq_r.salt
@@ -698,6 +715,18 @@ class Admitter:
                     tok_arr[r, : len(ch)] = ch
                     start[r] = pos[r]
                     lens[r] = len(ch)
+                    if e.window is not None and ch:
+                        # The window group turns over as the prefill runs:
+                        # pages behind this round's first query go back,
+                        # this chunk's are taken.
+                        seq_r = pending.batch[r][0]
+                        if e._window_advance(seq_r, pos[r], pos[r] + len(ch) - 1) is None:
+                            raise RuntimeError(
+                                "window page group exhausted in a prefill round"
+                            )
+                        tables[r, 1, :] = 0
+                        held = np.maximum(seq_r.win_ids[: tables.shape[2]], 0)
+                        tables[r, 1, : len(held)] = held
                 mm_chunk = None
                 if mm_slot_of is not None:
                     mm_chunk = np.full((Bp, c_bucket), -1, dtype=np.int32)
@@ -708,7 +737,7 @@ class Admitter:
                 first_chunk = bool(np.all(start[:rows] == 0))
                 if not first_chunk and procs is None and mm_embeds is None:
                     self._note_prefix_hit_round(
-                        Bp, c_bucket, tables.shape[1], want_top
+                        Bp, c_bucket, tables.shape[-1], want_top
                     )
                 hybrid_args = ()
                 if hybrid:
@@ -719,7 +748,7 @@ class Admitter:
                 t0 = time.monotonic()
                 with phase(
                     "tick.prefill_wait", rows=rows, chunk=c_bucket,
-                    nb=tables.shape[1], tokens=int(lens.sum()),
+                    nb=tables.shape[-1], tokens=int(lens.sum()),
                 ):
                     toks, logps, topv, topi, *state = await e._device(
                         e._run_step,
@@ -793,6 +822,8 @@ class Admitter:
             for i in range(prep.matched, full):
                 parent = prep.hashes[i - 1] if i else None
                 e.pool.commit(prep.ids[i], prep.hashes[i], parent)
+                if e.window is not None:
+                    e.window.commit(seq.win_ids, i, prep.hashes[i], parent)
                 seq.block_hashes.append(prep.hashes[i])
                 if e.kvbm is not None:
                     e.kvbm.notify_commit(prep.hashes[i], i + 1, parent=parent)

@@ -20,6 +20,16 @@ a state-space layer without the state at its end, so a prefix is a hit only
 as far as K/V blocks AND a snapshot both reach, and a pool built with
 ``announce_commits=False`` tells the router of a committed block only once a
 snapshot covers it (``announce``).
+
+Two page groups for one sequence (a model that mixes sliding-window and full
+attention layers, ``ModelConfig.cache_groups``): the full layers' pages are
+this ``BlockPool``, the sliding layers' a second one inside ``WindowPages``
+with an id space and a pool shape of its own. A sequence's window-group
+pages behind its window are given back while it runs and are at once
+another row's to take; what stays cached of a finished chain is its trailing
+window. A prefix is then a hit only as far as the full group holds the
+blocks AND the window group still holds the window in front of the resume
+position (``WindowPages.cut_match``). KV events describe the full group.
 """
 
 from __future__ import annotations
@@ -189,6 +199,22 @@ class BlockPool:
             entry.announced = False
             self._emit(KvEvent(kind="removed", block_hashes=[block_hash]))
 
+    def discard(self, block_id: int, block_hash: Optional[int]) -> None:
+        """A holder gives ``block_id`` up for good (a window-group page that
+        fell behind its sequence's window): freed at once, and forgotten as a
+        cached block, unless another sequence holds it too."""
+        entry = None if block_hash is None else self._by_hash.get(block_hash)
+        if entry is None or entry.block_id != block_id:
+            self._free.append(block_id)
+            return
+        entry.ref_count -= 1
+        if entry.ref_count <= 0:
+            del self._by_hash[block_hash]
+            self._lru.pop(block_hash, None)
+            if entry.announced:
+                self._emit(KvEvent(kind="removed", block_hashes=[block_hash]))
+            self._free.append(block_id)
+
     def release(self, block_ids: Sequence[int], block_hashes: Sequence[int]) -> None:
         """Sequence done. `block_hashes[i]` pairs with `block_ids[i]` for the
         committed prefix; remaining ids are private/partial blocks → freed."""
@@ -222,6 +248,147 @@ class BlockPool:
     def _emit(self, event: KvEvent) -> None:
         if self._on_event is not None:
             self._on_event(event)
+
+
+class WindowPages:
+    """The window page group: a pool of its own for the sliding-window
+    layers, and the rules by which a sequence holds only the pages its
+    window (and the chunk or look-ahead in front of it) can read.
+
+    A sequence's pages are a list indexed by LOGICAL block, -1 where it
+    holds none (behind the window: released, or never taken by a prefix
+    hit). Which pages stay in the cache when they fall behind the window of
+    a running sequence: those it pinned FROM the cache (a chain's trailing
+    window, shared) and its own prompt's trailing window (``keep``: where
+    the next request that shares this prompt resumes); every other page of
+    its own is freed for good (``BlockPool.discard``), so that a long
+    prefill does not push the chains' tails out of the cache. What a
+    sequence still holds when it ends goes to the cache as the full
+    group's blocks do: the chain's end is where its next turn resumes."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int) -> None:
+        self.pool = BlockPool(num_blocks, block_size)  # no events: see the module's head
+        self.block_size = block_size
+        self.window = window
+        self.released = 0  # pages given back behind a running sequence's window
+        self.cut_hits = 0  # prefix hits shortened (or lost) for want of a window tail
+
+    @staticmethod
+    def blocks_needed(
+        max_num_seqs: int, window: int, block_size: int, prefill_chunk: int,
+        lookahead: int,
+    ) -> int:
+        """The group's size: every row its most pages (``row_bound``) and as
+        many cached chains' trailing windows again."""
+        row = WindowPages.row_bound(window, block_size, max(prefill_chunk, lookahead))
+        tail = (window - 1) // block_size + 2
+        return max_num_seqs * (row + tail)
+
+    @staticmethod
+    def row_bound(window: int, block_size: int, ahead: int) -> int:
+        """Most pages one sequence holds: the window behind its next query,
+        the ``ahead`` tokens (a prefill chunk, the decode look-ahead) in
+        front, and the page both may straddle."""
+        return (window + ahead - 2) // block_size + 2
+
+    def first_live(self, pos: int) -> int:
+        """Logical block of the first key a query at ``pos`` sees."""
+        return max(pos - self.window + 1, 0) // self.block_size
+
+    def _resume_pos(self, blocks: int, n_tokens: int) -> int:
+        return min(blocks * self.block_size, n_tokens - 1)
+
+    def cut_match(self, hashes: Sequence[int], matched: int, n_tokens: int) -> int:
+        """The longest prefix of at most ``matched`` blocks (what the full
+        group holds) at whose end this group still holds the window: blocks
+        [first_live(resume position), m) all cached here."""
+        run, best = 0, 0
+        runs = []
+        for i in range(matched):
+            run = run + 1 if self.pool.contains(hashes[i]) else 0
+            runs.append(run)
+        for m in range(matched, 0, -1):
+            if runs[m - 1] >= m - self.first_live(self._resume_pos(m, n_tokens)):
+                best = m
+                break
+        if best < matched:
+            self.cut_hits += 1
+        return best
+
+    def pin_tail(self, hashes: Sequence[int], matched: int, n_tokens: int) -> List[int]:
+        """Pin the window in front of the resume position of a ``matched``
+        block prefix (``cut_match`` said it is here). Returns the sequence's
+        page list: -1 up to the tail, then the pinned ids."""
+        lo = self.first_live(self._resume_pos(matched, n_tokens)) if matched else 0
+        got, ids = self.pool.pin_prefix(list(hashes[lo:matched]))
+        assert got == matched - lo, "cut_match said the window tail is cached"
+        return [-1] * lo + ids
+
+    def prompt_tail(self, n_tokens: int) -> Tuple[int, int]:
+        """Logical blocks [lo, hi) of a prompt's trailing window: the whole
+        blocks a request resuming at the prompt's end would need."""
+        hi = n_tokens // self.block_size
+        return min(self.first_live(hi * self.block_size), hi), hi
+
+    def advance(
+        self, pages: List[int], hashes: Sequence[int], pinned: int,
+        keep: Tuple[int, int], pos: int, upto: int,
+    ) -> Optional[List[int]]:
+        """Before a step whose first query is at ``pos`` and whose last
+        written position is ``upto``: give back the pages wholly behind the
+        window (to the cache where pinned from it or in ``keep``, else for
+        good) and take pages up to ``upto``'s. Returns the logical blocks
+        newly taken, None where the pool is dry (nothing taken then)."""
+        # What a sequence holds is one run of slots up to the list's end, so
+        # the pages behind the window are found from the window backwards:
+        # the cost is what is released, not the context's length.
+        i = min(self.first_live(pos), len(pages)) - 1
+        while i >= 0 and pages[i] >= 0:
+            h = hashes[i] if i < len(hashes) else None
+            if i < pinned or keep[0] <= i < keep[1]:
+                self.pool.release([pages[i]], [] if h is None else [h])
+            else:
+                self.pool.discard(pages[i], h)
+            pages[i] = -1
+            self.released += 1
+            i -= 1
+        need = upto // self.block_size + 1
+        if need - len(pages) > self.pool.free_blocks:
+            return None
+        taken = []
+        while len(pages) < need:
+            taken.append(len(pages))
+            pages.append(self.pool.alloc())
+        return taken
+
+    def commit(self, pages: Sequence[int], index: int, block_hash: int,
+               parent: Optional[int]) -> None:
+        if index < len(pages) and pages[index] >= 0:
+            self.pool.commit(pages[index], block_hash, parent)
+
+    def release_all(self, pages: List[int], hashes: Sequence[int]) -> None:
+        """Sequence done (finished, preempted, aborted): committed pages go
+        to the cache, where the chain's trailing window can be hit; the
+        rest are freed."""
+        for i, b in enumerate(pages):
+            if b >= 0:
+                self.pool.release([b], [hashes[i]] if i < len(hashes) else [])
+        del pages[:]
+
+    @staticmethod
+    def _run_back(pages: Sequence[int], end: int) -> int:
+        """Held slots in the run that ends just before ``end``."""
+        i = end
+        while i > 0 and pages[i - 1] >= 0:
+            i -= 1
+        return end - i
+
+    def held(self, pages: Sequence[int]) -> int:
+        return self._run_back(pages, len(pages))
+
+    def dead(self, pages: Sequence[int], pos: int) -> int:
+        """Pages held wholly behind the window of a query at ``pos``."""
+        return self._run_back(pages, min(self.first_live(pos), len(pages)))
 
 
 # Entries of the recurrent-state snapshot store of a hybrid model (one entry =

@@ -36,7 +36,7 @@ import numpy as np
 from dynamo_tpu.engines.mock.kv_manager import KvEvent
 from dynamo_tpu.disagg.wire import check_config as wire_check_config
 from dynamo_tpu.engines.tpu import block_pool
-from dynamo_tpu.engines.tpu.block_pool import BlockPool, StateSnapshots
+from dynamo_tpu.engines.tpu.block_pool import BlockPool, StateSnapshots, WindowPages
 from dynamo_tpu.engines.tpu.runner import DeviceRunner, _next_pow2
 from dynamo_tpu.engines.tpu.tick_budget import (
     BUDGET_STATE_OFF,
@@ -175,6 +175,20 @@ class JaxEngineArgs:
     def max_blocks_per_seq(self) -> int:
         return math.ceil(self.max_model_len / self.block_size)
 
+    @property
+    def num_window_blocks(self) -> int:
+        """Size of the window page group (a model that mixes sliding-window
+        and full attention layers; 0 without one), derived: every row's most
+        pages (window, prefill chunk, look-ahead) and as many cached chains'
+        trailing windows again. ``num_kv_blocks`` sizes the full group."""
+        win = self.config.window_group
+        if win is None:
+            return 0
+        return WindowPages.blocks_needed(
+            self.max_num_seqs, win.window, self.block_size, self.prefill_chunk,
+            self.decode_steps * PIPELINE_LOOKAHEAD_BURSTS,
+        )
+
 
 @dataclass
 class _Sequence:
@@ -186,6 +200,11 @@ class _Sequence:
     generated: List[int] = field(default_factory=list)
     block_ids: List[int] = field(default_factory=list)
     block_hashes: List[int] = field(default_factory=list)  # committed prefix
+    # Window page group (block_pool.WindowPages): pages by logical block, -1
+    # where none is held; blocks below win_pinned came from the cache.
+    win_ids: List[int] = field(default_factory=list)
+    win_pinned: int = 0
+    win_keep: Tuple[int, int] = (0, 0)  # the prompt's trailing window
     slot: int = -1
     next_token: int = 0  # decode input token
     logprob_pending: Optional[float] = None
@@ -319,6 +338,21 @@ class JaxEngine:
             args.num_kv_blocks, args.block_size, on_event=on_kv_event,
             announce_commits=not recurrent,
         )
+        # A second page group for the sliding-window layers of a model that
+        # also has full ones: its own pool and id space, one admission
+        # decision over both, pages behind a row's window given back as it
+        # advances (block_pool.WindowPages).
+        self.window: Optional[WindowPages] = None
+        win_group = self.config.window_group
+        if win_group is not None:
+            if recurrent:
+                raise ValueError(
+                    f"{self.config.name}: a window page group beside recurrent "
+                    "state is not implemented"
+                )
+            self.window = WindowPages(
+                args.num_window_blocks, args.block_size, win_group.window
+            )
         self.snapshots: Optional[StateSnapshots] = None
         self._ssm_stride = 0  # tokens between snapshot boundaries
         if recurrent:
@@ -405,7 +439,7 @@ class JaxEngine:
         self._slots: List[Optional[_Sequence]] = [None] * S
         self._pos = np.zeros(S, dtype=np.int32)  # tokens resident in cache
         self._block_tables = np.zeros(
-            (S, args.max_blocks_per_seq), dtype=np.int32
+            self.tables_shape(S, args.max_blocks_per_seq), dtype=np.int32
         )
         self._temp = np.ones(S, dtype=np.float32)
         self._topk = np.zeros(S, dtype=np.int32)
@@ -688,7 +722,8 @@ class JaxEngine:
         out = {
             "active_seqs": sum(1 for s in self._slots if s is not None),
             "waiting": len(self._waiting),
-            "kv_usage": self.pool.usage,
+            "kv_usage": self.pool.usage if self.window is None else max(
+                self.pool.usage, self.window.pool.usage),
             "free_blocks": self.pool.free_blocks,
             "cached_blocks": self.pool.cached_blocks,
             "total_blocks": self.args.num_kv_blocks,
@@ -748,6 +783,19 @@ class JaxEngine:
             "prefill_family_programs": self._admitter.family_programs,
             "prefill_family_pending": len(self._admitter.family_pending),
         }
+        if self.window is not None:
+            out["kv_groups"] = {
+                name: {
+                    "used": pool.active_blocks, "cached": pool.cached_blocks,
+                    "total": pool.num_blocks,
+                }
+                for name, pool in (("full", self.pool), ("window", self.window.pool))
+            }
+            out["window_pages_released"] = self.window.released
+            out["prefix_hits_cut_by_window"] = self.window.cut_hits
+            self.step_metrics.observe_kv_groups(
+                out["kv_groups"], self.window.released, self.window.cut_hits
+            )
         if self.config.has_latent_cache:
             out["latent_pool"] = dict(self.runner.kv_pool)
             out["mla_attention"] = self.runner.mla_attention
@@ -787,11 +835,39 @@ class JaxEngine:
         per_block = total // max(self.args.num_kv_blocks, 1)
         return self.pool.bytes_breakdown(per_block)
 
+    def tables_shape(self, rows: int, width: int) -> Tuple[int, ...]:
+        """Shape of a block-table array of ``rows``: [rows, width], and with
+        a window page group [rows, 2, width]: the full group's table, then
+        the window group's (logically indexed, 0 where no page is held)."""
+        return self.config.tables_shape(rows, width)
+
+    def _release_blocks(self, seq: _Sequence) -> None:
+        """Give back everything ``seq`` holds, in every page group (a
+        sequence still in prefill holds ``prep.ids`` under the hashes it
+        matched: ``_prepare_admission`` left both on it)."""
+        self.pool.release(seq.block_ids, seq.block_hashes)
+        if self.window is not None:
+            self.window.release_all(seq.win_ids, seq.block_hashes)
+            seq.win_pinned = 0
+        seq.block_ids = []
+        seq.block_hashes = []
+
+    def _window_advance(self, seq: _Sequence, pos: int, upto: int) -> Optional[List[int]]:
+        """Window group, before a step of ``seq`` whose first query is at
+        ``pos`` and that writes up to ``upto``: pages behind the window go
+        back, pages up to ``upto`` are taken. The logical blocks taken, or
+        None where the group is dry."""
+        return self.window.advance(
+            seq.win_ids, seq.block_hashes, seq.win_pinned, seq.win_keep, pos, upto
+        )
+
     def clear_kv_blocks(self) -> int:
         """Flush the reusable prefix cache (ref: clear_kv_blocks.rs route).
         In-flight sequences keep their pinned blocks."""
         n = self.pool.cached_blocks
         self.pool.clear()
+        if self.window is not None:
+            self.window.pool.clear()
         if self.snapshots is not None:
             self.snapshots.clear()
         return n
@@ -1209,7 +1285,7 @@ class JaxEngine:
                 )
         while self._adoptions:
             seq = self._adoptions.popleft()
-            self.pool.release(seq.block_ids, seq.block_hashes)
+            self._release_blocks(seq)
             seq.queue.put_nowait(BackendOutput(error=err, finish_reason=reason))
         self._publish_stats()
 
@@ -1327,8 +1403,8 @@ class JaxEngine:
         if pending is None:
             return
         self._pending_prefill = None
-        for seq, prep in reversed(pending.batch):
-            self.pool.release(prep.ids, prep.hashes[: prep.matched])
+        for seq, _ in reversed(pending.batch):
+            self._release_blocks(seq)
             self._requeue(seq)
         self.flight.record("prefill_unpark", rows=len(pending.batch))
 
@@ -1378,6 +1454,8 @@ class JaxEngine:
     def _requeue(self, seq: _Sequence) -> None:
         seq.block_ids = []
         seq.block_hashes = []
+        seq.win_ids = []
+        seq.win_pinned = 0
         self._waiting.appendleft(seq)
 
     def set_spec_suspended(self, suspended: bool) -> None:
@@ -1488,14 +1566,24 @@ class JaxEngine:
                 pos + lookahead - 1, args.max_blocks_per_seq * args.block_size - 1
             )
             need_blocks = last_pos // args.block_size + 1
+            full_table = self._block_tables[slot] if self.window is None else (
+                self._block_tables[slot, 0])
             while len(seq.block_ids) < need_blocks:
                 b = self.pool.alloc()
                 if b is None:
                     self._preempt(seq)
                     break
-                self._block_tables[slot, len(seq.block_ids)] = b
+                full_table[len(seq.block_ids)] = b
                 seq.block_ids.append(b)
                 self._dirty_tables.add(slot)
+            if self.window is not None and seq.slot >= 0:
+                taken = self._window_advance(seq, pos, last_pos)
+                if taken is None:
+                    self._preempt(seq, "window page group exhausted")
+                    continue
+                for i in taken:
+                    self._block_tables[slot, 1, i] = seq.win_ids[i]
+                    self._dirty_tables.add(slot)
         return [s for s in self._slots if s is not None]
 
     # -- speculative decoding (prompt-lookup / n-gram) ---------------------
@@ -1561,6 +1649,9 @@ class JaxEngine:
                 args.max_blocks_per_seq * args.block_size - 1,
             )
             need += max(0, last_pos // args.block_size + 1 - len(seq.block_ids))
+        # (The window group cannot fall short: it is sized for every row's
+        # most pages, and what a row holds behind its window goes back
+        # before it takes more.)
         return need - self.pool.free_blocks
 
     async def _dispatch_burst(self) -> bool:
@@ -1592,11 +1683,17 @@ class JaxEngine:
             inflight_off = K * len(self._inflight)
             max_blocks = 1
             live_pages = 0
+            win = self.window
+            win_live = win_held = win_dead = 0
             for seq in active:
                 ctx = int(self._pos[seq.slot]) + inflight_off + K
                 blocks = (ctx - 1) // args.block_size + 1
                 live_pages += blocks
                 max_blocks = max(max_blocks, blocks)
+                if win is not None:
+                    win_live += blocks - win.first_live(ctx - K)
+                    win_held += win.held(seq.win_ids)
+                    win_dead += win.dead(seq.win_ids, ctx - K)
             nb_bucket = table_width_bucket(max_blocks, args.max_blocks_per_seq)
             want_logprobs = any(
                 s.request.sampling.logprobs is not None for s in active
@@ -1620,6 +1717,8 @@ class JaxEngine:
         self.step_metrics.observe_decode_pages(
             live_pages, args.max_num_seqs * nb_bucket
         )
+        if win is not None:
+            self.step_metrics.observe_window_pages(win_live, win_held, win_dead)
         self._inflight.append(
             _InflightBurst(
                 handles=handles,
@@ -1922,6 +2021,8 @@ class JaxEngine:
                 salt=seq.hash_salt,
             )[0]
             self.pool.commit(seq.block_ids[bi], h, parent)
+            if self.window is not None:
+                self.window.commit(seq.win_ids, bi, h, parent)
             seq.block_hashes.append(h)
             if self.kvbm is not None:
                 self.kvbm.notify_commit(h, bi + 1, parent=parent)
@@ -1939,7 +2040,7 @@ class JaxEngine:
             "preempt", request_id=seq.request.request_id, slot=seq.slot,
             blocks=len(seq.block_ids),
         )
-        self.pool.release(seq.block_ids, seq.block_hashes)
+        self._release_blocks(seq)
         slot = seq.slot
         self._slots[slot] = None
         self._pos[slot] = 0
@@ -2278,9 +2379,7 @@ class JaxEngine:
     def release_detached(self, seq: _Sequence) -> None:
         """Free a detached sequence's pool blocks (after the peer accepted
         the handoff, or before failing it down the ladder)."""
-        self.pool.release(seq.block_ids, seq.block_hashes)
-        seq.block_ids = []
-        seq.block_hashes = []
+        self._release_blocks(seq)
 
     def fail_detached(self, seq: _Sequence, exc: BaseException) -> None:
         """Surface ``exc`` through the sequence's output stream (the
@@ -2400,8 +2499,12 @@ class JaxEngine:
         seq.slot = slot
         self._slots[slot] = seq
         self._pos[slot] = pos
-        self._block_tables[slot, :] = 0
-        self._block_tables[slot, : len(block_ids)] = block_ids
+        self._block_tables[slot] = 0
+        if self.window is None:
+            self._block_tables[slot, : len(block_ids)] = block_ids
+        else:
+            self._block_tables[slot, 0, : len(block_ids)] = block_ids
+            self._block_tables[slot, 1, : len(seq.win_ids)] = np.maximum(seq.win_ids, 0)
         self._temp[slot], self._topk[slot], self._topp[slot] = sp
         self._adapter_ids[slot] = adapter_id
         self._salts[slot] = seq.salt
@@ -2505,9 +2608,7 @@ class JaxEngine:
             "finish", request_id=seq.request.request_id, reason=reason.value,
             generated=len(seq.generated),
         )
-        self.pool.release(seq.block_ids, seq.block_hashes)
-        seq.block_ids = []
-        seq.block_hashes = []
+        self._release_blocks(seq)
         if seq.slot >= 0:
             self._slots[seq.slot] = None
             self._pos[seq.slot] = 0

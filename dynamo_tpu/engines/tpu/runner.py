@@ -447,7 +447,7 @@ class DeviceRunner:
             k: self._dev_persistent(v) for k, v in state0.items()
         }
         self.slot_tables = self._dev_persistent(
-            np.zeros((S, args.max_blocks_per_seq), np.int32)
+            np.zeros(self.config.tables_shape(S, args.max_blocks_per_seq), np.int32)
         )
         # H2D accounting for the hot path: every slot-state upload and
         # decode dispatch appends ("slot_sync"|"table_sync", rows) /
@@ -525,6 +525,35 @@ class DeviceRunner:
                 self.kv_pool["gb"], values.dtype.name, list(values.shape),
                 len(self.k_cache), spec.kv_rank, spec.rope_dim, values.shape[-1],
                 self.mla_attention,
+            )
+        elif self.config.window_group is not None:
+            # Two page groups: each group's bytes, pool shape and layers,
+            # and what the same tenants would take on one block id for all
+            # layers (every layer's pool as large as the full group's).
+            groups = {}
+            for group in self.config.cache_groups:
+                pools = [self.k_cache[i] for i in group.layers]
+                pools += [self.v_cache[i] for i in group.layers]
+                groups[group.name] = {
+                    "gb": round(tree_device_bytes(pools) / 1e9, 3),
+                    "bytes": tree_device_bytes(pools),
+                    "dtype": pools[0].dtype.name, "shape": list(pools[0].shape),
+                    "layers": len(group.layers), "window": group.window,
+                }
+            self.kv_pool["groups"] = groups
+            full, win = groups["full"], groups["window"]
+            one_id = full["bytes"] // full["layers"] * (full["layers"] + win["layers"])
+            one_id_gb = one_id / 1e9
+            self.kv_pool["one_block_id_bytes"] = one_id
+            self.kv_pool["one_block_id_gb"] = round(one_id_gb, 3)
+            logger.info(
+                "kv pool: full %.2f GB %s%s x %d | window %.2f GB %s%s x %d "
+                "(the last %d tokens of a row) | %.2f GB resident; one block id "
+                "for all %d layers would hold %.2f GB for the same tenants",
+                full["gb"], full["dtype"], full["shape"], full["layers"],
+                win["gb"], win["dtype"], win["shape"], win["layers"],
+                win["window"], self.kv_pool["gb"],
+                full["layers"] + win["layers"], one_id_gb,
             )
         else:
             logger.info(
@@ -694,6 +723,7 @@ class DeviceRunner:
             self.config, self.args.num_kv_blocks, self.args.block_size,
             layered=self.args.layered_cache,
             kv_dtype=getattr(self.args, "kv_cache_dtype", None),
+            window_blocks=getattr(self.args, "num_window_blocks", 0),
         )
         if self.mesh is not None:
             if self.args.layered_cache:
@@ -1310,7 +1340,7 @@ class DeviceRunner:
             )
             self._decode_state_fns[variant] = fn
         st = self.slot_state
-        tables_nb = self.slot_tables[:, :nb]
+        tables_nb = self.slot_tables[..., :nb]
         topv = topi = None
         if self.hybrid:
             args = [
@@ -1457,9 +1487,9 @@ class DeviceRunner:
             },
         )
         tables = np.asarray(block_tables, np.int32)
-        nb = tables.shape[1]
-        full = np.zeros((S, self.slot_tables.shape[1]), np.int32)
-        full[:, : min(nb, full.shape[1])] = tables[:, : full.shape[1]]
+        nb = tables.shape[-1]
+        full = np.zeros(self.slot_tables.shape, np.int32)
+        full[..., : min(nb, full.shape[-1])] = tables[..., : full.shape[-1]]
         self.sync_tables(list(range(S)), full)
         handles = self.decode_dispatch(
             nb, want_logprobs=want_logprobs, use_procs=procs is not None

@@ -12,6 +12,7 @@ import json
 import os
 import dataclasses
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax.numpy as jnp
@@ -28,11 +29,36 @@ import jax.numpy as jnp
 
 
 @dataclass(frozen=True)
+class RopeLaw:
+    """A rotary law as data of an attention spec: the first ``rotary_dim``
+    lanes of a head rotate (the rest carry no position), at ``theta``;
+    ``yarn`` = (factor, original positions, beta_fast, beta_slow) blends,
+    per frequency, ``inv_freq`` and ``inv_freq / factor`` by the linear ramp
+    between the two correction dims, and cos and sin are multiplied by
+    ``attention_factor`` (ops/rope.rope_freqs)."""
+
+    theta: float
+    rotary_dim: int
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
 class AttentionSpec:
     n_heads: int
     n_kv_heads: int
     head_dim: int
     positions: str = "rope"  # "rope" | "none" (no positional rotation)
+    # Keys a query at position t sees: (t - window, t]; 0 = every position.
+    # A windowed layer's pages live in a page group of their own
+    # (``ModelConfig.cache_groups``): the pool keeps the window, not the
+    # context.
+    window: int = 0
+    # None with positions "rope": the model's ``rope_theta`` over the head.
+    rope: Optional[RopeLaw] = None
+    # A sigmoid gate per head on the attention output, from the sublayer's
+    # normed input (``w_g`` [d, heads]), before the output projection.
+    gate: bool = False
     kind: str = "attention"
 
 
@@ -124,6 +150,18 @@ class ExpertsSpec:
 
     def holding(self, lo: int, hi: int) -> "ExpertsSpec":
         return dataclasses.replace(self, held=(lo, hi))
+
+
+@dataclass(frozen=True)
+class CacheGroup:
+    """Attention layers (indices among the attention layers) that share a
+    block table, and how much of a sequence their pools keep: ``window`` 0 =
+    all of it, else the last ``window`` tokens (pages wholly behind it are
+    released while the sequence runs)."""
+
+    name: str  # "full" | "window"
+    layers: Tuple[int, ...]
+    window: int
 
 
 LayerSpec = Union[
@@ -240,6 +278,42 @@ class ModelConfig:
         """The paged pools hold one latent row a token a layer, not K and V."""
         return bool(self.specs_of("mla"))
 
+    @cached_property
+    def cache_groups(self) -> Tuple[CacheGroup, ...]:
+        """The cache spec: which attention layers share a page group and how
+        much of a sequence each group keeps. One entry ("full", every
+        attention layer, keep all) but for a hybrid model that mixes
+        windowed and full attention layers: then the full layers' group
+        first and one "window" group (one window a model) after it."""
+        attn = self.specs_of("attention")
+        windows = sorted({s.window for s in attn if s.window})
+        if not windows:
+            n = len(attn) or len(self.specs_of("mla")) or self.n_layers
+            return (CacheGroup("full", tuple(range(n)), 0),)
+        if len(windows) > 1:
+            raise ValueError(
+                f"{self.name}: windows {windows}: one window page group a "
+                "model is implemented"
+            )
+        full = tuple(i for i, s in enumerate(attn) if not s.window)
+        win = tuple(i for i, s in enumerate(attn) if s.window)
+        if not full:  # nothing to tell apart: one table, every page kept
+            return (CacheGroup("full", win, 0),)
+        return (CacheGroup("full", full, 0), CacheGroup("window", win, windows[0]))
+
+    def tables_shape(self, rows: int, width: int) -> Tuple[int, ...]:
+        """Shape of a block-table array: one table a row, or one per page
+        group where the model has two."""
+        groups = len(self.cache_groups)
+        return (rows, width) if groups == 1 else (rows, groups, width)
+
+    @property
+    def window_group(self) -> Optional[CacheGroup]:
+        """The page group that keeps a window of each sequence, where the
+        model has one BESIDE a full group (two tables a row)."""
+        groups = self.cache_groups
+        return groups[1] if len(groups) > 1 else None
+
     def hybrid_refusal(self, mechanism: str) -> Optional[str]:
         """Why ``mechanism`` cannot serve this configuration, or None. The
         mechanisms that spell out the per-layer K/V tuple (disaggregation
@@ -247,6 +321,17 @@ class ModelConfig:
         megakernel) know nothing of per-sequence recurrent state, nor of a
         pool that is one latent tile a layer."""
         mla = self.specs_of("mla")
+        win = self.window_group
+        if win is not None:
+            return (
+                f"{mechanism} moves one list of paged K/V blocks per sequence, "
+                f"the same ids in every layer, and {self.name} keeps two page "
+                f"groups: {len(win.layers)} sliding-window layers whose pages "
+                f"behind the last {win.window} tokens are released while the "
+                f"sequence runs, beside {len(self.cache_groups[0].layers)} "
+                f"full layers; {mechanism} knows nothing of the second table "
+                "nor of a page that is gone"
+            )
         if mla:
             return (
                 f"{mechanism} carries a (K, V) pair of paged blocks per layer, "
@@ -288,6 +373,8 @@ class ModelConfig:
             return _nemotron_h_from_hf(cfg, name)
         if str(cfg.get("model_type", "")) == "pangu_ultra_moe":
             return _pangu_ultra_moe_from_hf(cfg, name)
+        if str(cfg.get("model_type", "")) == "laguna":
+            return _laguna_from_hf(cfg, name)
         archs = cfg.get("architectures") or [""]
         arch = archs[0].lower()
         eos = cfg.get("eos_token_id")
@@ -588,6 +675,147 @@ def openpangu_ultra_moe_ep16_config() -> ModelConfig:
     )
 
 
+def _rope_law_from_hf(rp: Dict[str, Any], head_dim: int) -> RopeLaw:
+    rotary = int(head_dim * float(rp.get("partial_rotary_factor", 1.0)))
+    kind = rp.get("rope_type", "default")
+    if kind == "default":
+        return RopeLaw(float(rp["rope_theta"]), rotary)
+    if kind != "yarn":
+        raise ValueError(f"laguna: rope_type {kind!r} is not implemented")
+    return RopeLaw(
+        float(rp["rope_theta"]), rotary,
+        yarn=(float(rp["factor"]), int(rp["original_max_position_embeddings"]),
+              float(rp["beta_fast"]), float(rp["beta_slow"])),
+        attention_factor=float(rp["attention_factor"]),
+    )
+
+
+def _laguna_from_hf(cfg: Dict[str, Any], name: str = "") -> ModelConfig:
+    """``laguna``: every published layer is attention, then a dense FFN
+    (``mlp_layer_types`` "dense") or the experts; two entries of
+    ``layer_specs`` a layer. ``layer_types`` says full or sliding-window
+    attention, ``num_attention_heads_per_layer`` the query heads of each,
+    ``rope_parameters`` the rotary law of each kind, ``gating`` the sigmoid
+    gate on the attention output. Assumed, where the config names a switch
+    and not its shape: the gate is per head; sigmoid scores choose and weigh
+    the experts, normalised over the chosen, times
+    ``moe_routed_scaling_factor``; no query/key norm; the dense layer has no
+    shared expert (models/laguna_reference.py states the same)."""
+    if cfg.get("attention_bias"):
+        raise ValueError("laguna: attention_bias is not implemented")
+    if cfg.get("moe_apply_router_weight_on_input"):
+        raise ValueError("laguna: router weights on the experts' input are not implemented")
+    hd, kv = int(cfg["head_dim"]), int(cfg["num_key_value_heads"])
+    n = int(cfg["num_hidden_layers"])
+    laws = {
+        k: _rope_law_from_hf(rp, hd)
+        for k, rp in cfg["rope_parameters"].items() if isinstance(rp, dict)
+    }
+    heads = cfg.get("num_attention_heads_per_layer") or [int(cfg["num_attention_heads"])] * n
+    dense = DenseFFNSpec(d_ff=int(cfg["intermediate_size"]))
+    experts = ExpertsSpec(
+        n_experts=int(cfg["num_experts"]), top_k=int(cfg["num_experts_per_tok"]),
+        d_ff=int(cfg["moe_intermediate_size"]), routing="sigmoid", norm_topk=True,
+        scale=float(cfg.get("moe_routed_scaling_factor", 1.0)),
+        shared_d_ff=int(cfg.get("shared_expert_intermediate_size", 0)),
+    )
+    specs: List[LayerSpec] = []
+    for i in range(n):
+        kind = cfg["layer_types"][i]
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"laguna: layer type {kind!r}")
+        specs.append(AttentionSpec(
+            n_heads=int(heads[i]), n_kv_heads=kv, head_dim=hd,
+            window=int(cfg["sliding_window"]) if kind == "sliding_attention" else 0,
+            rope=laws[kind], gate=bool(cfg.get("gating", False)),
+        ))
+        specs.append(dense if cfg["mlp_layer_types"][i] == "dense" else experts)
+    eos = cfg.get("eos_token_id")
+    return ModelConfig(
+        vocab_size=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        n_layers=len(specs), n_heads=int(cfg["num_attention_heads"]), n_kv_heads=kv,
+        head_dim=hd, d_ff=dense.d_ff, rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        rope_theta=laws["full_attention"].theta,
+        max_position_embeddings=int(cfg.get("max_position_embeddings", 8192)),
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        eos_token_ids=[] if eos is None else [int(e) for e in (eos if isinstance(eos, list) else [eos])],
+        bos_token_id=cfg.get("bos_token_id"),
+        name=name or "laguna", layer_specs=tuple(specs),
+    )
+
+
+# Laguna-XS.2, the keys of its public config.json that say something about its
+# shape (https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json).
+_LAGUNA_PERIOD = ["full_attention"] + ["sliding_attention"] * 3
+LAGUNA_XS2_HF: Dict[str, Any] = {
+    "model_type": "laguna", "vocab_size": 100352, "hidden_size": 2048,
+    "intermediate_size": 8192, "num_hidden_layers": 40, "num_attention_heads": 48,
+    "num_key_value_heads": 8, "head_dim": 128, "max_position_embeddings": 262144,
+    "attention_bias": False, "rms_norm_eps": 1e-06, "num_experts": 256,
+    "num_experts_per_tok": 8, "moe_intermediate_size": 512,
+    "shared_expert_intermediate_size": 512, "tie_word_embeddings": False,
+    "gating": True, "sliding_window": 512,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "original_max_position_embeddings": 4096, "beta_slow": 1, "beta_fast": 64,
+            "attention_factor": 1.4158883083359672, "partial_rotary_factor": 0.5},
+        "sliding_attention": {
+            "rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1},
+        "original_max_position_embeddings": 4096},
+    "layer_types": _LAGUNA_PERIOD * 10,
+    "moe_apply_router_weight_on_input": False, "partial_rotary_factor": 0.5,
+    "mlp_layer_types": ["dense"] + ["sparse"] * 39,
+    "moe_routed_scaling_factor": 2.5,
+    "num_attention_heads_per_layer": [48, 64, 64, 64] * 10,
+}
+
+
+def laguna_xs2_pp8_config() -> ModelConfig:
+    """Laguna-XS.2 at its published widths, as stage 0 of its served
+    deployment: eight chips as eight pipeline stages of five layers, every
+    layer held whole by its stage's chip (every expert, the whole
+    vocabulary). This chip: layers 0-4, ``full+dense, sliding, sliding,
+    sliding, full``, ten sublayers; the head sits here so that the stage
+    yields logits (in the deployment it is stage 7's)."""
+    hf = dict(LAGUNA_XS2_HF, num_hidden_layers=5)
+    return dataclasses.replace(
+        ModelConfig.from_hf_config(hf), name="laguna-xs.2-pp8"
+    )
+
+
+def tiny_swa_config(n_layers: int = 5, **overrides) -> ModelConfig:
+    """The Laguna layer at toy widths (tests, the CPU rehearsal): the period
+    F S S S from a dense layer 0 on (``n_layers`` 5 ends on a full layer, as
+    the served stage does), window 8, 6 and 8 queries over 2 K/V heads,
+    partial + YaRN rotary in the full layers and plain rotary in the
+    sliding ones, the per-head output gate, 16 experts top 4 + a shared
+    one."""
+    full = AttentionSpec(
+        n_heads=12, n_kv_heads=2, head_dim=16, gate=True,
+        rope=RopeLaw(500000.0, 8, yarn=(8.0, 32, 8.0, 1.0), attention_factor=1.2079),
+    )
+    slide = AttentionSpec(
+        n_heads=16, n_kv_heads=2, head_dim=16, gate=True, window=8,
+        rope=RopeLaw(10000.0, 16),
+    )
+    dense = DenseFFNSpec(d_ff=192)
+    experts = ExpertsSpec(
+        n_experts=16, top_k=4, d_ff=64, routing="sigmoid", scale=2.5, shared_d_ff=64,
+    )
+    specs: List[LayerSpec] = []
+    for i in range(n_layers):
+        specs += [slide if i % 4 else full, experts if i else dense]
+    base = dict(
+        vocab_size=512, d_model=128, n_layers=len(specs), n_heads=12, n_kv_heads=2,
+        head_dim=16, d_ff=192, max_position_embeddings=2048, eos_token_ids=[2],
+        rms_norm_eps=1e-6, dtype=jnp.float32, name="tiny-swa",
+        layer_specs=tuple(specs),
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
 def tiny_mla_config(**overrides) -> ModelConfig:
     """The openPangu layer at toy widths (tests, the CPU rehearsal): one
     leading dense layer and two expert layers, sandwich norms, latent
@@ -826,6 +1054,7 @@ def all_presets() -> Dict[str, "ModelConfig"]:
         llama3_70b_config(), qwen3_8b_config(), gemma3_1b_config(),
         gemma2_2b_config(), tiny_hybrid_config(), nemotron3_nano_ep2_config(),
         tiny_mla_config(), openpangu_ultra_moe_ep16_config(),
+        tiny_swa_config(), laguna_xs2_pp8_config(),
     ]
     return {c.name: c for c in presets}
 

@@ -52,7 +52,7 @@ from dynamo_tpu.ops.attention import (
     write_chunk_to_cache,
 )
 from dynamo_tpu.ops.moe import moe_ffn
-from dynamo_tpu.ops.rope import apply_rope, rope_table
+from dynamo_tpu.ops.rope import apply_rope, rope_table, rope_table_for
 
 Params = Dict[str, Any]
 _F32 = jnp.float32
@@ -110,6 +110,8 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                 wq=norm(k[0], (d, hq), d**-0.5), wk=norm(k[1], (d, hk), d**-0.5),
                 wv=norm(k[2], (d, hk), d**-0.5), wo=norm(k[3], (hq, d), hq**-0.5),
             )
+            if spec.gate:
+                lp["w_gate_attn"] = norm(k[4], (d, spec.n_heads), d**-0.5)
         elif spec.kind == "mamba2":
             H, di = spec.n_heads, spec.d_inner
             dt = jnp.exp(
@@ -162,12 +164,16 @@ def param_logical_axes(config: ModelConfig) -> Params:
     return jax.tree.map(lambda a: (None,) * a.ndim, shapes)
 
 
-def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int):
+def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int,
+                  window_blocks: int = 0):
     """One layered pool per ATTENTION layer, in the layout the kernels read
     (llama.init_kv_cache's layered form). A latent-attention model has one
     LATENT pool per such layer and no V pool: logically
     [blocks, block, 1, cache_width], held as [blocks, block, width] with the
-    row at whole lane tiles (ops/attention.latent_pool_width)."""
+    row at whole lane tiles (ops/attention.latent_pool_width). Where the
+    model has a window page group (``config.window_group``) its layers'
+    pools hold ``window_blocks`` blocks, the other layers' ``num_blocks``:
+    two pool shapes, two id spaces."""
     latent = config.specs_of("mla")
     if latent:
         if config.specs_of("attention"):
@@ -182,8 +188,10 @@ def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int):
             for s in latent
         ), ()
     k, v = [], []
-    for spec in config.specs_of("attention"):
-        shape = (num_blocks, block_size, spec.n_kv_heads, pool_head_dim(spec.head_dim))
+    win = config.window_group
+    for i, spec in enumerate(config.specs_of("attention")):
+        blocks = window_blocks if win is not None and i in win.layers else num_blocks
+        shape = (blocks, block_size, spec.n_kv_heads, pool_head_dim(spec.head_dim))
         k.append(jnp.zeros(shape, config.dtype))
         v.append(jnp.zeros(shape, config.dtype))
     return tuple(k), tuple(v)
@@ -267,8 +275,34 @@ def _mamba_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store):
     return jnp.einsum("bci,id->bcd", y, lp["w_out"]), conv_new, S_new, snap_store
 
 
+def _window_view(block_tables, start_pos, window: int, C: int, block_size: int,
+                 num_blocks: int):
+    """A sliding layer's view of its (logically indexed) table: the slots
+    from the page of the first key the chunk's first query sees on, as many
+    as a chunk of ``C`` can span, and the positions rebased to that page.
+    Attention and the cache write depend on positions only through their
+    differences, so every path (both kernels, the XLA form) runs on the view
+    as it is: a slot behind the window, whose page the engine has released,
+    is in no view, and a chunk over a long context gathers the window's
+    pages only. Returns (table for reads, table for writes, start_pos'):
+    a slot past the table's end reads block 0 (positions past every query)
+    and writes nowhere."""
+    P = block_tables.shape[1]
+    width = (window + C - 2) // block_size + 2
+    if width >= P:  # the table is no wider than a view: nothing to cut
+        return block_tables, block_tables, start_pos
+    poff = jnp.maximum(start_pos.astype(jnp.int32) - window + 1, 0) // block_size
+    slot = poff[:, None] + jnp.arange(width, dtype=jnp.int32)[None]
+    ids = jnp.take_along_axis(block_tables, jnp.minimum(slot, P - 1), axis=1)
+    inside = slot < P
+    return (
+        jnp.where(inside, ids, 0), jnp.where(inside, ids, num_blocks),
+        start_pos - poff * block_size,
+    )
+
+
 def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_lens,
-                     rope, *, use_kernel, first_chunk, plan):
+                     rope, *, use_kernel, first_chunk, plan, write_tables=None):
     B, C, _ = h.shape
     hd = spec.head_dim
     q = jnp.einsum("bcd,dh->bch", h, lp["wq"]).reshape(B, C, spec.n_heads, hd)
@@ -276,9 +310,10 @@ def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_le
     v = jnp.einsum("bcd,dh->bch", h, lp["wv"]).reshape(B, C, spec.n_kv_heads, hd)
     if spec.positions == "rope":
         q, k = apply_rope(q, *rope), apply_rope(k, *rope)
-    k_c = write_chunk_to_cache(k_c, k, block_tables, start_pos, chunk_lens)
-    v_c = write_chunk_to_cache(v_c, v, block_tables, start_pos, chunk_lens)
-    win = jnp.asarray(0, jnp.int32)
+    wt = block_tables if write_tables is None else write_tables
+    k_c = write_chunk_to_cache(k_c, k, wt, start_pos, chunk_lens)
+    v_c = write_chunk_to_cache(v_c, v, wt, start_pos, chunk_lens)
+    win = jnp.asarray(spec.window, jnp.int32)
     if first_chunk:
         attn = dense_chunk_attention(q, k, v, chunk_lens, sm_scale=hd**-0.5, window=win)
     else:
@@ -286,6 +321,11 @@ def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_le
             q, k_c, v_c, block_tables, start_pos, chunk_lens, use_kernel=use_kernel,
             sm_scale=hd**-0.5, window=win, plan=plan,
         )
+    if spec.gate:
+        g = jax.nn.sigmoid(
+            jnp.einsum("bcd,dh->bch", h, lp["w_gate_attn"], preferred_element_type=_F32)
+        )
+        attn = (attn.astype(_F32) * g[..., None]).astype(attn.dtype)
     return jnp.einsum("bch,hd->bcd", attn.reshape(B, C, -1), lp["wo"]), k_c, v_c
 
 
@@ -337,6 +377,12 @@ def _dense_ffn(lp, h):
 _SCOPES = {"mla": "mixer_mla", "dense_ffn": "ffn_dense"}
 
 
+def _scope(c, spec) -> str:
+    if spec.kind == "attention" and c.window_group is not None:
+        return "mixer_attention_window" if spec.window else "mixer_attention_full"
+    return _SCOPES.get(spec.kind, f"mixer_{spec.kind}")
+
+
 # -- forward -------------------------------------------------------------------
 
 
@@ -366,21 +412,38 @@ def forward(
     x = params["embed"][tokens].astype(c.dtype)
     rope = None
     latent = c.specs_of("mla")
-    if latent or any(s.positions == "rope" for s in c.specs_of("attention")):
-        pos = start_pos[:, None] + jax.lax.broadcasted_iota(jnp.int32, (B, C), 1)
+    attn_specs = c.specs_of("attention")
+    pos = start_pos[:, None] + jax.lax.broadcasted_iota(jnp.int32, (B, C), 1)
+    if latent or any(s.positions == "rope" and s.rope is None for s in attn_specs):
         rope = rope_table(
             pos, latent[0].rope_dim if latent else c.head_dim_, c.rope_theta
         )
+    # One rope table per distinct law and one view + plan per distinct
+    # (page group, window, queries a K/V head), not per layer.
+    ropes = {law: rope_table_for(pos, law) for law in {s.rope for s in attn_specs if s.rope}}
+    group_of = {}  # attention layer -> its page group's index
+    for g, group in enumerate(c.cache_groups):
+        group_of.update(dict.fromkeys(group.layers, g))
+    views, plans = {}, {}
     plan = None
     if not first_chunk and latent:
         plan = mla_attention_plan(
             C, k_cache[0], block_tables, start_pos, chunk_lens, use_kernel=use_kernel
         )
-    elif not first_chunk and c.specs_of("attention"):
-        plan = paged_attention_plan(
-            C, c.n_heads, k_cache[0], block_tables, start_pos, chunk_lens,
-            use_kernel=use_kernel, window=0,
-        )
+    for i, s in enumerate(attn_specs):
+        g = group_of.get(i, 0)
+        if (g, s.window) not in views:
+            table = block_tables if block_tables.ndim == 2 else block_tables[:, g]
+            views[g, s.window] = (table, None, start_pos) if not s.window else (
+                _window_view(table, start_pos, s.window, C, k_cache[i].shape[1],
+                             k_cache[i].shape[0]))
+        key = (g, s.window, s.n_heads)
+        if not first_chunk and key not in plans:
+            table, _, start = views[g, s.window]
+            plans[key] = paged_attention_plan(
+                C, s.n_heads, k_cache[i], table, start, chunk_lens,
+                use_kernel=use_kernel, window=s.window,
+            )
     real = jax.lax.broadcasted_iota(jnp.int32, (B, C), 1) < chunk_lens[:, None]
     k_out, v_out, conv_out, s_out = list(k_cache), list(v_cache), [], []
     store = None if snap is None else snap["store"]
@@ -390,7 +453,7 @@ def forward(
     ia = im = 0
     for spec, lp in zip(c.layer_specs, params["layers"]):
         h = _rms(x, lp["norm"], c.rms_norm_eps)
-        with jax.named_scope(_SCOPES.get(spec.kind, f"mixer_{spec.kind}")):
+        with jax.named_scope(_scope(c, spec)):
             if spec.kind == "mla":
                 out, k_out[ia] = _mla_mixer(
                     c, spec, lp, h, k_cache[ia], block_tables, start_pos, chunk_lens,
@@ -400,10 +463,13 @@ def forward(
             elif spec.kind == "dense_ffn":
                 out = _dense_ffn(lp, h)
             elif spec.kind == "attention":
+                g = group_of.get(ia, 0)
+                table, wtable, start = views[g, spec.window]
                 out, k_out[ia], v_out[ia] = _attention_mixer(
-                    c, spec, lp, h, k_cache[ia], v_cache[ia], block_tables, start_pos,
-                    chunk_lens, rope, use_kernel=use_kernel, first_chunk=first_chunk,
-                    plan=plan,
+                    c, spec, lp, h, k_cache[ia], v_cache[ia], table, start,
+                    chunk_lens, ropes.get(spec.rope, rope), use_kernel=use_kernel,
+                    first_chunk=first_chunk,
+                    plan=plans.get((g, spec.window, spec.n_heads)), write_tables=wtable,
                 )
                 ia += 1
             elif spec.kind == "mamba2":

@@ -153,6 +153,7 @@ def kv_cache_shape(
 def init_kv_cache(
     config: ModelConfig, num_blocks: int, block_size: int, *,
     layered: bool = False, kv_dtype: Optional[str] = None,
+    window_blocks: int = 0,
 ):
     """Zeroed K/V pools. ``layered=False``: one stacked [L, NB, BS, KH, D]
     array each (checkpoint/transfer-friendly). ``layered=True``: L-tuples of
@@ -177,7 +178,11 @@ def init_kv_cache(
                 f"{config.name}: a hybrid model serves from layered, "
                 "unquantized K/V pools only"
             )
-        return hybrid.init_kv_cache(config, num_blocks, block_size)
+        # window_blocks: the blocks of a window page group's pools (hybrid
+        # models that have one; 0 = as many as the full group's).
+        return hybrid.init_kv_cache(
+            config, num_blocks, block_size, window_blocks or num_blocks
+        )
     if kv_dtype == "int8":
         if not layered:
             raise ValueError("int8 KV cache requires the layered layout")

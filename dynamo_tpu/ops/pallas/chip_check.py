@@ -101,8 +101,8 @@ def _attention_shapes() -> List[Dict[str, Any]]:
 
     seen: Dict[tuple, Dict[str, Any]] = {}
     for name, make in BUILTIN_CONFIGS.items():
-        if name == "tiny":
-            continue  # float32 test shape, never a TPU worker
+        if name in ("tiny", "tiny-swa"):
+            continue  # float32 test shapes, never a TPU worker
         c = make()
         windows = [w for w in c.layer_windows() if w]
         key = (
@@ -157,7 +157,9 @@ def _attention_inputs(
         start = np.where(live, ctx, 4 * T).astype(np.int32)
         lens = np.where(live, C, 0).astype(np.int32)
     elif rows == "full":
-        start = rng.integers(max(T - 200, 0), T - C, B).astype(np.int32)
+        # (a chunk longer than the 200 tokens of slack ends inside it)
+        low = max(T - 200, 0) if C < 200 else max(T - C - 200, 0)
+        start = rng.integers(low, T - C, B).astype(np.int32)
     else:
         start = rng.integers(0, T - C, B).astype(np.int32)
     return q, pools[0], pools[1], tables, jnp.asarray(start), jnp.asarray(lens)
@@ -209,7 +211,8 @@ def _us_per_call(call, q, *rest) -> float:
     return round(best / TIMED_CALLS * 1e6, 1)
 
 
-def attention_jobs(interpret: bool, B: int, P: int):
+def _attention_job(interpret: bool, B: int, P: int):
+    """The function that makes one paged-attention row of the table."""
     from dynamo_tpu.ops.attention import _paged_attention_xla
     from dynamo_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_kernel,
@@ -267,6 +270,11 @@ def attention_jobs(interpret: bool, B: int, P: int):
             )
         return row
 
+    return job
+
+
+def attention_jobs(interpret: bool, B: int, P: int):
+    job = _attention_job(interpret, B, P)
     shapes = _attention_shapes()
     jobs = [
         functools.partial(job, shape, kernel_name, C, quantized)
@@ -310,6 +318,38 @@ def attention_jobs(interpret: bool, B: int, P: int):
                 for quantized in (False, True)
             ]
     return jobs
+
+
+def swa_attention_jobs(interpret: bool):
+    """The live-span decode kernel at the two head counts of a model that
+    mixes sliding-window and full attention layers over pages of 128 tokens
+    (Laguna-XS.2: 8 K/V heads of 128; 64 query heads in a sliding layer,
+    window 512, whose table is the window's view of five slots; 48 in a full
+    layer, over a 32 k context), each against the XLA form and timed."""
+    job = _attention_job(interpret, B=4, P=4)
+    base = {"KH": 8, "D": 128, "softcap": 0.0, "presets": ["laguna-xs.2-pp8"]}
+    if interpret:
+        return [
+            functools.partial(job, dict(base, H=16, KH=2, D=128, window=40),
+                              "paged_attention_decode", 1, False, B=4, P=5,
+                              rows="full", block_size=16),
+        ]
+    slide, full = dict(base, H=64, window=512), dict(base, H=48, window=0)
+    return [
+        functools.partial(job, slide, "paged_attention_decode", 1, False, B=B, P=5,
+                          rows="full", block_size=128)
+        for B in (8, 64)
+    ] + [
+        functools.partial(job, full, "paged_attention_decode", 1, False, B=B, P=256,
+                          rows="full", block_size=128)
+        for B in (1, 8)
+    ] + [
+        # a turn's chunk over the window's view, and over a 32 k context
+        functools.partial(job, slide, "paged_attention", 256, False, B=1, P=7,
+                          rows="full", block_size=128),
+        functools.partial(job, full, "paged_attention", 256, False, B=1, P=256,
+                          rows="full", block_size=128),
+    ]
 
 
 def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
@@ -418,9 +458,11 @@ def expert_ffn_jobs(interpret: bool):
             xs = jnp.asarray(rng.standard_normal((T, d)), dtype)
             comb = np.zeros((T, n_held), np.float32)
             chosen = rng.permutation(n_held)[:hit]
+            picks = np.full((T, K), n_held, np.int32)  # n_held: no expert
             for t in range(T):
                 mine = rng.permutation(chosen)[:K]
                 comb[t, mine] = rng.random(len(mine)) + 0.1
+                picks[t, : len(mine)] = mine
             comb[:, chosen] += (comb[:, chosen].sum(0) == 0) * 0.5  # each one hit
             comb = jnp.asarray(comb)
             ids, count = hit_list(comb.sum(0))
@@ -447,9 +489,23 @@ def expert_ffn_jobs(interpret: bool):
             )
             if row["status"] == "compiled" and not interpret:
 
+                def grouped(xs, comb, lp, ids, count):
+                    # the grouped form over the same picks (their weights
+                    # from comb; the rows comb padded to "each one hit" stay
+                    # out: a timing, not a comparison)
+                    local = jnp.asarray(picks)
+                    valid = local < n_held
+                    top_w = jnp.take_along_axis(
+                        comb, jnp.minimum(local, n_held - 1), axis=1)
+                    return moe._experts_grouped(
+                        xs, top_w, local, valid, lp, spec, n_held).astype(xs.dtype)
+
                 def both():
                     args = (xs, comb, weights(), ids, count)
                     row["message"] = f"xla dense {_us_per_call(dense, *args)} us/call"
+                    if n_held >= 128:  # where the grouped form may be the better one
+                        row["message"] += (
+                            f", xla grouped {_us_per_call(grouped, *args)} us/call")
                     return _us_per_call(kernel, *args)
 
                 row["time"] = both
@@ -469,6 +525,12 @@ def expert_ffn_jobs(interpret: bool):
                [(64, 1), (64, 8), (64, 19), (64, 29), (64, 64), (128, 64), (256, 64)])
         + family("openpangu-ultra-moe-718b-ep16", 7680, 2048, 16, 8, "silu_gated",
                  [(32, 1)] + [(T, hit) for T in (8, 64, 128, 256) for hit in (2, 16)])
+        # Laguna-XS.2: 256 small experts held whole (d 2048, f 512, gated
+        # silu, f minor): two grid steps an expert, many short steps where
+        # the other two cells take few long ones.
+        + family("laguna-xs.2-pp8", 2048, 512, 256, 8, "silu_gated",
+                 [(64, 1), (64, 16), (64, 57), (64, 128), (64, 256), (128, 128),
+                  (128, 256), (256, 256)])
     )
 
 
@@ -727,6 +789,7 @@ def main() -> int:
                 True, fused_cfg, B=4, widths=[1, 4]),
             "expert_ffn": lambda: expert_ffn_jobs(True),
             "mla_paged_decode": lambda: mla_jobs(True),
+            "paged_attention_swa": lambda: swa_attention_jobs(True),
         }
     else:
         from dynamo_tpu.worker.__main__ import build_parser
@@ -741,6 +804,7 @@ def main() -> int:
                 False, qwen3_8b_config(), B=worker.max_num_seqs, widths=widths),
             "expert_ffn": lambda: expert_ffn_jobs(False),
             "mla_paged_decode": lambda: mla_jobs(False),
+            "paged_attention_swa": lambda: swa_attention_jobs(False),
         }
     families["kv_pool_layout"] = lambda: []  # one row, after the timings
     if args.only is not None and args.only not in families:

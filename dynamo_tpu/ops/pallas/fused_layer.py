@@ -137,6 +137,12 @@ def supports_reason(
             "head width per layer, and this model keeps one latent pool a "
             "layer (c_kv beside the shared rotary key) and no V pool"
         )
+    if getattr(c, "window_group", None) is not None:
+        return (
+            "two page groups: the kernel follows ONE block table a row, and "
+            "this model's sliding-window layers keep a page group of their "
+            "own whose pages behind the window are released"
+        )
     if getattr(c, "is_hybrid", False):
         return (
             "hybrid model (one mixer per layer: the kernel fuses attention "

@@ -84,6 +84,28 @@ NEG_INF = -1e30
 # fit; the body's f32 temporaries take their share of the rest).
 DECODE_GROUP_PAGES = 16
 DECODE_PAGES_VMEM_BYTES = 4 << 20
+# Chunk kernel: what a grid step keeps of its queries in VMEM, per (query,
+# head) row of D lanes: q and the output block, two pipeline buffers each, in
+# the queries' dtype, and the float32 accumulator. Over ``SPLIT_ABOVE`` (the
+# compiler scopes a kernel to 16 MiB: such a call never compiled) the chunk's
+# query positions are served in blocks that keep at most ``SPLIT_TO``.
+CHUNK_ROWS_SPLIT_ABOVE_BYTES = 14 << 20
+CHUNK_ROWS_SPLIT_TO_BYTES = 7 << 20
+
+
+def chunk_query_block(C: int, n_heads: int, head_dim: int, itemsize: int) -> int:
+    """Query positions of a chunk that one call of the chunk kernel keeps in
+    VMEM at once: all ``C`` where they fit (every shape served before PR 44:
+    nothing changes for them), else the largest power-of-two part of C whose
+    rows are within ``CHUNK_ROWS_SPLIT_TO_BYTES`` (a 256-token chunk at 64
+    heads of 128 lanes is 24 MiB whole: 64 positions a block)."""
+    row = n_heads * head_dim * (4 * itemsize + 4)
+    if C * row <= CHUNK_ROWS_SPLIT_ABOVE_BYTES:
+        return C
+    cq = C
+    while cq > 8 and cq % 2 == 0 and cq * row > CHUNK_ROWS_SPLIT_TO_BYTES:
+        cq //= 2
+    return cq
 
 
 def _kernel(
@@ -535,6 +557,23 @@ def _paged_attention_kernel_impl(
 
     quantized = is_quantized_pool(k_cache)
     B, C, n_heads, head_dim = q.shape
+    cq = chunk_query_block(C, n_heads, head_dim, q.dtype.itemsize)
+    if cq < C:
+        # Blocks of query positions as rows of their own: a block is a chunk
+        # that starts ``j * cq`` later over the same table (the chunk's K/V
+        # is in the cache already, and the mask follows positions), so the
+        # kernel serves it as it is and its VMEM holds one block's rows.
+        nq = C // cq
+        off = jnp.arange(nq, dtype=jnp.int32) * cq
+        part = lambda a: jnp.repeat(a.astype(jnp.int32), nq, axis=0)
+        out = _paged_attention_kernel_impl(
+            q.reshape(B * nq, cq, n_heads, head_dim), k_cache, v_cache,
+            part(block_tables), part(start_pos) + jnp.tile(off, B),
+            jnp.clip(part(chunk_lens) - jnp.tile(off, B), 0, cq), window,
+            sm_scale=sm_scale, interpret=interpret, pages_per_step=pages_per_step,
+            logit_cap=logit_cap,
+        )
+        return out.reshape(B, C, n_heads, head_dim)
     k_values = k_cache["q8"] if quantized else k_cache
     num_blocks, block_size, n_kv_heads, page_dim = k_values.shape
     P = block_tables.shape[1]

@@ -135,6 +135,22 @@ ENGINE_SSM_STATE_SLOTS = f"{ENGINE_PREFIX}_ssm_state_slots"
 ENGINE_SSM_SNAPSHOTS = f"{ENGINE_PREFIX}_ssm_snapshots"
 ENGINE_SSM_SNAPSHOT_HITS_TOTAL = f"{ENGINE_PREFIX}_ssm_snapshot_hits_total"
 ENGINE_SSM_SNAPSHOT_EVICTIONS_TOTAL = f"{ENGINE_PREFIX}_ssm_snapshot_evictions_total"
+# Two page groups for one sequence (a model that mixes sliding-window and full
+# attention layers; never touched otherwise). The gauge: blocks of each group
+# (label group=full|window, state=used|cached|total). Released: window-group
+# pages given back behind a running sequence's window. Dead / held, per
+# dispatched decode burst: window-group pages a live row holds wholly behind
+# its window / all it holds (dead / held = what the release leaves behind).
+# Window live pages: the window-group pages the burst's rows attend over
+# (``decode_live_pages_total`` counts the full group's). Cut: prefix hits
+# shortened or lost because the window group no longer held the window in
+# front of the resume position.
+ENGINE_KV_GROUP_BLOCKS = f"{ENGINE_PREFIX}_kv_group_blocks"
+ENGINE_WINDOW_PAGES_RELEASED_TOTAL = f"{ENGINE_PREFIX}_window_pages_released_total"
+ENGINE_WINDOW_PAGES_DEAD_TOTAL = f"{ENGINE_PREFIX}_window_pages_dead_total"
+ENGINE_WINDOW_PAGES_HELD_TOTAL = f"{ENGINE_PREFIX}_window_pages_held_total"
+ENGINE_DECODE_WINDOW_LIVE_PAGES_TOTAL = f"{ENGINE_PREFIX}_decode_window_live_pages_total"
+ENGINE_PREFIX_HITS_CUT_BY_WINDOW_TOTAL = f"{ENGINE_PREFIX}_prefix_hits_cut_by_window_total"
 
 # The tick-phase vocabulary: every name EngineStepMetrics.phase accepts, in
 # exactly one class. ``device_wait``: the loop awaits the device thread
@@ -644,4 +660,10 @@ ALL_ENGINE = (
     ENGINE_SSM_SNAPSHOTS,
     ENGINE_SSM_SNAPSHOT_HITS_TOTAL,
     ENGINE_SSM_SNAPSHOT_EVICTIONS_TOTAL,
+    ENGINE_KV_GROUP_BLOCKS,
+    ENGINE_WINDOW_PAGES_RELEASED_TOTAL,
+    ENGINE_WINDOW_PAGES_DEAD_TOTAL,
+    ENGINE_WINDOW_PAGES_HELD_TOTAL,
+    ENGINE_DECODE_WINDOW_LIVE_PAGES_TOTAL,
+    ENGINE_PREFIX_HITS_CUT_BY_WINDOW_TOTAL,
 )

@@ -16,6 +16,7 @@ from dynamo_tpu.models.config import (
     ModelConfig,
     gemma2_2b_config,
     gemma3_1b_config,
+    laguna_xs2_pp8_config,
     llama3_3b_config,
     llama3_8b_config,
     llama3_70b_config,
@@ -27,6 +28,7 @@ from dynamo_tpu.models.config import (
     tiny_config,
     tiny_hybrid_config,
     tiny_mla_config,
+    tiny_swa_config,
 )
 from dynamo_tpu.parallel import MeshConfig, make_mesh
 from dynamo_tpu.router import KvEventPublisher, LoadPublisher
@@ -53,6 +55,8 @@ BUILTIN_CONFIGS = {
     "nemotron-3-nano-30b-a3b-ep2": nemotron3_nano_ep2_config,
     "tiny-mla": tiny_mla_config,
     "openpangu-ultra-moe-718b-ep16": openpangu_ultra_moe_ep16_config,
+    "tiny-swa": tiny_swa_config,
+    "laguna-xs.2-pp8": laguna_xs2_pp8_config,
 }
 
 

@@ -213,6 +213,9 @@ DOCUMENTED_PRESET_EXCLUSIONS = {
     # one latent pool a layer, no K and no V page
     "tiny-mla": "latent",
     "openpangu-ultra-moe-718b-ep16": "latent",
+    # two page groups: a second block table a row, pages behind the window released
+    "tiny-swa": "two page groups",
+    "laguna-xs.2-pp8": "two page groups",
 }
 
 

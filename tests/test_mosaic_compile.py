@@ -56,6 +56,11 @@ DECODE_SHAPES = {
     "qwen3-8b-verify-c8-bs128": (16, 8, 32, 8, 128, 16, False, 0.0, 128),
     "gemma-2-2b-softcap-bs64": (16, 1, 8, 4, 256, 32, False, 50.0, 64),
     "gemma-2-9b-d256-bs128": (16, 1, 16, 8, 256, 16, False, 50.0, 128),
+    # Laguna-XS.2 over pages of 128: a sliding layer (64 query heads, 8 a
+    # K/V head) over its window's view of five slots, a full layer (48, 6 a
+    # K/V head) over a 32 k table; the window is the traced scalar.
+    "laguna-sliding-h64-view5": (64, 1, 64, 8, 128, 5, False, 0.0, 128),
+    "laguna-full-h48-32k": (64, 1, 48, 8, 128, 256, False, 0.0, 128),
 }
 
 # Shapes whose bf16 page is held at a 128-lane tile for a head of 64
@@ -116,6 +121,24 @@ def test_chunk_kernel_compiles_for_v5e_at_head_64(one_chip, page):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("heads,pages", [(64, 7), (48, 256)])
+def test_chunk_kernel_compiles_for_v5e_at_laguna_heads(one_chip, heads, pages):
+    """The generic (B, pages) kernel for a turn's 256-token chunk at
+    Laguna-XS.2's two head counts over pages of 128: a sliding layer over its
+    window's view (7 slots), a full layer over a 32 k table."""
+    from dynamo_tpu.ops.pallas.paged_attention import _paged_attention_kernel_impl
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((768, 128, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(_paged_attention_kernel_impl).lower(
+        sds((1, 256, heads, 128), jnp.bfloat16), pool, pool, sds((1, pages), jnp.int32),
+        sds((1,), jnp.int32), sds((1,), jnp.int32), sds((), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 EXPERT_WIDTHS = {
     # name: (d, f, held, gated). Hybrid: f is 14.5 lane tiles, so XLA hands
     # ``we_up`` over as it is resident, d minor (the kernel's transpose is
@@ -124,12 +147,17 @@ EXPERT_WIDTHS = {
     # 2048] row tiles of up and gate, [128, 7680] tiles of down.
     "hybrid": (2688, 1856, 64, False),
     "latent": (7680, 2048, 16, True),
+    # Laguna: 256 small experts held whole, f fills the lanes: whole [2048,
+    # 512] matrices of up and gate in one step, [512, 2048] of down in the
+    # next; the combine matrix is [T, 256].
+    "laguna": (2048, 512, 256, True),
 }
 
 
 @pytest.mark.parametrize("widths,tokens", [
     ("hybrid", 64), ("hybrid", 128), ("hybrid", 256),
     ("latent", 32), ("latent", 128), ("latent", 256),
+    ("laguna", 64), ("laguna", 256),
 ])
 def test_expert_kernel_compiles_for_v5e_at_the_served_widths(one_chip, widths, tokens):
     """ops/pallas/expert_ffn.py at the two served configurations' widths,
@@ -462,6 +490,87 @@ def test_mla_served_programs_compile_for_the_chip(one_chip, program):
     resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k)
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
     hit_listed = program == "decode_burst" or program.endswith("_one_row")
+    assert ("expert_ffn_hit_list" in text) == hit_listed
+    if hit_listed:
+        experts = params["layers"][3]
+        assert [whole_pool_copies(text, experts[m])
+                for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]
+
+
+def _laguna_program(one_chip, program):
+    """One served program of the Laguna-XS.2 stage at its published widths
+    (layers 0-2: full + dense, two sliding expert layers; then the last full
+    expert layer), as the runner builds it, two tables a row."""
+    import dataclasses
+    import types
+
+    from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import hybrid, llama
+    from dynamo_tpu.models.config import laguna_xs2_pp8_config
+
+    cfg = dataclasses_replace_layers(laguna_xs2_pp8_config(), [0, 1, 2, 3, 8, 9])
+    NB, S, P, bs = 3328, 64, 256, 128
+    args = JaxEngineArgs(
+        config=cfg, block_size=bs, num_kv_blocks=NB, max_num_seqs=S, max_model_len=P * bs,
+        prefill_chunk=256, use_kernel=True,
+    )
+    runner = types.SimpleNamespace(config=cfg, args=args, use_kernel=True,
+                                   _decode_sig_budget=None)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = lambda f: jax.tree.map(on_chip, jax.eval_shape(f))
+    params = shapes(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    k, v = shapes(lambda: llama.init_kv_cache(
+        cfg, NB, bs, layered=True, window_blocks=args.num_window_blocks))
+    store = shapes(lambda: hybrid.init_ssm_state(cfg, 16))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def rows(B):
+        return [arr((B,), i32), arr((2,), jnp.uint32), arr((B,), f32),
+                arr((B,), i32), arr((B,), f32)]
+
+    if program == "decode_burst":
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, S))
+        lowered = DeviceRunner._build_decode_fn_hybrid(runner, False, False).lower(
+            params, k, v, state, arr((S,), i32), arr((S,), i32), arr((S,), i32),
+            arr((S, 2, P), i32), *rows(S),
+        )
+    else:
+        fresh = program == "prefill_fresh"
+        B, C, width = (8, 256, 2) if fresh else (1, 256, P)
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
+        lowered = DeviceRunner._build_step_fn_hybrid(runner, False, 0, fresh).lower(
+            params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
+            arr((B,), i32), arr((B, 2, width), i32), arr((B, 0), i32), *rows(B),
+        )
+    return lowered.compile(), (params, k, args)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+def test_laguna_served_programs_compile_for_the_chip(one_chip, program):
+    """The decode burst, a batch of fresh first chunks and a turn's chunk over
+    a 32 k table, of the Laguna-XS.2 stage at its published widths, compiled
+    for the v5e as the runner builds them: both paged kernels lower at 48 and
+    64 query heads, the two page groups' pools (two shapes) alias in and out,
+    no program copies a whole pool or an expert stack, the burst's 64 slots
+    and a turn's 256 tokens go through the hit-list expert kernel at [256,
+    2048, 512], and a prefill program holds no ``while``."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+
+    compiled, (params, k, args) = _laguna_program(one_chip, program)
+    text = compiled.as_text()
+    assert {a.shape[0] for a in k} == {3328, args.num_window_blocks} and args.num_window_blocks == 768
+    assert ("tpu_custom_call" in text) and (" while(" in text) == (program == "decode_burst")
+    assert whole_pool_copies(text, k[0]) == whole_pool_copies(text, k[1]) == 0
+    resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= resident
+    hit_listed = program != "prefill_fresh"  # 8 rows of 256 go through the grouped form
     assert ("expert_ffn_hit_list" in text) == hit_listed
     if hit_listed:
         experts = params["layers"][3]

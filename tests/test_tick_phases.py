@@ -628,10 +628,12 @@ def served():
             await engine.stop()
 
     async def run():
-        from dynamo_tpu.models.config import tiny_hybrid_config, tiny_mla_config
+        from dynamo_tpu.models.config import (
+            tiny_hybrid_config, tiny_mla_config, tiny_swa_config)
 
         hybrid_body = await hybrid_scrape(tiny_hybrid_config())
         mla_body = await hybrid_scrape(tiny_mla_config())
+        swa_body = await hybrid_scrape(tiny_swa_config())
         engine, _ = make_engine(decode_steps=4)
         server = SystemStatusServer(host="127.0.0.1", port=0)
         attach_engine(server, engine)
@@ -662,6 +664,7 @@ def served():
                 return {
                     "workers_hybrid": hybrid_body,
                     "workers_mla": mla_body,
+                    "workers_swa": swa_body,
                     "workers": await scrape(s, server.port, "/metrics"),
                     "frontend": await scrape(s, http_port, "/metrics"),
                     "routes": routes,
@@ -694,7 +697,7 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
     families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND) | {
         mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
     labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
-              | set(mn.FRAME_KINDS) | {"used", "total"})
+              | set(mn.FRAME_KINDS) | {"used", "total", "window"})
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
     workers = "workers"
@@ -702,6 +705,8 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
         workers = "workers_hybrid"
     if entry.get("workloads") and all("openpangu" in w for w in entry["workloads"]):
         workers = "workers_mla"
+    if entry.get("workloads") and all("laguna" in w for w in entry["workloads"]):
+        workers = "workers_swa"
     flags = {o for a in build_parser()._actions for o in a.option_strings}  # noqa: SLF001
     for key in ("per_flag", "percent_of_worker_flag"):
         assert params.get(key) in flags | {None}, (name, key)
@@ -726,7 +731,8 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
             assert key == "*" or all(key in x for x in at), (name, key)
             at = [v for x in at for v in (x if key == "*" else [x[key]])]
     else:
-        assert spec["reader"] in ("trace", "hybrid_roofline", "mla_roofline")
+        assert spec["reader"] in (
+            "trace", "hybrid_roofline", "mla_roofline", "swa_roofline")
         assert ("POST", "/debug/profile") in served["routes"]
         for key, family in params.items():
             if key.endswith("_metric"):
