@@ -197,6 +197,12 @@ class EngineStepMetrics:
             mn.ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL,
             "Mean tokens on a held expert, summed over layer-steps",
         )
+        self.moe_prefill_tokens = self.registry.counter(
+            mn.ENGINE_MOE_PREFILL_TOKENS_TOTAL,
+            "Live prompt tokens of reaped prefill steps through expert "
+            "layers, by the form the step's static token count takes",
+            ["form"],
+        )
         self.ssm_state_slots = self.registry.gauge(
             mn.ENGINE_SSM_STATE_SLOTS,
             "Per-sequence recurrent-state slots (one per decode row)",
@@ -413,6 +419,10 @@ class EngineStepMetrics:
         self.moe_expert_slots.inc(slots)
         self.moe_max_expert_tokens.inc(most)
         self.moe_mean_expert_tokens.inc(mean)
+
+    def observe_moe_prefill(self, tokens_by_form: Dict[str, int]) -> None:
+        for form, tokens in tokens_by_form.items():
+            self.moe_prefill_tokens.set_total(tokens, form=form)
 
     def observe_kv_groups(self, groups: Dict[str, Dict[str, int]],
                           released: int, cut: int) -> None:

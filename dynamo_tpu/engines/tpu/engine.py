@@ -51,6 +51,7 @@ from dynamo_tpu.llm.protocols.common import (
 )
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.moe import FORMS as MOE_FORMS
 from dynamo_tpu.ops.sampling import compute_logprobs, sample_tokens
 from dynamo_tpu.parallel.mesh import AxisNames
 from dynamo_tpu.parallel.sharding import ShardingRules, param_shardings, shard_params
@@ -503,6 +504,11 @@ class JaxEngine:
         self.steps = 0  # decode iterations (observability)
         self.prefill_tokens = 0
         self.generated_tokens = 0
+        # Prefill tokens through expert layers by the form their step took
+        # (runner.prefill_expert_form); None for a model without experts.
+        self.moe_prefill_tokens: Optional[Dict[str, int]] = (
+            dict.fromkeys(MOE_FORMS, 0) if self.runner.expert_ffn is not None else None
+        )
         # Step-loop metric families (registered on the system server by
         # attach_engine; dependency-free, so always on).
         from dynamo_tpu.engines.metrics import EngineStepMetrics
@@ -796,6 +802,9 @@ class JaxEngine:
             self.step_metrics.observe_kv_groups(
                 out["kv_groups"], self.window.released, self.window.cut_hits
             )
+        if self.moe_prefill_tokens is not None:
+            out["moe_prefill_tokens"] = dict(self.moe_prefill_tokens)
+            self.step_metrics.observe_moe_prefill(self.moe_prefill_tokens)
         if self.config.has_latent_cache:
             out["latent_pool"] = dict(self.runner.kv_pool)
             out["mla_attention"] = self.runner.mla_attention

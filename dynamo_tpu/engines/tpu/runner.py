@@ -500,6 +500,7 @@ class DeviceRunner:
         self.sleep_level = 0
         self.host_params: Optional[Any] = None
         self.expert_ffn = self._describe_expert_ffn()
+        self._prefill_expert_forms: Dict[int, Optional[str]] = {}
         logger.info(
             "device runner: platform=%s device_kind=%s devices=%d mesh=%s | "
             "decode path: %s (%s) | attention: %s (%s) | expert_ffn: %s",
@@ -604,6 +605,29 @@ class DeviceRunner:
             if spec.kind == "experts"
         }
         return "; ".join(sorted(forms)) or None
+
+    def prefill_expert_form(self, tokens: int) -> Optional[str]:
+        """ops/moe.form_of for a prefill step of ``tokens`` static tokens
+        (rows bucket x chunk bucket): what ``moe_ffn`` branches on when that
+        program is traced, at the first expert layer; None for a model
+        without expert layers."""
+        from dynamo_tpu.ops.moe import form_of
+
+        c = self.config
+        if tokens not in self._prefill_expert_forms:
+            layers = self.params["layers"]
+            if self.hybrid:
+                given = next(
+                    ((self.use_kernel, lp, spec) for spec, lp in zip(c.layer_specs, layers)
+                     if spec.kind == "experts"), None)
+            elif c.is_moe:  # the stacked layer loop takes no kernel
+                lp = layers if isinstance(layers, dict) else layers[0]
+                given = (False, lp, c.experts_spec())
+            else:
+                given = None
+            self._prefill_expert_forms[tokens] = (
+                given and form_of(given[0], tokens, *given[1:])[0])
+        return self._prefill_expert_forms[tokens]
 
     # -- path selection ----------------------------------------------------
 

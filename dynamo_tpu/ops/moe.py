@@ -12,30 +12,45 @@ expert matrices are sharded on their first axis and GSPMD partitions the
 dense form's einsums by itself: no exchange of tokens, every shard sees the
 whole batch, as the two chips of the served deployment do.
 
-Three ways to compute the held experts' part. ``hit_list_reason`` chooses
-the first from what it is given (the caller's ``use_kernel``, the static
-token count, the matrices, the spec); the static token count alone chooses
-between the other two:
+Four forms of the held experts' part, two on each side of
+``DENSE_TOKENS_MAX`` tokens a step. ``form_of`` chooses from what it is given
+(the caller's ``use_kernel``, the static token count, the matrices, the
+spec): ``hit_list_reason`` says why a small step is not the first,
+``grouped_reason`` why a large one is not the third. No flag, no name of a
+model:
 
-* hit list (a decode step or a small prefill step on one TPU chip): the
-  dense form's own products, float32 accumulation, over the experts that
-  got a LIVE token and no others, by a Pallas kernel whose grid is that
-  list (ops/pallas/expert_ffn.py). A step streams from HBM the matrices of
-  the experts hit, which is what ``want_stats`` counts: a dead slot
-  (``row_mask`` false) routes to no expert. The kernel reads ``relu2``
+* hit list (a decode step or a prefill step of up to 256 tokens on one TPU
+  chip): the dense form's own products, float32 accumulation, over the
+  experts that got a LIVE token and no others, by a Pallas kernel whose
+  grid is that list (ops/pallas/expert_ffn.py). A step streams from HBM the
+  matrices of the experts hit, which is what ``want_stats`` counts: a dead
+  slot (``row_mask`` false) routes to no expert. The kernel reads ``relu2``
   experts (two matrices) and ``silu_gated`` ones (three), with ``we_up`` /
   ``we_gate`` in either layout XLA holds them in: d minor-most where f does
   not fill the 128 lanes (the hybrid cell, 2688 x 1856), f minor-most where
   it does (the latent cell, 7680 x 2048); a model width that does not fill
   the lanes stays on the XLA forms.
-* dense (few tokens, everywhere else): every token through every held
-  expert with a [T, E_held] weight matrix that is zero off the routing and
-  on dead rows. The weights of all held experts stream once, and the
-  einsums are static shapes that GSPMD partitions over an ``experts``
-  sharded axis by itself.
-* grouped (many tokens, a prefill chunk): assignments sorted by expert, one
-  grouped matmul per matrix (``jax.lax.ragged_dot``), scattered back
-  weighted.
+* dense (few tokens, everywhere else; quantized matrices at any count):
+  every token through every held expert with a [T, E_held] weight matrix
+  that is zero off the routing and on dead rows. The weights of all held
+  experts stream once, and the einsums are static shapes that GSPMD
+  partitions over an ``experts`` sharded axis by itself.
+* grouped kernel (a prefill step of more than 256 tokens on one TPU chip,
+  since PR 45): assignments sorted by held expert, dead rows and absent
+  experts last; each expert's rows padded to whole row tiles, so that a
+  tile belongs to one expert; ONE Pallas kernel whose grid is the list of
+  (expert, row tile) pairs (``expert_ffn_grouped``): a step is ``act(x_tile
+  @ up) @ down`` with the expert's matrices whole in VMEM, read in the
+  layout they are resident in (no copy of the stack), the [rows, f]
+  intermediate never in HBM, float32 accumulation; an expert's second tile
+  streams nothing. What is not live is no grid step. Each token's
+  weighted sum over its K result rows stays in XLA, as a gather.
+* grouped XLA (everything the kernel refuses: the CPU, a mesh, an
+  activation or width it does not read, an expert whose matrices do not
+  fit VMEM whole, as the latent cell's 3 x 31.5 MB): the same sort, one
+  ``jax.lax.ragged_dot`` per matrix with the [T*K, f] intermediate through
+  HBM (and, where ``we_up`` is resident d minor, a copy of the stack in
+  front of it), scattered back weighted.
 
 Shapes: T = B*C tokens, E router width, Eh experts held, K experts a token,
 f expert width. ``lp`` holds ``router_w`` [d, E], optionally ``router_bias``
@@ -52,7 +67,11 @@ from typing import TYPE_CHECKING, Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.ops.pallas.expert_ffn import ACTIVATIONS, expert_ffn, hit_list
+from dynamo_tpu.ops.pallas.expert_ffn import (
+    ACTIVATIONS, GROUPED_ROW_TILE_MAX, GROUPED_VMEM_BYTES_MAX, expert_ffn,
+    expert_ffn_grouped, grouped_row_tile, grouped_tiles,
+    grouped_vmem_bytes, grouped_work_list, hit_list,
+)
 from dynamo_tpu.ops.quant import qeinsum
 
 if TYPE_CHECKING:  # models/ imports this module: the spec is data, named only
@@ -60,8 +79,8 @@ if TYPE_CHECKING:  # models/ imports this module: the spec is data, named only
 
 # Token count up to which every token goes through every expert hit (the
 # hit-list kernel, where ``hit_list_reason`` finds none against it) or
-# through every held expert (the dense form); above it the grouped form
-# serves. At the hybrid cell's widths (d 2688, f 1856, 64 held) the dense
+# through every held expert (the dense form); above it the grouped forms
+# serve. At the hybrid cell's widths (d 2688, f 1856, 64 held) the dense
 # form's FLOPs pass the time the weights take to stream at about 256 tokens
 # on a v5e, and up to there the kernel with all 64 experts hit is no slower
 # than the dense form (chip_check's expert_ffn rows at 64, 128 and 256
@@ -113,15 +132,11 @@ def _activate(up: jnp.ndarray, gate: Optional[jnp.ndarray], spec: ExpertsSpec):
     raise ValueError(f"unknown expert activation {spec.activation!r}")
 
 
-def hit_list_reason(
-    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
-) -> Optional[str]:
-    """None where the held experts' part goes through the hit-list kernel;
-    otherwise why it keeps the XLA forms."""
-    if not use_kernel:
-        return "no Pallas kernels here (use_kernel is false)"
-    if T > DENSE_TOKENS_MAX:
-        return f"{T} tokens a step is over {DENSE_TOKENS_MAX}"
+NO_KERNELS = "no Pallas kernels here (use_kernel is false)"
+
+
+def _kernel_refusal(lp: Dict[str, Any], spec: ExpertsSpec) -> Optional[str]:
+    """What either Pallas kernel cannot read, whatever the token count."""
     if isinstance(lp["we_up"], dict):
         return "quantized expert matrices"
     if spec.activation not in ACTIVATIONS:
@@ -130,28 +145,80 @@ def hit_list_reason(
     if not n_held:
         return "no expert held"
     if d % 128:
-        # The kernel reads we_up (and we_gate) through the resident layout:
+        # The kernels read we_up (and we_gate) through the resident layout:
         # f minor-most where f fills the 128 lanes, d minor-most where it
         # does not and d does (expert_ffn.f_minor). Neither: XLA pads.
         return f"widths d {d}, f {f}: d does not fill the 128 lanes"
     return None
 
 
+def hit_list_reason(
+    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+) -> Optional[str]:
+    """None where the held experts' part goes through the hit-list kernel;
+    otherwise why it keeps the XLA forms."""
+    if not use_kernel:
+        return NO_KERNELS
+    if T > DENSE_TOKENS_MAX:
+        return f"{T} tokens a step is over {DENSE_TOKENS_MAX}"
+    return _kernel_refusal(lp, spec)
+
+
+def grouped_reason(
+    use_kernel: bool, lp: Dict[str, Any], spec: ExpertsSpec
+) -> Optional[str]:
+    """None where a step of more than ``DENSE_TOKENS_MAX`` tokens goes
+    through the grouped kernel; otherwise why it keeps ``ragged_dot``. From
+    the caller's ``use_kernel``, the matrices and the spec alone."""
+    if not use_kernel:
+        return NO_KERNELS
+    why = _kernel_refusal(lp, spec)
+    if why is not None:
+        return why
+    # The kernel keeps an expert's matrices whole in VMEM, so that the
+    # second row tile of an expert streams nothing.
+    _, d, f = lp["we_up"].shape
+    matrices = 3 if spec.activation == "silu_gated" else 2
+    need = grouped_vmem_bytes(
+        GROUPED_ROW_TILE_MAX, d, f, matrices, lp["we_up"].dtype.itemsize)
+    if need > GROUPED_VMEM_BYTES_MAX:
+        return (f"an expert's {matrices} matrices of {d} x {f}, twice, are "
+                f"{need >> 20} MiB of VMEM, over {GROUPED_VMEM_BYTES_MAX >> 20}")
+    return None
+
+
+def form_of(
+    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+) -> Tuple[str, Optional[str]]:
+    """(form, why not a kernel) of a step of ``T`` tokens: ``hit_list`` or
+    ``dense`` up to ``DENSE_TOKENS_MAX`` tokens (and ``dense`` for quantized
+    matrices, which neither ``ragged_dot`` nor a kernel takes),
+    ``grouped_kernel`` or ``grouped_xla`` above. What ``moe_ffn`` branches
+    on, the runner logs and the engine counts prefill tokens by."""
+    why = hit_list_reason(use_kernel, T, lp, spec)
+    if why is None:
+        return "hit_list", None
+    if T <= DENSE_TOKENS_MAX:
+        return "dense", why
+    why = grouped_reason(use_kernel, lp, spec)
+    if isinstance(lp["we_up"], dict):
+        return "dense", why
+    return ("grouped_kernel" if why is None else "grouped_xla"), why
+
+
+FORMS = ("hit_list", "dense", "grouped_kernel", "grouped_xla")
+
+
 def form_in_use(
     use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
 ) -> str:
     """Which form a step of ``T`` tokens takes, for the log: ``pallas hit
-    list``, or ``xla dense|grouped, <why not the kernel>``."""
-    why = hit_list_reason(use_kernel, T, lp, spec)
+    list``, ``pallas grouped``, or ``xla dense|grouped, <why not the
+    kernel>``."""
+    form, why = form_of(use_kernel, T, lp, spec)
     if why is None:
-        return "pallas hit list"
-    return f"xla {'dense' if _dense_serves(T, lp) else 'grouped'}, {why}"
-
-
-def _dense_serves(T: int, lp: Dict[str, Any]) -> bool:
-    """Off the kernel: the dense form (few tokens, or quantized matrices,
-    which ``ragged_dot`` does not take) rather than the grouped one."""
-    return T <= DENSE_TOKENS_MAX or isinstance(lp["we_up"], dict)
+        return {"hit_list": "pallas hit list", "grouped_kernel": "pallas grouped"}[form]
+    return f"xla {form.split('_')[0]}, {why}"
 
 
 def _experts_dense(xs, comb, lp, spec):
@@ -180,6 +247,47 @@ def _experts_grouped(xs, top_w, local, valid, lp, spec, n_held):
     # grouped matmul left there is not a result.
     out = jnp.where((w != 0.0)[:, None], out.astype(jnp.float32) * w[:, None], 0.0)
     return jnp.zeros((T, xs.shape[-1]), jnp.float32).at[token_of].add(out)
+
+
+def _experts_grouped_kernel(xs, top_w, local, valid, lp, spec, n_held):
+    """The grouped form through ops/pallas/expert_ffn.expert_ffn_grouped:
+    assignments sorted by held expert as above, each expert's rows padded to
+    whole row tiles (dead rows and absent experts sort last and own no
+    tile), one kernel over the (expert, row tile) pairs, then each token's
+    weighted sum over the K result rows it owns: a gather, where the XLA
+    form scatter-adds (13.1 -> 10.2 ms a layer at 8,192 tokens, 2.4 -> 2.0
+    at 512, hybrid widths, my chip run, PR 45)."""
+    T, K = local.shape
+    A = T * K
+    tm = grouped_row_tile(A, spec.n_experts)
+    n_tiles = grouped_tiles(A, n_held, tm)
+    flat = jnp.where(valid, local, n_held).reshape(A)  # absent ones last
+    order = jnp.argsort(flat)
+    sizes = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(jnp.int32)
+    tile_expert, n_work, first, left, pad_before = grouped_work_list(sizes, tm, n_tiles)
+    # Row r of tile t is the (first[t] + r)-th sorted assignment, or padding
+    # from left[t] on (every tile past the work list is padding).
+    r = jnp.arange(tm, dtype=jnp.int32)[None, :]
+    src = jnp.where(r < left[:, None], order[jnp.clip(first[:, None] + r, 0, A - 1)], 0)
+    out = expert_ffn_grouped(
+        xs[src.reshape(-1) // K], lp["we_up"], lp["we_down"], tile_expert, n_work,
+        lp["we_gate"] if spec.activation == "silu_gated" else None,
+        tm=tm,
+    )
+    # Where the i-th sorted assignment sits in the padded layout: i plus the
+    # padding in front of its expert, a step function of i that rises at
+    # each expert's offset (a masked sum over [A, Eh], no table lookup);
+    # assignment (t, k) is the argsort(order)-th sorted one. One that is not
+    # valid reads row 0 and weighs nothing: the padding rows and the tiles
+    # the kernel never wrote are no one's.
+    offset = jnp.cumsum(sizes) - sizes
+    step = jnp.diff(pad_before, prepend=0)
+    i = jnp.arange(A, dtype=jnp.int32)
+    at_sorted = i + jnp.sum(
+        jnp.where(i[:, None] >= offset[None, :], step[None, :], 0), axis=1)
+    at = at_sorted[jnp.argsort(order)].reshape(T, K)
+    picked = jnp.where(valid[..., None], out[jnp.where(valid, at, 0)], 0.0)
+    return jnp.einsum("tk,tkd->td", jnp.where(valid, top_w, 0.0), picked)
 
 
 def _combine(top_w, local, valid, n_held):
@@ -222,7 +330,8 @@ def moe_ffn(
     valid = (local >= 0) & (local < n_held)
     if row_mask is not None:
         valid = valid & row_mask.reshape(T, 1)
-    hit_listed = hit_list_reason(use_kernel, T, lp, spec) is None
+    form, _ = form_of(use_kernel, T, lp, spec)
+    hit_listed = form == "hit_list"
     if hit_listed or want_stats:  # tokens on each held expert, [Eh] float32
         load = jnp.zeros((n_held + 1,), jnp.float32).at[
             jnp.where(valid, local, n_held).reshape(-1)
@@ -233,9 +342,11 @@ def moe_ffn(
             lp["we_down"], *hit_list(load),
             lp["we_gate"] if spec.activation == "silu_gated" else None,
         )
-    elif _dense_serves(T, lp):
+    elif form == "dense":
         comb = _combine(top_w, local, valid, n_held)
         y = _experts_dense(xs, comb, lp, spec).astype(jnp.float32)
+    elif form == "grouped_kernel":
+        y = _experts_grouped_kernel(xs, top_w, local, valid, lp, spec, n_held)
     else:
         y = _experts_grouped(xs, top_w, local, valid, lp, spec, n_held)
     if spec.shared_d_ff:

@@ -12,7 +12,10 @@ against ops/moe.py's dense form at the hybrid configuration's served widths
 tokens with all hit) and at the latent configuration's (three matrices of
 7680 x 2048, 16 held: 8, 64, 128 and 256 tokens with 2 and all 16 hit, and
 the 32 decode slots on one expert): the rows that decide how many tokens
-the kernel serves, each with both forms' ``us/call``. The decode kernel gets four more rows: the tail of
+the kernel serves, each with both forms' ``us/call``; and
+``expert_ffn_grouped`` (a family of its own: the grouped expert kernel of a
+prefill step) beside ``ragged_dot``, both as ops/moe.py serves them, at the
+three served widths from 512 to 8,192 tokens a step. The decode kernel gets four more rows: the tail of
 a prefix-hit prefill (one row, four tokens, eight pages), the
 benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
 contexts, the others empty with a stale position, 128 pages of table; bf16
@@ -417,6 +420,28 @@ def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
     return [functools.partial(job, P) for P in widths]
 
 
+_DRAWING = threading.Lock()  # rows run side by side: one draw of a stack
+
+
+@functools.cache
+def _drawn_experts(n_held, d, f, gated, dtype):
+    keys = jax.random.split(jax.random.PRNGKey(37), 3)
+    lp = {
+        "we_up": jax.random.normal(keys[0], (n_held, d, f), dtype) * d**-0.5,
+        "we_down": jax.random.normal(keys[1], (n_held, f, d), dtype) * f**-0.5,
+    }
+    if gated:
+        lp["we_gate"] = jax.random.normal(keys[2], (n_held, d, f), dtype) * d**-0.5
+    return lp
+
+
+def _expert_weights(n_held, d, f, activation, dtype):
+    """One stack of expert matrices a width, shared by every row (and both
+    expert families) that reads it."""
+    with _DRAWING:
+        return _drawn_experts(n_held, d, f, activation == "silu_gated", dtype)
+
+
 def expert_ffn_jobs(interpret: bool):
     """The hit-list expert kernel against the XLA dense form
     (ops/moe._experts_dense, what it replaces in a decode step), bf16, at
@@ -432,26 +457,10 @@ def expert_ffn_jobs(interpret: bool):
     from dynamo_tpu.ops.pallas.expert_ffn import expert_ffn, hit_list
 
     dtype = jnp.float32 if interpret else jnp.bfloat16
-    drawing = threading.Lock()  # rows run side by side: one draw of a stack
 
     def family(preset, d, f, n_held, K, activation, rows):
         spec = ExpertsSpec(n_experts=n_held, top_k=K, d_ff=f, activation=activation)
-
-        def weights():
-            with drawing:
-                return drawn()
-
-        @functools.cache
-        def drawn():
-            keys = jax.random.split(jax.random.PRNGKey(37), 3)
-            lp = {
-                "we_up": jax.random.normal(keys[0], (n_held, d, f), dtype) * d**-0.5,
-                "we_down": jax.random.normal(keys[1], (n_held, f, d), dtype) * f**-0.5,
-            }
-            if activation == "silu_gated":
-                lp["we_gate"] = (
-                    jax.random.normal(keys[2], (n_held, d, f), dtype) * d**-0.5)
-            return lp
+        weights = functools.partial(_expert_weights, n_held, d, f, activation, dtype)
 
         def job(T, hit):
             rng = np.random.default_rng(T * 100 + hit)
@@ -502,10 +511,9 @@ def expert_ffn_jobs(interpret: bool):
 
                 def both():
                     args = (xs, comb, weights(), ids, count)
-                    row["message"] = f"xla dense {_us_per_call(dense, *args)} us/call"
-                    if n_held >= 128:  # where the grouped form may be the better one
-                        row["message"] += (
-                            f", xla grouped {_us_per_call(grouped, *args)} us/call")
+                    row["message"] = (
+                        f"xla dense {_us_per_call(dense, *args)} us/call, "
+                        f"xla grouped {_us_per_call(grouped, *args)} us/call")
                     return _us_per_call(kernel, *args)
 
                 row["time"] = both
@@ -531,6 +539,111 @@ def expert_ffn_jobs(interpret: bool):
         + family("laguna-xs.2-pp8", 2048, 512, 256, 8, "silu_gated",
                  [(64, 1), (64, 16), (64, 57), (64, 128), (64, 256), (128, 128),
                   (128, 256), (256, 256)])
+    )
+
+
+def expert_ffn_grouped_jobs(interpret: bool):
+    """The grouped expert kernel (a prefill step of more than
+    ``DENSE_TOKENS_MAX`` tokens) against ``ragged_dot``, both as ops/moe.py
+    serves them, sort and scatter included, at the three served widths:
+    top-K over the router's whole width with uneven expert popularity
+    (lognormal, as random routers give: the most loaded held expert gets
+    several times the mean), a third of the rows dead as a padded batch has
+    them, the experts past ``n_held`` absent. Where ``moe.grouped_reason``
+    keeps a width on ``ragged_dot`` the row says why and times that alone.
+    The ``required`` row is a work list of ONE row tile (PR 25)."""
+    from dynamo_tpu.models.config import ExpertsSpec
+    from dynamo_tpu.ops import moe
+    from dynamo_tpu.ops.pallas.expert_ffn import expert_ffn_grouped, grouped_row_tile
+
+    dtype = jnp.float32 if interpret else jnp.bfloat16
+    if interpret:
+        moe.expert_ffn_grouped = functools.partial(expert_ffn_grouped, interpret=True)
+
+    def family(preset, d, f, n_held, n_experts, K, activation, tokens):
+        spec = ExpertsSpec(n_experts=n_experts, top_k=K, d_ff=f,
+                           activation=activation, held=(0, n_held))
+        weights = functools.partial(_expert_weights, n_held, d, f, activation, dtype)
+
+        def job(T, one_tile=False):
+            rng = np.random.default_rng(T + n_held)
+            xs = jnp.asarray(rng.standard_normal((T, d)), dtype)
+            popularity = rng.normal(0.0, 1.0, n_experts)
+            local = np.argsort(
+                -(popularity + rng.gumbel(size=(T, n_experts))), axis=1)[:, :K]
+            live = rng.random(T) < 2 / 3
+            valid = (local < n_held) & live[:, None]
+            if one_tile:  # three assignments in all, on held expert 1
+                valid[:] = False
+                valid[:3, 0], local[:3, 0] = True, 1
+            top_w = jnp.asarray(rng.random((T, K)) + 0.1, jnp.float32)
+            local, valid = jnp.asarray(local.astype(np.int32)), jnp.asarray(valid)
+            sizes = np.bincount(np.asarray(local)[np.asarray(valid)], minlength=n_held)
+            row = {
+                "kernel": "expert_ffn_grouped",
+                "shape": f"T{T} d{d} f{f} held{n_held}/{n_experts} top{K} "
+                         f"{activation} {dtype.__name__} "
+                         f"tm{grouped_row_tile(T * K, n_experts)}: {int(sizes.sum())} rows on "
+                         f"{int((sizes > 0).sum())} experts, most {int(sizes.max())}",
+                "presets": [preset],
+                "required": one_tile,
+            }
+
+            def kernel(xs, lp):
+                return moe._experts_grouped_kernel(
+                    xs, top_w, local, valid, lp, spec, n_held).astype(xs.dtype)
+
+            def grouped(xs, lp):
+                return moe._experts_grouped(
+                    xs, top_w, local, valid, lp, spec, n_held).astype(xs.dtype)
+
+            why = moe.grouped_reason(True, weights(), spec)
+            if why is not None:
+                # (a width the kernel is not built for is no failed row)
+                row.update(status="refused", message=why, seconds=0.0, required=False)
+                if not interpret:
+                    row["time"] = lambda: row.update(
+                        message=f"{why}; xla grouped "
+                        f"{_us_per_call(grouped, xs, weights())} us/call")
+                return row
+            def dense(xs, lp):
+                # 1,024 tokens at a time: [Eh, T, f] in float32 is the
+                # size of the stack at 8,192 (``ragged_dot``, a Mosaic
+                # kernel itself, does not lower at ``highest`` precision)
+                comb = moe._combine(top_w, local, valid, n_held)
+                return jnp.concatenate([
+                    moe._experts_dense(xs[i:i + 1024], comb[i:i + 1024], lp, spec)
+                    for i in range(0, T, 1024)])
+
+            row = _timed(row, lambda: kernel(xs, weights()),
+                         lambda: dense(xs, weights()), ulps=4)
+            if row["status"] == "compiled" and not interpret:
+
+                def both():
+                    row["message"] = (
+                        f"xla grouped {_us_per_call(grouped, xs, weights())} us/call")
+                    return _us_per_call(kernel, xs, weights())
+
+                row["time"] = both
+            return row
+
+        return [functools.partial(job, T) for T in tokens] + [
+            functools.partial(job, tokens[0], True)]
+
+    if interpret:
+        return (
+            family("tiny-hybrid", 128, 48, 8, 16, 2, "relu2", [320, 512])
+            + family("tiny-swa", 128, 128, 8, 8, 2, "silu_gated", [320])
+        )
+    return (
+        # (128 and 256 tokens are the hit-list kernel's today: the rows say
+        # where the two kernels cross, ops/moe.DENSE_TOKENS_MAX)
+        family("nemotron-3-nano-30b-a3b-ep2", 2688, 1856, 64, 128, 6, "relu2",
+               [512, 256, 1024, 2048, 4096, 8192])
+        + family("laguna-xs.2-pp8", 2048, 512, 256, 256, 8, "silu_gated",
+                 [512, 128, 256, 1024, 2048])
+        + family("openpangu-ultra-moe-718b-ep16", 7680, 2048, 16, 256, 8,
+                 "silu_gated", [512, 2048])
     )
 
 
@@ -788,6 +901,7 @@ def main() -> int:
             "fused_decoder_layer": lambda: fused_layer_jobs(
                 True, fused_cfg, B=4, widths=[1, 4]),
             "expert_ffn": lambda: expert_ffn_jobs(True),
+            "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(True),
             "mla_paged_decode": lambda: mla_jobs(True),
             "paged_attention_swa": lambda: swa_attention_jobs(True),
         }
@@ -803,6 +917,7 @@ def main() -> int:
             "fused_decoder_layer": lambda: fused_layer_jobs(
                 False, qwen3_8b_config(), B=worker.max_num_seqs, widths=widths),
             "expert_ffn": lambda: expert_ffn_jobs(False),
+            "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(False),
             "mla_paged_decode": lambda: mla_jobs(False),
             "paged_attention_swa": lambda: swa_attention_jobs(False),
         }

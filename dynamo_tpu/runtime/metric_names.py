@@ -128,6 +128,12 @@ ENGINE_MOE_EXPERTS_HIT_TOTAL = f"{ENGINE_PREFIX}_moe_experts_hit_total"
 ENGINE_MOE_EXPERT_SLOTS_TOTAL = f"{ENGINE_PREFIX}_moe_expert_slots_total"
 ENGINE_MOE_MAX_EXPERT_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_max_expert_tokens_total"
 ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_mean_expert_tokens_total"
+# Live prompt tokens of reaped prefill steps that passed expert layers, by the
+# form the step's STATIC token count gives (ops/moe.form_of: label
+# form=hit_list|dense|grouped_kernel|grouped_xla); all four from start-up in an
+# engine with expert layers: grouped_kernel / all four = how often a prefill
+# step's experts run through the grouped Pallas kernel.
+ENGINE_MOE_PREFILL_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_prefill_tokens_total"
 # Recurrent (state-space) state beside the paged K/V: slots are one per decode
 # row, snapshots are the block-aligned state copies prefix reuse resumes from
 # (label state=used|total).
@@ -656,6 +662,7 @@ ALL_ENGINE = (
     ENGINE_MOE_EXPERT_SLOTS_TOTAL,
     ENGINE_MOE_MAX_EXPERT_TOKENS_TOTAL,
     ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL,
+    ENGINE_MOE_PREFILL_TOKENS_TOTAL,
     ENGINE_SSM_STATE_SLOTS,
     ENGINE_SSM_SNAPSHOTS,
     ENGINE_SSM_SNAPSHOT_HITS_TOTAL,

@@ -660,7 +660,9 @@ FORM_CASES = {
     "decode_slots_on_the_chip": ((True, 64, 128, 48, 4, "relu2", False), "pallas hit list"),
     "small_prefill_on_the_chip": ((True, 256, 128, 48, 4, "relu2", False), "pallas hit list"),
     "no_kernels_here": ((False, 64, 128, 48, 4, "relu2", False), "xla dense, no Pallas"),
-    "a_prefill_chunk": ((True, 512, 128, 48, 4, "relu2", False), "xla grouped, 512 tokens"),
+    "a_prefill_chunk": ((True, 512, 128, 48, 4, "relu2", False), "pallas grouped"),
+    "a_prefill_chunk_off_the_chip": ((False, 512, 128, 48, 4, "relu2", False),
+                                     "xla grouped, no Pallas"),
     "quantized_matrices": ((True, 64, 128, 48, 4, "relu2", True), "xla dense, quantized"),
     "gated_experts": ((True, 64, 128, 48, 4, "silu_gated", False), "pallas hit list"),
     "unknown_activation": ((True, 64, 128, 48, 4, "gelu", False), "xla dense, activation gelu"),

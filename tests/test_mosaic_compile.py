@@ -214,6 +214,75 @@ def test_hybrid_expert_kernel_is_the_program_it_was(tokens):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == HYBRID_KERNEL_JAXPR[tokens]
 
 
+def _grouped_kernel_shapes(widths, tokens, top_k, router_width, sds):
+    """The grouped kernel's operands for a prefill step of ``tokens`` tokens
+    at a served configuration's widths, as ops/moe.py hands them over."""
+    from dynamo_tpu.ops.pallas.expert_ffn import grouped_row_tile, grouped_tiles
+
+    d, f, held, gated = EXPERT_WIDTHS[widths]
+    tm = grouped_row_tile(tokens * top_k, router_width)
+    n_tiles = grouped_tiles(tokens * top_k, held, tm)
+    up, down = sds((held, d, f), jnp.bfloat16), sds((held, f, d), jnp.bfloat16)
+    return tm, (sds((n_tiles * tm, d), jnp.bfloat16), up, down,
+                sds((n_tiles + 1,), jnp.int32), sds((1,), jnp.int32),
+                *([up] if gated else []))
+
+
+@pytest.mark.parametrize("widths,tokens,top_k,router_width", [
+    ("hybrid", 512, 6, 128), ("hybrid", 2048, 6, 128), ("hybrid", 8192, 6, 128),
+    ("laguna", 512, 8, 256), ("laguna", 2048, 8, 256),
+])
+def test_grouped_expert_kernel_compiles_for_v5e_at_the_served_widths(
+    one_chip, widths, tokens, top_k, router_width
+):
+    """ops/pallas/expert_ffn.expert_ffn_grouped at the hybrid cell's widths
+    (d minor, relu2: an expert's two matrices whole in VMEM, 2 x 19.96 MB)
+    and the window cell's (f minor, gated silu), bf16, at the row tile the
+    step's shape gives: Mosaic takes it, and the program holds no copy of a
+    matrix stack."""
+    import functools
+
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+    from dynamo_tpu.ops.pallas.expert_ffn import _expert_ffn_grouped_impl
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm, operands = _grouped_kernel_shapes(widths, tokens, top_k, router_width, sds)
+    compiled = jax.jit(
+        functools.partial(_expert_ffn_grouped_impl, tm=tm)).lower(*operands).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "expert_ffn_grouped" in text
+    assert whole_pool_copies(text, operands[1]) == whole_pool_copies(text, operands[2]) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+# sha256 (first 16 hex digits) of the grouped kernel's jaxpr at the hybrid
+# cell's widths, by tokens a step: the kernel as PR 45 measured it (grid,
+# index maps, block shapes, compiler parameters, every operation in order).
+# A deliberate change records new digests AND measures the hybrid cell.
+HYBRID_GROUPED_KERNEL_JAXPR = {512: "1bc39c2a809b33ff", 2048: "3cc8b586840dafd9",
+                               8192: "34d6624e85450986"}
+
+
+@pytest.mark.parametrize("tokens", sorted(HYBRID_GROUPED_KERNEL_JAXPR))
+def test_hybrid_grouped_kernel_is_the_program_it_was(tokens):
+    import functools
+    import hashlib
+    import re
+
+    from dynamo_tpu.ops.pallas.expert_ffn import _expert_ffn_grouped_impl
+
+    tm, operands = _grouped_kernel_shapes(
+        "hybrid", tokens, 6, 128, jax.ShapeDtypeStruct)
+    text = str(jax.make_jaxpr(
+        functools.partial(_expert_ffn_grouped_impl, tm=tm))(*operands))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert "dynamo_tpu" not in text  # no path of a checkout in what is hashed
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == HYBRID_GROUPED_KERNEL_JAXPR[tokens])
+
+
 @pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
 def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
     """The decode burst and the prefill step of a two-layer qwen2.5-0.5b,
@@ -288,14 +357,25 @@ def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
     assert resident <= aliased < resident + (1 << 20)
 
 
-@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+HYBRID_PROGRAMS = {
+    # name: (fresh prompts?, rows, chunk, table width); None: the decode burst
+    "decode_burst": None,
+    "prefill_fresh": (True, 4, 512, 32),
+    "prefill_fresh_2x256": (True, 2, 256, 16),
+    "prefill_fresh_8x1024": (True, 8, 1024, 64),
+    "prefill_tail": (False, 1, 128, 16),
+}
+
+
+@pytest.mark.parametrize("program", sorted(HYBRID_PROGRAMS))
 def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     """The decode burst and the prefill step of the hybrid configuration at
-    its PUBLISHED widths (one layer of each kind: Mamba-2, experts with 8 of
-    128 held, attention), compiled for the v5e as the runner builds them:
-    the paged-attention kernels lower at head 128 with 16 Q per KV head,
-    the scan, the grouped matmul and the snapshot scatter compile, and the
-    donated pools, recurrent state and snapshot store alias in and out."""
+    its PUBLISHED widths (one layer of each kind: Mamba-2, experts with 64 of
+    128 held as the cell serves them, attention), compiled for the v5e as
+    the runner builds them: the paged-attention kernels lower at head 128
+    with 16 Q per KV head, the scan, the grouped expert kernel and the
+    snapshot scatter compile, and the donated pools, recurrent state and
+    snapshot store alias in and out."""
     import types
 
     from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
@@ -307,7 +387,7 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
     full = ModelConfig.from_hf_config(NEMOTRON_3_NANO_30B_A3B_HF)
-    cfg = cut_hybrid(full, n_layers=6, experts_held=(0, 8), vocab_rows=8192, name="cut")
+    cfg = cut_hybrid(full, n_layers=6, experts_held=(0, 64), vocab_rows=8192, name="cut")
     cfg = dataclasses_replace_layers(cfg, (0, 1, 5))  # M, E, *
     NB, S, SNAP = 2048, 64, 16
     args = JaxEngineArgs(
@@ -343,8 +423,7 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
         )
         donated = (k, v, state)
     else:
-        fresh = program == "prefill_fresh"
-        B, C, P = (4, 512, 32) if fresh else (1, 128, 16)
+        fresh, B, C, P = HYBRID_PROGRAMS[program]
         state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
         lowered = build_step(fresh).lower(
             params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
@@ -355,17 +434,19 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the paged-attention kernel
     # benchmark/trace_names tells the two programs apart by a ``while``: the
-    # burst is one, and a prefill step (scan, sort, grouped matmul) holds none
+    # burst is one, and a prefill step (scan, sort, grouped kernel) holds none
     assert (" while(" in text) == (program == "decode_burst")
     # The burst's 64 slots and the tail's 128 tokens go through the hit-list
-    # expert kernel (2,048 fresh tokens through the grouped form), and no
-    # program re-lays a stack of expert matrices on the way to either.
-    assert ("expert_ffn_hit_list" in text) == (program != "prefill_fresh")
+    # expert kernel, 512 to 8,192 fresh tokens through the grouped one (no
+    # ``ragged_dot`` since PR 45), and no program re-lays a stack of expert
+    # matrices on the way to either: ``we_up`` is read d minor, as resident.
+    grouped = program.startswith("prefill_fresh")
+    assert ("expert_ffn_hit_list" in text) == (not grouped)
+    assert ("expert_ffn_grouped" in text) == grouped
+    assert "ragged-dot" not in text and "ragged_dot" not in text
     experts = params["layers"][1]
     copies = [whole_pool_copies(text, experts[k]) for k in ("we_up", "we_down")]
-    # (the grouped form's ``ragged_dot`` does re-lay ``we_up``, resident
-    # with d minor, once a fresh prefill step: PERF.md §7)
-    assert copies == ([1, 0] if program == "prefill_fresh" else [0, 0])
+    assert copies == [0, 0]
     resident = sum(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(donated))
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
@@ -491,6 +572,10 @@ def test_mla_served_programs_compile_for_the_chip(one_chip, program):
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
     hit_listed = program == "decode_burst" or program.endswith("_one_row")
     assert ("expert_ffn_hit_list" in text) == hit_listed
+    # an expert of 94 MB does not fit VMEM whole: ``ops/moe.grouped_reason``
+    # keeps eight rows of 256 on ``ragged_dot``
+    assert "expert_ffn_grouped" not in text
+    assert ("ragged-dot" in text) == (not hit_listed)
     if hit_listed:
         experts = params["layers"][3]
         assert [whole_pool_copies(text, experts[m])
@@ -570,9 +655,12 @@ def test_laguna_served_programs_compile_for_the_chip(one_chip, program):
     assert whole_pool_copies(text, k[0]) == whole_pool_copies(text, k[1]) == 0
     resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k) * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
-    hit_listed = program != "prefill_fresh"  # 8 rows of 256 go through the grouped form
+    # 8 rows of 256 go through the grouped kernel (f minor, gated silu: an
+    # expert's three matrices whole in VMEM), no ``ragged_dot`` since PR 45
+    hit_listed = program != "prefill_fresh"
     assert ("expert_ffn_hit_list" in text) == hit_listed
-    if hit_listed:
-        experts = params["layers"][3]
-        assert [whole_pool_copies(text, experts[m])
-                for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]
+    assert ("expert_ffn_grouped" in text) == (not hit_listed)
+    assert "ragged-dot" not in text
+    experts = params["layers"][3]
+    assert [whole_pool_copies(text, experts[m])
+            for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]

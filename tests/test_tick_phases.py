@@ -685,6 +685,7 @@ def served():
 
 @pytest.mark.parametrize("name", PROGRAM_READ)
 def test_layer_metric_file_reads_what_the_program_exports(name, served):
+    from dynamo_tpu.ops.moe import FORMS as MOE_FORMS
     from dynamo_tpu.worker.__main__ import build_parser
 
     spec = _layer_metric(name)
@@ -697,7 +698,8 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
     families = set(mn.ALL_ENGINE) | set(mn.ALL_FRONTEND) | {
         mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
     labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
-              | set(mn.FRAME_KINDS) | {"used", "total", "window"})
+              | set(mn.FRAME_KINDS) | {"used", "total", "window"}
+              | set(MOE_FORMS))
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
     workers = "workers"
