@@ -250,6 +250,22 @@ class EngineStepMetrics:
             "Prefix hits shortened because the window group no longer held "
             "the window in front of the resume position",
         )
+        # Sparse attention layers (metric_names.py says what each counts);
+        # never touched by a model without them.
+        self.sparse_pages_selected = self.registry.counter(
+            mn.ENGINE_SPARSE_PAGES_SELECTED_TOTAL,
+            "Pages a sparse attention layer's kernel visits for the rows of "
+            "dispatched decode bursts",
+        )
+        self.sparse_pages_live = self.registry.counter(
+            mn.ENGINE_SPARSE_PAGES_LIVE_TOTAL,
+            "Pages those rows hold (what dense attention would visit)",
+        )
+        self.sparse_rows = self.registry.counter(
+            mn.ENGINE_SPARSE_ROWS_TOTAL,
+            "Rows of dispatched decode bursts by the path their sparse "
+            "layers take", ["path"],
+        )
         # phase name -> is it a device wait (the vocabulary and its one
         # class the counts below ask about, in one lookup)
         self._phases = {
@@ -431,6 +447,12 @@ class EngineStepMetrics:
                 self.kv_group_blocks.set(n, group=group, state=state)
         self.window_pages_released.set_total(released)
         self.prefix_hits_cut_by_window.set_total(cut)
+
+    def observe_sparse(self, selected: int, live: int, rows: Dict[str, int]) -> None:
+        self.sparse_pages_selected.inc(selected)
+        self.sparse_pages_live.inc(live)
+        for path, n in rows.items():
+            self.sparse_rows.inc(n, path=path)
 
     def observe_window_pages(self, live: int, held: int, dead: int) -> None:
         self.decode_window_live_pages.inc(live)

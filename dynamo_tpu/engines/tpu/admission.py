@@ -524,7 +524,10 @@ class Admitter:
         return len(ladder)
 
     def _note_prefix_hit_round(self, Bp: int, c: int, nb: int, want_top: bool) -> None:
-        """A chunk round over cached context dispatched at this shape. Such
+        """A chunk round of a batch that resumed from a prefix hit,
+        dispatched at this shape (a long fresh prompt's own later chunks
+        are no hit and are not counted: the 256 rounds of one 65 k prompt
+        say nothing of what the next asks will run). Such
         programs are in no start-up ladder (most workers never meet one,
         and a ladder program costs seconds). One that RECURS says this
         worker's traffic hits prefixes: at its second meeting the sibling
@@ -735,7 +738,18 @@ class Admitter:
                 # Fresh prefills (no prefix-cache hit, first chunk round) take
                 # the dense in-chunk attention program — zero paged reads.
                 first_chunk = bool(np.all(start[:rows] == 0))
-                if not first_chunk and procs is None and mm_embeds is None:
+                # Such a round reads no page and writes its chunk's own, the
+                # table's first: it runs at the start-up ladder's width, so a
+                # long fresh prompt's first round is the ladder's program
+                # whatever table its later rounds need.
+                step_tables = tables
+                if first_chunk:
+                    step_tables = np.ascontiguousarray(tables[..., : prefill_table_bucket(
+                        math.ceil(c_bucket / args.block_size), c_bucket, args)])
+                if (
+                    not first_chunk and procs is None and mm_embeds is None
+                    and any(prep.matched_tokens for _, prep in pending.batch)
+                ):
                     self._note_prefix_hit_round(
                         Bp, c_bucket, tables.shape[-1], want_top
                     )
@@ -748,11 +762,11 @@ class Admitter:
                 t0 = time.monotonic()
                 with phase(
                     "tick.prefill_wait", rows=rows, chunk=c_bucket,
-                    nb=tables.shape[-1], tokens=int(lens.sum()),
+                    nb=step_tables.shape[-1], tokens=int(lens.sum()),
                 ):
                     toks, logps, topv, topi, *state = await e._device(
                         e._run_step,
-                        tok_arr, start, lens, tables,
+                        tok_arr, start, lens, step_tables,
                         temp, topk, topp, adapter,
                         mm_embeds, mm_chunk, procs, want_top, first_chunk,
                         salts, *hybrid_args,
@@ -796,15 +810,19 @@ class Admitter:
     def _reserve_snapshots(
         self, pending: PendingPrefill, snap_dst: np.ndarray, lens: np.ndarray
     ) -> None:
-        """Name, for this round, the snapshot-store entry of every stride
+        """Name, for this round, the snapshot-store entry of every snapshot
         boundary a row's chunk crosses and that is not kept yet (rows start
-        their rounds on a boundary: a resume point is one, and a round
-        advances by whole chunks)."""
+        their rounds on a scan-block boundary: a resume point is one, and a
+        round advances by whole chunks). The program can write the state at
+        every scan block's end; a boundary is every ``_snap_every`` tokens."""
         e = self.e
-        stride, bs = e._ssm_stride, e.args.block_size
+        stride, every, bs = e._ssm_stride, e._snap_every, e.args.block_size
         for r, (_, prep) in enumerate(pending.batch):
             for j in range(int(lens[r]) // stride):
-                b = (pending.pos[r] + (j + 1) * stride) // bs
+                at = pending.pos[r] + (j + 1) * stride
+                if at % every:
+                    continue
+                b = at // bs
                 if b > len(prep.hashes):
                     break
                 idx = e.snapshots.reserve(prep.hashes[b - 1])

@@ -392,9 +392,27 @@ class WindowPages:
 
 
 # Entries of the recurrent-state snapshot store of a hybrid model (one entry =
-# every Mamba-2 layer's conv tail and SSM state at one block boundary): the
-# runner allocates that many, the engine's StateSnapshots indexes them.
+# every recurrent layer's state at one snapshot boundary): the runner
+# allocates that many, the engine's StateSnapshots indexes them.
 SSM_SNAPSHOT_ENTRIES = 256
+
+
+def snapshot_entries(config, num_kv_blocks: int, block_size: int, max_num_seqs: int) -> int:
+    """Entries of the snapshot store. A model whose recurrent spec names no
+    spacing of its own (or that has no recurrent layer: the store is then an
+    empty tree) snapshots every scan block into ``SSM_SNAPSHOT_ENTRIES``; one
+    that does (an entry of lightning attention is megabytes a layer) gets one
+    entry for every boundary the K/V pool's tokens can hold (a snapshot no
+    page stands behind serves no hit), at least two a decode row, at most
+    ``SSM_SNAPSHOT_ENTRIES``."""
+    if not config.has_recurrent_state:
+        return SSM_SNAPSHOT_ENTRIES
+    scan, every = config.snapshot_stride
+    if every == scan:
+        return SSM_SNAPSHOT_ENTRIES
+    return min(
+        SSM_SNAPSHOT_ENTRIES, max(2 * max_num_seqs, num_kv_blocks * block_size // every)
+    )
 
 
 class StateSnapshots:
@@ -402,7 +420,8 @@ class StateSnapshots:
     snapshot store holds the state at the END of which block (keyed by that
     block's chained content hash, so a key names the whole prefix). Bounded:
     ``capacity`` entries, least recently used evicted first. Snapshots sit
-    at every ``stride_blocks``-th block boundary of a prompt."""
+    at every ``stride_blocks``-th block boundary of a prompt (the recurrent
+    spec's ``snapshot_every`` tokens)."""
 
     def __init__(
         self, capacity: int, stride_blocks: int,

@@ -354,19 +354,31 @@ class JaxEngine:
             self.window = WindowPages(
                 args.num_window_blocks, args.block_size, win_group.window
             )
+        sparse = self.config.sparse_index
+        if sparse is not None and sparse.block != args.block_size:
+            raise ValueError(
+                f"{self.config.name} selects attention blocks of {sparse.block} "
+                f"tokens and a page is the unit its kernels visit: serve it with "
+                f"--block-size {sparse.block} (got {args.block_size})"
+            )
         self.snapshots: Optional[StateSnapshots] = None
-        self._ssm_stride = 0  # tokens between snapshot boundaries
+        # Tokens a scan block of the recurrent layers holds (the device
+        # program can write the state at each), and between the boundaries
+        # the engine keeps a snapshot at.
+        self._ssm_stride = self._snap_every = 0
         if recurrent:
-            self._ssm_stride = self.config.specs_of("mamba2")[0].scan_block
+            self._ssm_stride, self._snap_every = self.config.snapshot_stride
             if args.prefill_chunk % self._ssm_stride:
                 raise ValueError(
                     f"prefill_chunk {args.prefill_chunk} is not a multiple of "
                     f"the state-space scan block {self._ssm_stride}"
                 )
-            if args.enable_prefix_caching and self._ssm_stride % args.block_size == 0:
+            if args.enable_prefix_caching and self._snap_every % args.block_size == 0:
                 self.snapshots = StateSnapshots(
-                    block_pool.SSM_SNAPSHOT_ENTRIES,
-                    self._ssm_stride // args.block_size,
+                    block_pool.snapshot_entries(
+                        self.config, args.num_kv_blocks, args.block_size,
+                        args.max_num_seqs),
+                    self._snap_every // args.block_size,
                     on_evict=self.pool.retract,
                 )
         # All device state (params, LoRA stacks, KV caches, RNG, compiled
@@ -514,6 +526,14 @@ class JaxEngine:
         from dynamo_tpu.engines.metrics import EngineStepMetrics
 
         self.step_metrics = EngineStepMetrics(inflight=self._inflight.__len__)
+        # What the sparse attention layers' decode kernel visits, summed over
+        # dispatched bursts (the counters of the same names); None for a
+        # model without such layers. The series exist from the first scrape.
+        self.sparse_attention: Optional[Dict[str, Any]] = None
+        if self.config.sparse_index is not None:
+            self.sparse_attention = {
+                "pages_selected": 0, "pages_live": 0, "rows": {"sparse": 0, "dense": 0}}
+            self.step_metrics.observe_sparse(0, 0, self.sparse_attention["rows"])
         # Device-plane observability (runtime/device_observe.py):
         # - flight: the tick loop's single-writer event ring (admit,
         #   preempt, dispatch, reap, spec tick, KV transfers, abort). The
@@ -808,8 +828,16 @@ class JaxEngine:
         if self.config.has_latent_cache:
             out["latent_pool"] = dict(self.runner.kv_pool)
             out["mla_attention"] = self.runner.mla_attention
+        if self.sparse_attention is not None:
+            out["sparse_attention"] = dict(
+                self.sparse_attention, rows=dict(self.sparse_attention["rows"]),
+                **self.runner.kv_pool.get("indexer", {}))
         if self.config.has_recurrent_state:
             snaps = self.snapshots
+            out["ssm_snapshot_spacing"] = {
+                "tokens": self._snap_every,
+                "entry_bytes": self.runner.snap_entry_bytes,
+            }
             out["ssm_state_slots"] = {
                 "used": out["active_seqs"], "total": self.args.max_num_seqs,
             }
@@ -1694,11 +1722,20 @@ class JaxEngine:
             live_pages = 0
             win = self.window
             win_live = win_held = win_dead = 0
+            sparse = self.config.sparse_index
+            picked = {"pages_selected": 0, "pages_live": 0}
+            on_path = {"sparse": 0, "dense": 0}
             for seq in active:
                 ctx = int(self._pos[seq.slot]) + inflight_off + K
                 blocks = (ctx - 1) // args.block_size + 1
                 live_pages += blocks
                 max_blocks = max(max_blocks, blocks)
+                if sparse is not None:
+                    on_sparse = ctx >= sparse.dense_len
+                    on_path["sparse" if on_sparse else "dense"] += 1
+                    picked["pages_live"] += blocks
+                    picked["pages_selected"] += (
+                        min(sparse.topk, blocks) if on_sparse else blocks)
                 if win is not None:
                     win_live += blocks - win.first_live(ctx - K)
                     win_held += win.held(seq.win_ids)
@@ -1728,6 +1765,14 @@ class JaxEngine:
         )
         if win is not None:
             self.step_metrics.observe_window_pages(win_live, win_held, win_dead)
+        if sparse is not None:
+            self.step_metrics.observe_sparse(
+                picked["pages_selected"], picked["pages_live"], on_path)
+            acc = self.sparse_attention
+            for key, n in picked.items():
+                acc[key] += n
+            for path, n in on_path.items():
+                acc["rows"][path] += n
         self._inflight.append(
             _InflightBurst(
                 handles=handles,
@@ -1808,8 +1853,8 @@ class JaxEngine:
         self.steps += 1
         gen0 = self.generated_tokens
         moe = getattr(rec.handles, "moe_host", None)
-        if moe is not None:
-            experts = self.config.specs_of("experts")
+        experts = self.config.specs_of("experts")
+        if moe is not None and experts:
             held = experts[0].n_held
             self.step_metrics.observe_moe(
                 float(moe[0]), self.args.decode_steps * len(experts) * held,

@@ -395,8 +395,10 @@ class DeviceRunner:
             from dynamo_tpu.models import hybrid
 
             self.ssm_state = hybrid.init_ssm_state(self.config, args.max_num_seqs)
-            self.snap_entries = block_pool.SSM_SNAPSHOT_ENTRIES
+            self.snap_entries = block_pool.snapshot_entries(
+                self.config, args.num_kv_blocks, args.block_size, args.max_num_seqs)
             self.snap_store = hybrid.init_ssm_state(self.config, self.snap_entries)
+            self.snap_entry_bytes = hybrid.ssm_state_bytes(self.config)
 
         # Multi-LoRA state: adapter name → index into the stacked arrays
         # (index 0 is the zero "no adapter" slot).
@@ -562,15 +564,34 @@ class DeviceRunner:
                 self.kv_pool["gb"], values.dtype.name, list(values.shape),
                 self.config.head_dim_,
             )
+        sparse = self.config.sparse_index
+        if sparse is not None:
+            n_attn = len(self.config.specs_of("attention"))
+            rows = self.k_cache[n_attn:]
+            self.kv_pool["indexer"] = {
+                "indexer_gb": round(tree_device_bytes(rows) / 1e9, 3),
+                "indexer_shape": list(rows[0].shape), "indexer_layers": len(rows),
+            }
+            logger.info(
+                "indexer rows: %.3f GB | %s%s per sparse layer x %d under the "
+                "K/V pool's block ids: %d compressed keys a page (the mean of "
+                "%d keys every %d), top %d blocks of %d tokens from %d tokens on",
+                self.kv_pool["indexer"]["indexer_gb"], rows[0].dtype.name,
+                list(rows[0].shape), len(rows), sparse.keys_per_block, sparse.kernel,
+                sparse.stride, sparse.topk, sparse.block, sparse.dense_len,
+            )
         if self.config.has_recurrent_state:
             logger.info(
                 "recurrent state: %.2f GB in %d slots, %.2f GB in %d snapshots "
-                "| %d attention, %d mamba2, %d expert layers",
+                "(one every %d tokens of a prompt, %.2f MB each) "
+                "| %d attention, %d mamba2, %d lightning, %d expert layers",
                 tree_device_bytes(self.ssm_state) / 1e9, args.max_num_seqs,
                 tree_device_bytes(self.snap_store) / 1e9,
                 jax.tree.leaves(self.snap_store)[0].shape[0],
+                self.config.snapshot_stride[1], self.snap_entry_bytes / 1e6,
                 len(self.config.specs_of("attention")),
                 len(self.config.specs_of("mamba2")),
+                len(self.config.specs_of("lightning")),
                 len(self.config.specs_of("experts")),
             )
 

@@ -44,6 +44,35 @@ class RopeLaw:
 
 
 @dataclass(frozen=True)
+class SparseIndex:
+    """Trainable block-sparse attention (InfLLM-v2, the MiniCPM4 family) as
+    data of an attention spec. Keys are mean-pooled into COMPRESSED keys
+    (windows of ``kernel`` tokens every ``stride``: the indexer's cache); a
+    query at position t whose sequence is at least ``dense_len`` long scores
+    the compressed keys whose window ends at or before t (an exact softmax),
+    sums the scores over the query heads of a K/V head, takes for each
+    ``block`` of tokens the largest score of a window that meets it, and
+    attends over ``topk`` blocks only: the first ``init_blocks``, the blocks
+    that hold the last ``window`` tokens, and the best-scored others. A
+    shorter sequence attends densely. ``kernel`` is twice ``stride`` and
+    ``block`` a multiple of it; the pool's page is one ``block``."""
+
+    kernel: int = 32
+    stride: int = 16
+    block: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window: int = 2048
+    dense_len: int = 8192
+
+    @property
+    def keys_per_block(self) -> int:
+        """Compressed keys filed with a page: those whose window's LAST
+        token lies in it."""
+        return self.block // self.stride
+
+
+@dataclass(frozen=True)
 class AttentionSpec:
     n_heads: int
     n_kv_heads: int
@@ -57,8 +86,16 @@ class AttentionSpec:
     # None with positions "rope": the model's ``rope_theta`` over the head.
     rope: Optional[RopeLaw] = None
     # A sigmoid gate per head on the attention output, from the sublayer's
-    # normed input (``w_g`` [d, heads]), before the output projection.
+    # normed input (``w_g`` [d, heads]), before the output projection;
+    # ``gate_lanes``: per lane instead (``w_g`` [d, heads * head_dim]).
     gate: bool = False
+    gate_lanes: bool = False
+    # An RMS norm (a weight of ``head_dim``) on every query and key head,
+    # before any rotation.
+    qk_norm: bool = False
+    # Block-sparse attention chosen by an indexer over compressed keys, which
+    # the layer caches beside K and V under the pool's own block ids.
+    sparse: Optional[SparseIndex] = None
     kind: str = "attention"
 
 
@@ -109,6 +146,10 @@ class Mamba2Spec:
     # it (the source's ``chunk_size`` 128 is its own kernel's block).
     scan_block: int = 64
     state_dtype: str = "float32"
+    # Tokens between the snapshot boundaries of a prompt (a multiple of
+    # ``scan_block``); None = every ``scan_block``, in a store of
+    # block_pool.SSM_SNAPSHOT_ENTRIES entries.
+    snapshot_every: Optional[int] = None
     kind: str = "mamba2"
 
     @property
@@ -122,6 +163,37 @@ class Mamba2Spec:
     @property
     def in_width(self) -> int:  # [z | xBC | dt]
         return self.d_inner + self.conv_channels + self.n_heads
+
+
+@dataclass(frozen=True)
+class LightningSpec:
+    """Lightning (linear) attention as recurrent state: per head h a matrix
+    ``S_t = lambda_h S_{t-1} + k_t^T v_t`` [head_dim, head_dim] in float32,
+    ``o_t = q_t S_t / sqrt(head_dim)``, with q/k RMS norms, rotary over all
+    lanes at ``rope_theta``, an RMS norm on ``o`` and a per-lane sigmoid gate.
+    ``lambda_h = exp(-2^(-8 (h + 1) / n_heads))`` (``slopes``). It is
+    ops/mamba2's recurrence with dt = 1, A = -slope, B = k, C = q, x = v and
+    one group a head, so prefill and decode run ``ssd_chunk_scan`` and
+    ``ssd_step``; there is no conv tail."""
+
+    n_heads: int
+    head_dim: int
+    rope_theta: float = 10000.0
+    scan_block: int = 64
+    state_dtype: str = "float32"
+    # Tokens between snapshot boundaries: an entry is n_heads * head_dim^2
+    # float32 a layer (2.1 MB at 32 heads of 128), so a long prompt keeps
+    # one every so many tokens, not one a scan block.
+    snapshot_every: Optional[int] = 4096
+    kind: str = "lightning"
+
+    @property
+    def slopes(self) -> Tuple[float, ...]:
+        n = self.n_heads
+        return tuple(2.0 ** (-8.0 * (h + 1) / n) for h in range(n))
+
+
+RECURRENT_KINDS = ("mamba2", "lightning")
 
 
 @dataclass(frozen=True)
@@ -165,7 +237,8 @@ class CacheGroup:
 
 
 LayerSpec = Union[
-    AttentionSpec, LatentAttentionSpec, Mamba2Spec, ExpertsSpec, DenseFFNSpec
+    AttentionSpec, LatentAttentionSpec, Mamba2Spec, LightningSpec, ExpertsSpec,
+    DenseFFNSpec,
 ]
 
 
@@ -236,6 +309,13 @@ class ModelConfig:
     # rope_scaling_factor, the HF rope_scaling={linear, factor} dialect).
     rope_local_theta: Optional[float] = None
     rope_scaling_factor: Optional[float] = None
+    # MiniCPM's scalings (hybrid models only; 1.0 = none): the embedding is
+    # multiplied by ``embed_multiplier``, every sublayer's output by
+    # ``residual_multiplier`` before the residual add, and the final hidden
+    # state divided by ``logit_divisor`` before the head.
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logit_divisor: float = 1.0
 
     @property
     def head_dim_(self) -> int:
@@ -268,10 +348,43 @@ class ModelConfig:
     def specs_of(self, kind: str) -> List[LayerSpec]:
         return [s for s in (self.layer_specs or ()) if s.kind == kind]
 
+    @cached_property
+    def recurrent_specs(self) -> List[LayerSpec]:
+        """The layers whose sequences carry state beside the paged pools
+        (Mamba-2, lightning attention), in layer order."""
+        return [s for s in (self.layer_specs or ()) if s.kind in RECURRENT_KINDS]
+
     @property
     def has_recurrent_state(self) -> bool:
-        """Sequences carry state beside the paged pools (Mamba-2 layers)."""
-        return bool(self.specs_of("mamba2"))
+        return bool(self.recurrent_specs)
+
+    @property
+    def snapshot_stride(self) -> Tuple[int, int]:
+        """(scan block, tokens between snapshot boundaries) of the recurrent
+        layers; one of each a model."""
+        specs = self.recurrent_specs
+        strides = {(s.scan_block, s.snapshot_every or s.scan_block) for s in specs}
+        if len(strides) != 1:
+            raise ValueError(
+                f"{self.name}: recurrent layers with scan blocks and snapshot "
+                f"spacings {sorted(strides)}: one of each a model is implemented"
+            )
+        scan, every = next(iter(strides))
+        if every % scan:
+            raise ValueError(
+                f"{self.name}: snapshot spacing {every} is not a multiple of "
+                f"the scan block {scan}"
+            )
+        return scan, every
+
+    @cached_property
+    def sparse_index(self) -> Optional[SparseIndex]:
+        """The indexer sizes of the model's sparse attention layers (one set
+        a model), or None."""
+        found = {s.sparse for s in self.specs_of("attention") if s.sparse}
+        if len(found) > 1:
+            raise ValueError(f"{self.name}: one set of sparse sizes a model")
+        return next(iter(found)) if found else None
 
     @property
     def has_latent_cache(self) -> bool:
@@ -322,6 +435,20 @@ class ModelConfig:
         pool that is one latent tile a layer."""
         mla = self.specs_of("mla")
         win = self.window_group
+        sparse = [s for s in self.specs_of("attention") if s.sparse]
+        if sparse:
+            return (
+                f"{mechanism} moves a (K, V) pair of paged blocks per layer, "
+                f"and {self.name} keeps a third array under the same block "
+                f"ids in {len(sparse)} sparse-attention layers (the indexer's "
+                f"compressed keys, {sparse[0].sparse.keys_per_block} a page, "
+                "which the selection of every later query reads)"
+                + (
+                    f" beside recurrent state in {len(self.recurrent_specs)} "
+                    "lightning-attention layers" if self.recurrent_specs else ""
+                )
+                + f": {mechanism} carries neither"
+            )
         if win is not None:
             return (
                 f"{mechanism} moves one list of paged K/V blocks per sequence, "
@@ -340,8 +467,15 @@ class ModelConfig:
                 f"in {len(mla)} latent-attention layers: there is no K and no "
                 f"V block for {mechanism} to move"
             )
-        if not self.specs_of("mamba2"):
+        if not self.recurrent_specs:
             return None
+        if not self.specs_of("mamba2"):
+            return (
+                f"{mechanism} moves paged K/V blocks only, and {self.name} "
+                f"keeps per-sequence recurrent state (a float32 matrix a head) "
+                f"in {len(self.recurrent_specs)} lightning-attention layers "
+                f"that {mechanism} does not carry"
+            )
         return (
             f"{mechanism} moves paged K/V blocks only, and {self.name} keeps "
             f"per-sequence recurrent state (conv tail + SSM state) in "
@@ -375,6 +509,8 @@ class ModelConfig:
             return _pangu_ultra_moe_from_hf(cfg, name)
         if str(cfg.get("model_type", "")) == "laguna":
             return _laguna_from_hf(cfg, name)
+        if str(cfg.get("model_type", "")) == "minicpm_sala":
+            return _minicpm_sala_from_hf(cfg, name)
         archs = cfg.get("architectures") or [""]
         arch = archs[0].lower()
         eos = cfg.get("eos_token_id")
@@ -784,6 +920,151 @@ def laguna_xs2_pp8_config() -> ModelConfig:
     )
 
 
+# What the MiniCPM4 family publishes as ``sparse_config`` and the MiniCPM-SALA
+# config.json does not carry (benchmark/configs/minicpm-sala-pp4.json lists
+# the same under ``assumed``).
+MINICPM4_SPARSE = SparseIndex(
+    kernel=32, stride=16, block=64, topk=64, init_blocks=1, window=2048,
+    dense_len=8192,
+)
+
+
+def _minicpm_sala_from_hf(
+    cfg: Dict[str, Any], name: str = "", *, sparse: SparseIndex = MINICPM4_SPARSE,
+) -> ModelConfig:
+    """``minicpm_sala``: every published layer is a mixer (``mixer_types``:
+    ``minicpm4`` block-sparse GQA without rotary, or ``lightning-attn``
+    linear attention), then a gated-silu FFN; two entries of ``layer_specs``
+    a layer. MiniCPM's scalings: embeddings x ``scale_emb``, every sublayer's
+    output x ``scale_depth / sqrt(mup_denominator)`` (the PUBLISHED depth,
+    whatever ``num_hidden_layers`` is cut to), the final hidden state /
+    (``hidden_size / dim_model_base``). Assumed, where the config names a
+    switch and not its shape: the output gates are per lane; the lightning
+    slopes are ALiBi's (``LightningSpec.slopes``), the same in every layer;
+    the sparse sizes are the MiniCPM4 family's
+    (models/minicpm_sala_reference.py states the same)."""
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"minicpm_sala: hidden_act {cfg['hidden_act']!r} is not implemented")
+    if cfg.get("attention_bias"):
+        raise ValueError("minicpm_sala: attention_bias is not implemented")
+    n = int(cfg["num_hidden_layers"])
+    kinds = list(cfg["mixer_types"])[:n]
+    hd = int(cfg["head_dim"])
+    attn = AttentionSpec(
+        n_heads=int(cfg["num_attention_heads"]),
+        n_kv_heads=int(cfg["num_key_value_heads"]), head_dim=hd,
+        positions="rope" if cfg.get("attn_use_rope", False) else "none",
+        gate=bool(cfg.get("attn_use_output_gate", False)), gate_lanes=True,
+        qk_norm=bool(cfg.get("qk_norm", False)), sparse=sparse,
+    )
+    if attn.positions != "none":
+        raise ValueError("minicpm_sala: rotary sparse-attention layers are not implemented")
+    if int(cfg.get("lightning_nkv", cfg["lightning_nh"])) != int(cfg["lightning_nh"]):
+        raise ValueError("minicpm_sala: grouped lightning K/V heads are not implemented")
+    for key in ("qk_norm", "use_output_gate", "use_output_norm", "lightning_use_rope"):
+        if not cfg.get(key, True):
+            raise ValueError(f"minicpm_sala: {key} false is not implemented")
+    lightning = LightningSpec(
+        n_heads=int(cfg["lightning_nh"]), head_dim=int(cfg["lightning_head_dim"]),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+    )
+    ffn = DenseFFNSpec(d_ff=int(cfg["intermediate_size"]))
+    mixers = {"minicpm4": attn, "lightning-attn": lightning}
+    unknown = sorted(set(kinds) - set(mixers))
+    if unknown:
+        raise ValueError(f"minicpm_sala: mixer types {unknown} are not implemented")
+    specs: List[LayerSpec] = []
+    for kind in kinds:
+        specs += [mixers[kind], ffn]
+    d = int(cfg["hidden_size"])
+    eos = cfg.get("eos_token_id")
+    return ModelConfig(
+        vocab_size=int(cfg["vocab_size"]), d_model=d, n_layers=len(specs),
+        n_heads=attn.n_heads, n_kv_heads=attn.n_kv_heads, head_dim=hd, d_ff=ffn.d_ff,
+        rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        max_position_embeddings=int(cfg.get("max_position_embeddings", 8192)),
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        eos_token_ids=[] if eos is None else [int(e) for e in (eos if isinstance(eos, list) else [eos])],
+        bos_token_id=cfg.get("bos_token_id"),
+        name=name or "minicpm_sala", layer_specs=tuple(specs),
+        embed_multiplier=float(cfg.get("scale_emb", 1.0)),
+        residual_multiplier=float(cfg.get("scale_depth", 1.0))
+        / float(cfg.get("mup_denominator", 1.0)) ** 0.5,
+        logit_divisor=d / float(cfg.get("dim_model_base", d)),
+    )
+
+
+# MiniCPM-SALA, the keys of its public config.json that say something about
+# its shape (https://huggingface.co/openbmb/MiniCPM-SALA/blob/main/config.json).
+_SALA_MIXERS = "SLLLLLLLLSLLLLLLSSLLLLSLLLLLLSSS"
+MINICPM_SALA_HF: Dict[str, Any] = {
+    "model_type": "minicpm_sala", "attention_bias": False, "attn_use_rope": False,
+    "head_dim": 128, "hidden_act": "silu", "hidden_size": 4096,
+    "intermediate_size": 16384, "lightning_head_dim": 128, "lightning_nh": 32,
+    "lightning_nkv": 32, "lightning_scale": "1/sqrt(d)", "lightning_use_rope": True,
+    "max_position_embeddings": 524288,
+    "mixer_types": [
+        "minicpm4" if ch == "S" else "lightning-attn" for ch in _SALA_MIXERS],
+    "num_attention_heads": 32, "num_hidden_layers": 32, "num_key_value_heads": 2,
+    "qk_norm": True, "rand_init": False, "rms_norm_eps": 1e-06, "vocab_size": 73448,
+    "rope_theta": 10000, "scale_emb": 12, "scale_depth": 1.4, "mup_denominator": 32,
+    "dim_model_base": 256, "tie_word_embeddings": False, "use_output_gate": True,
+    "use_output_norm": True, "attn_use_output_gate": True,
+}
+# The served stage: the published layers 9..16, two whole periods at 1 : 3.
+SALA_PP4_LAYERS = (9, 17)
+
+
+def minicpm_sala_pp4_config() -> ModelConfig:
+    """MiniCPM-SALA at its published widths, as one pipeline stage of eight
+    layers of a v5e-4 host (four stages, every layer whole on its chip): the
+    published layers 9..16, ``minicpm4, lightning x 6, minicpm4``, two whole
+    periods in the published 1 : 3 ratio (no stage-aligned span of eight has
+    it: the stages hold 1 : 7, 1 : 7, 3 : 5, 3 : 5). Sixteen sublayers;
+    embedding and head both sit here so that the stage takes ids and yields
+    logits. The residual scale keeps the PUBLISHED depth's 1.4 / sqrt(32)."""
+    lo, hi = SALA_PP4_LAYERS
+    hf = dict(
+        MINICPM_SALA_HF, num_hidden_layers=hi - lo,
+        mixer_types=MINICPM_SALA_HF["mixer_types"][lo:hi],
+    )
+    return dataclasses.replace(
+        ModelConfig.from_hf_config(hf), name="minicpm-sala-pp4",
+        max_position_embeddings=524288,
+    )
+
+
+def tiny_sala_config(**overrides) -> ModelConfig:
+    """The served MiniCPM-SALA stage at toy widths (tests, the CPU
+    rehearsal): 2 sparse + 6 lightning layers in the cell's order, 4 query
+    over 2 K/V heads of 32, indexer window 8 / stride 4, blocks of 16, top 4
+    of which 1 leading and the last 32 tokens' are forced, dense under 64
+    tokens, so that the sparse path is taken at a few hundred tokens;
+    lightning heads 4 x 32, snapshots every 64 tokens."""
+    sparse = SparseIndex(
+        kernel=8, stride=4, block=16, topk=4, init_blocks=1, window=32, dense_len=64,
+    )
+    attn = AttentionSpec(
+        n_heads=4, n_kv_heads=2, head_dim=32, positions="none", gate=True,
+        gate_lanes=True, qk_norm=True, sparse=sparse,
+    )
+    lightning = LightningSpec(n_heads=4, head_dim=32, scan_block=16, snapshot_every=64)
+    ffn = DenseFFNSpec(d_ff=128)
+    specs: List[LayerSpec] = []
+    for mixer in (attn,) + (lightning,) * 6 + (attn,):
+        specs += [mixer, ffn]
+    base = dict(
+        vocab_size=512, d_model=64, n_layers=len(specs), n_heads=4, n_kv_heads=2,
+        head_dim=32, d_ff=128, max_position_embeddings=4096, eos_token_ids=[2],
+        rms_norm_eps=1e-6, rope_theta=10000.0, dtype=jnp.float32, name="tiny-sala",
+        layer_specs=tuple(specs), embed_multiplier=12.0,
+        residual_multiplier=1.4 / 32**0.5, logit_divisor=64 / 16,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
 def tiny_swa_config(n_layers: int = 5, **overrides) -> ModelConfig:
     """The Laguna layer at toy widths (tests, the CPU rehearsal): the period
     F S S S from a dense layer 0 on (``n_layers`` 5 ends on a full layer, as
@@ -1055,6 +1336,7 @@ def all_presets() -> Dict[str, "ModelConfig"]:
         gemma2_2b_config(), tiny_hybrid_config(), nemotron3_nano_ep2_config(),
         tiny_mla_config(), openpangu_ultra_moe_ep16_config(),
         tiny_swa_config(), laguna_xs2_pp8_config(),
+        tiny_sala_config(), minicpm_sala_pp4_config(),
     ]
     return {c.name: c for c in presets}
 

@@ -16,12 +16,19 @@ caches ONE row a token a layer, ``c_kv`` beside the rotary key all heads
 share: ``k_cache[i]`` is then the i-th latent layer's pool
 ``[blocks, block, width]`` and ``v_cache`` is empty.
 
+A sparse-attention layer (``AttentionSpec.sparse``: ``minicpm_sala``) caches
+a third array, its indexer's compressed keys, under the K/V pool's own block
+ids: those pools follow the attention layers' in ``k_cache``
+(ops/sparse_attention.py). MiniCPM's scalings (``embed_multiplier``,
+``residual_multiplier``, ``logit_divisor``) apply where the config sets them.
+
 Two kinds of state travel with a sequence. K/V pages exist only for the
 attention layers: ``k_cache[i]`` belongs to the i-th ATTENTION layer. The
-recurrent state of the Mamba-2 layers is ``{"conv": (...), "S": (...)}``,
-one entry per Mamba-2 layer, batch-major: the conv tail ``[B, K-1, channels]``
-in the model's dtype and the SSM state ``[B, H, P, N]`` in the spec's
-``state_dtype`` (float32). A prefill chunk starts from the state it is
+recurrent state of the Mamba-2 and lightning-attention layers is ``{"conv":
+(...), "S": (...)}``, batch-major: ``S`` one entry per recurrent layer, the
+SSM state ``[B, H, P, N]`` (lightning: ``[B, H, D value, D key]``) in the
+spec's ``state_dtype`` (float32); ``conv`` one entry per Mamba-2 layer, the
+conv tail ``[B, K-1, channels]`` in the model's dtype. A prefill chunk starts from the state it is
 given and returns the state after each row's last real token; positions
 past ``chunk_lens`` leave it untouched. It can also write the state at the
 end of every ``scan_block`` tokens into a snapshot store
@@ -53,6 +60,11 @@ from dynamo_tpu.ops.attention import (
 )
 from dynamo_tpu.ops.moe import moe_ffn
 from dynamo_tpu.ops.rope import apply_rope, rope_table, rope_table_for
+from dynamo_tpu.ops.sparse_attention import (
+    compressed_pool,
+    sparse_paged_attention,
+    write_compressed_keys,
+)
 
 Params = Dict[str, Any]
 _F32 = jnp.float32
@@ -111,7 +123,21 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                 wv=norm(k[2], (d, hk), d**-0.5), wo=norm(k[3], (hq, d), hq**-0.5),
             )
             if spec.gate:
-                lp["w_gate_attn"] = norm(k[4], (d, spec.n_heads), d**-0.5)
+                lp["w_gate_attn"] = norm(
+                    k[4], (d, hq if spec.gate_lanes else spec.n_heads), d**-0.5)
+            if spec.qk_norm:
+                lp.update(q_norm=jnp.ones((spec.head_dim,), c.dtype),
+                          k_norm=jnp.ones((spec.head_dim,), c.dtype))
+        elif spec.kind == "lightning":
+            hq = spec.n_heads * spec.head_dim
+            lp.update(
+                wq=norm(k[0], (d, hq), d**-0.5), wk=norm(k[1], (d, hq), d**-0.5),
+                wv=norm(k[2], (d, hq), d**-0.5), wo=norm(k[3], (hq, d), hq**-0.5),
+                w_gate_attn=norm(k[4], (d, hq), d**-0.5),
+                q_norm=jnp.ones((spec.head_dim,), c.dtype),
+                k_norm=jnp.ones((spec.head_dim,), c.dtype),
+                o_norm=jnp.ones((spec.head_dim,), c.dtype),
+            )
         elif spec.kind == "mamba2":
             H, di = spec.n_heads, spec.d_inner
             dt = jnp.exp(
@@ -173,7 +199,10 @@ def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int,
     row at whole lane tiles (ops/attention.latent_pool_width). Where the
     model has a window page group (``config.window_group``) its layers'
     pools hold ``window_blocks`` blocks, the other layers' ``num_blocks``:
-    two pool shapes, two id spaces."""
+    two pool shapes, two id spaces. A sparse-attention layer's compressed
+    keys (ops/sparse_attention.py: the indexer's cache, under the K/V pool's
+    own block ids) follow the attention layers' K pools in the first tuple,
+    one a sparse layer in layer order."""
     latent = config.specs_of("mla")
     if latent:
         if config.specs_of("attention"):
@@ -194,27 +223,35 @@ def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int,
         shape = (blocks, block_size, spec.n_kv_heads, pool_head_dim(spec.head_dim))
         k.append(jnp.zeros(shape, config.dtype))
         v.append(jnp.zeros(shape, config.dtype))
+    for spec in config.specs_of("attention"):
+        if spec.sparse is not None:
+            k.append(compressed_pool(
+                num_blocks, spec.sparse, spec.n_kv_heads, spec.head_dim, config.dtype))
     return tuple(k), tuple(v)
 
 
 def init_ssm_state(config: ModelConfig, rows: int) -> Dict[str, Tuple[jnp.ndarray, ...]]:
-    """Zeroed recurrent state for ``rows`` sequences (or snapshot entries)."""
+    """Zeroed recurrent state for ``rows`` sequences (or snapshot entries):
+    ``S`` one matrix stack per recurrent layer in layer order (Mamba-2
+    [rows, H, P, N]; lightning attention [rows, H, D value, D key]), ``conv``
+    one tail per Mamba-2 layer (lightning attention has none)."""
     conv, S = [], []
-    for spec in config.specs_of("mamba2"):
-        conv.append(
-            jnp.zeros((rows, spec.conv_kernel - 1, spec.conv_channels), config.dtype)
-        )
+    for spec in config.recurrent_specs:
+        if spec.kind == "mamba2":
+            conv.append(
+                jnp.zeros((rows, spec.conv_kernel - 1, spec.conv_channels), config.dtype)
+            )
+        width = spec.state_size if spec.kind == "mamba2" else spec.head_dim
         S.append(
             jnp.zeros(
-                (rows, spec.n_heads, spec.head_dim, spec.state_size),
-                jnp.dtype(spec.state_dtype),
+                (rows, spec.n_heads, spec.head_dim, width), jnp.dtype(spec.state_dtype)
             )
         )
     return {"conv": tuple(conv), "S": tuple(S)}
 
 
 def ssm_state_bytes(config: ModelConfig) -> int:
-    """Bytes of one sequence's recurrent state over all Mamba-2 layers."""
+    """Bytes of one sequence's recurrent state over all recurrent layers."""
     return sum(
         a.size * a.dtype.itemsize
         for a in jax.tree.leaves(jax.eval_shape(lambda: init_ssm_state(config, 1)))
@@ -301,32 +338,95 @@ def _window_view(block_tables, start_pos, window: int, C: int, block_size: int,
     )
 
 
+def _gated(lp, h, attn, per_lane):
+    """The sigmoid output gate from the sublayer's normed input, per head or
+    per lane (``w_gate_attn`` [d, H] or [d, H D]). attn [B, C, H, D]."""
+    g = jax.nn.sigmoid(
+        jnp.einsum("bcd,dh->bch", h, lp["w_gate_attn"], preferred_element_type=_F32)
+    )
+    out = attn.astype(_F32)
+    return (out * (g.reshape(attn.shape) if per_lane else g[..., None])).astype(attn.dtype)
+
+
 def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_lens,
-                     rope, *, use_kernel, first_chunk, plan, write_tables=None):
+                     rope, *, use_kernel, first_chunk, plan, write_tables=None,
+                     kc_c=None, want_selection=False):
+    """Returns (out, k pool, v pool) and, for a sparse layer (``kc_c`` its
+    compressed keys), also (compressed pool, selection or None)."""
     B, C, _ = h.shape
     hd = spec.head_dim
     q = jnp.einsum("bcd,dh->bch", h, lp["wq"]).reshape(B, C, spec.n_heads, hd)
     k = jnp.einsum("bcd,dh->bch", h, lp["wk"]).reshape(B, C, spec.n_kv_heads, hd)
     v = jnp.einsum("bcd,dh->bch", h, lp["wv"]).reshape(B, C, spec.n_kv_heads, hd)
+    if spec.qk_norm:
+        q = _rms(q, lp["q_norm"], c.rms_norm_eps)
+        k = _rms(k, lp["k_norm"], c.rms_norm_eps)
     if spec.positions == "rope":
         q, k = apply_rope(q, *rope), apply_rope(k, *rope)
     wt = block_tables if write_tables is None else write_tables
     k_c = write_chunk_to_cache(k_c, k, wt, start_pos, chunk_lens)
     v_c = write_chunk_to_cache(v_c, v, wt, start_pos, chunk_lens)
     win = jnp.asarray(spec.window, jnp.int32)
-    if first_chunk:
+    picked = None
+    if spec.sparse is not None:
+        with jax.named_scope("sparse_index"):
+            kc_c = write_compressed_keys(
+                kc_c, k_c, block_tables, start_pos, chunk_lens, C, spec.sparse)
+    # A fresh chunk attends over its own registers; a sparse layer's only
+    # where no query of the chunk reaches ``dense_len``.
+    if first_chunk and (spec.sparse is None or C < spec.sparse.dense_len) and not want_selection:
         attn = dense_chunk_attention(q, k, v, chunk_lens, sm_scale=hd**-0.5, window=win)
+    elif spec.sparse is not None:
+        attn = sparse_paged_attention(
+            q, k_c, v_c, kc_c, block_tables, start_pos, chunk_lens, spec.sparse,
+            sm_scale=hd**-0.5, use_kernel=use_kernel, want_selection=want_selection,
+        )
+        if want_selection:
+            attn, picked = attn
     else:
         attn = paged_attention(
             q, k_c, v_c, block_tables, start_pos, chunk_lens, use_kernel=use_kernel,
             sm_scale=hd**-0.5, window=win, plan=plan,
         )
     if spec.gate:
-        g = jax.nn.sigmoid(
-            jnp.einsum("bcd,dh->bch", h, lp["w_gate_attn"], preferred_element_type=_F32)
-        )
-        attn = (attn.astype(_F32) * g[..., None]).astype(attn.dtype)
-    return jnp.einsum("bch,hd->bcd", attn.reshape(B, C, -1), lp["wo"]), k_c, v_c
+        attn = _gated(lp, h, attn, spec.gate_lanes)
+    out = jnp.einsum("bch,hd->bcd", attn.reshape(B, C, -1), lp["wo"])
+    if spec.sparse is not None:
+        return out, k_c, v_c, kc_c, picked
+    return out, k_c, v_c
+
+
+def _lightning_mixer(c, spec, lp, h, chunk_lens, rope, S, snap_dst, snap_S):
+    """Lightning attention (config.LightningSpec). h [B, C, d] -> (out, S',
+    snapshot stack'). The recurrence is ops/mamba2's with dt = 1 on real
+    positions (0 on padding: the state stays), A = -slope, B = k, C = q,
+    x = v; C == 1 with ``snap_dst`` None is the decode step."""
+    B, C, _ = h.shape
+    H, D = spec.n_heads, spec.head_dim
+    q = jnp.einsum("bcd,dh->bch", h, lp["wq"]).reshape(B, C, H, D)
+    k = jnp.einsum("bcd,dh->bch", h, lp["wk"]).reshape(B, C, H, D)
+    v = jnp.einsum("bcd,dh->bch", h, lp["wv"]).reshape(B, C, H, D)
+    q = _rms(q, lp["q_norm"], c.rms_norm_eps)
+    k = _rms(k, lp["k_norm"], c.rms_norm_eps)
+    q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+    real = jax.lax.broadcasted_iota(jnp.int32, (B, C), 1) < chunk_lens[:, None]
+    dt = jnp.broadcast_to(real[..., None].astype(_F32), (B, C, H))
+    A = -jnp.asarray(spec.slopes, _F32)
+    qs = q.astype(_F32) * D**-0.5
+    if C == 1 and snap_dst is None:
+        y, S_new = m2.ssd_step(v[:, 0], dt[:, 0], A, k[:, 0], qs[:, 0], S)
+        y = y[:, None]
+    else:
+        y, ends = m2.ssd_chunk_scan(v, dt, A, k, qs, S, chunk=spec.scan_block)
+        ends = ends.astype(S.dtype)
+        S_new = ends[:, -1]
+        if snap_dst is not None:
+            nb = C // spec.scan_block
+            snap_S = snap_S.at[snap_dst.reshape(B * nb)].set(
+                ends.reshape((B * nb,) + ends.shape[2:]), mode="drop")
+    y = _rms(y, lp["o_norm"], c.rms_norm_eps).astype(c.dtype)  # [B, C, H, D]
+    y = _gated(lp, h, y, True)
+    return jnp.einsum("bch,hd->bcd", y.reshape(B, C, H * D), lp["wo"]), S_new, snap_S
 
 
 def _mla_mixer(c, spec, lp, h, pool, block_tables, start_pos, chunk_lens, rope,
@@ -380,6 +480,8 @@ _SCOPES = {"mla": "mixer_mla", "dense_ffn": "ffn_dense"}
 def _scope(c, spec) -> str:
     if spec.kind == "attention" and c.window_group is not None:
         return "mixer_attention_window" if spec.window else "mixer_attention_full"
+    if spec.kind == "attention" and spec.sparse is not None:
+        return "mixer_sparse_attention"
     return _SCOPES.get(spec.kind, f"mixer_{spec.kind}")
 
 
@@ -402,14 +504,20 @@ def forward(
     all_logits: bool = False,
     snap: Optional[Dict[str, Any]] = None,
     want_moe_stats: bool = False,
+    want_selection: bool = False,
 ):
     """One step over a chunk: (logits, k_cache, v_cache, ssm', snap store' or
     None, expert-load stats float32 [3] summed over the expert layers or
     None). ``chunk_lens`` marks the real tokens of each row; a row of
-    length 0 changes nothing of its own."""
+    length 0 changes nothing of its own. ``want_selection`` (tests, the
+    benchmark's reference) replaces the last entry by what every sparse
+    layer's indexer selected, ``ops/sparse_attention.select_blocks``'s
+    triple a layer."""
     c = config
     B, C = tokens.shape
     x = params["embed"][tokens].astype(c.dtype)
+    if c.embed_multiplier != 1.0:
+        x = x * jnp.asarray(c.embed_multiplier, c.dtype)
     rope = None
     latent = c.specs_of("mla")
     attn_specs = c.specs_of("attention")
@@ -421,6 +529,12 @@ def forward(
     # One rope table per distinct law and one view + plan per distinct
     # (page group, window, queries a K/V head), not per layer.
     ropes = {law: rope_table_for(pos, law) for law in {s.rope for s in attn_specs if s.rope}}
+    for s in c.specs_of("lightning"):  # one table per (head, theta), not per layer
+        if ("lightning", s.head_dim, s.rope_theta) not in ropes:
+            ropes["lightning", s.head_dim, s.rope_theta] = rope_table(
+                pos, s.head_dim, s.rope_theta)
+    n_attn = len(attn_specs)
+    selections = []
     group_of = {}  # attention layer -> its page group's index
     for g, group in enumerate(c.cache_groups):
         group_of.update(dict.fromkeys(group.layers, g))
@@ -438,6 +552,8 @@ def forward(
                 _window_view(table, start_pos, s.window, C, k_cache[i].shape[1],
                              k_cache[i].shape[0]))
         key = (g, s.window, s.n_heads)
+        if s.sparse is not None:
+            continue  # a sparse layer's grids follow its own selection
         if not first_chunk and key not in plans:
             table, _, start = views[g, s.window]
             plans[key] = paged_attention_plan(
@@ -450,7 +566,7 @@ def forward(
     store_conv = None if store is None else list(store["conv"])
     store_S = None if store is None else list(store["S"])
     stats = jnp.zeros((3,), _F32)
-    ia = im = 0
+    ia = im = ir = isp = 0
     for spec, lp in zip(c.layer_specs, params["layers"]):
         h = _rms(x, lp["norm"], c.rms_norm_eps)
         with jax.named_scope(_scope(c, spec)):
@@ -465,25 +581,44 @@ def forward(
             elif spec.kind == "attention":
                 g = group_of.get(ia, 0)
                 table, wtable, start = views[g, spec.window]
-                out, k_out[ia], v_out[ia] = _attention_mixer(
+                sparse = {} if spec.sparse is None else dict(
+                    kc_c=k_cache[n_attn + isp], want_selection=want_selection)
+                out, k_out[ia], v_out[ia], *more = _attention_mixer(
                     c, spec, lp, h, k_cache[ia], v_cache[ia], table, start,
                     chunk_lens, ropes.get(spec.rope, rope), use_kernel=use_kernel,
                     first_chunk=first_chunk,
                     plan=plans.get((g, spec.window, spec.n_heads)), write_tables=wtable,
+                    **sparse,
                 )
+                if more:
+                    k_out[n_attn + isp] = more[0]
+                    selections.append(more[1])
+                    isp += 1
                 ia += 1
             elif spec.kind == "mamba2":
                 one = None if store is None else {
-                    "conv": store_conv[im], "S": store_S[im]}
+                    "conv": store_conv[im], "S": store_S[ir]}
                 out, cv, S, one = _mamba_mixer(
-                    c, spec, lp, h, chunk_lens, ssm["conv"][im], ssm["S"][im],
+                    c, spec, lp, h, chunk_lens, ssm["conv"][im], ssm["S"][ir],
                     None if snap is None else snap["dst"], one,
                 )
                 conv_out.append(cv)
                 s_out.append(S)
                 if one is not None:
-                    store_conv[im], store_S[im] = one["conv"], one["S"]
+                    store_conv[im], store_S[ir] = one["conv"], one["S"]
                 im += 1
+                ir += 1
+            elif spec.kind == "lightning":
+                out, S, one = _lightning_mixer(
+                    c, spec, lp, h, chunk_lens,
+                    ropes["lightning", spec.head_dim, spec.rope_theta], ssm["S"][ir],
+                    None if snap is None else snap["dst"],
+                    None if store is None else store_S[ir],
+                )
+                s_out.append(S)
+                if store is not None:
+                    store_S[ir] = one
+                ir += 1
             else:
                 if want_moe_stats:
                     out, st = moe_ffn(
@@ -495,6 +630,8 @@ def forward(
                     out = moe_ffn(h, lp, spec, row_mask=real, use_kernel=use_kernel)
             if "post_norm" in lp:
                 out = _rms(out.astype(x.dtype), lp["post_norm"], c.rms_norm_eps)
+        if c.residual_multiplier != 1.0:
+            out = out * jnp.asarray(c.residual_multiplier, out.dtype)
         x = x + out.astype(x.dtype)
     ssm_new = {"conv": tuple(conv_out), "S": tuple(s_out)}
     store_new = None if store is None else {
@@ -504,9 +641,13 @@ def forward(
         x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     with jax.named_scope("lm_head"):
         x = _rms(x, params["final_norm"], c.rms_norm_eps)
+        if c.logit_divisor != 1.0:
+            x = x * jnp.asarray(1.0 / c.logit_divisor, x.dtype)
         logits = jnp.einsum(
             "...d,dv->...v", x, params["lm_head"], preferred_element_type=_F32
         )
+    if want_selection:
+        return logits, tuple(k_out), tuple(v_out), ssm_new, store_new, selections
     return (
         logits, tuple(k_out), tuple(v_out), ssm_new, store_new,
         stats if want_moe_stats else None,

@@ -249,6 +249,7 @@ def _decode_kernel(
     group_pages: int,
     logit_cap: float = 0.0,
     quantized: bool = False,
+    head_pages: bool = False,
 ):
     """Live-span kernel for decode (C=1) and SHORT chunks (C ≤ 8, the
     speculative-verify shape). The grid is ONE axis of work steps whose
@@ -273,14 +274,23 @@ def _decode_kernel(
 
     Query rows per head are (c, g) pairs, c-major; key t is visible to
     row (c, g) iff t <= start + c (the chunk's own K/V are already in the
-    cache, as in the generic kernel)."""
+    cache, as in the generic kernel).
+
+    ``head_pages``: every K/V head has a table of its own ([B, KH, P]: the
+    pages a sparse layer's indexer selected for that head), so a step holds
+    S pages PER HEAD and head h reads its own (``selected_pages_attention``
+    says what the positions then mean)."""
     S = group_pages
     stride = 4 if quantized else 2
-    kv_refs = refs[: stride * S]
-    o_ref = refs[stride * S]
-    m_ref, l_ref, acc_ref = refs[stride * S + 1 :]
-
     KH = q_ref.shape[1]
+    n_ops = stride * S * (KH if head_pages else 1)
+    kv_refs = refs[:n_ops]
+    o_ref = refs[n_ops]
+    m_ref, l_ref, acc_ref = refs[n_ops + 1 :]
+
+    def at(s, h):  # the first operand of page s as head h reads it
+        return stride * (s * KH + h) if head_pages else stride * s
+
     CG = q_ref.shape[2]
     D = q_ref.shape[3]  # the head; a page's lanes past it are padding
     G = n_groups
@@ -316,7 +326,7 @@ def _decode_kernel(
         q = q_ref[0, h].astype(jnp.float32)  # [CG, D]
         scores = []
         for s in range(S):
-            k = kv_refs[stride * s][0, :, h, :D].astype(jnp.float32)
+            k = kv_refs[at(s, h)][0, :, h, :D].astype(jnp.float32)
             s_mat = (
                 jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
@@ -327,7 +337,7 @@ def _decode_kernel(
             if quantized:
                 # Per-token scales ride the score/prob rows instead of
                 # touching the [bs, D] pages (ops/kv_quant.py layout).
-                s_mat = s_mat * kv_refs[stride * s + 1][0, h][None, :]
+                s_mat = s_mat * kv_refs[at(s, h) + 1][0, h][None, :]
             if logit_cap > 0.0:
                 s_mat = logit_cap * jnp.tanh(s_mat / logit_cap)
             scores.append(jnp.where(visible[s], s_mat, NEG_INF))
@@ -342,8 +352,8 @@ def _decode_kernel(
             probs = jnp.exp(s_mat - m_new)
             l_new = l_new + jnp.sum(probs, axis=-1, keepdims=True)
             if quantized:
-                probs = probs * kv_refs[stride * s + 3][0, h][None, :]
-            v = kv_refs[stride * s + stride // 2][0, :, h, :D].astype(
+                probs = probs * kv_refs[at(s, h) + 3][0, h][None, :]
+            v = kv_refs[at(s, h) + stride // 2][0, :, h, :D].astype(
                 jnp.float32
             )
             acc = acc + jax.lax.dot_general(
@@ -417,6 +427,30 @@ def decode_plan(
     return DecodePlan(tables, pcount, poff, step_row, step_page, total)
 
 
+def _selected_group_pages(k_cache, table_width: int) -> int:
+    """Pages a grid step visits PER K/V HEAD where every head has its own
+    table: the same VMEM as a step of the one-table form."""
+    n_kv_heads = k_cache.shape[2]
+    return max(1, _decode_group_pages(k_cache, table_width) // n_kv_heads)
+
+
+def selected_plan(k_cache, head_tables, n_pages) -> DecodePlan:
+    """The live-span kernel's grid over SELECTED pages: ``head_tables``
+    [B, KH, W] names, for each row and K/V head, the ``n_pages`` [B] pages
+    its query attends over, in ascending order of position; a row with
+    ``n_pages`` 0 is no grid step."""
+    W = head_tables.shape[-1]
+    pcount = n_pages.astype(jnp.int32)
+    poff = jnp.zeros_like(pcount)
+    total, step_row, step_page = live_work_list(
+        pcount, poff, _selected_group_pages(k_cache, W), W
+    )
+    tables = jnp.where(
+        (pcount > 0)[:, None, None], head_tables.astype(jnp.int32), 0
+    )
+    return DecodePlan(tables, pcount, poff, step_row, step_page, total)
+
+
 def _paged_attention_decode_kernel_impl(
     q: jnp.ndarray,  # [B, C, n_heads, head_dim], C <= 8
     k_cache,  # [num_blocks, block_size, KH, >= D] — or {"q8", "s"} int8 pool
@@ -450,11 +484,17 @@ def _paged_attention_decode_kernel_impl(
     G = n_heads // n_kv_heads
     CG = C * G
     scale = sm_scale if sm_scale is not None else head_dim**-0.5
-    S = _decode_group_pages(k_cache, block_tables.shape[1])
     if plan is None:
         plan = decode_plan(
             k_cache, block_tables, start_pos, chunk_lens, C, window
         )
+    # A plan over selected pages (``selected_plan``) has a table per K/V
+    # head: S pages a head a step, each head's its own operands.
+    head_pages = plan.tables.ndim == 3
+    if head_pages:
+        S = _selected_group_pages(k_cache, plan.tables.shape[-1])
+    else:
+        S = _decode_group_pages(k_cache, block_tables.shape[1])
     live = plan.pcount > plan.poff  # rows the grid visits
 
     # [B, C, H, D] → [B, KH, C*G, D]; rows (c, g) c-major, as the kernel's
@@ -469,25 +509,30 @@ def _paged_attention_decode_kernel_impl(
     def q_map(t, bt, sp, pc, po, w, srow, spage):
         return (srow[t], 0, 0, 0)
 
-    def page_map(s, ndim):
+    def page_map(s, ndim, h=None):
         """Index map of an operand that holds page s of the step's group
-        (clamped to the row's live pages: always a block that exists)."""
+        (clamped to the row's live pages: always a block that exists), of
+        head h's own table where every head has one."""
 
         def index_map(t, bt, sp, pc, po, w, srow, spage):
             b = srow[t]
             page = jnp.maximum(jnp.minimum(spage[t] + s, pc[b] - 1), 0)
-            return (bt[b, page],) + (0,) * (ndim - 1)
+            block = bt[b, page] if h is None else bt[b, h, page]
+            return (block,) + (0,) * (ndim - 1)
 
         return index_map
 
     in_specs = [pl.BlockSpec((1, n_kv_heads, CG, head_dim), q_map)]
     kv_args = []
-    for s in range(S):
+    for s, h in (
+        (s, h) for s in range(S)
+        for h in (range(n_kv_heads) if head_pages else (None,))
+    ):
         spec = pl.BlockSpec(
-            (1, block_size, n_kv_heads, page_dim), page_map(s, 4)
+            (1, block_size, n_kv_heads, page_dim), page_map(s, 4, h)
         )
         if quantized:
-            s_spec = pl.BlockSpec((1, n_kv_heads, block_size), page_map(s, 3))
+            s_spec = pl.BlockSpec((1, n_kv_heads, block_size), page_map(s, 3, h))
             in_specs.extend([spec, s_spec, spec, s_spec])
             kv_args.extend(
                 [k_cache["q8"], k_cache["s"], v_cache["q8"], v_cache["s"]]
@@ -510,6 +555,7 @@ def _paged_attention_decode_kernel_impl(
     kernel = functools.partial(
         _decode_kernel, sm_scale=scale, block_size=block_size, n_groups=G,
         group_pages=S, logit_cap=logit_cap, quantized=quantized,
+        head_pages=head_pages,
     )
     out = pl.pallas_call(
         kernel,

@@ -157,6 +157,16 @@ ENGINE_WINDOW_PAGES_DEAD_TOTAL = f"{ENGINE_PREFIX}_window_pages_dead_total"
 ENGINE_WINDOW_PAGES_HELD_TOTAL = f"{ENGINE_PREFIX}_window_pages_held_total"
 ENGINE_DECODE_WINDOW_LIVE_PAGES_TOTAL = f"{ENGINE_PREFIX}_decode_window_live_pages_total"
 ENGINE_PREFIX_HITS_CUT_BY_WINDOW_TOTAL = f"{ENGINE_PREFIX}_prefix_hits_cut_by_window_total"
+# Block-sparse attention chosen by an indexer (a model with sparse attention
+# layers; never touched otherwise), per dispatched decode burst and per sparse
+# layer: the pages the layer's kernel visits for the burst's rows (a row of
+# ``dense_len`` tokens or more: the ``topk`` its indexer selects; a shorter
+# one: all it holds) against the pages those rows hold, and the rows on each
+# path (label path=sparse|dense). selected / live = what the selection leaves
+# of the dense read.
+ENGINE_SPARSE_PAGES_SELECTED_TOTAL = f"{ENGINE_PREFIX}_sparse_pages_selected_total"
+ENGINE_SPARSE_PAGES_LIVE_TOTAL = f"{ENGINE_PREFIX}_sparse_pages_live_total"
+ENGINE_SPARSE_ROWS_TOTAL = f"{ENGINE_PREFIX}_sparse_rows_total"
 
 # The tick-phase vocabulary: every name EngineStepMetrics.phase accepts, in
 # exactly one class. ``device_wait``: the loop awaits the device thread
@@ -673,4 +683,7 @@ ALL_ENGINE = (
     ENGINE_WINDOW_PAGES_HELD_TOTAL,
     ENGINE_DECODE_WINDOW_LIVE_PAGES_TOTAL,
     ENGINE_PREFIX_HITS_CUT_BY_WINDOW_TOTAL,
+    ENGINE_SPARSE_PAGES_SELECTED_TOTAL,
+    ENGINE_SPARSE_PAGES_LIVE_TOTAL,
+    ENGINE_SPARSE_ROWS_TOTAL,
 )

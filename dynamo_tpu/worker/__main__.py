@@ -20,6 +20,7 @@ from dynamo_tpu.models.config import (
     llama3_3b_config,
     llama3_8b_config,
     llama3_70b_config,
+    minicpm_sala_pp4_config,
     mixtral_8x7b_config,
     nemotron3_nano_ep2_config,
     openpangu_ultra_moe_ep16_config,
@@ -28,6 +29,7 @@ from dynamo_tpu.models.config import (
     tiny_config,
     tiny_hybrid_config,
     tiny_mla_config,
+    tiny_sala_config,
     tiny_swa_config,
 )
 from dynamo_tpu.parallel import MeshConfig, make_mesh
@@ -57,6 +59,8 @@ BUILTIN_CONFIGS = {
     "openpangu-ultra-moe-718b-ep16": openpangu_ultra_moe_ep16_config,
     "tiny-swa": tiny_swa_config,
     "laguna-xs.2-pp8": laguna_xs2_pp8_config,
+    "tiny-sala": tiny_sala_config,
+    "minicpm-sala-pp4": minicpm_sala_pp4_config,
 }
 
 

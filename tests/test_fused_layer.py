@@ -216,6 +216,10 @@ DOCUMENTED_PRESET_EXCLUSIONS = {
     # two page groups: a second block table a row, pages behind the window released
     "tiny-swa": "two page groups",
     "laguna-xs.2-pp8": "two page groups",
+    # one mixer per layer: sparse attention over a cached indexer beside
+    # lightning attention's recurrent state
+    "tiny-sala": "hybrid",
+    "minicpm-sala-pp4": "hybrid",
 }
 
 

@@ -664,3 +664,119 @@ def test_laguna_served_programs_compile_for_the_chip(one_chip, program):
     experts = params["layers"][3]
     assert [whole_pool_copies(text, experts[m])
             for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]
+
+
+SALA_SELECTED = {
+    # name: (rows, table slots a K/V head): the live-span decode kernel over
+    # SELECTED pages at MiniCPM-SALA's widths (32 query heads over 2 K/V heads
+    # of 128, pages of 64): a table per (row, K/V head) in scalar memory.
+    "decode_32_rows": (32, 64),
+    "chunk_256_queries": (256, 64),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SALA_SELECTED))
+def test_selected_pages_kernel_compiles_for_v5e(one_chip, name):
+    """ops/sparse_attention.py's call of the decode kernel: every (row, K/V
+    head) visits the 64 pages its indexer selected; a decode burst's 32 slots
+    and the 256 queries of a turn's chunk (each a row of its own)."""
+    from dynamo_tpu.ops.pallas.paged_attention import (
+        _paged_attention_decode_kernel_impl,
+        selected_plan,
+    )
+
+    B, W = SALA_SELECTED[name]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, pool, tables, pages, start):
+        plan = selected_plan(pool, tables, pages)
+        return _paged_attention_decode_kernel_impl(
+            q, pool, pool, tables[:, 0], start, 0, None, plan, sm_scale=128**-0.5)
+
+    pool = sds((11264, 64, 2, 128), jnp.bfloat16)
+    compiled = jax.jit(call).lower(
+        sds((B, 1, 32, 128), jnp.bfloat16), pool, sds((B, 2, W), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _sala_program(one_chip, program):
+    """One served program of the MiniCPM-SALA stage at its published widths
+    (a sparse layer, a lightning layer and the last sparse layer, each with
+    its FFN), as the runner builds it, over a 66,560-token table."""
+    import types
+
+    from dynamo_tpu.engines.tpu import block_pool
+    from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import hybrid, llama
+    from dynamo_tpu.models.config import minicpm_sala_pp4_config
+
+    cfg = dataclasses_replace_layers(minicpm_sala_pp4_config(), [0, 1, 2, 3, 14, 15])
+    NB, S, P, bs = 11264, 32, 1040, 64
+    args = JaxEngineArgs(
+        config=cfg, block_size=bs, num_kv_blocks=NB, max_num_seqs=S, max_model_len=P * bs,
+        prefill_chunk=256, use_kernel=True,
+    )
+    runner = types.SimpleNamespace(config=cfg, args=args, use_kernel=True,
+                                   _decode_sig_budget=None)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = lambda f: jax.tree.map(on_chip, jax.eval_shape(f))
+    params = shapes(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    k, v = shapes(lambda: llama.init_kv_cache(cfg, NB, bs, layered=True))
+    entries = block_pool.snapshot_entries(cfg, NB, bs, S)
+    store = shapes(lambda: hybrid.init_ssm_state(cfg, entries))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def rows(B):
+        return [arr((B,), i32), arr((2,), jnp.uint32), arr((B,), f32),
+                arr((B,), i32), arr((B,), f32)]
+
+    if program == "decode_burst":
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, S))
+        lowered = DeviceRunner._build_decode_fn_hybrid(runner, False, False).lower(
+            params, k, v, state, arr((S,), i32), arr((S,), i32), arr((S,), i32),
+            arr((S, P), i32), *rows(S),
+        )
+    else:
+        fresh = program == "prefill_fresh"
+        B, C, width = (8, 256, 4) if fresh else (8, 256, P)
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
+        lowered = DeviceRunner._build_step_fn_hybrid(runner, False, 0, fresh).lower(
+            params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
+            arr((B,), i32), arr((B, width), i32), arr((B, C // 64), i32), *rows(B),
+        )
+    return lowered.compile(), (k, v, store, entries)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+def test_sala_served_programs_compile_for_the_chip(one_chip, program):
+    """The decode burst, a batch of fresh first chunks and eight turns'
+    chunks over 66,560-token tables, of the MiniCPM-SALA stage at its
+    published widths, compiled for the v5e as the runner builds them: the
+    decode kernel lowers over selected pages and over the dense rows' own, the
+    chunk kernel in blocks of 128 query positions (256 of 32 heads are 21 MiB
+    of VMEM), the pools, the indexer's rows, the state and the snapshot store
+    alias in and out, no program copies a whole pool, and a prefill program
+    holds no ``while``."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+
+    compiled, (k, v, store, entries) = _sala_program(one_chip, program)
+    text = compiled.as_text()
+    assert entries == 176 and [a.shape for a in k[2:]] == [(11264, 4, 2, 128)] * 2
+    assert ("tpu_custom_call" in text) == (program != "prefill_fresh")
+    assert (" while(" in text) == (program == "decode_burst")
+    assert whole_pool_copies(text, k[0]) == whole_pool_copies(text, k[2]) == 0
+    resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k + v)
+    if program != "decode_burst":
+        resident += sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in store["S"])
+    assert compiled.memory_analysis().alias_size_in_bytes >= resident

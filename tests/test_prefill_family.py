@@ -140,6 +140,37 @@ async def test_one_prefix_hit_starts_nothing_and_a_second_brings_the_family(monk
         await engine.stop()
 
 
+@pytest.mark.parametrize("model", ["tiny-mla", "tiny-hybrid"])
+async def test_a_long_fresh_prompts_own_chunks_are_no_recurring_hit(model, monkeypatch):
+    """A resident context being built: one fresh prompt of many chunks meets
+    its later-chunk shape round after round and hits no prefix, so it brings
+    no sibling (the sparse cell's 65,536-token contexts are built at a table
+    width no ask resumes at: three programs a width that nothing ran); two
+    asks over it are the recurring hit, at THEIR shape."""
+    config = {"tiny-mla": tiny_mla_config, "tiny-hybrid": tiny_hybrid_config}[model]()
+    engine = _engine(config, prefill_chunk=128, max_model_len=1024)
+    seen = _spy_on_steps(engine, monkeypatch)
+    doc = np.random.default_rng(47).integers(3, 500, 504).tolist()  # + 8: four chunks, 32 blocks
+    try:
+        await _serve(engine, "build", doc=doc)
+        later = [s for s in seen if not s[3]]
+        assert len(later) >= 3 and {s[:3] for s in later} == {(1, 128, 32)}
+        # ... and its first round, which reads no page, is the start-up
+        # ladder's program of (rows 1, chunk 128): a table of the chunk's 8 blocks.
+        assert [s for s in seen if s[3]] == [(1, 128, 8, True)]
+        assert (1, 128, 8) in engine._admitter.prefill_ladder()
+        for _ in range(5):
+            await asyncio.sleep(0.06)  # idle ticks
+        assert engine._admitter._families == {} and not engine._admitter.family_pending
+        await _serve(engine, "hit-1", doc=doc, question=24)
+        await _serve(engine, "hit-2", doc=doc, question=24)
+        await _family_whole(engine, 3)
+        assert set(engine._admitter._families) == {(128, 64, False)}  # 528 tokens: 33 blocks
+        assert engine.stats()["prefill_family_programs"] == 3
+    finally:
+        await engine.stop()
+
+
 async def test_a_rows_bucket_met_before_its_turn_is_not_run_again(monkeypatch):
     """A batch of two asks that arrives while the siblings are pending
     compiles its own program (as before); the family leaves that one out."""
