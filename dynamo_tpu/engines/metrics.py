@@ -203,6 +203,14 @@ class EngineStepMetrics:
             "layers, by the form the step's static token count takes",
             ["form"],
         )
+        self.ssm_decode_rows = self.registry.counter(
+            mn.ENGINE_SSM_DECODE_ROWS_TOTAL,
+            "Rows of recurrent state the steps of dispatched decode bursts "
+            "pass: state=updated the rows read and written (the live rows "
+            "under the live-row kernel, every slot under the XLA form), "
+            "state=slots every slot",
+            ["state"],
+        )
         self.ssm_state_slots = self.registry.gauge(
             mn.ENGINE_SSM_STATE_SLOTS,
             "Per-sequence recurrent-state slots (one per decode row)",
@@ -458,6 +466,10 @@ class EngineStepMetrics:
         self.decode_window_live_pages.inc(live)
         self.window_pages_held.inc(held)
         self.window_pages_dead.inc(dead)
+
+    def observe_ssm_decode(self, updated: int, slots: int) -> None:
+        self.ssm_decode_rows.inc(updated, state="updated")
+        self.ssm_decode_rows.inc(slots, state="slots")
 
     def observe_ssm(self, slots_used: int, slots_total: int,
                     snaps_used: int, snaps_total: int) -> None:

@@ -526,6 +526,8 @@ class JaxEngine:
         from dynamo_tpu.engines.metrics import EngineStepMetrics
 
         self.step_metrics = EngineStepMetrics(inflight=self._inflight.__len__)
+        if self.runner.ssd_step is not None:
+            self.step_metrics.observe_ssm_decode(0, 0)  # both series from start-up
         # What the sparse attention layers' decode kernel visits, summed over
         # dispatched bursts (the counters of the same names); None for a
         # model without such layers. The series exist from the first scrape.
@@ -797,6 +799,7 @@ class JaxEngine:
             "attention_impl": self.runner.attention_impl,
             "attention_reason": self.runner.attention_reason,
             "expert_ffn": self.runner.expert_ffn,
+            "ssd_step": self.runner.ssd_step,
             "mk_fused_bursts": self.runner.mk_fused_bursts,
             "mk_fallback_bursts": self.runner.mk_fallback_bursts,
             "mk_bursts_by_variant": dict(self.runner.mk_bursts_by_variant),
@@ -1763,6 +1766,12 @@ class JaxEngine:
         self.step_metrics.observe_decode_pages(
             live_pages, args.max_num_seqs * nb_bucket
         )
+        if self.runner.ssd_step is not None:
+            kernel = self.runner.ssd_step == self.runner.SSD_STEP_LIVE
+            self.step_metrics.observe_ssm_decode(
+                args.decode_steps * (len(active) if kernel else args.max_num_seqs),
+                args.decode_steps * args.max_num_seqs,
+            )
         if win is not None:
             self.step_metrics.observe_window_pages(win_live, win_held, win_dead)
         if sparse is not None:

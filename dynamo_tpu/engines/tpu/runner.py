@@ -502,14 +502,17 @@ class DeviceRunner:
         self.sleep_level = 0
         self.host_params: Optional[Any] = None
         self.expert_ffn = self._describe_expert_ffn()
+        self.ssd_step = self._describe_ssd_step()
         self._prefill_expert_forms: Dict[int, Optional[str]] = {}
         logger.info(
             "device runner: platform=%s device_kind=%s devices=%d mesh=%s | "
-            "decode path: %s (%s) | attention: %s (%s) | expert_ffn: %s",
+            "decode path: %s (%s) | attention: %s (%s) | expert_ffn: %s | "
+            "ssd_step: %s",
             backend, jax.devices()[0].device_kind, len(jax.devices()),
             dict(mesh.shape) if mesh is not None else None,
             self.decode_path, self.decode_path_reason,
             self.attention_impl, self.attention_reason, self.expert_ffn,
+            self.ssd_step,
         )
         values = jax.tree.leaves(self.k_cache)[0]  # int8 pools: "q8" sorts first
         self.kv_pool = {
@@ -626,6 +629,24 @@ class DeviceRunner:
             if spec.kind == "experts"
         }
         return "; ".join(sorted(forms)) or None
+
+    SSD_STEP_LIVE = "pallas live rows"
+
+    def _describe_ssd_step(self) -> Optional[str]:
+        """The form a decode step's recurrence takes and why
+        (ops/pallas/ssd_step.ssd_step_reason over the slots' states); None
+        for a model without recurrent layers. Not a choice:
+        ``hybrid._decode_recurrence`` makes it, from the same arguments,
+        every time it is traced."""
+        from dynamo_tpu.ops.pallas.ssd_step import ssd_step_reason
+
+        if not self.ssm_state or not self.ssm_state["S"]:
+            return None
+        whys = {ssd_step_reason(self.use_kernel, S.shape, S.dtype)
+                for S in self.ssm_state["S"]}
+        return "; ".join(sorted(
+            self.SSD_STEP_LIVE if why is None else f"xla every slot, {why}"
+            for why in whys))
 
     def prefill_expert_form(self, tokens: int) -> Optional[str]:
         """ops/moe.form_of for a prefill step of ``tokens`` static tokens

@@ -59,6 +59,7 @@ from dynamo_tpu.ops.attention import (
     write_chunk_to_cache,
 )
 from dynamo_tpu.ops.moe import moe_ffn
+from dynamo_tpu.ops.pallas.ssd_step import ssd_step_live, ssd_step_reason
 from dynamo_tpu.ops.rope import apply_rope, rope_table, rope_table_for
 from dynamo_tpu.ops.sparse_attention import (
     compressed_pool,
@@ -261,7 +262,20 @@ def ssm_state_bytes(config: ModelConfig) -> int:
 # -- mixers --------------------------------------------------------------------
 
 
-def _mamba_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store):
+def _decode_recurrence(x, dt, A, Bm, Cm, S, live_rows):
+    """The one-token recurrence of a decode step, (y, S'): given a burst's
+    live-row list (``llama.decode_multi`` derives one where ``use_kernel``)
+    and a state the kernel takes (``ssd_step_reason``), over the rows that
+    decode and no others, in place; otherwise ``m2.ssd_step`` over every
+    slot (a dead slot's dt is 0). One recurrence for both callers: they
+    differ in A, B, C and the shapes."""
+    if live_rows is not None and ssd_step_reason(True, S.shape, S.dtype) is None:
+        return ssd_step_live(x, dt, A, Bm, Cm, S, *live_rows)
+    return m2.ssd_step(x, dt, A, Bm, Cm, S)
+
+
+def _mamba_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store,
+                 live_rows=None):
     """h [B, C, d] -> (out [B, C, d], conv', S', snap_store'). C == 1 with
     ``snap_dst`` None is the decode step's one-token recurrence."""
     B, C, _ = h.shape
@@ -279,7 +293,7 @@ def _mamba_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store):
         x = act[:, :di].reshape(B, H, P)
         Bm = act[:, di : di + G * N].reshape(B, G, N)
         Cm = act[:, di + G * N :].reshape(B, G, N)
-        y, S_new = m2.ssd_step(x, dt[:, 0], A, Bm, Cm, S)
+        y, S_new = _decode_recurrence(x, dt[:, 0], A, Bm, Cm, S, live_rows)
         y = (y + lp["D"][None, :, None] * x)[:, None]  # [B, 1, H, P]
     else:
         act, padded = m2.conv_chunk(xbc, conv, lp["conv_w"], lp["conv_b"])
@@ -396,7 +410,8 @@ def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_le
     return out, k_c, v_c
 
 
-def _lightning_mixer(c, spec, lp, h, chunk_lens, rope, S, snap_dst, snap_S):
+def _lightning_mixer(c, spec, lp, h, chunk_lens, rope, S, snap_dst, snap_S,
+                     live_rows=None):
     """Lightning attention (config.LightningSpec). h [B, C, d] -> (out, S',
     snapshot stack'). The recurrence is ops/mamba2's with dt = 1 on real
     positions (0 on padding: the state stays), A = -slope, B = k, C = q,
@@ -414,7 +429,8 @@ def _lightning_mixer(c, spec, lp, h, chunk_lens, rope, S, snap_dst, snap_S):
     A = -jnp.asarray(spec.slopes, _F32)
     qs = q.astype(_F32) * D**-0.5
     if C == 1 and snap_dst is None:
-        y, S_new = m2.ssd_step(v[:, 0], dt[:, 0], A, k[:, 0], qs[:, 0], S)
+        y, S_new = _decode_recurrence(
+            v[:, 0], dt[:, 0], A, k[:, 0], qs[:, 0], S, live_rows)
         y = y[:, None]
     else:
         y, ends = m2.ssd_chunk_scan(v, dt, A, k, qs, S, chunk=spec.scan_block)
@@ -505,6 +521,7 @@ def forward(
     snap: Optional[Dict[str, Any]] = None,
     want_moe_stats: bool = False,
     want_selection: bool = False,
+    live_rows=None,
 ):
     """One step over a chunk: (logits, k_cache, v_cache, ssm', snap store' or
     None, expert-load stats float32 [3] summed over the expert layers or
@@ -512,7 +529,9 @@ def forward(
     length 0 changes nothing of its own. ``want_selection`` (tests, the
     benchmark's reference) replaces the last entry by what every sparse
     layer's indexer selected, ``ops/sparse_attention.select_blocks``'s
-    triple a layer."""
+    triple a layer. ``live_rows`` (ops/pallas/ssd_step.live_row_list of a
+    decode burst's ``active``, derived once a burst): the rows whose
+    recurrent state a decode step updates; every recurrent layer shares it."""
     c = config
     B, C = tokens.shape
     x = params["embed"][tokens].astype(c.dtype)
@@ -601,6 +620,7 @@ def forward(
                 out, cv, S, one = _mamba_mixer(
                     c, spec, lp, h, chunk_lens, ssm["conv"][im], ssm["S"][ir],
                     None if snap is None else snap["dst"], one,
+                    live_rows=live_rows,
                 )
                 conv_out.append(cv)
                 s_out.append(S)
@@ -614,6 +634,7 @@ def forward(
                     ropes["lightning", spec.head_dim, spec.rope_theta], ssm["S"][ir],
                     None if snap is None else snap["dst"],
                     None if store is None else store_S[ir],
+                    live_rows=live_rows,
                 )
                 s_out.append(S)
                 if store is not None:

@@ -475,6 +475,7 @@ def forward_paged(
     ssm: Optional[Dict[str, Any]] = None,  # hybrid: recurrent state (models/hybrid.py)
     snap: Optional[Dict[str, Any]] = None,  # hybrid: snapshot store + destinations
     want_moe_stats: bool = False,
+    live_rows: Optional[Any] = None,  # hybrid decode burst: ssd_step.live_row_list
 ) -> Tuple[jnp.ndarray, ...]:
     """One forward step over a chunk. Returns (last_logits [B, V], k_cache,
     v_cache). K/V for the chunk are scattered into the pools before attending,
@@ -499,6 +500,7 @@ def forward_paged(
             params, c, tokens, start_pos, chunk_lens, block_tables, k_cache,
             v_cache, ssm, use_kernel=use_kernel, first_chunk=first_chunk,
             all_logits=all_logits, snap=snap, want_moe_stats=want_moe_stats,
+            live_rows=live_rows,
         )
     B, C = tokens.shape
     hd = c.head_dim_
@@ -818,6 +820,13 @@ def decode_multi(
     )
 
     hybrid_state = ssm is not None
+    # ``active`` is constant over the burst: the list of rows whose recurrent
+    # state the steps update is derived once, outside the scan.
+    live_rows = None
+    if hybrid_state and use_kernel and config.has_recurrent_state:
+        from dynamo_tpu.ops.pallas.ssd_step import live_row_list
+
+        live_rows = live_row_list(active)
 
     def one(carry, step_rng):
         if hybrid_state:
@@ -831,6 +840,7 @@ def decode_multi(
             logits, k_c, v_c, ssm_c, _, moe_st = forward_paged(
                 params, config, toks[:, None], pos, active, block_tables,
                 k_c, v_c, use_kernel=use_kernel, ssm=ssm_c, want_moe_stats=True,
+                live_rows=live_rows,
             )
             moe_acc = moe_acc + moe_st
         else:

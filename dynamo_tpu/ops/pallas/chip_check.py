@@ -15,7 +15,9 @@ the 32 decode slots on one expert): the rows that decide how many tokens
 the kernel serves, each with both forms' ``us/call``; and
 ``expert_ffn_grouped`` (a family of its own: the grouped expert kernel of a
 prefill step) beside ``ragged_dot``, both as ops/moe.py serves them, at the
-three served widths from 512 to 8,192 tokens a step. The decode kernel gets four more rows: the tail of
+three served widths from 512 to 8,192 tokens a step; and ``ssd_step`` (the
+live-row state-update kernel of a recurrent layer's decode step against
+``ops/mamba2.ssd_step`` over every slot, at the two served shapes). The decode kernel gets four more rows: the tail of
 a prefix-hit prefill (one row, four tokens, eight pages), the
 benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
 contexts, the others empty with a stale position, 128 pages of table; bf16
@@ -729,17 +731,159 @@ def mla_jobs(interpret: bool):
     ]
 
 
-def whole_pool_copies(hlo_text: str, pool) -> int:
-    """``copy`` instructions of a compiled program's optimised HLO whose
-    result is a whole per-layer KV pool (``pool``: its shape and dtype):
-    what re-laying a resident pool for a kernel's operand looks like."""
+def _build_ssd_step_xla(x, dt, A, Bm, Cm):
+    """``ops/mamba2.ssd_step`` over every slot (``dt`` 0 on the dead ones),
+    jitted over the state alone."""
+    from dynamo_tpu.ops import mamba2 as m2
+
+    return watched_jit(
+        "chip_check.ssd_step_xla",
+        jax.jit(lambda state: m2.ssd_step(x, dt, A, Bm, Cm, state)))
+
+
+def _build_state_calls(step, calls: int):
+    """``calls`` calls of a state update (state -> (y, state)) chained
+    through the donated state in one jitted loop."""
+
+    def chained(state):
+        def body(_, carry):
+            y, new = step(carry[1])
+            return carry[0] + y[0, 0, 0], new
+
+        return jax.lax.fori_loop(0, calls, body, (jnp.float32(0), state))
+
+    return watched_jit(
+        "chip_check.timed_state_calls", jax.jit(chained, donate_argnums=(0,)))
+
+
+def ssd_step_jobs(interpret: bool):
+    """The live-row state-update kernel (``ssd_step_live``) against
+    ``ops/mamba2.ssd_step`` over every slot (dead slots given dt = 0: what it
+    replaces in a decode step), float32, at the two served shapes: the hybrid
+    cell's Mamba-2 layers (64 slots, 64 heads x 64 x 128, B and C in 8
+    groups) and the sparse cell's lightning layers (32 slots, 32 heads x 128
+    x 128, B and C per head), a scattered set of rows live. Compared on the
+    chip: the live rows' state and y to float32 rounding, the dead rows'
+    state BIT FOR BIT and their y zero. The one-live-row case is
+    ``required`` (a one-entry work list halted the core once, PR 25). Timed
+    rows print the bytes the live rows' state moves (read and written) and
+    their share of 819 GB/s beside the XLA form's us/call; both forms timed
+    as the difference of two chained loops (24 and 120 calls), so that the
+    loop's one dispatch is in neither."""
+    from dynamo_tpu.ops.pallas.ssd_step import live_row_list, ssd_step_live
+
+    def job(preset, slots, H, P, N, G, live, heads_a_step=None):
+        rng = np.random.default_rng(slots * 1000 + live)
+        f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        x, Bm, Cm = f32(slots, H, P), f32(slots, G, N), f32(slots, G, N)
+        dt = jax.nn.softplus(f32(slots, H))
+        A = -jnp.exp(f32(H))
+        state = f32(slots, H, P, N)
+        active = np.zeros(slots, np.int32)
+        active[rng.permutation(slots)[:live]] = 1
+        rows = live_row_list(jnp.asarray(active))
+        dead = active == 0
+        row = {
+            "kernel": "ssd_step_live",
+            "shape": f"slots{slots} H{H} P{P} N{N} G{G} live{live} float32"
+                     + (f" heads_a_step{heads_a_step}" if heads_a_step else ""),
+            "presets": [preset],
+            "required": live == 1,
+        }
+
+        def kernel(state):
+            return ssd_step_live(x, dt, A, Bm, Cm, state, *rows,
+                                 heads_a_step=heads_a_step, interpret=interpret)
+
+        xla = _build_ssd_step_xla(x, jnp.where(rows.mask[:, None], dt, 0.0), A, Bm, Cm)
+
+        t0 = time.monotonic()
+        try:
+            y, new = jax.block_until_ready(kernel(state + 0.0))  # the call donates
+        except Exception as exc:
+            row.update(status="refused", message=_first_line(exc))
+        else:
+            y_ref, new_ref = xla(state)
+            y, new, y_ref, new_ref, old = (
+                np.asarray(a) for a in (y, new, y_ref, new_ref, state))
+            bad = None
+            if not np.array_equal(new[dead], old[dead]):
+                bad = "a dead row's state moved"
+            elif y[dead].any():
+                bad = "a dead row's y is not zero"
+            # (the XLA form's y of a dead row is its old state's read-out)
+            for name, out, ref in (("state", new, new_ref), ("y", y[~dead], y_ref[~dead])):
+                if bad is not None or not out.size:
+                    continue
+                err = float(np.abs(out - ref).max())
+                if not np.isfinite(out).all() or err > 1e-5 * max(float(np.abs(ref).max()), 1.0):
+                    bad = f"{name}: max |kernel-reference| {err:.4g}"
+            row.update(status="compiled" if bad is None else "disagrees",
+                       message=bad or "")
+        row["seconds"] = round(time.monotonic() - t0, 1)
+        if row["status"] == "compiled" and not interpret:
+
+            def us_per_call(step):
+                # Two loop lengths, the difference: a chained loop's one
+                # dispatch and read-back (~0.7 ms from this host) is 28 us a
+                # call over 24 calls, as much as one live row's update.
+                def seconds(calls):
+                    many = _build_state_calls(step, calls)
+                    carry = jax.block_until_ready(many(state + 0.0))
+                    best = float("inf")
+                    for _ in range(5):
+                        t0 = time.perf_counter()
+                        carry = jax.block_until_ready(many(carry[1]))
+                        best = min(best, time.perf_counter() - t0)
+                    return best
+
+                short, long = seconds(TIMED_CALLS), seconds(5 * TIMED_CALLS)
+                return round((long - short) / (4 * TIMED_CALLS) * 1e6, 1)
+
+            def timed():
+                us = us_per_call(kernel)
+                moved = 2.0 * live * H * P * N * 4
+                row["message"] = (
+                    f"{moved / 1e6:.1f} MB moved, {100 * moved / (us * 1e-6) / 819e9:.1f}% of "
+                    f"819 GB/s; xla over every slot {us_per_call(xla)} us/call")
+                return us
+
+            row["time"] = timed
+        return row
+
+    if interpret:
+        return [functools.partial(job, "tiny-hybrid", 6, 8, 16, 128, 2, live)
+                for live in (0, 1, 3, 6)] + [
+            functools.partial(job, "tiny-sala", 5, 4, 8, 128, 4, live, 2) for live in (1, 5)]
+    hybrid = ("nemotron-3-nano-30b-a3b-ep2", 64, 64, 64, 128, 8)
+    sala = ("minicpm-sala-pp4", 32, 32, 128, 128, 32)
+    return (
+        [functools.partial(job, *hybrid, live) for live in (1, 14, 20, 64)]
+        + [functools.partial(job, *sala, live) for live in (1, 10, 32)]
+        # the head tile: a whole row a step is what is served
+        + [functools.partial(job, *hybrid, 14, hs) for hs in (32, 16, 8)]
+        + [functools.partial(job, *sala, 10, hs) for hs in (16, 8)]
+    )
+
+
+def whole_array_ops(hlo_text: str, array) -> List[str]:
+    """Opcodes of a compiled program's optimised HLO whose result is an array
+    of ``array``'s shape and dtype, other than those that move none of it: a
+    resident pool re-laid for a kernel's operand shows as ``copy``, a
+    recurrent state passed through XLA whole as ``fusion``."""
     import re
 
-    dtype = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(pool.dtype).name]
-    dims = ",".join(str(d) for d in pool.shape)
-    return len(re.findall(
-        rf"= {dtype}\[{re.escape(dims)}\][^ ]* copy\(", hlo_text
-    ))
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(array.dtype).name]
+    dims = ",".join(str(d) for d in array.shape)
+    ops = re.findall(rf"= {dtype}\[{re.escape(dims)}\][^ ]* ([a-z\-]+)\(", hlo_text)
+    return [op for op in ops
+            if op not in ("parameter", "get-tuple-element", "bitcast", "tuple")]
+
+
+def whole_pool_copies(hlo_text: str, pool) -> int:
+    """``copy`` instructions whose result is a whole per-layer KV pool
+    (``pool``: its shape and dtype)."""
+    return whole_array_ops(hlo_text, pool).count("copy")
 
 
 def pool_layout_job(interpret: bool):
@@ -903,6 +1047,7 @@ def main() -> int:
             "expert_ffn": lambda: expert_ffn_jobs(True),
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(True),
             "mla_paged_decode": lambda: mla_jobs(True),
+            "ssd_step": lambda: ssd_step_jobs(True),
             "paged_attention_swa": lambda: swa_attention_jobs(True),
         }
     else:
@@ -919,6 +1064,7 @@ def main() -> int:
             "expert_ffn": lambda: expert_ffn_jobs(False),
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(False),
             "mla_paged_decode": lambda: mla_jobs(False),
+            "ssd_step": lambda: ssd_step_jobs(False),
             "paged_attention_swa": lambda: swa_attention_jobs(False),
         }
     families["kv_pool_layout"] = lambda: []  # one row, after the timings

@@ -134,6 +134,14 @@ ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_mean_expert_tokens_t
 # engine with expert layers: grouped_kernel / all four = how often a prefill
 # step's experts run through the grouped Pallas kernel.
 ENGINE_MOE_PREFILL_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_prefill_tokens_total"
+# Rows of recurrent state the steps of dispatched decode bursts pass (label
+# state=updated|slots), a burst adding steps x slots to ``slots`` and to
+# ``updated`` steps x its live rows where the step's recurrence runs through
+# the live-row kernel (ops/pallas/ssd_step.py), steps x slots where it keeps
+# the XLA form over every slot: updated / slots = the share of the slots'
+# state a decode step reads and writes (100% says the kernel did not engage).
+# Both series at 0 from start-up in an engine with recurrent layers.
+ENGINE_SSM_DECODE_ROWS_TOTAL = f"{ENGINE_PREFIX}_ssm_decode_rows_total"
 # Recurrent (state-space) state beside the paged K/V: slots are one per decode
 # row, snapshots are the block-aligned state copies prefix reuse resumes from
 # (label state=used|total).
@@ -673,6 +681,7 @@ ALL_ENGINE = (
     ENGINE_MOE_MAX_EXPERT_TOKENS_TOTAL,
     ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL,
     ENGINE_MOE_PREFILL_TOKENS_TOTAL,
+    ENGINE_SSM_DECODE_ROWS_TOTAL,
     ENGINE_SSM_STATE_SLOTS,
     ENGINE_SSM_SNAPSHOTS,
     ENGINE_SSM_SNAPSHOT_HITS_TOTAL,

@@ -367,15 +367,11 @@ HYBRID_PROGRAMS = {
 }
 
 
-@pytest.mark.parametrize("program", sorted(HYBRID_PROGRAMS))
-def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
-    """The decode burst and the prefill step of the hybrid configuration at
-    its PUBLISHED widths (one layer of each kind: Mamba-2, experts with 64 of
-    128 held as the cell serves them, attention), compiled for the v5e as
-    the runner builds them: the paged-attention kernels lower at head 128
-    with 16 Q per KV head, the scan, the grouped expert kernel and the
-    snapshot scatter compile, and the donated pools, recurrent state and
-    snapshot store alias in and out."""
+def _hybrid_program(one_chip, program):
+    """One served program of the hybrid configuration at its PUBLISHED widths
+    (one layer of each kind: Mamba-2, experts with 64 of 128 held as the cell
+    serves them, attention), as the runner builds it: (compiled, params,
+    donated arguments)."""
     import types
 
     from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
@@ -384,7 +380,6 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     from dynamo_tpu.models.config import (
         NEMOTRON_3_NANO_30B_A3B_HF, ModelConfig, cut_hybrid,
     )
-    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
     full = ModelConfig.from_hf_config(NEMOTRON_3_NANO_30B_A3B_HF)
     cfg = cut_hybrid(full, n_layers=6, experts_held=(0, 64), vocab_rows=8192, name="cut")
@@ -430,7 +425,19 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
             arr((B,), i32), arr((B, P), i32), arr((B, C // 64), i32), *rows(B),
         )
         donated = (k, v, store, state)
-    compiled = lowered.compile()
+    return lowered.compile(), params, donated
+
+
+@pytest.mark.parametrize("program", sorted(HYBRID_PROGRAMS))
+def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
+    """The decode burst and the prefill step of the hybrid configuration,
+    compiled for the v5e as the runner builds them: the paged-attention
+    kernels lower at head 128 with 16 Q per KV head, the scan, the grouped
+    expert kernel and the snapshot scatter compile, and the donated pools,
+    recurrent state and snapshot store alias in and out."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+
+    compiled, params, donated = _hybrid_program(one_chip, program)
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the paged-attention kernel
     # benchmark/trace_names tells the two programs apart by a ``while``: the
@@ -450,6 +457,59 @@ def test_hybrid_served_programs_compile_for_the_chip(one_chip, program):
     resident = sum(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(donated))
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
+
+
+SSD_STEP_SHAPES = {
+    # name: (slots, H, P, N, G): the two served shapes and a one-slot engine
+    "mamba2-64-slots": (64, 64, 64, 128, 8),
+    "lightning-32-slots": (32, 32, 128, 128, 32),
+    "lightning-one-slot": (1, 32, 128, 128, 32),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SSD_STEP_SHAPES))
+def test_state_update_kernel_compiles_for_v5e(one_chip, name):
+    """``ssd_step_live`` at the served widths (a whole row of state a grid
+    step: 2 MiB in, 2 MiB out, each twice buffered) and with one slot (a list
+    of two entries): Mosaic lowers the lane slices, the sublane broadcasts
+    and the float32 scalar prefetch, and the state aliases in and out."""
+    from dynamo_tpu.ops.pallas.ssd_step import _ssd_step_live_impl
+
+    B, H, P, N, G = SSD_STEP_SHAPES[name]
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(_ssd_step_live_impl, donate_argnums=(5,)).lower(
+        sds((B, H, P)), sds((B, H)), sds((H,)), sds((B, G, N)), sds((B, G, N)),
+        sds((B, H, P, N)), sds((1,), jnp.int32), sds((B + 1,), jnp.int32),
+        sds((B,), jnp.bool_),
+    ).compile()
+    assert "ssd_step_live" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes == B * H * P * N * 4
+
+
+@pytest.mark.parametrize("cell", ["hybrid", "sala"])
+def test_decode_burst_updates_the_live_rows_state_in_place(one_chip, cell):
+    """The decode bursts of the two cells with recurrent layers: every such
+    layer's recurrence is the live-row kernel, the slots' state aliases in
+    and out, and the optimised HLO holds NO operation whose result is a whole
+    ``f32[slots, H, P, N]`` state (the XLA form's multiply and add over every
+    slot, a copy for the kernel's operand)."""
+    from dynamo_tpu.models import hybrid
+    from dynamo_tpu.ops.pallas.chip_check import whole_array_ops
+
+    if cell == "hybrid":
+        compiled, _, (_, _, state) = _hybrid_program(one_chip, "decode_burst")
+    else:
+        compiled, _ = _sala_program(one_chip, "decode_burst")
+        state = jax.eval_shape(
+            lambda: hybrid.init_ssm_state(_sala_config(), 32))
+    text = compiled.as_text()
+    assert "ssd_step_live" in text
+    assert {a.shape for a in state["S"]} == {
+        (64, 64, 64, 128) if cell == "hybrid" else (32, 32, 128, 128)}
+    assert whole_array_ops(text, state["S"][0]) == []
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in state["S"])
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
 
 
 def dataclasses_replace_layers(cfg, keep):
@@ -703,6 +763,12 @@ def test_selected_pages_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _sala_config():
+    from dynamo_tpu.models.config import minicpm_sala_pp4_config
+
+    return dataclasses_replace_layers(minicpm_sala_pp4_config(), [0, 1, 2, 3, 14, 15])
+
+
 def _sala_program(one_chip, program):
     """One served program of the MiniCPM-SALA stage at its published widths
     (a sparse layer, a lightning layer and the last sparse layer, each with
@@ -713,9 +779,8 @@ def _sala_program(one_chip, program):
     from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
     from dynamo_tpu.engines.tpu.runner import DeviceRunner
     from dynamo_tpu.models import hybrid, llama
-    from dynamo_tpu.models.config import minicpm_sala_pp4_config
 
-    cfg = dataclasses_replace_layers(minicpm_sala_pp4_config(), [0, 1, 2, 3, 14, 15])
+    cfg = _sala_config()
     NB, S, P, bs = 11264, 32, 1040, 64
     args = JaxEngineArgs(
         config=cfg, block_size=bs, num_kv_blocks=NB, max_num_seqs=S, max_model_len=P * bs,

@@ -701,11 +701,14 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
         mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
     labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
               | set(mn.FRAME_KINDS) | {"used", "total", "window", "sparse", "dense"}
+              | {"updated", "slots"}
               | set(MOE_FORMS))
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
     workers = "workers"
-    if entry.get("workloads") and all("nemotron" in w for w in entry["workloads"]):
+    # (one of both cells with recurrent layers too: the hybrid engine has them)
+    if entry.get("workloads") and all(
+            "nemotron" in w or "minicpm-sala" in w for w in entry["workloads"]):
         workers = "workers_hybrid"
     if entry.get("workloads") and all("openpangu" in w for w in entry["workloads"]):
         workers = "workers_mla"
