@@ -211,6 +211,15 @@ class EngineStepMetrics:
             "state=slots every slot",
             ["state"],
         )
+        self.sampler_decode_steps = self.registry.counter(
+            mn.ENGINE_SAMPLER_DECODE_STEPS_TOTAL,
+            "Steps of dispatched decode bursts by the sampler's branch: "
+            "path=greedy no live row samples (arg-max of the logits), "
+            "path=full a live row does (candidates, sort, noise)",
+            ["path"],
+        )
+        for path in ("greedy", "full"):  # both series from start-up
+            self.sampler_decode_steps.inc(0, path=path)
         self.ssm_state_slots = self.registry.gauge(
             mn.ENGINE_SSM_STATE_SLOTS,
             "Per-sequence recurrent-state slots (one per decode row)",
@@ -466,6 +475,9 @@ class EngineStepMetrics:
         self.decode_window_live_pages.inc(live)
         self.window_pages_held.inc(held)
         self.window_pages_dead.inc(dead)
+
+    def observe_sampler_steps(self, steps: int, any_sampled: bool) -> None:
+        self.sampler_decode_steps.inc(steps, path="full" if any_sampled else "greedy")
 
     def observe_ssm_decode(self, updated: int, slots: int) -> None:
         self.ssm_decode_rows.inc(updated, state="updated")

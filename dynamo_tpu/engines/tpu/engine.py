@@ -1766,6 +1766,9 @@ class JaxEngine:
         self.step_metrics.observe_decode_pages(
             live_pages, args.max_num_seqs * nb_bucket
         )
+        # The burst's own predicate (llama.decode_multi): live rows only.
+        self.step_metrics.observe_sampler_steps(
+            K, any(self._temp[s.slot] > 0.0 for s in active))
         if self.runner.ssd_step is not None:
             kernel = self.runner.ssd_step == self.runner.SSD_STEP_LIVE
             self.step_metrics.observe_ssm_decode(

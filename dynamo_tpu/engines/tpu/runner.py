@@ -29,7 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engines.tpu import block_pool
 from dynamo_tpu.models import llama
-from dynamo_tpu.ops.sampling import compute_logprobs, fold_row_keys, sample_tokens
+from dynamo_tpu.ops.sampling import compute_logprobs, sample_tokens
 from dynamo_tpu.parallel.sharding import (
     ShardingRules,
     param_shardings,
@@ -929,8 +929,9 @@ class DeviceRunner:
             # sampled token) — start_pos + chunk_lens is exactly the index
             # the sampled token will occupy, matching decode_multi's
             # per-step fold so a preempted sequence's recompute redraws
-            # identical noise for the same position.
-            row_keys = fold_row_keys(rng, salts, start_pos + chunk_lens)
+            # identical noise for the same position. A padding row of the rows
+            # bucket (chunk_lens 0) is no reason to sample: a step whose live
+            # rows are all greedy takes the arg-max (ops/sampling.py).
             with jax.named_scope("sample"):
                 if want_procs:
                     from dynamo_tpu.ops import logits_process as lp
@@ -940,11 +941,9 @@ class DeviceRunner:
                     pp = lp.ProcParams(rep=rep, pres=pres, freq=freq,
                                        bias_ids=bias_ids, bias_vals=bias_vals)
                     logits = lp.apply_prompt_only(logits, pmask, pp)
-                    toks = sample_tokens(logits, None, temp, topk, topp, minp,
-                                         row_keys=row_keys)
-                else:
-                    toks = sample_tokens(logits, None, temp, topk, topp,
-                                         row_keys=row_keys)
+                toks = sample_tokens(  # (minp is None without the processors)
+                    logits, rng, temp, topk, topp, minp, salts=salts,
+                    positions=start_pos + chunk_lens, live=chunk_lens > 0)
                 logp = compute_logprobs(logits, toks)
             if num_top > 0:
                 from dynamo_tpu.ops.sampling import top_logprobs as top_op
@@ -978,7 +977,6 @@ class DeviceRunner:
                 first_chunk=first_chunk, ssm=ssm,
                 snap={"store": snap_store, "dst": snap_dst},
             )
-            row_keys = fold_row_keys(rng, salts, start_pos + chunk_lens)
             with jax.named_scope("sample"):
                 if want_procs:
                     from dynamo_tpu.ops import logits_process as lp
@@ -986,11 +984,9 @@ class DeviceRunner:
                     pp = lp.ProcParams(rep=rep, pres=pres, freq=freq,
                                        bias_ids=bias_ids, bias_vals=bias_vals)
                     logits = lp.apply_prompt_only(logits, pmask, pp)
-                    toks = sample_tokens(logits, None, temp, topk, topp, minp,
-                                         row_keys=row_keys)
-                else:
-                    toks = sample_tokens(logits, None, temp, topk, topp,
-                                         row_keys=row_keys)
+                toks = sample_tokens(
+                    logits, rng, temp, topk, topp, minp, salts=salts,
+                    positions=start_pos + chunk_lens, live=chunk_lens > 0)
                 logp = compute_logprobs(logits, toks)
             small = (toks, logp)
             if num_top > 0:

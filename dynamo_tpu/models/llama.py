@@ -813,13 +813,18 @@ def decode_multi(
     """
     from dynamo_tpu.ops import logits_process as lp
     from dynamo_tpu.ops.sampling import (
+        any_row_samples,
         compute_logprobs,
-        fold_row_keys,
         sample_tokens,
         top_logprobs as top_logprobs_op,
     )
 
     hybrid_state = ssm is not None
+    # ``active`` and the temperatures are constant over the burst: whether a
+    # live row samples (a dead slot keeps a stale temperature) is decided
+    # once, outside the scan; the steps' sampler takes its arg-max branch
+    # when none does.
+    any_sampled = any_row_samples(temperature, active > 0)
     # ``active`` is constant over the burst: the list of rows whose recurrent
     # state the steps update is derived once, outside the scan.
     live_rows = None
@@ -858,14 +863,14 @@ def decode_multi(
                 # pos) — the same index the prefill program folds for the
                 # first generated token, so preemption-by-recompute redraws
                 # identical noise.
-                row_keys = fold_row_keys(rng, salts, pos + 1)
                 nxt = sample_tokens(
-                    logits, None, temperature, top_k, top_p, min_p,
-                    row_keys=row_keys,
+                    logits, rng, temperature, top_k, top_p, min_p,
+                    salts=salts, positions=pos + 1, any_sampled=any_sampled,
                 )
             else:
                 nxt = sample_tokens(
-                    logits, step_rng, temperature, top_k, top_p, min_p
+                    logits, step_rng, temperature, top_k, top_p, min_p,
+                    any_sampled=any_sampled,
                 )
             nxt = jnp.where(active > 0, nxt, toks)
         if want_logprobs:

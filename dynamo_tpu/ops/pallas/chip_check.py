@@ -17,7 +17,9 @@ the kernel serves, each with both forms' ``us/call``; and
 prefill step) beside ``ragged_dot``, both as ops/moe.py serves them, at the
 three served widths from 512 to 8,192 tokens a step; and ``ssd_step`` (the
 live-row state-update kernel of a recurrent layer's decode step against
-``ops/mamba2.ssd_step`` over every slot, at the two served shapes). The decode kernel gets four more rows: the tail of
+``ops/mamba2.ssd_step`` over every slot, at the two served shapes); and
+``sampler`` (no kernel: ``ops/sampling.sample_tokens`` beside the form without
+its arg-max branch, at the five cells' slots and vocabularies). The decode kernel gets four more rows: the tail of
 a prefix-hit prefill (one row, four tokens, eight pages), the
 benchmark cell's decode at head_dim 64 (64 slots, a third live with ragged
 contexts, the others empty with a stale position, 128 pages of table; bf16
@@ -214,6 +216,28 @@ def _us_per_call(call, q, *rest) -> float:
         jax.block_until_ready(many(q, *rest))
         best = min(best, time.perf_counter() - t0)
     return round(best / TIMED_CALLS * 1e6, 1)
+
+
+def _us_per_call_two_lengths(build, first, carried: int) -> float:
+    """Device time of one call as the difference of two chained loops
+    (``TIMED_CALLS`` and five times as many, best of five each): a loop's one
+    dispatch and read-back (~0.7 ms from this host, 28 us a call over 24
+    calls) is in neither. ``build(calls)`` gives the jitted loop, which
+    donates its argument; ``first()`` its first argument, and element
+    ``carried`` of its result the next one."""
+
+    def seconds(calls):
+        many = build(calls)
+        carry = jax.block_until_ready(many(first()))
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            carry = jax.block_until_ready(many(carry[carried]))
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    short, long = seconds(TIMED_CALLS), seconds(5 * TIMED_CALLS)
+    return round((long - short) / (4 * TIMED_CALLS) * 1e6, 1)
 
 
 def _attention_job(interpret: bool, B: int, P: int):
@@ -824,21 +848,9 @@ def ssd_step_jobs(interpret: bool):
         if row["status"] == "compiled" and not interpret:
 
             def us_per_call(step):
-                # Two loop lengths, the difference: a chained loop's one
-                # dispatch and read-back (~0.7 ms from this host) is 28 us a
-                # call over 24 calls, as much as one live row's update.
-                def seconds(calls):
-                    many = _build_state_calls(step, calls)
-                    carry = jax.block_until_ready(many(state + 0.0))
-                    best = float("inf")
-                    for _ in range(5):
-                        t0 = time.perf_counter()
-                        carry = jax.block_until_ready(many(carry[1]))
-                        best = min(best, time.perf_counter() - t0)
-                    return best
-
-                short, long = seconds(TIMED_CALLS), seconds(5 * TIMED_CALLS)
-                return round((long - short) / (4 * TIMED_CALLS) * 1e6, 1)
+                # (the loop's dispatch is as much as one live row's update)
+                return _us_per_call_two_lengths(
+                    functools.partial(_build_state_calls, step), lambda: state + 0.0, 1)
 
             def timed():
                 us = us_per_call(kernel)
@@ -864,6 +876,124 @@ def ssd_step_jobs(interpret: bool):
         + [functools.partial(job, *hybrid, 14, hs) for hs in (32, 16, 8)]
         + [functools.partial(job, *sala, 10, hs) for hs in (16, 8)]
     )
+
+
+def _build_sampler_call(name: str, sample):
+    return watched_jit(f"chip_check.{name}", jax.jit(sample))
+
+
+def _build_sampler_calls(sample, calls: int):
+    """``calls`` sampler calls chained in one jitted loop. Each call's picked
+    token is struck out of its row's logits (a 1-element write a row, in
+    place), so that no call is loop-invariant and none can be hoisted."""
+
+    def chained(logits):
+        rows = jnp.arange(logits.shape[0])
+        low = jnp.finfo(logits.dtype).min
+
+        def body(_, carry):
+            logits, _ = carry
+            toks = sample(logits)
+            return logits.at[rows, toks].set(low), toks
+
+        return jax.lax.fori_loop(
+            0, calls, body, (logits, jnp.zeros(logits.shape[0], jnp.int32)))
+
+    return watched_jit(
+        "chip_check.timed_sampler_calls", jax.jit(chained, donate_argnums=(0,)))
+
+
+def sampler_jobs(interpret: bool):
+    """``ops/sampling.sample_tokens`` alone (no kernel of ours: the table's
+    one XLA-only family), at the five cells' slots and vocabularies, bfloat16
+    logits, a third of the slots live and the dead ones holding the start-up
+    temperature 1.0: every live row greedy (the arg-max branch), one live row
+    sampling, every live row sampling (both the candidates' branch), each
+    beside the form it replaces, ``sample_candidates`` over every slot
+    whatever the temperatures. Compared on the chip: a row that samples and a
+    greedy row of a sampling call get the parent's token exactly; a row of
+    the arg-max branch gets numpy's arg-max (the lowest index of the largest
+    logit), and a live one's logit is the one the parent's token has (the two
+    may differ in index where logits tie). Timed as the difference of two chained loops
+    (24 and 120 calls)."""
+    from dynamo_tpu.ops import sampling
+
+    def job(preset, slots, vocab, mix):
+        rng = np.random.default_rng(slots * 1000003 + vocab)
+        logits = jnp.asarray(rng.standard_normal((slots, vocab)), jnp.bfloat16)
+        live = np.zeros(slots, bool)
+        live[rng.permutation(slots)[: max(slots // 3, 2)]] = True
+        temp = np.where(live, 0.0, 1.0).astype(np.float32)  # stale on the dead
+        if mix == "one sampled":
+            temp[np.flatnonzero(live)[0]] = 0.8
+        elif mix == "all sampled":
+            temp[live] = 0.8
+        key = jax.random.PRNGKey(vocab)
+        temp, salts = jnp.asarray(temp), jnp.arange(slots, dtype=jnp.int32)
+        pos = jnp.full((slots,), 7, jnp.int32)
+        top_k, top_p = jnp.full((slots,), 40, jnp.int32), jnp.full((slots,), 0.95, jnp.float32)
+        row = {
+            "kernel": "sample_tokens",
+            "shape": f"slots{slots} vocab{vocab} bfloat16 live{int(live.sum())} {mix}",
+            "presets": [preset],
+            "required": False,
+        }
+
+        def new(logits):
+            return sampling.sample_tokens(
+                logits, key, temp, top_k, top_p, salts=salts, positions=pos,
+                live=jnp.asarray(live))
+
+        def parent(logits):
+            return sampling.sample_candidates(
+                logits, None, temp, top_k, top_p, None,
+                sampling.fold_row_keys(key, salts, pos))
+
+        new = _build_sampler_call("sample_tokens", new)
+        parent = _build_sampler_call("sample_candidates", parent)
+        t0 = time.monotonic()
+        try:
+            got, want = np.asarray(new(logits)), np.asarray(parent(logits))
+        except Exception as exc:
+            row.update(status="refused", message=_first_line(exc))
+        else:
+            host = np.asarray(logits.astype(jnp.float32))
+            at = lambda toks: host[np.arange(slots), toks]
+            if mix == "all greedy":
+                ok = (np.array_equal(got, host.argmax(-1))
+                      and np.array_equal(at(got)[live], at(want)[live]))
+            else:
+                ok = np.array_equal(got, want)
+            bad = None if ok else (
+                f"{int((got != want)[live].sum())} of {int(live.sum())} live rows' "
+                "tokens are not the parent's")
+            row.update(status="compiled" if bad is None else "disagrees",
+                       message=bad or "")
+        row["seconds"] = round(time.monotonic() - t0, 1)
+        if row["status"] == "compiled" and not interpret:
+
+            def us_per_call(sample):
+                return _us_per_call_two_lengths(
+                    functools.partial(_build_sampler_calls, sample), lambda: logits + 0, 0)
+
+            def timed():
+                us = us_per_call(new)
+                row["message"] = (
+                    f"{slots * vocab * 2 / 1e6:.1f} MB of logits; parent form "
+                    f"(candidates over every slot) {us_per_call(parent)} us/call")
+                return us
+
+            row["time"] = timed
+        return row
+
+    mixes = ("all greedy", "one sampled", "all sampled")
+    if interpret:
+        return [functools.partial(job, "tiny", 6, 1000, mix) for mix in mixes]
+    cells = [("qwen2.5-0.5b", 64, 151936), ("laguna-xs.2-pp8", 64, 100352),
+             ("nemotron-3-nano-30b-a3b-ep2", 64, 65536),
+             ("minicpm-sala-pp4", 32, 73448),
+             ("openpangu-ultra-moe-718b-ep16", 32, 19200)]
+    return [functools.partial(job, *cell, mix) for cell in cells for mix in mixes]
 
 
 def whole_array_ops(hlo_text: str, array) -> List[str]:
@@ -1048,6 +1178,7 @@ def main() -> int:
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(True),
             "mla_paged_decode": lambda: mla_jobs(True),
             "ssd_step": lambda: ssd_step_jobs(True),
+            "sampler": lambda: sampler_jobs(True),
             "paged_attention_swa": lambda: swa_attention_jobs(True),
         }
     else:
@@ -1065,6 +1196,7 @@ def main() -> int:
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(False),
             "mla_paged_decode": lambda: mla_jobs(False),
             "ssd_step": lambda: ssd_step_jobs(False),
+            "sampler": lambda: sampler_jobs(False),
             "paged_attention_swa": lambda: swa_attention_jobs(False),
         }
     families["kv_pool_layout"] = lambda: []  # one row, after the timings

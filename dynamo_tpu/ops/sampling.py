@@ -1,15 +1,29 @@
 """Batched token sampling under jit.
 
-Greedy / temperature / top-k / top-p with per-sequence parameters so one
-compiled decode step serves a continuous batch of heterogeneous requests
+Greedy / temperature / top-k / top-p / min-p with per-sequence parameters so
+one compiled decode step serves a continuous batch of heterogeneous requests
 (the reference delegates this to vLLM's sampler; here it is part of the
 engine's fused decode step).
 
-TPU note: a full-vocab argsort per step dominated decode time (~tens of ms
-for 150k vocabs), so filtering happens inside the top-`SAMPLE_WIDTH` logits
-via `lax.top_k` (O(V log W)). top-p truncates at SAMPLE_WIDTH candidates —
-the standard accelerator-side approximation; requests asking for
-top_k > SAMPLE_WIDTH are clamped.
+``sample_tokens`` has two branches, chosen on the device by a
+``jax.lax.cond`` from what the call can observe (``any_row_samples``):
+
+- no LIVE row has ``temperature > 0``: the arg-max of the logits, one
+  reduction over ``[B, V]`` and nothing else. Ties go to the lowest index, as
+  ``lax.top_k`` (the CPU path) and the plain references have always had it.
+- otherwise ``sample_candidates``: the ``SAMPLE_WIDTH`` largest logits of a
+  row (on the TPU ``approx_max_k`` with ``recall_target=0.99``, whose result
+  is unsorted, so an ``argsort`` of the candidates follows; its first
+  candidate is exact: the largest logit is the largest of its own bucket),
+  top-k / top-p / min-p inside them (top-p truncates at SAMPLE_WIDTH
+  candidates, the standard accelerator-side approximation; a request's
+  top_k > SAMPLE_WIDTH is clamped), per-row Gumbel noise, and the first
+  candidate for the rows at temperature 0.
+
+Both name the same token for a greedy row except where two logits tie for the
+largest: there the approximate top-k may name either, the arg-max the lowest.
+A dead slot keeps its last request's temperature (a fresh one holds 1.0), so
+the predicate reads live rows only.
 """
 
 from __future__ import annotations
@@ -41,6 +55,16 @@ def fold_row_keys(
     )
 
 
+def any_row_samples(
+    temperature: jnp.ndarray,  # [B] float
+    live: jnp.ndarray = None,  # [B] bool; None: every row is live
+) -> jnp.ndarray:
+    """Scalar bool: some live row has ``temperature > 0``. False sends
+    ``sample_tokens`` down its arg-max branch."""
+    samples = temperature > 0.0
+    return jnp.any(samples if live is None else samples & live)
+
+
 def sample_tokens(
     logits: jnp.ndarray,  # [B, V] float
     rng: jax.Array,  # single PRNG key (ignored when row_keys given)
@@ -49,12 +73,51 @@ def sample_tokens(
     top_p: jnp.ndarray,  # [B] float; >=1 means off
     min_p: jnp.ndarray = None,  # [B] float; <=0/None means off
     row_keys: jax.Array = None,  # [B] per-row keys (fold_row_keys)
+    live: jnp.ndarray = None,  # [B] bool: the rows whose token is used
+    any_sampled: jnp.ndarray = None,  # scalar bool, for a caller that has it
+    salts: jnp.ndarray = None,  # [B] int: with positions, row_keys folded here
+    positions: jnp.ndarray = None,  # [B] int
 ) -> jnp.ndarray:
     """Returns sampled token ids [B]. Fully vectorized, static shapes.
 
+    When no live row samples (``any_row_samples(temperature, live)``; a
+    caller whose temperatures hold over many calls passes the scalar as
+    ``any_sampled``) the result is ``argmax(logits)``, ties to the lowest
+    index, and the candidate search, the sort and the noise do not run.
+    Otherwise every row goes through ``sample_candidates``: a row that
+    samples, alone or beside greedy rows, gets the token it always got for
+    the same key. ``live=None`` keeps every row live.
+
     With ``row_keys``, each row draws its gumbel noise from its own key so
     the sample depends only on that row's (key, logits, params) — batch
-    layout and the other rows' state cannot perturb it."""
+    layout and the other rows' state cannot perturb it. ``salts`` and
+    ``positions`` give the same keys (``fold_row_keys(rng, salts,
+    positions)``), folded inside the sampling branch."""
+    if any_sampled is None:
+        any_sampled = any_row_samples(temperature, live)
+
+    def full():
+        keys = row_keys if salts is None else fold_row_keys(rng, salts, positions)
+        return sample_candidates(
+            logits, rng, temperature, top_k, top_p, min_p, keys)
+
+    def greedy():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    return jax.lax.cond(any_sampled, full, greedy)
+
+
+def sample_candidates(
+    logits: jnp.ndarray,  # [B, V] float
+    rng: jax.Array,  # single PRNG key (ignored when row_keys given)
+    temperature: jnp.ndarray,  # [B] float; <=0 means greedy
+    top_k: jnp.ndarray,  # [B] int; <=0 means off
+    top_p: jnp.ndarray,  # [B] float; >=1 means off
+    min_p: jnp.ndarray = None,  # [B] float; <=0/None means off
+    row_keys: jax.Array = None,  # [B] per-row keys (fold_row_keys)
+) -> jnp.ndarray:
+    """``sample_tokens``' sampling branch: filtering and noise inside the
+    top ``SAMPLE_WIDTH`` logits of every row."""
     B, V = logits.shape
     W = min(SAMPLE_WIDTH, V)
 

@@ -142,6 +142,15 @@ ENGINE_MOE_PREFILL_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_prefill_tokens_total"
 # state a decode step reads and writes (100% says the kernel did not engage).
 # Both series at 0 from start-up in an engine with recurrent layers.
 ENGINE_SSM_DECODE_ROWS_TOTAL = f"{ENGINE_PREFIX}_ssm_decode_rows_total"
+# Steps of dispatched decode bursts by the branch their sampler takes (label
+# path=greedy|full): a burst adds --decode-steps to ``greedy`` when none of its
+# live rows has temperature > 0 (ops/sampling.sample_tokens returns the arg-max
+# of the logits and runs nothing else), else to ``full`` (candidate search,
+# sort, filters and noise over every slot). The host applies the program's own
+# predicate to the burst's rows: a dead slot's stale temperature counts in
+# neither. greedy / both = how often the sampler's work is skipped. Both series
+# at 0 from start-up.
+ENGINE_SAMPLER_DECODE_STEPS_TOTAL = f"{ENGINE_PREFIX}_sampler_decode_steps_total"
 # Recurrent (state-space) state beside the paged K/V: slots are one per decode
 # row, snapshots are the block-aligned state copies prefix reuse resumes from
 # (label state=used|total).
@@ -682,6 +691,7 @@ ALL_ENGINE = (
     ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL,
     ENGINE_MOE_PREFILL_TOKENS_TOTAL,
     ENGINE_SSM_DECODE_ROWS_TOTAL,
+    ENGINE_SAMPLER_DECODE_STEPS_TOTAL,
     ENGINE_SSM_STATE_SLOTS,
     ENGINE_SSM_SNAPSHOTS,
     ENGINE_SSM_SNAPSHOT_HITS_TOTAL,

@@ -283,23 +283,17 @@ def test_hybrid_grouped_kernel_is_the_program_it_was(tokens):
             == HYBRID_GROUPED_KERNEL_JAXPR[tokens])
 
 
-@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
-def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
-    """The decode burst and the prefill step of a two-layer qwen2.5-0.5b,
-    compiled for the v5e as the runner builds them: the optimised HLO holds
-    no copy of a whole per-layer pool (96 a program with the pool at its
-    logical head size of 64: the resident layout was not the kernels'),
-    and the donated pools alias in and out."""
+@functools.cache
+def _dense_program(one_chip, program):
+    """(compiled, a layer's K pool, resident bytes) of one served program of
+    a two-layer qwen2.5-0.5b, as the runner builds it."""
     import dataclasses
     import types
-
-    import numpy as np
 
     from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
     from dynamo_tpu.engines.tpu.runner import DeviceRunner
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import qwen2_500m_config
-    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
     cfg = dataclasses.replace(qwen2_500m_config(), n_layers=2, vocab_size=8192)
     NB, S = 2048, 64
@@ -351,8 +345,20 @@ def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
             params, None, k, v, arr((B, C), i32), arr((B,), i32),
             arr((B,), i32), arr((B, P), i32), *rows(B), None, None,
         )
-    compiled = lowered.compile()
-    assert whole_pool_copies(compiled.as_text(), k[0]) == 0
+    return lowered.compile(), k[0], resident
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
+    """The decode burst and the prefill step of a two-layer qwen2.5-0.5b,
+    compiled for the v5e as the runner builds them: the optimised HLO holds
+    no copy of a whole per-layer pool (96 a program with the pool at its
+    logical head size of 64: the resident layout was not the kernels'),
+    and the donated pools alias in and out."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
+
+    compiled, pool, resident = _dense_program(one_chip, program)
+    assert whole_pool_copies(compiled.as_text(), pool) == 0
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert resident <= aliased < resident + (1 << 20)
 
@@ -367,6 +373,7 @@ HYBRID_PROGRAMS = {
 }
 
 
+@functools.cache
 def _hybrid_program(one_chip, program):
     """One served program of the hybrid configuration at its PUBLISHED widths
     (one layer of each kind: Mamba-2, experts with 64 of 128 held as the cell
@@ -549,6 +556,7 @@ def test_mla_kernel_compiles_for_v5e_at_the_served_widths(one_chip, name):
     assert "mla_paged_decode" in compiled.as_text()
 
 
+@functools.cache
 def _mla_program(one_chip, program, depth):
     """(compiled, donated shapes) of one served program of the openPangu
     configuration at its published widths, ``depth`` expert layers after the
@@ -642,6 +650,7 @@ def test_mla_served_programs_compile_for_the_chip(one_chip, program):
                 for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]
 
 
+@functools.cache
 def _laguna_program(one_chip, program):
     """One served program of the Laguna-XS.2 stage at its published widths
     (layers 0-2: full + dense, two sliding expert layers; then the last full
@@ -769,6 +778,7 @@ def _sala_config():
     return dataclasses_replace_layers(minicpm_sala_pp4_config(), [0, 1, 2, 3, 14, 15])
 
 
+@functools.cache
 def _sala_program(one_chip, program):
     """One served program of the MiniCPM-SALA stage at its published widths
     (a sparse layer, a lightning layer and the last sparse layer, each with
@@ -845,3 +855,80 @@ def test_sala_served_programs_compile_for_the_chip(one_chip, program):
     if program != "decode_burst":
         resident += sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in store["S"])
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
+
+
+def _computations(hlo_text):
+    """name -> text of every computation of an optimised HLO module."""
+    import re
+
+    return {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?^\}$", hlo_text, re.M | re.S)}
+
+
+def _reached(computations, name):
+    """The text of a computation and of every one it calls, fusions' bodies
+    and comparators included."""
+    import re
+
+    seen, todo = {}, [name]
+    while todo:
+        at = todo.pop()
+        if at in seen:
+            continue
+        seen[at] = computations[at]
+        for m in re.finditer(
+                r"(?:calls|to_apply|body|condition|true_computation|false_computation)=%([\w.\-]+)"
+                r"|(?:branch_computations|called_computations)=\{([^}]*)\}", seen[at]):
+            todo += [m.group(1)] if m.group(1) else re.findall(r"%([\w.\-]+)", m.group(2))
+    return "\n".join(seen.values())
+
+
+def sampler_branches(hlo_text):
+    """(arg-max branch, candidates' branch) of each ``conditional`` that
+    ``ops/sampling.sample_tokens`` put into an optimised program (traced
+    under the ``sample`` scope), each branch with everything it calls."""
+    import re
+
+    computations = _computations(hlo_text)
+    found = []
+    for line in hlo_text.splitlines():
+        if " conditional(" not in line or "sample/cond" not in line:
+            continue
+        m = re.search(r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}", line)
+        if m is None:  # the predicated form names the true branch first
+            m = re.search(r"true_computation=%([\w.\-]+), false_computation=%([\w.\-]+)", line)
+            names = (m.group(2), m.group(1))
+        else:
+            names = m.groups()  # index 0 is ``lax.cond``'s false branch
+        found.append(tuple(_reached(computations, n) for n in names))
+    return found
+
+
+SERVED_PROGRAMS = {
+    "dense": _dense_program,
+    "hybrid": _hybrid_program,
+    "latent": lambda chip, program: _mla_program(chip, program, depth=1),
+    "window": _laguna_program,
+    "sparse": _sala_program,
+}
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+@pytest.mark.parametrize("cell", sorted(SERVED_PROGRAMS))
+def test_served_programs_hold_one_conditional_for_the_sampler(one_chip, cell, program):
+    """The decode burst and the prefill steps of the five served shapes, as
+    the tests above compile them: ONE ``conditional`` from the sampler (in
+    the burst inside the loop's body), whose arg-max branch holds no ``sort``
+    and no top-k, exact or approximate, while the other holds both (traced
+    here, where the default backend is the CPU, the candidates' search is
+    ``lax.top_k``; on the chip ``approx_max_k``, a ``PartialReduce``); and a
+    prefill program still holds no ``while``, by which benchmark/trace_names
+    tells it from the burst."""
+    text = SERVED_PROGRAMS[cell](one_chip, program)[0].as_text()
+    ((greedy, full),) = sampler_branches(text)
+    assert "PartialReduce" not in greedy
+    for op in (" sort(", "top_k"):
+        assert op not in greedy, op
+        assert op in full, op
+    assert " reduce(" in greedy  # the arg-max: one pass over the logits
+    assert (" while(" in text) == (program == "decode_burst")

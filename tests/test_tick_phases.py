@@ -701,7 +701,7 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
         mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
     labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
               | set(mn.FRAME_KINDS) | {"used", "total", "window", "sparse", "dense"}
-              | {"updated", "slots"}
+              | {"updated", "slots"} | {"greedy", "full"}
               | set(MOE_FORMS))
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
