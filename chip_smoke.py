@@ -7,10 +7,10 @@ calls — ``python -m dynamo_tpu.discd``, ``python -m dynamo_tpu.worker
 at the published Qwen3-8B widths (all 36 layers, random weights from the
 seed), sends a few OpenAI requests over HTTP, and checks what came out by
 the repo's own means: token counts and finish reasons, the device the
-worker says it holds (``/debug/memory``), the decode path that served
-(``/engine/stats``), the compiles (``/debug/compiles``) and the prefix hit
-(``/debug/kvcache``). Then it starts the worker a second time, which must
-be served from the compile cache, and compiles every Pallas kernel against
+worker says it holds (``/debug/memory``), that decode bursts ran and what
+attention served them (``/engine/stats``), the compiles
+(``/debug/compiles``) and the prefix hit (``/debug/kvcache``). Then it
+starts the worker a second time, which must be served from the compile cache, and compiles every Pallas kernel against
 its XLA reference (``python -m dynamo_tpu.ops.pallas.chip_check``). On a
 host with four chips it goes on to a tensor-parallel worker over all four
 and a prefill/decode pair on two different chips.
@@ -422,8 +422,7 @@ class Cluster:
 
 def read_devices(cluster: Cluster, idx: int, want_platform: str) -> List[dict]:
     """The device rows of the process that HOLDS the devices. Read before
-    any burst counter is trusted: off-chip the fused kernel would run in
-    the Pallas interpreter."""
+    any burst counter is trusted: off-chip nothing it counts is the chip's."""
     devices = cluster.system(idx, "/debug/memory")["devices"]
     if not devices or any(d.get("platform") != want_platform for d in devices):
         raise SmokeFailure(
@@ -433,20 +432,26 @@ def read_devices(cluster: Cluster, idx: int, want_platform: str) -> List[dict]:
     return devices
 
 
-def served_path(cluster: Cluster, idx: int) -> Tuple[str, dict]:
-    """Exactly one decode path may have served. Returns it and the stats."""
+def served_path(cluster: Cluster, idx: int, run: "Run", tp: int = 1) -> dict:
+    """Decode bursts ran through the decode program, and the attention the
+    runner chose is the platform's (the Pallas kernels on one chip; XLA on
+    the CPU and under a mesh). Returns the stats."""
     stats = cluster.system(idx, "/engine/stats", body={})
-    fused, xla = stats["mk_fused_bursts"], stats["mk_fallback_bursts"]
-    if (fused > 0) == (xla > 0):
+    program = cluster.system(idx, "/debug/compiles")["programs"].get(
+        "runner.decode_state", {})
+    if stats["decode_steps"] < 1 or program.get("compiles", 0) < 1:
         raise SmokeFailure(
-            f"decode bursts fused={fused} xla={xla}: exactly one path must serve"
+            f"no decode burst served: decode_steps={stats['decode_steps']}, "
+            f"runner.decode_state={program}"
         )
-    served = "fused" if fused else "xla"
-    if served != stats["decode_path"]:
+    want = "pallas" if run.platform == "tpu" and tp == 1 else "xla"
+    if stats["attention_impl"] != want:
         raise SmokeFailure(
-            f"runner chose {stats['decode_path']!r} but {served!r} bursts ran"
+            f"attention served from {stats['attention_impl']!r} "
+            f"({stats['attention_reason']}), want {want!r} on {run.platform} "
+            f"with tp={tp}"
         )
-    return served, stats
+    return stats
 
 
 def compile_snapshot(cluster: Cluster, idx: int) -> dict:
@@ -609,10 +614,17 @@ def stage_serve(run: Run) -> Dict[str, Any]:
             f"device 0 bytes_in_use {mem0.get('bytes_in_use')} does not exceed "
             f"the weight bytes {weights}: the weights are not on the chip"
         )
-    served, stats = served_path(cluster, 0)
-    for key in ("mk_fused_bursts", "mk_fallback_bursts"):
-        if metric_value(cluster, 0, f"dynamo_tpu_engine_{key}") != stats[key]:
-            raise SmokeFailure(f"/metrics {key} disagrees with /engine/stats")
+    for _ in range(3):  # a burst in flight when the streams ended is reaped late
+        stats = served_path(cluster, 0, run)
+        scraped = metric_value(cluster, 0, "dynamo_tpu_engine_decode_steps")
+        if scraped == stats["decode_steps"]:
+            break
+        time.sleep(0.5)
+    else:
+        raise SmokeFailure(
+            f"/metrics decode_steps {scraped} disagrees with /engine/stats "
+            f"{stats['decode_steps']}"
+        )
     compiles = compile_snapshot(cluster, 0)
     kv = cluster.system(0, "/debug/kvcache")
     if (kv.get("hits") or {}).get("device", 0) < 1 or \
@@ -635,8 +647,9 @@ def stage_serve(run: Run) -> Dict[str, Any]:
           f"+ [16, 151936] f32 logits temporary {logits_tmp / 1e6:.1f} MB; "
           f"peak bytes in use {mem0.get('peak_bytes_in_use')} of limit "
           f"{mem0.get('bytes_limit')}")
-    print(f"[serve] decode path served: {served} ({stats['decode_path_reason']}); "
-          f"bursts fused={stats['mk_fused_bursts']} xla={stats['mk_fallback_bursts']}")
+    print(f"[serve] decode bursts reaped: {stats['decode_steps']} "
+          f"(runner.decode_state: "
+          f"{compiles['programs']['runner.decode_state']['compiles']} programs)")
     print(f"[serve] attention: {stats['attention_impl']} ({stats['attention_reason']})")
     print(f"[serve] compiles: {json.dumps(compiles['totals'])}")
     for name, prog in compiles["programs"].items():
@@ -760,12 +773,12 @@ def stage_tp(run: Run) -> None:
     )])
     setup_s = run_requests(run, cluster, cluster.workers[0][0].t_spawn)
     devices = read_devices(cluster, 0, run.platform)
-    served, stats = served_path(cluster, 0)
+    stats = served_path(cluster, 0, run, tp=run.tp)
     compiles = compile_snapshot(cluster, 0)
     cluster.stop()
     held = [(d["id"], (d.get("memory_stats") or {}).get("bytes_in_use")) for d in devices]
     print(f"[{tag}] bytes in use per device: {held}")
-    print(f"[{tag}] decode path {served} ({stats['decode_path_reason']}); "
+    print(f"[{tag}] decode bursts reaped: {stats['decode_steps']}; "
           f"attention {stats['attention_impl']} ({stats['attention_reason']})")
     print(f"[{tag}] compiles: {json.dumps(compiles['totals'])}")
     print(f"[{tag}] SET-UP seconds (spawn -> first token): {setup_s:.1f}", flush=True)

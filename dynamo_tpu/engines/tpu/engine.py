@@ -92,8 +92,8 @@ class JaxEngineArgs:
     admit_kv_high_watermark: float = 0.95
     # Batched prefill: pack up to this many admissions into ONE device
     # dispatch ([Bp, C] with per-row start/len). B=1 prefill wastes the MXU
-    # (measured: B=8 costs only ~1.4× B=1 on a v5e) and serial admission was
-    # the round-2 bench's bottleneck (64-slot engine ramping 4 seqs/tick).
+    # and serial admission ramps a 64-slot engine a few rows a tick (what
+    # was measured: PERF.md).
     prefill_batch: int = 8
     admit_batches_per_tick: int = 8  # bounds decode stall per scheduler tick
     enable_prefix_caching: bool = True
@@ -125,21 +125,14 @@ class JaxEngineArgs:
     # KV cache layout: per-layer 4D pools (tuple of [NB, BS, KH, D]) instead
     # of one stacked 5D array. The layered form lets XLA update each pool in
     # place; the stacked form forces the layer-scan to rematerialize the FULL
-    # cache as scan ys every step (~2× cache size of HBM traffic — measured
-    # 22.2 → 15.2 ms/step at the bench shape). Stacked remains for
-    # pipeline-parallel stages that slice the layer axis.
+    # cache as scan ys every step (~2× cache size of HBM traffic). Stacked
+    # remains for pipeline-parallel stages that slice the layer axis.
     layered_cache: bool = True
     # KV-cache quantization: "int8" = per-token-per-head dynamic int8 pools
     # (ops/kv_quant.py) — halves the decode step's history-read bytes and
     # doubles the sequences a fixed HBM budget can hold. The reference's
     # kv_cache_dtype=fp8 engine lever, TPU-style. Requires layered_cache.
     kv_cache_dtype: Optional[str] = None
-    # Fused-layer decode megakernel (ops/pallas/fused_layer.py): one pallas
-    # program per layer streaming int8 weights with the attention page
-    # fetches overlapped. None = auto (TPU + int8 weights + layered bf16
-    # cache + eligible architecture). The XLA path stays the fallback for
-    # every ineligible shape and for prefill.
-    use_megakernel: Optional[bool] = None
     # Decode-tick pipelining: how many fused decode bursts may be in flight
     # on the device at once. 2 (default) double-buffers — burst N+1 is
     # dispatched from the device-resident carry while the host reads back
@@ -271,10 +264,9 @@ PIPELINE_LOOKAHEAD_BURSTS = 2
 def table_width_bucket(max_blocks: int, cap: int) -> int:
     """Pow2 bucket for a dispatched block-table width, clamped to the
     engine's per-sequence table capacity. Every distinct width is a
-    separate compiled decode program — the megakernel's dynamic page loop
-    makes the TRACE width-independent, but XLA still specializes on the
-    operand shape — so bucketing bounds the program count to ~log2(cap)
-    as contexts grow instead of one program per context length. Shared by
+    separate compiled decode program (XLA specializes on the operand
+    shape), so bucketing bounds the program count to ~log2(cap) as
+    contexts grow instead of one program per context length. Shared by
     the decode tick and the speculative-verify dispatch (spec.py)."""
     return min(_next_pow2(max(max_blocks, 1)), cap)
 
@@ -790,19 +782,15 @@ class JaxEngine:
                 self._budgeter.rollovers
                 if self._budgeter is not None else 0
             ),
-            # Which decode path and attention implementation the runner
-            # chose at start and why, and the bursts each path served
-            # (exactly one of the two totals moves; the per-variant split
-            # flattens into per-variant gauges on the metrics surface).
-            "decode_path": self.runner.decode_path,
-            "decode_path_reason": self.runner.decode_path_reason,
+            # One decode path serves (llama.decode_multi); the two literals
+            # stay for benchmark/run.py's log line (ROADMAP D16). Which
+            # attention implementation the runner chose at start and why.
+            "decode_path": "xla",
+            "decode_path_reason": "the XLA decode step is the only decode path",
             "attention_impl": self.runner.attention_impl,
             "attention_reason": self.runner.attention_reason,
             "expert_ffn": self.runner.expert_ffn,
             "ssd_step": self.runner.ssd_step,
-            "mk_fused_bursts": self.runner.mk_fused_bursts,
-            "mk_fallback_bursts": self.runner.mk_fallback_bursts,
-            "mk_bursts_by_variant": dict(self.runner.mk_bursts_by_variant),
             # What was compiled before this engine served a request
             # (compile_prefill_ladder; zeros where it was never called):
             # against /debug/compiles, what serving has compiled since.

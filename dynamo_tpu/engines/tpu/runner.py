@@ -317,9 +317,6 @@ class DeviceRunner:
         self.use_kernel, self.attention_reason = self._choose_attention(
             args, backend, mesh
         )
-        self.use_megakernel, self.decode_path_reason = (
-            self._choose_decode_path(args, backend, mesh)
-        )
         if self.multihost and mesh is None:
             raise ValueError("multihost topology requires a device mesh")
         self._repl = (
@@ -489,15 +486,6 @@ class DeviceRunner:
             (False, False, False): self._step_fn
         }
         self.proc_state: Optional[Any] = None  # logits_process.ProcState
-        # Which decode path served, total and per (width bucket, variant):
-        # a runner decodes on ONE path for its whole life (chosen at start,
-        # _choose_decode_path), so exactly one of the two totals moves.
-        # Surfaced through engine stats()/metrics. Written on the
-        # device-executor thread, read by stats snapshots (plain int/dict
-        # reads).
-        self.mk_fused_bursts = 0
-        self.mk_fallback_bursts = 0
-        self.mk_bursts_by_variant: Dict[str, int] = {}
         self._spec_fn: Optional[Any] = None  # speculative verify program
         self.sleep_level = 0
         self.host_params: Optional[Any] = None
@@ -506,11 +494,9 @@ class DeviceRunner:
         self._prefill_expert_forms: Dict[int, Optional[str]] = {}
         logger.info(
             "device runner: platform=%s device_kind=%s devices=%d mesh=%s | "
-            "decode path: %s (%s) | attention: %s (%s) | expert_ffn: %s | "
-            "ssd_step: %s",
+            "attention: %s (%s) | expert_ffn: %s | ssd_step: %s",
             backend, jax.devices()[0].device_kind, len(jax.devices()),
             dict(mesh.shape) if mesh is not None else None,
-            self.decode_path, self.decode_path_reason,
             self.attention_impl, self.attention_reason, self.expert_ffn,
             self.ssd_step,
         )
@@ -606,10 +592,6 @@ class DeviceRunner:
         return self.config.is_hybrid
 
     @property
-    def decode_path(self) -> str:
-        return "fused" if self.use_megakernel else "xla"
-
-    @property
     def attention_impl(self) -> str:
         return "pallas" if self.use_kernel else "xla"
 
@@ -702,42 +684,6 @@ class DeviceRunner:
         # table shows refused gets its reason returned here, so its worker
         # serves from XLA visibly instead of dying at the first request.
         return True, "platform is tpu, single device"
-
-    @staticmethod
-    def _choose_decode_path(args, backend: str, mesh) -> Tuple[bool, str]:
-        """(use the fused-layer megakernel for decode?, why)."""
-        from dynamo_tpu.ops.pallas.fused_layer import supports_reason
-
-        if args.use_megakernel is not None and not args.use_megakernel:
-            return False, "use_megakernel=False set by the caller"
-        why_not = None
-        if not args.layered_cache:
-            why_not = "stacked KV cache layout"
-        elif getattr(args, "kv_cache_dtype", None):
-            why_not = "quantized KV pool (the kernel streams bf16 pages)"
-        elif args.quantization != "int8":
-            why_not = "weights not int8-quantized"
-        elif mesh is not None:
-            why_not = "device mesh present"
-        elif args.max_num_seqs % 4 != 0:
-            why_not = "max_num_seqs not a multiple of the batch wave (4)"
-        else:
-            why_not = supports_reason(
-                args.config, lora=bool(args.lora_dir), quantized_weights=True
-            )
-        if args.use_megakernel:
-            if why_not is not None:
-                logger.warning(
-                    "use_megakernel=True requested but the configuration "
-                    "is ineligible (%s) — decoding on the XLA path", why_not,
-                )
-                return False, f"ineligible: {why_not}"
-            return True, "use_megakernel=True set by the caller"
-        if why_not is not None:
-            return False, f"ineligible: {why_not}"
-        if backend != "tpu":
-            return False, f"platform is {backend}"
-        return True, "platform is tpu and the configuration is eligible"
 
     # -- SPMD --------------------------------------------------------------
 
@@ -1062,7 +1008,6 @@ class DeviceRunner:
             return self._build_decode_fn_hybrid(want_logprobs, want_procs)
         cfg = self.config
         use_kernel = self.use_kernel
-        use_megakernel = self.use_megakernel
         num_steps = self.args.decode_steps
 
         # The logprobs program variants also surface the per-step top-N
@@ -1077,7 +1022,6 @@ class DeviceRunner:
                     params, cfg, tokens, pos, active, block_tables,
                     k_cache, v_cache, rng, temp, topk, topp,
                     num_steps=num_steps, use_kernel=use_kernel,
-                    use_megakernel=use_megakernel,
                     lora=lora, adapter_ids=adapter_ids,
                     want_logprobs=want_logprobs,
                     num_top_logprobs=num_top,
@@ -1109,7 +1053,6 @@ class DeviceRunner:
                 params, cfg, tokens, pos, active, block_tables,
                 k_cache, v_cache, rng, temp, topk, topp,
                 num_steps=num_steps, use_kernel=use_kernel,
-                use_megakernel=use_megakernel,
                 lora=lora, adapter_ids=adapter_ids,
                 want_logprobs=want_logprobs,
                 min_p=minp, proc_params=pp, proc_state=st,
@@ -1433,7 +1376,6 @@ class DeviceRunner:
                 self.slot_state, tokens=carry_tok, pos=carry_pos
             )
             self._log_transfer("decode", nb)
-            self.mk_fallback_bursts += 1
             return _DecodeHandles(
                 toks=toks, logp=logp, topv=topv, topi=topi, moe=moe
             )
@@ -1474,24 +1416,7 @@ class DeviceRunner:
             self.slot_state, tokens=carry_tok, pos=carry_pos
         )
         self._log_transfer("decode", nb)
-        if self.use_megakernel:
-            self.mk_fused_bursts += 1
-            label = self._variant_label(nb, want_logprobs, use_procs)
-            self.mk_bursts_by_variant[label] = (
-                self.mk_bursts_by_variant.get(label, 0) + 1
-            )
-        else:
-            self.mk_fallback_bursts += 1
         return _DecodeHandles(toks=toks, logp=logp, topv=topv, topi=topi)
-
-    @staticmethod
-    def _variant_label(nb, want_logprobs, use_procs) -> str:
-        """Prometheus-safe per-variant key for the burst counters."""
-        return (
-            f"w{int(nb)}"
-            + ("_logprobs" if want_logprobs else "")
-            + ("_procs" if use_procs else "")
-        )
 
     def decode_read(self, handles: "_DecodeHandles"):
         """Blocking readback half of decode_dispatch. Returns ([S, K]

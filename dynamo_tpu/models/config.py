@@ -430,9 +430,9 @@ class ModelConfig:
     def hybrid_refusal(self, mechanism: str) -> Optional[str]:
         """Why ``mechanism`` cannot serve this configuration, or None. The
         mechanisms that spell out the per-layer K/V tuple (disaggregation
-        wire, KVBM tiers, KV checkpoints, int8 KV, the fused-layer
-        megakernel) know nothing of per-sequence recurrent state, nor of a
-        pool that is one latent tile a layer."""
+        wire, KVBM tiers, KV checkpoints, int8 KV) know nothing of
+        per-sequence recurrent state, nor of a pool that is one latent
+        tile a layer."""
         mla = self.specs_of("mla")
         win = self.window_group
         sparse = [s for s in self.specs_of("attention") if s.sparse]
@@ -1321,24 +1321,6 @@ def gemma3_1b_config() -> ModelConfig:
         eos_token_ids=[1, 106],
         name="gemma-3-1b",
     )
-
-
-def all_presets() -> Dict[str, "ModelConfig"]:
-    """Every named preset, keyed by its ``name``. The megakernel
-    supports-matrix test iterates THIS registry (a new preset is
-    automatically checked against the fused path's supports() gate or
-    the documented-exclusion table — it can never silently drift to the
-    slow decode path)."""
-    presets = [
-        tiny_config(), tiny_moe_config(), mixtral_8x7b_config(),
-        qwen2_500m_config(), llama3_8b_config(), llama3_3b_config(),
-        llama3_70b_config(), qwen3_8b_config(), gemma3_1b_config(),
-        gemma2_2b_config(), tiny_hybrid_config(), nemotron3_nano_ep2_config(),
-        tiny_mla_config(), openpangu_ultra_moe_ep16_config(),
-        tiny_swa_config(), laguna_xs2_pp8_config(),
-        tiny_sala_config(), minicpm_sala_pp4_config(),
-    ]
-    return {c.name: c for c in presets}
 
 
 def gemma2_2b_config() -> ModelConfig:

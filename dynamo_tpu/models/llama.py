@@ -161,8 +161,7 @@ def init_kv_cache(
     as the kernels read it. The layered form is what the hot path
     wants: the stacked form forces the layer-scan to rematerialize the FULL
     cache as scan ys every step (~2× cache size of HBM traffic per decode
-    step, measured 22.2 → 15.2 ms/step at the bench shape when switched),
-    while per-layer carries update in place.
+    step), while per-layer carries update in place.
 
     ``kv_dtype="int8"`` (layered only): each layer's pool is a quantized
     {"q8", "s"} dict (ops/kv_quant.py) — half the history-read bytes and
@@ -471,7 +470,6 @@ def forward_paged(
     mm_slot: Optional[jnp.ndarray] = None,  # [B, C] int32 row into mm_embeds, -1=text
     all_logits: bool = False,  # True → logits for EVERY position [B, C, V]
     first_chunk: bool = False,  # static: fresh prefill, dense in-chunk attention
-    use_megakernel: bool = False,  # C=1: fused-layer pallas decode path
     ssm: Optional[Dict[str, Any]] = None,  # hybrid: recurrent state (models/hybrid.py)
     snap: Optional[Dict[str, Any]] = None,  # hybrid: snapshot store + destinations
     want_moe_stats: bool = False,
@@ -491,10 +489,10 @@ def forward_paged(
         # ssm', snapshot store' | None, expert-load stats | None).
         from dynamo_tpu.models import hybrid
 
-        if lora or mm_embeds is not None or use_megakernel:
+        if lora or mm_embeds is not None:
             raise ValueError(
-                f"{c.name}: LoRA, multimodal splices and the megakernel are "
-                "not implemented for hybrid models"
+                f"{c.name}: LoRA and multimodal splices are not implemented "
+                "for hybrid models"
             )
         return hybrid.forward(
             params, c, tokens, start_pos, chunk_lens, block_tables, k_cache,
@@ -521,95 +519,14 @@ def forward_paged(
         # Serving layout: Python-unrolled layers over per-layer 4D pools.
         # Static layer indices let XLA update every pool in place (step-scan
         # carry / donated buffer). The stacked form below rematerializes the
-        # FULL cache as scan ys every call (~2× cache size of HBM traffic) —
-        # measured 22.2 → 15.2 ms/step at the bench shape when switched.
+        # FULL cache as scan ys every call (~2× cache size of HBM traffic).
         # HLO grows ~L× but is traced once; compile stays cached.
         win_list = c.layer_windows()
         layered_params = isinstance(params["layers"], (tuple, list))
 
-        if (
-            use_megakernel
-            and C == 1
-            and layered_params
-            and not lora
-        ):
-            # Fused-layer decode megakernel (ops/pallas/fused_layer.py):
-            # one pallas program per layer; the current token's K/V come
-            # back as outputs and are scattered AFTER (the kernel attends
-            # history pages + the in-register token). Family epilogues
-            # (qk-norm, softcap, post-norms, GeGLU, unit-offset norms,
-            # qkv-bias, sliding windows) run IN-KERNEL: the per-layer
-            # window rides a traced scalar operand (windowed and global
-            # layers share one compiled program) and the per-layer rope
-            # table is selected HERE (Gemma-3 dual-frequency: local table
-            # on windowed layers, unscaled).
-            from dynamo_tpu.ops.attention import write_chunk_to_cache
-            from dynamo_tpu.ops.pallas.fused_layer import (
-                fused_decoder_layer,
-            )
-
-            sm = (
-                c.query_scale**-0.5
-                if c.query_scale is not None
-                else c.head_dim_**-0.5
-            )
-            x2 = x[:, 0]
-            cos1, sin1 = cos[:, 0], sin[:, 0]
-            cosl1 = cos_loc[:, 0] if cos_loc is not None else None
-            sinl1 = sin_loc[:, 0] if sin_loc is not None else None
-            # Per-row history page counts (the kernel's scalar-prefetch
-            # loop bound): one derivation per STEP, shared by every layer,
-            # instead of recomputing from start_pos inside each layer call.
-            from dynamo_tpu.ops.pallas.live_pages import history_pcounts
-
-            pcounts = history_pcounts(
-                start_pos, k_cache[0].shape[1], block_tables.shape[1]
-            )
-            any_window = any(int(w) != 0 for w in win_list)
-            k_out, v_out = [], []
-            for l in range(c.n_layers):
-                win_l = int(win_list[l])
-                local = cosl1 is not None and win_l > 0
-                x2, k_n, v_n = fused_decoder_layer(
-                    x2,
-                    cosl1 if local else cos1,
-                    sinl1 if local else sin1,
-                    params["layers"][l],
-                    k_cache[l], v_cache[l], block_tables, start_pos,
-                    eps=c.rms_norm_eps, sm_scale=sm, pcounts=pcounts,
-                    # Traced operand (not static) whenever ANY layer is
-                    # windowed, so the model's layers share one compiled
-                    # program per width bucket; window-free models omit
-                    # the operand entirely (identical trace to r6).
-                    window=(
-                        jnp.asarray(win_l, jnp.int32) if any_window else None
-                    ),
-                    act_fn=c.act_fn,
-                    unit_offset=c.rmsnorm_unit_offset,
-                    softcap=float(c.attn_logit_softcap or 0.0),
-                )
-                k_out.append(
-                    write_chunk_to_cache(
-                        k_cache[l], k_n[:, None], block_tables,
-                        start_pos, chunk_lens,
-                    )
-                )
-                v_out.append(
-                    write_chunk_to_cache(
-                        v_cache[l], v_n[:, None], block_tables,
-                        start_pos, chunk_lens,
-                    )
-                )
-            x = x2[:, None]
-            k_cache, v_cache = tuple(k_out), tuple(v_out)
-            if all_logits:
-                return lm_head_logits(params, c, x), k_cache, v_cache
-            return (
-                lm_head_logits(params, c, x[:, 0]), k_cache, v_cache
-            )
         # The paged-attention kernel's grid follows from positions, table
         # and window alone: one derivation per STEP and distinct window,
-        # shared by the layers (as pcounts above for the megakernel).
+        # shared by the layers.
         attn_plans = {} if first_chunk else {
             w: paged_attention_plan(
                 C, c.n_heads, k_cache[0], block_tables, start_pos,
@@ -767,7 +684,6 @@ def decode_multi(
     *,
     num_steps: int,
     use_kernel: bool = False,
-    use_megakernel: bool = False,
     lora: Optional[Dict[str, Any]] = None,
     adapter_ids: Optional[jnp.ndarray] = None,
     want_logprobs: bool = True,
@@ -851,8 +767,7 @@ def decode_multi(
         else:
             logits, k_c, v_c = forward_paged(
                 params, config, toks[:, None], pos, active, block_tables, k_c, v_c,
-                use_kernel=use_kernel, use_megakernel=use_megakernel, lora=lora,
-                adapter_ids=adapter_ids,
+                use_kernel=use_kernel, lora=lora, adapter_ids=adapter_ids,
             )
         with jax.named_scope("sample"):
             if proc_params is not None:

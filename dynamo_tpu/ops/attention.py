@@ -141,6 +141,8 @@ def paged_attention(
     )
 
 
+# The gather form: the kernels' test reference, the CPU path and the path
+# under a mesh.
 def _paged_attention_xla_impl(
     q, k_cache, v_cache, block_tables, start_pos, chunk_lens,
     window=0, *, sm_scale=None, logit_cap: float = 0.0,

@@ -4,9 +4,8 @@
 stage (its own process: the smoke's parent never touches JAX). For each
 attention shape the worker's builtin presets produce it lowers
 ``paged_attention_decode_kernel`` and ``paged_attention_kernel`` with
-``interpret=False`` over bf16 and int8-KV pools, and ``fused_decoder_layer``
-at the Qwen3-8B layer shape for every pow2 table width up to the worker's
-default model length, and ``expert_ffn`` (the hit-list expert kernel)
+``interpret=False`` over bf16 and int8-KV pools, and ``expert_ffn`` (the
+hit-list expert kernel)
 against ops/moe.py's dense form at the hybrid configuration's served widths
 (64 tokens over 1, 8, 19, 29 and all 64 held experts hit, and 128 and 256
 tokens with all hit) and at the latent configuration's (three matrices of
@@ -26,8 +25,7 @@ contexts, the others empty with a stale position, 128 pages of table; bf16
 and int8) and the all-live control at 8B width (32 rows within 200 tokens
 of a 128-page table). One last row, ``kv_pool_layout``, is not a kernel's:
 the serving pool's resident layout (``pool_layout_job``). Each compiled call is compared with
-``_paged_attention_xla`` / ``decoder_layer`` under
-``jax.default_matmul_precision("highest")``.
+``_paged_attention_xla`` under ``jax.default_matmul_precision("highest")``.
 
 Decode rows that compiled are then timed, one after another with the chip
 to themselves (``us/call``: 24 calls chained in one jitted loop, best of
@@ -36,17 +34,14 @@ five); the interpreter rehearsal times nothing.
 A refusal is RECORDED here (kernel, shape, first line of the compiler's
 message), never served around: the table goes to CHANGES.md, a refused
 preset gets its reason into the runner's start-up choice
-(``DeviceRunner._choose_attention`` / ``_choose_decode_path``) so its
-worker is not routed to the kernel, and a refused or disagreeing kernel on
-the smoke model's own path (``required`` rows) fails the stage.
+(``DeviceRunner._choose_attention``) so its worker is not routed to the
+kernel, and a refused or disagreeing kernel on the smoke model's own path
+(``required`` rows) fails the stage.
 
 Tolerance, one for every row: outputs are bf16 (8 significand bits, ulp
 2^-8 relative), values are O(1), and the kernels accumulate in f32 in a
 different order than the reference, so |kernel − reference| ≤ 4 bf16 ulps
 of the largest magnitude in the output: ``atol = 4 · 2^-8 · max|ref|``.
-The fused layer adds the int8-weight matmuls, whose f32 partial sums are
-rounded to bf16 at each phase boundary in both implementations but tile by
-tile in the kernel; it gets 4× that bound.
 """
 
 from __future__ import annotations
@@ -65,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.models.config import qwen3_8b_config, tiny_config
+from dynamo_tpu.models.config import tiny_config
 from dynamo_tpu.runtime.device_observe import watched_jit
 from dynamo_tpu.utils.jax_env import (
     configure_compile_cache,
@@ -379,71 +374,6 @@ def swa_attention_jobs(interpret: bool):
         functools.partial(job, full, "paged_attention", 256, False, B=1, P=256,
                           rows="full", block_size=128),
     ]
-
-
-def fused_layer_jobs(interpret: bool, config, B: int, widths: List[int]):
-    """The megakernel at one model's layer shape, one job per table width,
-    against models/llama.decoder_layer on the same int8 weights. The
-    logprobs / logits-processor program variants wrap the SAME layer kernel
-    (they differ after the lm_head), so a width that compiles here compiles
-    for every variant."""
-    import dataclasses
-
-    from dynamo_tpu.models import llama
-    from dynamo_tpu.models.quantize import init_quantized_params
-    from dynamo_tpu.ops.pallas.fused_layer import fused_decoder_layer
-    from dynamo_tpu.ops.rope import rope_table
-
-    c = dataclasses.replace(config, n_layers=1, vocab_size=256)
-    params = init_quantized_params(c, seed=0)
-    lp = jax.tree.map(lambda a: a[0], params["layers"])
-    D, KH = c.head_dim_, c.n_kv_heads
-
-    def job(P):
-        rng = np.random.default_rng(P)
-        NB = B * P + 1
-        k_pool = jnp.asarray(
-            rng.standard_normal((NB, BLOCK_SIZE, KH, D)), jnp.bfloat16
-        )
-        v_pool = jnp.asarray(
-            rng.standard_normal((NB, BLOCK_SIZE, KH, D)), jnp.bfloat16
-        )
-        tables = jnp.asarray(
-            rng.permutation(NB)[: B * P].reshape(B, P).astype(np.int32)
-        )
-        # Leave the last slot free: the reference writes the new token.
-        start = jnp.asarray(
-            rng.integers(0, P * BLOCK_SIZE - 1, B).astype(np.int32)
-        )
-        x = jnp.asarray(rng.standard_normal((B, c.d_model)), jnp.bfloat16)
-        cos, sin = rope_table(start[:, None], D, c.rope_theta)
-        row = {
-            "kernel": "fused_decoder_layer",
-            "shape": (
-                f"{config.name} layer d{c.d_model} H{c.n_heads} KH{KH} "
-                f"D{D} F{c.d_ff} B{B} P{P} bf16-KV"
-            ),
-            "presets": [config.name],
-            # The runner selects it for the smoke worker (int8 qwen3-8b).
-            "required": True,
-        }
-
-        def run():
-            return fused_decoder_layer(
-                x, cos[:, 0], sin[:, 0], lp, k_pool, v_pool, tables, start,
-                eps=c.rms_norm_eps, sm_scale=D**-0.5, interpret=interpret,
-            )[0]
-
-        def reference():
-            return llama.decoder_layer(
-                c, lp, {}, jnp.asarray(0, jnp.int32), x[:, None], cos, sin,
-                k_pool, v_pool, tables, start, jnp.ones((B,), jnp.int32),
-                use_kernel=False, adapter_ids=None,
-            )[0][:, 0]
-
-        return _timed(row, run, reference, ulps=16)
-
-    return [functools.partial(job, P) for P in widths]
 
 
 _DRAWING = threading.Lock()  # rows run side by side: one draw of a stack
@@ -1166,14 +1096,8 @@ def main() -> int:
         )
         return 2
     if args.interpret:
-        fused_cfg = tiny_config(
-            d_model=256, head_dim=128, n_heads=4, n_kv_heads=2, d_ff=512,
-            qk_norm=True, dtype=jnp.bfloat16, name="tiny-fused",
-        )
         families = {
             "paged_attention": lambda: attention_jobs(True, B=4, P=4),
-            "fused_decoder_layer": lambda: fused_layer_jobs(
-                True, fused_cfg, B=4, widths=[1, 4]),
             "expert_ffn": lambda: expert_ffn_jobs(True),
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(True),
             "mla_paged_decode": lambda: mla_jobs(True),
@@ -1185,13 +1109,9 @@ def main() -> int:
         from dynamo_tpu.worker.__main__ import build_parser
 
         worker = build_parser().parse_args([])
-        top = worker.max_model_len // BLOCK_SIZE
-        widths = [1 << i for i in range(top.bit_length())]
         families = {
             "paged_attention": lambda: attention_jobs(
                 False, B=worker.max_num_seqs, P=16),
-            "fused_decoder_layer": lambda: fused_layer_jobs(
-                False, qwen3_8b_config(), B=worker.max_num_seqs, widths=widths),
             "expert_ffn": lambda: expert_ffn_jobs(False),
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(False),
             "mla_paged_decode": lambda: mla_jobs(False),

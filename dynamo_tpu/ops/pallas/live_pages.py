@@ -1,8 +1,6 @@
-"""Per-row live page bounds of a paged KV cache, shared by the decode
-megakernel (fused_layer.py) and the standalone live-span decode kernel
-(paged_attention.py). Plain XLA on [B]-sized operands: derived ONCE per
-forward step and shared by every layer
-(docs/design_docs/megakernel_paged_streaming.md).
+"""Per-row live page bounds of a paged KV cache, for the live-span decode
+kernel (paged_attention.py). Plain XLA on [B]-sized operands: derived ONCE
+per forward step and shared by every layer (docs/design_docs/engine.md).
 """
 
 from __future__ import annotations
@@ -15,11 +13,9 @@ import jax.numpy as jnp
 def history_pcounts(
     start_pos: jnp.ndarray, block_size: int, table_width: int
 ) -> jnp.ndarray:
-    """Per-row history page count for the decode megakernel's dynamic page
-    loop, clamped to the table width so a row can never index past its
-    table (the causal mask already hides any positions beyond it). Exposed
-    so the per-step caller (models/llama.py forward_paged) derives it ONCE
-    and shares it across all layers instead of recomputing per layer."""
+    """Per-row count of the pages that hold keys before ``start_pos``,
+    clamped to the table width so a row can never index past its table
+    (the causal mask already hides any positions beyond it)."""
     start32 = start_pos.astype(jnp.int32)
     return jnp.minimum((start32 + block_size - 1) // block_size, table_width)
 
@@ -43,8 +39,7 @@ def window_page_bounds(
 
 def live_page_bounds(start_pos, chunk_lens, C, window, block_size, table_width):
     """(pcount, poff) per row for the live-span decode kernel: the row's
-    keys live on pages ``[poff, pcount)``. Unlike the megakernel's history
-    (which excludes the current token), the cache here already holds the
+    keys live on pages ``[poff, pcount)``. The cache already holds the
     chunk, so the last needed key is ``start + C − 1``; a row with
     ``chunk_lens == 0`` (an empty slot) gets ``pcount`` 0 and costs
     nothing. ``poff`` is the page of the first key a sliding window
@@ -70,8 +65,8 @@ def live_work_list(pcount, poff, group_pages: int, table_width: int):
     call, while the same one-step grid over a list of two runs — and the
     entry at ``total``, which the pipeline's index maps may read one step
     ahead of the grid's last step, exists and names a block it already
-    holds (my chip runs, PR 25:
-    docs/design_docs/megakernel_paged_streaming.md)."""
+    holds (my chip runs, PR 25: docs/design_docs/engine.md, "The work
+    list is never one entry")."""
     S = group_pages
     B = pcount.shape[0]
     groups = (jnp.maximum(pcount - poff, 0) + S - 1) // S  # [B]

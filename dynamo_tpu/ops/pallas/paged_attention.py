@@ -40,8 +40,7 @@ dispatched shape:
     work list, step t → (row, first page) (ops/pallas/live_pages.py), once
     per forward step; the layers share it. An empty slot, or table width
     past a row's context, is no grid step — the kernel is not told the
-    slot count or the table width at all
-    (docs/design_docs/megakernel_paged_streaming.md).
+    slot count or the table width at all (docs/design_docs/engine.md).
   - A step visits up to ``DECODE_GROUP_PAGES`` consecutive pages of one
     row (fewer where a page is large: ``_decode_group_pages`` keeps the
     step's operands inside a VMEM budget), each a BlockSpec operand whose
@@ -49,10 +48,9 @@ dispatched shape:
     the next step's pages across row boundaries; the work list is one
     entry longer than the grid's most steps (live_pages.live_work_list: a
     one-entry list halts the core). The pages stay BlockSpec operands
-    (not manual DMAs from a whole-pool HBM ref as in the megakernel)
-    because Mosaic refuses to slice a pool whose minor dimension is
-    narrower than a 128-lane tile: head_dim 64, and the int8 pools'
-    [NB, KH, 16] scales.
+    (not manual DMAs from a whole-pool HBM ref) because Mosaic refuses to
+    slice a pool whose minor dimension is narrower than a 128-lane tile:
+    head_dim 64, and the int8 pools' [NB, KH, 16] scales.
   - One online-softmax update per step over all its pages' score tiles;
     the steps of a row are consecutive, so q, the output block and the
     flash state stay resident from the row's first step to its last.

@@ -5,8 +5,8 @@ PR 1 built the *serving-plane* observability layer (per-object metric
 registries, request timelines, trace exemplars); this module is the
 *device plane* — the reference Dynamo treats runtime-level metrics as a
 first-class layer next to the serving metrics (PAPER layer map), and the
-PR 2/3 decode path (width-bucketed programs, pipelined ticks, megakernel
-fallback arming) created exactly the failure classes that are invisible
+PR 2/3 decode path (width-bucketed programs, pipelined ticks) created
+exactly the failure classes that are invisible
 without it: a silent recompile storm, HBM-accounting drift, or a tick
 pipeline wedging with no record of the events that led there.
 
@@ -65,8 +65,9 @@ from dynamo_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-# Compile wall-times span ~10 ms (tiny scatter) to minutes (8B megakernel
-# variants) — latency DEFAULT_BUCKETS top out at 60 s and start at 1 ms.
+# Compile wall-times span ~10 ms (tiny scatter) to minutes (a served
+# program of many layers) — latency DEFAULT_BUCKETS top out at 60 s and
+# start at 1 ms.
 COMPILE_BUCKETS = (
     0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
     120.0, 300.0,

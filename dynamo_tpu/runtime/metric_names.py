@@ -54,12 +54,6 @@ ENGINE_DEADLINE_SHEDS = engine_gauge("deadline_sheds")
 # live handoff drain is in progress (rides load reports router-ward so
 # KvScheduler stops placing work here immediately).
 ENGINE_DRAINING = engine_gauge("draining")
-# Decode-path observability: decode bursts that dispatched on the fused
-# megakernel path vs the XLA decode program. The runner picks ONE path at
-# start, so exactly one of the two moves. The per-variant split rides the
-# nested stats sub-dict (flattened at scrape like the kvbm sub-dict).
-ENGINE_MK_FUSED_BURSTS = engine_gauge("mk_fused_bursts")
-ENGINE_MK_FALLBACK_BURSTS = engine_gauge("mk_fallback_bursts")
 # Tick budgeter (engines/tpu/tick_budget.py): the EFFECTIVE per-tick
 # prefill token budget (0 = budgeter off, unbounded admission), the
 # budgeter state (0 off, 1 throughput/ceiling, 2 adaptive, 3 floor /
@@ -664,8 +658,6 @@ ALL_ENGINE = (
     ENGINE_KV_HIGH_WATERMARK,
     ENGINE_DEADLINE_SHEDS,
     ENGINE_DRAINING,
-    ENGINE_MK_FUSED_BURSTS,
-    ENGINE_MK_FALLBACK_BURSTS,
     ENGINE_PREFILL_BUDGET_TOKENS,
     ENGINE_BUDGET_STATE,
     ENGINE_PREFILL_CHUNK_TOKENS,

@@ -19,6 +19,7 @@ import asyncio
 import threading
 
 import numpy as np
+import pytest
 
 from dynamo_tpu.engines.tpu import JaxEngine, JaxEngineArgs
 from dynamo_tpu.llm.protocols.common import (
@@ -567,3 +568,79 @@ async def test_budget_squeeze_mid_prefill_is_a_clean_resume():
     base = await run(1, squeeze=False)
     assert await run(1, squeeze=True) == base
     assert await run(2, squeeze=True) == base
+
+
+# -- one decode path: a dispatch error propagates, nothing else serves --------
+
+
+def _runner():
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+
+    return DeviceRunner(JaxEngineArgs(
+        config=tiny_config(), block_size=4, num_kv_blocks=64, max_num_seqs=4,
+        max_model_len=96, prefill_chunk=32, decode_steps=4,
+    ))
+
+
+def _dispatch(runner, program, nb):
+    """One decode burst, or one prefill step of four tokens a row, under a
+    block table of ``nb`` pages: each width is a program of its own."""
+    S = 4
+    f32, i32 = np.float32, np.int32
+    rows = (np.zeros(S, f32), np.zeros(S, i32), np.ones(S, f32), np.zeros(S, i32))
+    tables = np.arange(S * nb, dtype=i32).reshape(S, nb)
+    if program == "decode":
+        return runner.run_decode(
+            np.zeros(S, i32), np.zeros(S, i32), np.ones(S, i32), tables, *rows)
+    return runner.run_step(
+        np.ones((S, 4), i32), np.zeros(S, i32), np.full(S, 4, i32), tables, *rows)
+
+
+@pytest.mark.parametrize("when", ["first dispatch", "wider bucket"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_dispatch_error_propagates_and_no_other_path_serves(monkeypatch, program, when):
+    """An error raised while a program is traced and compiled reaches the
+    caller whatever its words (there is no classifier deciding which errors
+    may be served around), at the very first dispatch and at a wider, not
+    yet compiled table-width bucket after a narrower one served. The runner
+    keeps the attention implementation it chose at start, and the bucket
+    that compiled keeps dispatching."""
+    from dynamo_tpu.models import llama
+
+    runner = _runner()
+    impl = (runner.attention_impl, runner.use_kernel)
+    if when == "wider bucket":
+        assert _dispatch(runner, program, nb=1)[0].shape[0] == 4
+        error, words, nb = RuntimeError, "Mosaic lowering failed: scoped VMEM over budget", 2
+    else:
+        error, words, nb = ValueError, "socket closed: transient wire error", 1
+
+    def boom(*a, **k):
+        raise error(words)
+
+    with monkeypatch.context() as patched:
+        # forward_paged resolves it when the new width is traced
+        patched.setattr(llama, "decoder_layer", boom)
+        with pytest.raises(error, match=words.split(":")[0]):
+            _dispatch(runner, program, nb=nb)
+    assert (runner.attention_impl, runner.use_kernel) == impl
+    assert _dispatch(runner, program, nb=1)[0].shape[0] == 4
+
+
+def test_decode_program_compiles_once_per_table_width_bucket():
+    """``runner.decode_state`` grows by one program per DISTINCT table width
+    and stays flat on a repeat (what ``/debug/compiles`` counts): with
+    ``table_width_bucket`` collapsing widths into pow2 buckets, the program
+    count is bounded by the bucket count, not by context length."""
+    from dynamo_tpu.runtime.device_observe import global_compile_watcher
+
+    def compiles():
+        programs = global_compile_watcher().snapshot()["programs"]
+        return programs.get("runner.decode_state", {}).get("compiles", 0)
+
+    runner = _runner()
+    base, seen = compiles(), set()
+    for nb in (1, 1, 2, 2):
+        _dispatch(runner, "decode", nb)
+        seen.add(nb)
+        assert compiles() - base == len(seen)

@@ -619,15 +619,9 @@ def test_reference_child_compares_nothing_off_its_device():
 
 
 @pytest.mark.parametrize("mechanism", ["disaggregation wire", "KVBM tiers", "KV checkpoint",
-                                       "megakernel", "engine export"])
+                                       "engine export"])
 def test_mechanisms_that_carry_only_kv_refuse_a_hybrid_configuration(mechanism):
     c = tiny_hybrid_config()
-    if mechanism == "megakernel":
-        from dynamo_tpu.ops.pallas.fused_layer import supports_reason
-
-        why = supports_reason(c, lora=False, quantized_weights=True)
-        assert "hybrid" in why and "recurrent state" in why
-        return
     if mechanism == "engine export":
         async def run():
             engine = _engine()

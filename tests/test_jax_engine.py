@@ -266,3 +266,23 @@ async def test_systemic_admission_failure_goes_terminal():
         assert "engine failed" in (out[-1].error or "")
     finally:
         await engine.stop()
+
+
+def test_table_width_buckets_bounded():
+    """As contexts grow, dispatched table widths collapse into ~log2(cap)
+    pow2 buckets — the compiled-program-count bound for the decode and
+    spec-verify dispatches. (The jit-cache-growth companion is
+    tests/test_decode_pipeline.py's
+    test_decode_program_compiles_once_per_table_width_bucket.)"""
+    import math
+
+    from dynamo_tpu.engines.tpu.engine import table_width_bucket
+
+    cap = 256  # 4096 tokens at block_size 16
+    buckets = {table_width_bucket(n, cap) for n in range(1, cap + 1)}
+    assert len(buckets) <= int(math.log2(cap)) + 1, sorted(buckets)
+    assert max(buckets) == cap  # the top bucket still reaches capacity
+    assert table_width_bucket(0, cap) == 1
+    for n in range(1, cap + 1):
+        # a bucket always covers the width that requested it
+        assert n <= table_width_bucket(n, cap) <= cap

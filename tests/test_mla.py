@@ -381,15 +381,9 @@ def test_reference_child_compares_nothing_off_its_device():
 
 
 @pytest.mark.parametrize("mechanism", ["disaggregation wire", "KVBM tiers", "KV checkpoint",
-                                       "megakernel", "int8 KV", "engine export"])
+                                       "int8 KV", "engine export"])
 def test_mechanisms_that_carry_only_kv_refuse_a_latent_cache(mechanism):
     c = tiny_mla_config()
-    if mechanism == "megakernel":
-        from dynamo_tpu.ops.pallas.fused_layer import supports_reason
-
-        why = supports_reason(c, lora=False, quantized_weights=True)
-        assert "latent" in why and "no V pool" in why
-        return
     if mechanism == "int8 KV":
         with pytest.raises(ValueError, match="quantized KV pool.*ONE latent pool per layer"):
             _engine(kv_cache_dtype="int8")

@@ -284,25 +284,31 @@ def test_hybrid_grouped_kernel_is_the_program_it_was(tokens):
 
 
 @functools.cache
-def _dense_program(one_chip, program):
+def _dense_program(one_chip, program, model):
     """(compiled, a layer's K pool, resident bytes) of one served program of
-    a two-layer qwen2.5-0.5b, as the runner builds it."""
+    a two-layer qwen2.5-0.5b, or of a two-layer qwen3-8b with int8 weights
+    under the worker's default slots (the quick-start deployment), as the
+    runner builds it."""
     import dataclasses
     import types
 
     from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
     from dynamo_tpu.engines.tpu.runner import DeviceRunner
     from dynamo_tpu.models import llama
-    from dynamo_tpu.models.config import qwen2_500m_config
+    from dynamo_tpu.models.config import qwen2_500m_config, qwen3_8b_config
+    from dynamo_tpu.models.quantize import quantize_params
 
-    cfg = dataclasses.replace(qwen2_500m_config(), n_layers=2, vocab_size=8192)
-    NB, S = 2048, 64
+    int8 = model == "qwen3-8b-int8"
+    full = qwen3_8b_config() if int8 else qwen2_500m_config()
+    cfg = dataclasses.replace(full, n_layers=2, vocab_size=8192)
+    NB, S = 2048, (16 if int8 else 64)
     args = JaxEngineArgs(
         config=cfg, num_kv_blocks=NB, max_num_seqs=S, max_model_len=2048,
         prefill_chunk=1024, use_kernel=True,
+        quantization="int8" if int8 else None,
     )
     runner = types.SimpleNamespace(  # what the program builders read
-        config=cfg, args=args, use_kernel=True, use_megakernel=False,
+        config=cfg, args=args, use_kernel=True,
         multihost=False, _decode_sig_budget=None,
         _constrain_out=lambda *a: a if len(a) > 1 else a[0],
     )
@@ -313,9 +319,11 @@ def _dense_program(one_chip, program):
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = dict(jax.eval_shape(
-        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
-    ))
+    def drawn():
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        return quantize_params(params)[0] if int8 else params
+
+    params = dict(jax.eval_shape(drawn))
     params["layers"] = jax.eval_shape(
         lambda p: [jax.tree.map(lambda a: a[l], p) for l in range(cfg.n_layers)],
         params["layers"],
@@ -349,16 +357,25 @@ def _dense_program(one_chip, program):
 
 
 @pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
-def test_served_programs_hold_no_whole_pool_copy(one_chip, program):
+@pytest.mark.parametrize("model", ["qwen2.5-0.5b", "qwen3-8b-int8"])
+def test_served_programs_hold_no_whole_pool_copy(one_chip, model, program):
     """The decode burst and the prefill step of a two-layer qwen2.5-0.5b,
     compiled for the v5e as the runner builds them: the optimised HLO holds
     no copy of a whole per-layer pool (96 a program with the pool at its
     logical head size of 64: the resident layout was not the kernels'),
-    and the donated pools alias in and out."""
+    and the donated pools alias in and out. And of what ``worker --model
+    qwen3-8b --quantization int8`` decodes and prefills with since PR 49
+    (the programs every cell is judged on), at the model's layer widths:
+    Mosaic takes the kernels at 32 heads over 8 of 128 lanes beside the int8
+    matmuls."""
     from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
-    compiled, pool, resident = _dense_program(one_chip, program)
-    assert whole_pool_copies(compiled.as_text(), pool) == 0
+    compiled, pool, resident = _dense_program(one_chip, program, model)
+    text = compiled.as_text()
+    # a fresh prompt attends inside its chunk: no paged kernel there
+    assert ("tpu_custom_call" in text) == (program != "prefill_fresh")
+    assert ("s8[" in text) == (model == "qwen3-8b-int8")
+    assert whole_pool_copies(text, pool) == 0
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert resident <= aliased < resident + (1 << 20)
 
@@ -905,7 +922,7 @@ def sampler_branches(hlo_text):
 
 
 SERVED_PROGRAMS = {
-    "dense": _dense_program,
+    "dense": lambda chip, program: _dense_program(chip, program, "qwen2.5-0.5b"),
     "hybrid": _hybrid_program,
     "latent": lambda chip, program: _mla_program(chip, program, depth=1),
     "window": _laguna_program,

@@ -584,3 +584,79 @@ def test_forward_derives_the_decode_plan_once_per_window(monkeypatch):
         np.asarray(logits[True])[live], np.asarray(logits[False])[live],
         atol=2e-4, rtol=2e-4,
     )
+
+
+def test_window_page_bounds_semantics():
+    """window_page_bounds: wlo is the first VISIBLE key (max(0, pos−W+1)),
+    poff its page — including the straddle case where pos−W lands
+    mid-page (the boundary page is streamed and masked in-kernel)."""
+    from dynamo_tpu.ops.pallas.live_pages import window_page_bounds
+
+    BS = 16
+    start = jnp.asarray([0, 5, 100, 100, 64, 200], jnp.int32)
+    #                 W: full  windows below
+    wlo, poff = window_page_bounds(start, 0, BS)
+    assert np.all(np.asarray(wlo) == 0) and np.all(np.asarray(poff) == 0)
+
+    wlo, poff = window_page_bounds(start, 40, BS)
+    exp_wlo = np.maximum(np.asarray(start) - 40 + 1, 0)
+    np.testing.assert_array_equal(np.asarray(wlo), exp_wlo)
+    np.testing.assert_array_equal(np.asarray(poff), exp_wlo // BS)
+    # pos=100, W=40 → first visible key 61, mid-page on page 3 (straddle)
+    assert int(wlo[2]) == 61 and int(poff[2]) == 3 and 61 % BS != 0
+    # window covering the whole history → page 0
+    wlo, poff = window_page_bounds(start, 512, BS)
+    assert np.all(np.asarray(poff) == 0)
+
+
+def test_attention_is_chosen_once_with_a_reason():
+    """The runner decides the attention implementation at start from what
+    it can observe, and says why (the worker's start-up log line and
+    stats() carry the strings chip_smoke.py prints)."""
+    from dynamo_tpu.engines.tpu import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models.config import tiny_config
+
+    choose_attn = DeviceRunner._choose_attention
+    ok = JaxEngineArgs(config=tiny_config(), max_num_seqs=4, quantization="int8")
+
+    assert choose_attn(ok, "cpu", None) == (
+        False, "platform is cpu (Mosaic lowers on TPU only)"
+    )
+    assert choose_attn(ok, "tpu", None)[0] is True
+    use, why = choose_attn(ok, "tpu", object())  # any mesh
+    assert use is False and "mesh" in why
+    forced = JaxEngineArgs(config=tiny_config(), use_kernel=True)
+    with pytest.raises(ValueError, match="mesh"):
+        choose_attn(forced, "tpu", object())
+
+
+# The live-span decode kernel at contexts its other cases never reach (their
+# widest table is 8 pages): up to 256 pages of 16 tokens, every row at the
+# context's end, with and without a sliding window; and rows of under a page
+# beside one 4,096-token row.
+LONG_CONTEXT_CASES = {
+    f"ctx{ctx}-window{window}": ([ctx - 1, ctx - 2, ctx - 17, ctx // 2], window)
+    for ctx in (256, 1024, 4096) for window in (0, 1024)
+}
+LONG_CONTEXT_CASES["ragged-4096"] = ([4095, 3, 0, 15], 0)
+LONG_CONTEXT_CASES["ragged-4096-window1024"] = ([4095, 3, 0, 15], 1024)
+
+
+@pytest.mark.parametrize("case", sorted(LONG_CONTEXT_CASES))
+def test_decode_kernel_long_context(case):
+    starts, window = LONG_CONTEXT_CASES[case]
+    B, C, H, KH, D, bs = 5, 1, 4, 2, 128, 16
+    P = -(-(max(starts) + 1) // bs)
+    live = np.asarray([True] * 4 + [False])  # the last slot is empty
+    q, (k, v), tables, start, lens, stale_tables, stale_start = (
+        _live_span_inputs(B, C, H, KH, D, bs, P, live, starts + [0],
+                          seed=P + window)
+    )
+    ref = np.asarray(_paged_attention_xla(
+        q, k, v, tables, start, jnp.full((B,), C, jnp.int32), window
+    ))
+    out = np.asarray(_decode_kernel_grouped(  # 16 pages a step: as served
+        16, q, k, v, stale_tables, stale_start, window, lens))
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-5, rtol=2e-5)
+    assert (out[~live] == 0).all()

@@ -589,7 +589,7 @@ def test_reference_child_compares_nothing_off_its_device():
 
 
 @pytest.mark.parametrize("mechanism", ["disaggregation wire", "KVBM tiers", "KV checkpoint",
-                                       "int8 KV", "megakernel"])
+                                       "int8 KV"])
 def test_mechanisms_that_carry_one_block_list_refuse_a_window_page_group(mechanism):
     import dataclasses
 
@@ -598,11 +598,6 @@ def test_mechanisms_that_carry_one_block_list_refuse_a_window_page_group(mechani
     from dynamo_tpu.kvbm import tiers
 
     c = tiny_swa_config()
-    if mechanism == "megakernel":
-        from dynamo_tpu.ops.pallas.fused_layer import supports_reason
-
-        assert "two page groups" in supports_reason(c, lora=False, quantized_weights=True)
-        return
     if mechanism == "int8 KV":
         with pytest.raises(ValueError, match="quantized KV pool.*two page groups"):
             _engine(kv_cache_dtype="int8")
