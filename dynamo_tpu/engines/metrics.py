@@ -197,6 +197,13 @@ class EngineStepMetrics:
             mn.ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL,
             "Mean tokens on a held expert, summed over layer-steps",
         )
+        self.moe_assignments = self.registry.counter(
+            mn.ENGINE_MOE_ASSIGNMENTS_TOTAL,
+            "Top-k choices of the live rows of reaped decode bursts, summed "
+            "over steps and expert layers: held=1 those that fell on experts "
+            "held here, held=0 on the absent ones",
+            ["held"],
+        )
         self.moe_prefill_tokens = self.registry.counter(
             mn.ENGINE_MOE_PREFILL_TOKENS_TOTAL,
             "Live prompt tokens of reaped prefill steps through expert "
@@ -452,6 +459,10 @@ class EngineStepMetrics:
         self.moe_expert_slots.inc(slots)
         self.moe_max_expert_tokens.inc(most)
         self.moe_mean_expert_tokens.inc(mean)
+
+    def observe_moe_assignments(self, held: float, choices: float) -> None:
+        self.moe_assignments.inc(held, held="1")
+        self.moe_assignments.inc(max(choices - held, 0.0), held="0")
 
     def observe_moe_prefill(self, tokens_by_form: Dict[str, int]) -> None:
         for form, tokens in tokens_by_form.items():

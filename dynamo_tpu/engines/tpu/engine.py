@@ -520,6 +520,8 @@ class JaxEngine:
         self.step_metrics = EngineStepMetrics(inflight=self._inflight.__len__)
         if self.runner.ssd_step is not None:
             self.step_metrics.observe_ssm_decode(0, 0)  # both series from start-up
+        if self.runner.expert_ffn is not None:
+            self.step_metrics.observe_moe_assignments(0.0, 0.0)  # as those
         # What the sparse attention layers' decode kernel visits, summed over
         # dispatched bursts (the counters of the same names); None for a
         # model without such layers. The series exist from the first scrape.
@@ -1860,6 +1862,11 @@ class JaxEngine:
                 float(moe[0]), self.args.decode_steps * len(experts) * held,
                 float(moe[1]), float(moe[2]) / held,
             )
+            # Every live row of the burst chose top_k experts a layer-step;
+            # ``moe[2]`` counts the choices that fell on held ones.
+            choices = (self.args.decode_steps * len(experts) * experts[0].top_k
+                       * rec.occupancy)
+            self.step_metrics.observe_moe_assignments(float(moe[2]), choices)
         rows = 0
         for slot, seq in rec.seqs:
             if self._slots[slot] is not seq or seq.slot != slot:

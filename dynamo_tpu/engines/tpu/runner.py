@@ -573,7 +573,8 @@ class DeviceRunner:
             logger.info(
                 "recurrent state: %.2f GB in %d slots, %.2f GB in %d snapshots "
                 "(one every %d tokens of a prompt, %.2f MB each) "
-                "| %d attention, %d mamba2, %d lightning, %d expert layers",
+                "| %d attention, %d mamba2, %d lightning, %d gated_delta, "
+                "%d expert layers",
                 tree_device_bytes(self.ssm_state) / 1e9, args.max_num_seqs,
                 tree_device_bytes(self.snap_store) / 1e9,
                 jax.tree.leaves(self.snap_store)[0].shape[0],
@@ -581,6 +582,7 @@ class DeviceRunner:
                 len(self.config.specs_of("attention")),
                 len(self.config.specs_of("mamba2")),
                 len(self.config.specs_of("lightning")),
+                len(self.config.specs_of("gated_delta")),
                 len(self.config.specs_of("experts")),
             )
 
@@ -616,16 +618,16 @@ class DeviceRunner:
 
     def _describe_ssd_step(self) -> Optional[str]:
         """The form a decode step's recurrence takes and why
-        (ops/pallas/ssd_step.ssd_step_reason over the slots' states); None
-        for a model without recurrent layers. Not a choice:
-        ``hybrid._decode_recurrence`` makes it, from the same arguments,
-        every time it is traced."""
-        from dynamo_tpu.ops.pallas.ssd_step import ssd_step_reason
+        (hybrid.decode_recurrence_reason over the slots' states: a kind's
+        own ``*_step_reason``); None for a model without recurrent layers.
+        Not a choice: the mixers make it, from the same arguments, every
+        time they are traced."""
+        from dynamo_tpu.models.hybrid import decode_recurrence_reason
 
         if not self.ssm_state or not self.ssm_state["S"]:
             return None
-        whys = {ssd_step_reason(self.use_kernel, S.shape, S.dtype)
-                for S in self.ssm_state["S"]}
+        whys = {decode_recurrence_reason(spec, self.use_kernel, S)
+                for spec, S in zip(self.config.recurrent_specs, self.ssm_state["S"])}
         return "; ".join(sorted(
             self.SSD_STEP_LIVE if why is None else f"xla every slot, {why}"
             for why in whys))

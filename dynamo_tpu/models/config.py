@@ -193,7 +193,49 @@ class LightningSpec:
         return tuple(2.0 ** (-8.0 * (h + 1) / n) for h in range(n))
 
 
-RECURRENT_KINDS = ("mamba2", "lightning")
+@dataclass(frozen=True)
+class GatedDeltaSpec:
+    """Gated DeltaNet (arXiv:2412.06464; ``qwen3_next``'s linear-attention
+    layer) as recurrent state: per value head a float32 matrix S [key,
+    value] with a MATRIX transition,
+
+        S <- exp(g_t) S;  u_t = beta_t (v_t - k_t S);  S <- S + k_t^T u_t;
+        o_t = q_t S
+
+    (``g_t = -exp(A_log) softplus(a_t + dt_bias)``, ``beta_t = sigmoid(b_t)``,
+    q and k L2-normalised per head, q / sqrt(k_dim), a key head serving
+    ``n_heads / n_k_heads`` value heads), behind a causal depth-wise conv of
+    ``conv_kernel`` taps over ``[q | k | v]`` whose tail is state too, and in
+    front of a per-head RMS norm times ``silu(z)``. The state is multiplied
+    by ``exp(g)(I - beta k^T k)``, not by a scalar: ops/mamba2's recurrence
+    cannot express it (ops/gated_delta.py)."""
+
+    n_heads: int  # value heads
+    n_k_heads: int
+    head_dim: int  # a value head's lanes
+    k_dim: int  # a key head's lanes
+    conv_kernel: int = 4
+    scan_block: int = 64
+    state_dtype: str = "float32"
+    # An entry is n_heads * k_dim * head_dim float32 + the conv tail a layer
+    # (2.15 MB at 32 heads of 128 x 128): one every so many tokens.
+    snapshot_every: Optional[int] = 4096
+    kind: str = "gated_delta"
+
+    @property
+    def k_width(self) -> int:
+        return self.n_k_heads * self.k_dim
+
+    @property
+    def v_width(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_channels(self) -> int:  # [q | k | v]
+        return 2 * self.k_width + self.v_width
+
+
+RECURRENT_KINDS = ("mamba2", "lightning", "gated_delta")
 
 
 @dataclass(frozen=True)
@@ -207,6 +249,9 @@ class ExpertsSpec:
     scale: float = 1.0
     activation: str = "silu_gated"  # "silu_gated" | "relu2" (no gate matrix)
     shared_d_ff: int = 0  # one shared expert of this width (0 = none)
+    # The shared expert's output times ``sigmoid(x w)``, a scalar a token
+    # (``ws_gate_scalar`` [d, 1]).
+    shared_gate: bool = False
     held: Optional[Tuple[int, int]] = None  # [lo, hi) experts held here; None = all
     post_norm: bool = False
     kind: str = "experts"
@@ -237,8 +282,8 @@ class CacheGroup:
 
 
 LayerSpec = Union[
-    AttentionSpec, LatentAttentionSpec, Mamba2Spec, LightningSpec, ExpertsSpec,
-    DenseFFNSpec,
+    AttentionSpec, LatentAttentionSpec, Mamba2Spec, LightningSpec, GatedDeltaSpec,
+    ExpertsSpec, DenseFFNSpec,
 ]
 
 
@@ -351,7 +396,7 @@ class ModelConfig:
     @cached_property
     def recurrent_specs(self) -> List[LayerSpec]:
         """The layers whose sequences carry state beside the paged pools
-        (Mamba-2, lightning attention), in layer order."""
+        (Mamba-2, lightning attention, Gated DeltaNet), in layer order."""
         return [s for s in (self.layer_specs or ()) if s.kind in RECURRENT_KINDS]
 
     @property
@@ -469,6 +514,15 @@ class ModelConfig:
             )
         if not self.recurrent_specs:
             return None
+        gdn = self.specs_of("gated_delta")
+        if gdn:
+            return (
+                f"{mechanism} moves paged K/V blocks only, and {self.name} "
+                f"keeps per-sequence recurrent state (a conv tail and a "
+                f"float32 matrix a head under the delta rule's matrix "
+                f"transition) in {len(gdn)} Gated DeltaNet layers that "
+                f"{mechanism} does not carry"
+            )
         if not self.specs_of("mamba2"):
             return (
                 f"{mechanism} moves paged K/V blocks only, and {self.name} "
@@ -511,6 +565,8 @@ class ModelConfig:
             return _laguna_from_hf(cfg, name)
         if str(cfg.get("model_type", "")) == "minicpm_sala":
             return _minicpm_sala_from_hf(cfg, name)
+        if str(cfg.get("model_type", "")) == "qwen3_next":
+            return _qwen3_next_from_hf(cfg, name)
         archs = cfg.get("architectures") or [""]
         arch = archs[0].lower()
         eos = cfg.get("eos_token_id")
@@ -1060,6 +1116,133 @@ def tiny_sala_config(**overrides) -> ModelConfig:
         rms_norm_eps=1e-6, rope_theta=10000.0, dtype=jnp.float32, name="tiny-sala",
         layer_specs=tuple(specs), embed_multiplier=12.0,
         residual_multiplier=1.4 / 32**0.5, logit_divisor=64 / 16,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def _qwen3_next_from_hf(cfg: Dict[str, Any], name: str = "") -> ModelConfig:
+    """``qwen3_next``: every published layer is a mixer, then the experts;
+    two entries of ``layer_specs`` a layer. Layer i is gated softmax
+    attention when ``(i + 1) % full_attention_interval == 0`` (the doubled
+    ``q_proj`` is a query matrix and a per-lane gate matrix side by side;
+    zero-centred per-head q/k norms; rotary on the first
+    ``partial_rotary_factor`` of the lanes), a Gated DeltaNet layer
+    otherwise. Every RMS-norm weight of the family is zero-centred
+    (``rmsnorm_unit_offset``) but the Gated DeltaNet output norm's. Experts:
+    softmax over the whole router, top-k renormalised, one shared expert
+    under a scalar sigmoid gate. The multi-token-prediction module is a
+    draft head and is not built."""
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"qwen3_next: hidden_act {cfg['hidden_act']!r} is not implemented")
+    if cfg.get("rope_scaling"):
+        raise ValueError("qwen3_next: rope_scaling is not implemented")
+    if cfg.get("attention_bias"):
+        raise ValueError("qwen3_next: attention_bias is not implemented")
+    if cfg.get("mlp_only_layers") or int(cfg.get("decoder_sparse_step", 1)) != 1:
+        raise ValueError("qwen3_next: dense-MLP layers are not implemented")
+    if cfg.get("use_sliding_window"):
+        raise ValueError("qwen3_next: use_sliding_window is not implemented")
+    hd = int(cfg["head_dim"])
+    n = int(cfg["num_hidden_layers"])
+    every = int(cfg.get("full_attention_interval", 4))
+    attn = AttentionSpec(
+        n_heads=int(cfg["num_attention_heads"]),
+        n_kv_heads=int(cfg["num_key_value_heads"]), head_dim=hd,
+        rope=RopeLaw(float(cfg.get("rope_theta", 10000.0)),
+                     int(hd * float(cfg.get("partial_rotary_factor", 1.0)))),
+        gate=True, gate_lanes=True, qk_norm=True,
+    )
+    gdn = GatedDeltaSpec(
+        n_heads=int(cfg["linear_num_value_heads"]),
+        n_k_heads=int(cfg["linear_num_key_heads"]),
+        head_dim=int(cfg["linear_value_head_dim"]), k_dim=int(cfg["linear_key_head_dim"]),
+        conv_kernel=int(cfg.get("linear_conv_kernel_dim", 4)),
+    )
+    experts = ExpertsSpec(
+        n_experts=int(cfg["num_experts"]), top_k=int(cfg["num_experts_per_tok"]),
+        d_ff=int(cfg["moe_intermediate_size"]), routing="softmax",
+        norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        shared_d_ff=int(cfg.get("shared_expert_intermediate_size", 0)),
+        shared_gate=bool(cfg.get("shared_expert_intermediate_size", 0)),
+    )
+    specs: List[LayerSpec] = []
+    for i in range(n):
+        specs += [attn if (i + 1) % every == 0 else gdn, experts]
+    eos = cfg.get("eos_token_id")
+    return ModelConfig(
+        vocab_size=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        n_layers=len(specs), n_heads=attn.n_heads, n_kv_heads=attn.n_kv_heads,
+        head_dim=hd, d_ff=int(cfg.get("intermediate_size", 0)),
+        rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        rope_theta=attn.rope.theta,
+        max_position_embeddings=int(cfg.get("max_position_embeddings", 8192)),
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        eos_token_ids=[] if eos is None else [int(e) for e in (eos if isinstance(eos, list) else [eos])],
+        bos_token_id=cfg.get("bos_token_id"),
+        name=name or "qwen3_next", layer_specs=tuple(specs),
+        rmsnorm_unit_offset=True,
+    )
+
+
+# Qwen3-Next-80B-A3B-Instruct, the keys of its public config.json that say
+# something about its shape
+# (https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json).
+QWEN3_NEXT_80B_A3B_HF: Dict[str, Any] = {
+    "model_type": "qwen3_next", "decoder_sparse_step": 1, "full_attention_interval": 4,
+    "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
+    "intermediate_size": 5120, "linear_conv_kernel_dim": 4, "linear_key_head_dim": 128,
+    "linear_num_key_heads": 16, "linear_num_value_heads": 32,
+    "linear_value_head_dim": 128, "max_position_embeddings": 262144,
+    "mlp_only_layers": [], "moe_intermediate_size": 512, "norm_topk_prob": True,
+    "num_attention_heads": 16, "num_experts": 512, "num_experts_per_tok": 10,
+    "num_hidden_layers": 48, "num_key_value_heads": 2, "partial_rotary_factor": 0.25,
+    "rms_norm_eps": 1e-06, "rope_scaling": None, "rope_theta": 10000000,
+    "shared_expert_intermediate_size": 512, "tie_word_embeddings": False,
+    "use_sliding_window": False, "vocab_size": 151936,
+}
+
+
+def qwen3_next_ep2_config() -> ModelConfig:
+    """Qwen3-Next-80B-A3B-Instruct at its published widths, as share 0 of the
+    first stage of its served deployment: two chips share each layer by
+    expert parallelism over one batch (256 of the 512 routed experts and half
+    the vocabulary each; mixers, router and shared expert whole), the 48
+    layers on twelve such pairs, one period a pair. This chip: the published
+    layers 0..3, ``GDN, GDN, GDN, full``, eight sublayers; embedding and head
+    both sit here so that the stage takes ids and yields logits."""
+    hf = dict(QWEN3_NEXT_80B_A3B_HF, num_hidden_layers=4)
+    return cut_hybrid(
+        ModelConfig.from_hf_config(hf), n_layers=8, experts_held=(0, 256),
+        vocab_rows=75968, name="qwen3-next-80b-a3b-ep2",
+    )
+
+
+def tiny_gdn_config(**overrides) -> ModelConfig:
+    """The served Qwen3-Next stage at toy widths (tests, the CPU rehearsal):
+    ``GDN, GDN, GDN, full`` with the experts after each; Gated DeltaNet 2 key
+    heads serving 4 value heads of 16 x 16, conv 4, blocks of 16, snapshots
+    every 64 tokens; gated attention 4 queries over 2 K/V heads of 32, rotary
+    on 8 lanes; 16 experts routed over of which the first 8 are held, softmax
+    top 4, a shared expert under its scalar gate; zero-centred norms."""
+    attn = AttentionSpec(
+        n_heads=4, n_kv_heads=2, head_dim=32, rope=RopeLaw(1e7, 8), gate=True,
+        gate_lanes=True, qk_norm=True,
+    )
+    gdn = GatedDeltaSpec(
+        n_heads=4, n_k_heads=2, head_dim=16, k_dim=16, scan_block=16, snapshot_every=64)
+    experts = ExpertsSpec(
+        n_experts=16, top_k=4, d_ff=32, routing="softmax", shared_d_ff=32,
+        shared_gate=True, held=(0, 8),
+    )
+    specs: List[LayerSpec] = []
+    for mixer in (gdn, gdn, gdn, attn):
+        specs += [mixer, experts]
+    base = dict(
+        vocab_size=512, d_model=64, n_layers=len(specs), n_heads=4, n_kv_heads=2,
+        head_dim=32, d_ff=32, max_position_embeddings=4096, eos_token_ids=[2],
+        rms_norm_eps=1e-6, rope_theta=1e7, dtype=jnp.float32, name="tiny-gdn",
+        layer_specs=tuple(specs), rmsnorm_unit_offset=True,
     )
     base.update(overrides)
     return ModelConfig(**base)

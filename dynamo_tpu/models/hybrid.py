@@ -24,11 +24,12 @@ ids: those pools follow the attention layers' in ``k_cache``
 
 Two kinds of state travel with a sequence. K/V pages exist only for the
 attention layers: ``k_cache[i]`` belongs to the i-th ATTENTION layer. The
-recurrent state of the Mamba-2 and lightning-attention layers is ``{"conv":
-(...), "S": (...)}``, batch-major: ``S`` one entry per recurrent layer, the
-SSM state ``[B, H, P, N]`` (lightning: ``[B, H, D value, D key]``) in the
-spec's ``state_dtype`` (float32); ``conv`` one entry per Mamba-2 layer, the
-conv tail ``[B, K-1, channels]`` in the model's dtype. A prefill chunk starts from the state it is
+recurrent state of the Mamba-2, lightning-attention and Gated DeltaNet layers
+is ``{"conv": (...), "S": (...)}``, batch-major: ``S`` one entry per recurrent
+layer, the SSM state ``[B, H, P, N]`` (lightning: ``[B, H, D value, D key]``;
+Gated DeltaNet: ``[B, H value, D key, D value]``) in the spec's
+``state_dtype`` (float32); ``conv`` one entry per Mamba-2 or Gated DeltaNet
+layer, the conv tail ``[B, K-1, channels]`` in the model's dtype. A prefill chunk starts from the state it is
 given and returns the state after each row's last real token; positions
 past ``chunk_lens`` leave it untouched. It can also write the state at the
 end of every ``scan_block`` tokens into a snapshot store
@@ -45,6 +46,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import _rms_norm as _rms
+from dynamo_tpu.ops import gated_delta as gd
 from dynamo_tpu.ops import mamba2 as m2
 from dynamo_tpu.ops.attention import (
     dense_chunk_attention,
@@ -59,6 +61,7 @@ from dynamo_tpu.ops.attention import (
     write_chunk_to_cache,
 )
 from dynamo_tpu.ops.moe import moe_ffn
+from dynamo_tpu.ops.pallas.gdn_step import gdn_step_live, gdn_step_reason
 from dynamo_tpu.ops.pallas.ssd_step import ssd_step_live, ssd_step_reason
 from dynamo_tpu.ops.rope import apply_rope, rope_table, rope_table_for
 from dynamo_tpu.ops.sparse_attention import (
@@ -91,12 +94,15 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
             (jax.random.normal(k, shape, dtype=_F32) * scale).astype(dtype or c.dtype)
         )
 
+    def unit(shape):  # a norm weight of effective scale 1
+        return (jnp.zeros if c.rmsnorm_unit_offset else jnp.ones)(shape, c.dtype)
+
     layers = []
     for i, spec in enumerate(c.layer_specs):
         k = jax.random.split(jax.random.fold_in(key, i), 8)
-        lp: Params = {"norm": jnp.ones((d,), c.dtype)}
+        lp: Params = {"norm": unit((d,))}
         if getattr(spec, "post_norm", False):
-            lp["post_norm"] = jnp.ones((d,), c.dtype)
+            lp["post_norm"] = unit((d,))
         if spec.kind == "mla":
             H, qr, kr = spec.n_heads, spec.q_rank, spec.kv_rank
             # The published ``kv_b_proj`` is held as its key half and its
@@ -127,8 +133,28 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                 lp["w_gate_attn"] = norm(
                     k[4], (d, hq if spec.gate_lanes else spec.n_heads), d**-0.5)
             if spec.qk_norm:
-                lp.update(q_norm=jnp.ones((spec.head_dim,), c.dtype),
-                          k_norm=jnp.ones((spec.head_dim,), c.dtype))
+                lp.update(q_norm=unit((spec.head_dim,)), k_norm=unit((spec.head_dim,)))
+        elif spec.kind == "gated_delta":
+            # The decay's scalars: exp(A_log) in [1, 4] and a softplus bias
+            # whose rate is log-uniform in [1e-3, 2.5e-2], so that exp(g)
+            # spans roughly 0.9 to 0.999 a token over the heads (the
+            # projection's own part moves it a token): a state that forgets
+            # in ten tokens would hide its own errors.
+            H = spec.n_heads
+            dt = jnp.exp(
+                jax.random.uniform(k[2], (H,), _F32) * (jnp.log(2.5e-2) - jnp.log(1e-3))
+                + jnp.log(1e-3)
+            )
+            lp.update(
+                w_qkvz=norm(k[0], (d, spec.conv_channels + spec.v_width), d**-0.5),
+                w_ba=norm(k[4], (d, 2 * H), d**-0.5),
+                conv_w=norm(k[1], (spec.conv_kernel, spec.conv_channels),
+                            spec.conv_kernel**-0.5),
+                dt_bias=dt + jnp.log(-jnp.expm1(-dt)),  # inverse softplus
+                A_log=jnp.log(jax.random.uniform(k[3], (H,), _F32, 1.0, 4.0)),
+                o_norm=jnp.ones((spec.head_dim,), c.dtype),  # plain, not zero-centred
+                w_out=norm(k[5], (spec.v_width, d), spec.v_width**-0.5),
+            )
         elif spec.kind == "lightning":
             hq = spec.n_heads * spec.head_dim
             lp.update(
@@ -172,6 +198,9 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                           ws_down=norm(k[6], (fs, d), fs**-0.5))
                 if spec.activation == "silu_gated":
                     lp["ws_gate"] = norm(k[7], (d, fs), d**-0.5)
+                if spec.shared_gate:
+                    lp["ws_gate_scalar"] = norm(
+                        jax.random.fold_in(k[7], 1), (d, 1), d**-0.5)
         else:
             raise ValueError(f"unknown layer kind {spec.kind!r}")
         layers.append(lp)
@@ -179,7 +208,7 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     return {
         "embed": norm(ke, (c.vocab_size, d), 1.0),
         "layers": layers,
-        "final_norm": jnp.ones((d,), c.dtype),
+        "final_norm": unit((d,)),
         "lm_head": norm(kh, (d, c.vocab_size), d**-0.5),
     }
 
@@ -234,19 +263,22 @@ def init_kv_cache(config: ModelConfig, num_blocks: int, block_size: int,
 def init_ssm_state(config: ModelConfig, rows: int) -> Dict[str, Tuple[jnp.ndarray, ...]]:
     """Zeroed recurrent state for ``rows`` sequences (or snapshot entries):
     ``S`` one matrix stack per recurrent layer in layer order (Mamba-2
-    [rows, H, P, N]; lightning attention [rows, H, D value, D key]), ``conv``
-    one tail per Mamba-2 layer (lightning attention has none)."""
+    [rows, H, P, N]; lightning attention [rows, H, D value, D key]; Gated
+    DeltaNet [rows, H value, D key, D value]), ``conv`` one tail per Mamba-2
+    or Gated DeltaNet layer (lightning attention has none)."""
     conv, S = [], []
     for spec in config.recurrent_specs:
-        if spec.kind == "mamba2":
+        if spec.kind != "lightning":
             conv.append(
                 jnp.zeros((rows, spec.conv_kernel - 1, spec.conv_channels), config.dtype)
             )
-        width = spec.state_size if spec.kind == "mamba2" else spec.head_dim
+        if spec.kind == "gated_delta":
+            matrix = (spec.k_dim, spec.head_dim)
+        else:
+            matrix = (spec.head_dim,
+                      spec.state_size if spec.kind == "mamba2" else spec.head_dim)
         S.append(
-            jnp.zeros(
-                (rows, spec.n_heads, spec.head_dim, width), jnp.dtype(spec.state_dtype)
-            )
+            jnp.zeros((rows, spec.n_heads) + matrix, jnp.dtype(spec.state_dtype))
         )
     return {"conv": tuple(conv), "S": tuple(S)}
 
@@ -272,6 +304,86 @@ def _decode_recurrence(x, dt, A, Bm, Cm, S, live_rows):
     if live_rows is not None and ssd_step_reason(True, S.shape, S.dtype) is None:
         return ssd_step_live(x, dt, A, Bm, Cm, S, *live_rows)
     return m2.ssd_step(x, dt, A, Bm, Cm, S)
+
+
+def decode_recurrence_reason(spec, use_kernel: bool, S) -> Optional[str]:
+    """None where a decode step of a recurrent layer of ``spec``'s kind, over
+    the slots' state ``S``, runs its live-row Pallas kernel; otherwise why it
+    keeps the XLA step over every slot. What the mixers below branch on and
+    the runner logs."""
+    reason = gdn_step_reason if spec.kind == "gated_delta" else ssd_step_reason
+    return reason(use_kernel, S.shape, S.dtype)
+
+
+def _snap_blocks(snap_store, snap_dst, padded, ends, scan_block: int, K: int):
+    """The snapshot store with the conv tail and the state at each block end
+    of the chunk written to ``snap_dst`` [B, C // scan_block] (out of range:
+    nothing is written)."""
+    B, nb = ends.shape[:2]
+    at = (jnp.arange(nb, dtype=jnp.int32) + 1) * scan_block
+    idx = at[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+    tails = padded[:, idx]  # [B, nb, K-1, ch]: the tail at each block end
+    dst = snap_dst.reshape(B * nb)
+    return {
+        "conv": snap_store["conv"].at[dst].set(
+            tails.reshape((B * nb,) + tails.shape[2:]), mode="drop"),
+        "S": snap_store["S"].at[dst].set(
+            ends.reshape((B * nb,) + ends.shape[2:]), mode="drop"),
+    }
+
+
+def _gated_delta_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store,
+                       live_rows=None):
+    """Gated DeltaNet (config.GatedDeltaSpec; ops/gated_delta.py). h [B, C, d]
+    -> (out [B, C, d], conv', S', snap_store'). C == 1 with ``snap_dst`` None
+    is the decode step's one-token rule: over the burst's live rows, in
+    place, where ``gdn_step_reason`` finds nothing against the kernel."""
+    B, C, _ = h.shape
+    H, Dv, K = spec.n_heads, spec.head_dim, spec.conv_kernel
+    kw, ch = spec.k_width, spec.conv_channels
+    qkvz = jnp.einsum("bcd,dw->bcw", h, lp["w_qkvz"], preferred_element_type=_F32)
+    qkv, z = qkvz[..., :ch].astype(c.dtype), qkvz[..., ch:]
+    ba = jnp.einsum("bcd,dw->bcw", h, lp["w_ba"], preferred_element_type=_F32)
+    real = (jax.lax.broadcasted_iota(jnp.int32, (B, C), 1) < chunk_lens[:, None])[..., None]
+    beta = jnp.where(real, jax.nn.sigmoid(ba[..., :H]), 0.0)
+    g = jnp.where(
+        real,
+        -jnp.exp(lp["A_log"].astype(_F32)) * jax.nn.softplus(ba[..., H:] + lp["dt_bias"]),
+        0.0)
+    no_bias = jnp.zeros((ch,), _F32)
+
+    def heads(act):  # [..., ch] after the conv -> q, k [..., H, Dk], v [..., H, Dv]
+        act = jax.nn.silu(act)
+        lead = act.shape[:-1]
+        q, k = gd.prepare_qk(
+            act[..., :kw].reshape(lead + (spec.n_k_heads, spec.k_dim)),
+            act[..., kw : 2 * kw].reshape(lead + (spec.n_k_heads, spec.k_dim)),
+            H // spec.n_k_heads)
+        return q, k, act[..., 2 * kw :].reshape(lead + (H, Dv))
+
+    if C == 1 and snap_dst is None:
+        act, tail = m2.conv_step(qkv[:, 0], conv, lp["conv_w"], no_bias)
+        conv_new = jnp.where(real[:, :1], tail, conv)
+        q, k, v = heads(act)
+        if live_rows is not None and decode_recurrence_reason(spec, True, S) is None:
+            o, S_new = gdn_step_live(q, k, v, g[:, 0], beta[:, 0], S, *live_rows)
+        else:
+            o, S_new = gd.gdn_step(q, k, v, g[:, 0], beta[:, 0], S)
+        o = o[:, None]  # [B, 1, H, Dv]
+    else:
+        act, padded = m2.conv_chunk(qkv, conv, lp["conv_w"], no_bias)
+        conv_new = m2.conv_tail_at(padded, chunk_lens, K)
+        q, k, v = heads(act)
+        o, ends = gd.gdn_chunk_scan(q, k, v, g, beta, S, chunk=spec.scan_block)
+        ends = ends.astype(S.dtype)
+        S_new = ends[:, -1]
+        if snap_dst is not None:
+            snap_store = _snap_blocks(snap_store, snap_dst, padded, ends, spec.scan_block, K)
+    # A plain-weight RMS norm per head, times silu(z), then out.
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + c.rms_norm_eps)
+    o = o * lp["o_norm"].astype(_F32) * jax.nn.silu(z.reshape(o.shape))
+    out = jnp.einsum("bci,id->bcd", o.reshape(B, C, H * Dv).astype(c.dtype), lp["w_out"])
+    return out, conv_new, S_new, snap_store
 
 
 def _mamba_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store,
@@ -307,17 +419,7 @@ def _mamba_mixer(c, spec, lp, h, chunk_lens, conv, S, snap_dst, snap_store,
         S_new = ends[:, -1]
         y = y + lp["D"][None, None, :, None] * x
         if snap_dst is not None:
-            nb = C // spec.scan_block
-            at = (jnp.arange(nb, dtype=jnp.int32) + 1) * spec.scan_block
-            idx = at[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
-            tails = padded[:, idx]  # [B, nb, K-1, ch]: the tail at each block end
-            dst = snap_dst.reshape(B * nb)
-            snap_store = {
-                "conv": snap_store["conv"].at[dst].set(
-                    tails.reshape((B * nb,) + tails.shape[2:]), mode="drop"),
-                "S": snap_store["S"].at[dst].set(
-                    ends.reshape((B * nb,) + ends.shape[2:]), mode="drop"),
-            }
+            snap_store = _snap_blocks(snap_store, snap_dst, padded, ends, spec.scan_block, K)
     # Gated, grouped RMSNorm over groups of d_inner / n_groups, then out.
     y = y.reshape(B, C, di) * jax.nn.silu(z)
     yg = y.reshape(B, C, G, di // G)
@@ -373,8 +475,8 @@ def _attention_mixer(c, spec, lp, h, k_c, v_c, block_tables, start_pos, chunk_le
     k = jnp.einsum("bcd,dh->bch", h, lp["wk"]).reshape(B, C, spec.n_kv_heads, hd)
     v = jnp.einsum("bcd,dh->bch", h, lp["wv"]).reshape(B, C, spec.n_kv_heads, hd)
     if spec.qk_norm:
-        q = _rms(q, lp["q_norm"], c.rms_norm_eps)
-        k = _rms(k, lp["k_norm"], c.rms_norm_eps)
+        q = _rms(q, lp["q_norm"], c.rms_norm_eps, c.rmsnorm_unit_offset)
+        k = _rms(k, lp["k_norm"], c.rms_norm_eps, c.rmsnorm_unit_offset)
     if spec.positions == "rope":
         q, k = apply_rope(q, *rope), apply_rope(k, *rope)
     wt = block_tables if write_tables is None else write_tables
@@ -587,7 +689,7 @@ def forward(
     stats = jnp.zeros((3,), _F32)
     ia = im = ir = isp = 0
     for spec, lp in zip(c.layer_specs, params["layers"]):
-        h = _rms(x, lp["norm"], c.rms_norm_eps)
+        h = _rms(x, lp["norm"], c.rms_norm_eps, c.rmsnorm_unit_offset)
         with jax.named_scope(_scope(c, spec)):
             if spec.kind == "mla":
                 out, k_out[ia] = _mla_mixer(
@@ -614,10 +716,11 @@ def forward(
                     selections.append(more[1])
                     isp += 1
                 ia += 1
-            elif spec.kind == "mamba2":
+            elif spec.kind in ("mamba2", "gated_delta"):
                 one = None if store is None else {
                     "conv": store_conv[im], "S": store_S[ir]}
-                out, cv, S, one = _mamba_mixer(
+                mixer = _mamba_mixer if spec.kind == "mamba2" else _gated_delta_mixer
+                out, cv, S, one = mixer(
                     c, spec, lp, h, chunk_lens, ssm["conv"][im], ssm["S"][ir],
                     None if snap is None else snap["dst"], one,
                     live_rows=live_rows,
@@ -650,7 +753,8 @@ def forward(
                 else:
                     out = moe_ffn(h, lp, spec, row_mask=real, use_kernel=use_kernel)
             if "post_norm" in lp:
-                out = _rms(out.astype(x.dtype), lp["post_norm"], c.rms_norm_eps)
+                out = _rms(out.astype(x.dtype), lp["post_norm"], c.rms_norm_eps,
+                           c.rmsnorm_unit_offset)
         if c.residual_multiplier != 1.0:
             out = out * jnp.asarray(c.residual_multiplier, out.dtype)
         x = x + out.astype(x.dtype)
@@ -661,7 +765,7 @@ def forward(
         last = jnp.clip(chunk_lens - 1, 0, C - 1)
         x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     with jax.named_scope("lm_head"):
-        x = _rms(x, params["final_norm"], c.rms_norm_eps)
+        x = _rms(x, params["final_norm"], c.rms_norm_eps, c.rmsnorm_unit_offset)
         if c.logit_divisor != 1.0:
             x = x * jnp.asarray(1.0 / c.logit_divisor, x.dtype)
         logits = jnp.einsum(

@@ -57,7 +57,8 @@ f expert width. ``lp`` holds ``router_w`` [d, E], optionally ``router_bias``
 [E] (the score-correction bias: it chooses, it does not weigh), ``we_up``
 [Eh, d, f], ``we_down`` [Eh, f, d], ``we_gate`` [Eh, d, f] for a gated
 activation, and ``ws_up`` / ``ws_down`` (/ ``ws_gate``) for the shared
-expert.
+expert, whose output is multiplied by ``sigmoid(x ws_gate_scalar)`` ([d, 1])
+where the layer has one.
 """
 
 from __future__ import annotations
@@ -300,9 +301,13 @@ def _combine(top_w, local, valid, n_held):
 def _shared_expert(xs, lp, spec):
     up = qeinsum("td,df->tf", xs, lp["ws_up"])
     gate = qeinsum("td,df->tf", xs, lp["ws_gate"]) if "ws_gate" in lp else None
-    return qeinsum("tf,fd->td", _activate(up, gate, spec), lp["ws_down"]).astype(
+    out = qeinsum("tf,fd->td", _activate(up, gate, spec), lp["ws_down"]).astype(
         jnp.float32
     )
+    if "ws_gate_scalar" in lp:  # the shared expert's own sigmoid gate, a scalar a token
+        out = out * jax.nn.sigmoid(jnp.einsum(
+            "td,do->to", xs, lp["ws_gate_scalar"], preferred_element_type=jnp.float32))
+    return out
 
 
 def moe_ffn(

@@ -103,7 +103,7 @@ def _attention_shapes() -> List[Dict[str, Any]]:
 
     seen: Dict[tuple, Dict[str, Any]] = {}
     for name, make in BUILTIN_CONFIGS.items():
-        if name in ("tiny", "tiny-swa"):
+        if name in ("tiny", "tiny-swa", "tiny-gdn"):
             continue  # float32 test shapes, never a TPU worker
         c = make()
         windows = [w for w in c.layer_windows() if w]
@@ -695,6 +695,16 @@ def _build_ssd_step_xla(x, dt, A, Bm, Cm):
         jax.jit(lambda state: m2.ssd_step(x, dt, A, Bm, Cm, state)))
 
 
+def _build_gdn_step_xla(q, k, v, g, beta):
+    """``ops/gated_delta.gdn_step`` over every slot (``g`` and ``beta`` 0 on
+    the dead ones), jitted over the state alone."""
+    from dynamo_tpu.ops import gated_delta as gd
+
+    return watched_jit(
+        "chip_check.gdn_step_xla",
+        jax.jit(lambda state: gd.gdn_step(q, k, v, g, beta, state)))
+
+
 def _build_state_calls(step, calls: int):
     """``calls`` calls of a state update (state -> (y, state)) chained
     through the donated state in one jitted loop."""
@@ -708,6 +718,54 @@ def _build_state_calls(step, calls: int):
 
     return watched_jit(
         "chip_check.timed_state_calls", jax.jit(chained, donate_argnums=(0,)))
+
+
+def _state_kernel_row(row, kernel, xla, state, dead, moved: float, interpret: bool):
+    """Fill a live-row state kernel's row of the table: ``kernel`` and ``xla``
+    (state -> (out, state), the kernel's call donating) compared on the chip:
+    the live rows' state and output to float32 rounding, the dead rows' state
+    BIT FOR BIT and their output zero; then, not under the interpreter, both
+    timed as the difference of two chained loops, with the ``moved`` bytes
+    (the live rows' state, read and written) as a share of 819 GB/s."""
+    t0 = time.monotonic()
+    try:
+        out, new = jax.block_until_ready(kernel(state + 0.0))  # the call donates
+    except Exception as exc:
+        row.update(status="refused", message=_first_line(exc))
+    else:
+        out_ref, new_ref = xla(state)
+        out, new, out_ref, new_ref, old = (
+            np.asarray(a) for a in (out, new, out_ref, new_ref, state))
+        bad = None
+        if not np.array_equal(new[dead], old[dead]):
+            bad = "a dead row's state moved"
+        elif out[dead].any():
+            bad = "a dead row's output is not zero"
+        # (the XLA form's output of a dead row is its old state's read-out)
+        for name, got, ref in (("state", new, new_ref), ("output", out[~dead], out_ref[~dead])):
+            if bad is not None or not got.size:
+                continue
+            err = float(np.abs(got - ref).max())
+            if not np.isfinite(got).all() or err > 1e-5 * max(float(np.abs(ref).max()), 1.0):
+                bad = f"{name}: max |kernel-reference| {err:.4g}"
+        row.update(status="compiled" if bad is None else "disagrees", message=bad or "")
+    row["seconds"] = round(time.monotonic() - t0, 1)
+    if row["status"] == "compiled" and not interpret:
+
+        def us_per_call(step):
+            # (the loop's dispatch is as much as one live row's update)
+            return _us_per_call_two_lengths(
+                functools.partial(_build_state_calls, step), lambda: state + 0.0, 1)
+
+        def timed():
+            us = us_per_call(kernel)
+            row["message"] = (
+                f"{moved / 1e6:.1f} MB moved, {100 * moved / (us * 1e-6) / 819e9:.1f}% of "
+                f"819 GB/s; xla over every slot {us_per_call(xla)} us/call")
+            return us
+
+        row["time"] = timed
+    return row
 
 
 def ssd_step_jobs(interpret: bool):
@@ -750,48 +808,8 @@ def ssd_step_jobs(interpret: bool):
                                  heads_a_step=heads_a_step, interpret=interpret)
 
         xla = _build_ssd_step_xla(x, jnp.where(rows.mask[:, None], dt, 0.0), A, Bm, Cm)
-
-        t0 = time.monotonic()
-        try:
-            y, new = jax.block_until_ready(kernel(state + 0.0))  # the call donates
-        except Exception as exc:
-            row.update(status="refused", message=_first_line(exc))
-        else:
-            y_ref, new_ref = xla(state)
-            y, new, y_ref, new_ref, old = (
-                np.asarray(a) for a in (y, new, y_ref, new_ref, state))
-            bad = None
-            if not np.array_equal(new[dead], old[dead]):
-                bad = "a dead row's state moved"
-            elif y[dead].any():
-                bad = "a dead row's y is not zero"
-            # (the XLA form's y of a dead row is its old state's read-out)
-            for name, out, ref in (("state", new, new_ref), ("y", y[~dead], y_ref[~dead])):
-                if bad is not None or not out.size:
-                    continue
-                err = float(np.abs(out - ref).max())
-                if not np.isfinite(out).all() or err > 1e-5 * max(float(np.abs(ref).max()), 1.0):
-                    bad = f"{name}: max |kernel-reference| {err:.4g}"
-            row.update(status="compiled" if bad is None else "disagrees",
-                       message=bad or "")
-        row["seconds"] = round(time.monotonic() - t0, 1)
-        if row["status"] == "compiled" and not interpret:
-
-            def us_per_call(step):
-                # (the loop's dispatch is as much as one live row's update)
-                return _us_per_call_two_lengths(
-                    functools.partial(_build_state_calls, step), lambda: state + 0.0, 1)
-
-            def timed():
-                us = us_per_call(kernel)
-                moved = 2.0 * live * H * P * N * 4
-                row["message"] = (
-                    f"{moved / 1e6:.1f} MB moved, {100 * moved / (us * 1e-6) / 819e9:.1f}% of "
-                    f"819 GB/s; xla over every slot {us_per_call(xla)} us/call")
-                return us
-
-            row["time"] = timed
-        return row
+        return _state_kernel_row(
+            row, kernel, xla, state, dead, 2.0 * live * H * P * N * 4, interpret)
 
     if interpret:
         return [functools.partial(job, "tiny-hybrid", 6, 8, 16, 128, 2, live)
@@ -806,6 +824,78 @@ def ssd_step_jobs(interpret: bool):
         + [functools.partial(job, *hybrid, 14, hs) for hs in (32, 16, 8)]
         + [functools.partial(job, *sala, 10, hs) for hs in (16, 8)]
     )
+
+
+def gdn_step_jobs(interpret: bool):
+    """The live-row gated-delta-rule kernel (``gdn_step_live``) against
+    ``ops/gated_delta.gdn_step`` over every slot (dead slots given g = 0 and
+    beta = 0: what it replaces in a decode step), float32, at the served shape
+    (64 slots, 32 value heads x 128 keys x 128 values), a scattered set of
+    rows live. Compared and timed as ``ssd_step_jobs`` does: the live rows'
+    state and output to float32 rounding, the dead rows' state BIT FOR BIT and
+    their output zero; the one-live-row case is ``required``."""
+    from dynamo_tpu.ops import gated_delta as gd
+    from dynamo_tpu.ops.pallas.gdn_step import gdn_step_live
+    from dynamo_tpu.ops.pallas.ssd_step import live_row_list
+
+    def job(preset, slots, H, Dk, Dv, live, heads_a_step=None):
+        rng = np.random.default_rng(slots * 1000 + live)
+        f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        q, k = gd.prepare_qk(f32(slots, H, Dk), f32(slots, H, Dk), 1)
+        v, state = f32(slots, H, Dv), f32(slots, H, Dk, Dv)
+        active = np.zeros(slots, np.int32)
+        active[rng.permutation(slots)[:live]] = 1
+        rows = live_row_list(jnp.asarray(active))
+        on = rows.mask[:, None]
+        g = jnp.where(on, -0.05 * jax.nn.softplus(f32(slots, H)), 0.0)
+        beta = jnp.where(on, jax.nn.sigmoid(f32(slots, H)), 0.0)
+        dead = active == 0
+        row = {
+            "kernel": "gdn_step_live",
+            "shape": f"slots{slots} H{H} Dk{Dk} Dv{Dv} live{live} float32"
+                     + (f" heads_a_step{heads_a_step}" if heads_a_step else ""),
+            "presets": [preset],
+            "required": live == 1,
+        }
+
+        def kernel(state):
+            return gdn_step_live(q, k, v, g, beta, state, *rows,
+                                 heads_a_step=heads_a_step, interpret=interpret)
+
+        xla = _build_gdn_step_xla(q, k, v, g, beta)
+        return _state_kernel_row(
+            row, kernel, xla, state, dead, 2.0 * live * H * Dk * Dv * 4, interpret)
+
+    if interpret:
+        return [functools.partial(job, "tiny-gdn", 6, 4, 16, 128, live) for live in (0, 1, 3, 6)]
+    served = ("qwen3-next-80b-a3b-ep2", 64, 32, 128, 128)
+    return (
+        [functools.partial(job, *served, live) for live in (1, 10, 64)]
+        + [functools.partial(job, *served, 10, hs) for hs in (16, 8)]
+    )
+
+
+def gdn_attention_jobs(interpret: bool):
+    """Both paged-attention kernels at a head of 256 lanes (two lane tiles),
+    16 query heads over 2 K/V heads, pages of 128 tokens (Qwen3-Next's one
+    full layer of four): the decode kernel over tables of 32 pages (a 4 k
+    row) and 264 (a 33 k row), the chunk kernel for a turn's 256 queries over
+    both, each against the XLA form and the decode rows timed."""
+    job = _attention_job(interpret, B=4, P=4)
+    shape = {"H": 16, "KH": 2, "D": 256, "window": 0, "softcap": 0.0,
+             "presets": ["qwen3-next-80b-a3b-ep2"]}
+    if interpret:
+        return [functools.partial(job, dict(shape, H=4, D=256), "paged_attention_decode", 1,
+                                  False, B=4, P=5, rows="full", block_size=16)]
+    return [
+        functools.partial(job, shape, "paged_attention_decode", 1, False, B=B, P=P,
+                          rows="full", block_size=128)
+        for B, P in ((10, 32), (10, 264), (64, 32))
+    ] + [
+        functools.partial(job, shape, "paged_attention", 256, False, B=1, P=P,
+                          rows="full", block_size=128)
+        for P in (32, 264)
+    ]
 
 
 def _build_sampler_call(name: str, sample):
@@ -1102,6 +1192,8 @@ def main() -> int:
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(True),
             "mla_paged_decode": lambda: mla_jobs(True),
             "ssd_step": lambda: ssd_step_jobs(True),
+            "gdn_step": lambda: gdn_step_jobs(True),
+            "paged_attention_gdn": lambda: gdn_attention_jobs(True),
             "sampler": lambda: sampler_jobs(True),
             "paged_attention_swa": lambda: swa_attention_jobs(True),
         }
@@ -1116,6 +1208,8 @@ def main() -> int:
             "expert_ffn_grouped": lambda: expert_ffn_grouped_jobs(False),
             "mla_paged_decode": lambda: mla_jobs(False),
             "ssd_step": lambda: ssd_step_jobs(False),
+            "gdn_step": lambda: gdn_step_jobs(False),
+            "paged_attention_gdn": lambda: gdn_attention_jobs(False),
             "sampler": lambda: sampler_jobs(False),
             "paged_attention_swa": lambda: swa_attention_jobs(False),
         }

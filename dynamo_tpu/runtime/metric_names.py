@@ -122,6 +122,12 @@ ENGINE_MOE_EXPERTS_HIT_TOTAL = f"{ENGINE_PREFIX}_moe_experts_hit_total"
 ENGINE_MOE_EXPERT_SLOTS_TOTAL = f"{ENGINE_PREFIX}_moe_expert_slots_total"
 ENGINE_MOE_MAX_EXPERT_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_max_expert_tokens_total"
 ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_mean_expert_tokens_total"
+# Top-k choices the live rows of reaped decode bursts made, summed over steps
+# and expert layers (label held=1|0): those that fell on experts held here
+# (what ``moe_mean_expert_tokens`` sums, times the held count) and those on
+# the absent share of the router's width; held / both = this chip's share of
+# the deployment's routed work, 50% for a balanced half.
+ENGINE_MOE_ASSIGNMENTS_TOTAL = f"{ENGINE_PREFIX}_moe_assignments_total"
 # Live prompt tokens of reaped prefill steps that passed expert layers, by the
 # form the step's STATIC token count gives (ops/moe.form_of: label
 # form=hit_list|dense|grouped_kernel|grouped_xla); all four from start-up in an
@@ -682,6 +688,7 @@ ALL_ENGINE = (
     ENGINE_MOE_MAX_EXPERT_TOKENS_TOTAL,
     ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL,
     ENGINE_MOE_PREFILL_TOKENS_TOTAL,
+    ENGINE_MOE_ASSIGNMENTS_TOTAL,
     ENGINE_SSM_DECODE_ROWS_TOTAL,
     ENGINE_SAMPLER_DECODE_STEPS_TOTAL,
     ENGINE_SSM_STATE_SLOTS,

@@ -26,7 +26,9 @@ from dynamo_tpu.models.config import (
     openpangu_ultra_moe_ep16_config,
     qwen2_500m_config,
     qwen3_8b_config,
+    qwen3_next_ep2_config,
     tiny_config,
+    tiny_gdn_config,
     tiny_hybrid_config,
     tiny_mla_config,
     tiny_sala_config,
@@ -61,6 +63,8 @@ BUILTIN_CONFIGS = {
     "laguna-xs.2-pp8": laguna_xs2_pp8_config,
     "tiny-sala": tiny_sala_config,
     "minicpm-sala-pp4": minicpm_sala_pp4_config,
+    "tiny-gdn": tiny_gdn_config,
+    "qwen3-next-80b-a3b-ep2": qwen3_next_ep2_config,
 }
 
 

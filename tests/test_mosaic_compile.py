@@ -874,6 +874,130 @@ def test_sala_served_programs_compile_for_the_chip(one_chip, program):
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
 
 
+def _gdn_config():
+    from dynamo_tpu.models.config import qwen3_next_ep2_config
+
+    return dataclasses_replace_layers(qwen3_next_ep2_config(), [0, 1, 6, 7])
+
+
+@functools.cache
+def _gdn_program(one_chip, program):
+    """One served program of the Qwen3-Next stage at its published widths (a
+    Gated DeltaNet layer and the full gated-attention layer, each with its
+    experts: 256 of 512 held, top 10), as the runner builds it, over a
+    33,792-token table of 128-token pages."""
+    import types
+
+    from dynamo_tpu.engines.tpu import block_pool
+    from dynamo_tpu.engines.tpu.engine import JaxEngineArgs
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import hybrid, llama
+
+    cfg = _gdn_config()
+    NB, S, P, bs = 6144, 64, 264, 128
+    args = JaxEngineArgs(
+        config=cfg, block_size=bs, num_kv_blocks=NB, max_num_seqs=S, max_model_len=P * bs,
+        prefill_chunk=256, use_kernel=True,
+    )
+    runner = types.SimpleNamespace(config=cfg, args=args, use_kernel=True,
+                                   _decode_sig_budget=None)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = lambda f: jax.tree.map(on_chip, jax.eval_shape(f))
+    params = shapes(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    k, v = shapes(lambda: llama.init_kv_cache(cfg, NB, bs, layered=True))
+    entries = block_pool.snapshot_entries(cfg, NB, bs, S)
+    store = shapes(lambda: hybrid.init_ssm_state(cfg, entries))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def rows(B):
+        return [arr((B,), i32), arr((2,), jnp.uint32), arr((B,), f32),
+                arr((B,), i32), arr((B,), f32)]
+
+    if program == "decode_burst":
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, S))
+        lowered = DeviceRunner._build_decode_fn_hybrid(runner, False, False).lower(
+            params, k, v, state, arr((S,), i32), arr((S,), i32), arr((S,), i32),
+            arr((S, P), i32), *rows(S),
+        )
+    else:
+        fresh = program == "prefill_fresh"
+        B, C, width = (8, 256, 2) if fresh else (8, 256, P)
+        state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
+        lowered = DeviceRunner._build_step_fn_hybrid(runner, False, 0, fresh).lower(
+            params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
+            arr((B,), i32), arr((B, width), i32), arr((B, C // 64), i32), *rows(B),
+        )
+    return lowered.compile(), (params, k, v, store, state, entries)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+def test_gdn_served_programs_compile_for_the_chip(one_chip, program):
+    """The decode burst, a batch of fresh first chunks and eight turns' chunks
+    over 33,792-token tables, of the Qwen3-Next stage at its published widths,
+    compiled for the v5e as the runner builds them: both paged kernels lower
+    at a head of 256 (two lane tiles), 8 queries a K/V head; the delta rule's
+    chunk form (a static unroll over a chunk's four blocks) leaves a prefill
+    program without a ``while``; the burst's recurrence is ``gdn_step_live``
+    and no operation of it yields a whole ``f32[64, 32, 128, 128]`` state;
+    pools, state and snapshot store alias in and out; the burst's 64 slots go
+    through the hit-list expert kernel over 256 held experts, 8 x 256 tokens
+    through the grouped one."""
+    from dynamo_tpu.ops.pallas.chip_check import whole_array_ops, whole_pool_copies
+
+    compiled, (params, k, v, store, state, entries) = _gdn_program(one_chip, program)
+    text = compiled.as_text()
+    assert entries == 192 and k[0].shape == (6144, 128, 2, 256)
+    assert ("tpu_custom_call" in text) and (" while(" in text) == (program == "decode_burst")
+    assert whole_pool_copies(text, k[0]) == 0
+    # (the kernel's call, not its name: a module's text lists every function
+    # the process has traced)
+    assert ("gdn_step_live/pallas_call" in text) == (program == "decode_burst")
+    assert state["S"][0].shape[1:] == (32, 128, 128)
+    if program == "decode_burst":
+        assert whole_array_ops(text, state["S"][0]) == []
+    resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in k + v + state["S"] + state["conv"])
+    if program != "decode_burst":
+        resident += sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                        for a in store["S"] + store["conv"])
+    assert compiled.memory_analysis().alias_size_in_bytes >= resident
+    assert ("expert_ffn_hit_list/pallas_call" in text) == (program == "decode_burst")
+    assert ("expert_ffn_grouped/pallas_call" in text) == (program != "decode_burst")
+    assert "ragged-dot" not in text
+    experts = params["layers"][1]
+    assert [whole_pool_copies(text, experts[m])
+            for m in ("we_up", "we_gate", "we_down")] == [0, 0, 0]
+
+
+GDN_STEP_SHAPES = {"64-slots": 64, "one-slot": 1}
+
+
+@pytest.mark.parametrize("name", sorted(GDN_STEP_SHAPES))
+def test_gdn_state_update_kernel_compiles_for_v5e(one_chip, name):
+    """``gdn_step_live`` at the served widths (32 value heads of 128 x 128: a
+    whole row of state a grid step) and with one slot (a list of two
+    entries): Mosaic lowers the column and row selects, the sublane sums and
+    the two float32 scalar prefetches, and the state aliases in and out."""
+    from dynamo_tpu.ops.pallas.gdn_step import _gdn_step_live_impl
+
+    B, H, D = GDN_STEP_SHAPES[name], 32, 128
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(_gdn_step_live_impl, donate_argnums=(5,)).lower(
+        sds((B, H, D)), sds((B, H, D)), sds((B, H, D)), sds((B, H)), sds((B, H)),
+        sds((B, H, D, D)), sds((1,), jnp.int32), sds((B + 1,), jnp.int32),
+        sds((B,), jnp.bool_),
+    ).compile()
+    assert "gdn_step_live/pallas_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes == B * H * D * D * 4
+
+
 def _computations(hlo_text):
     """name -> text of every computation of an optimised HLO module."""
     import re
@@ -927,6 +1051,7 @@ SERVED_PROGRAMS = {
     "latent": lambda chip, program: _mla_program(chip, program, depth=1),
     "window": _laguna_program,
     "sparse": _sala_program,
+    "gdn": _gdn_program,
 }
 
 

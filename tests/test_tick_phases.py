@@ -629,12 +629,14 @@ def served():
 
     async def run():
         from dynamo_tpu.models.config import (
-            tiny_hybrid_config, tiny_mla_config, tiny_sala_config, tiny_swa_config)
+            tiny_gdn_config, tiny_hybrid_config, tiny_mla_config, tiny_sala_config,
+            tiny_swa_config)
 
         hybrid_body = await hybrid_scrape(tiny_hybrid_config())
         mla_body = await hybrid_scrape(tiny_mla_config())
         swa_body = await hybrid_scrape(tiny_swa_config())
         sala_body = await hybrid_scrape(tiny_sala_config())
+        gdn_body = await hybrid_scrape(tiny_gdn_config())
         engine, _ = make_engine(decode_steps=4)
         server = SystemStatusServer(host="127.0.0.1", port=0)
         attach_engine(server, engine)
@@ -667,6 +669,7 @@ def served():
                     "workers_mla": mla_body,
                     "workers_swa": swa_body,
                     "workers_sala": sala_body,
+                    "workers_gdn": gdn_body,
                     "workers": await scrape(s, server.port, "/metrics"),
                     "frontend": await scrape(s, http_port, "/metrics"),
                     "routes": routes,
@@ -701,7 +704,7 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
         mn.KVCACHE_REUSED_TOKENS_TOTAL, mn.KVCACHE_RECOMPUTED_TOKENS_TOTAL}
     labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
               | set(mn.FRAME_KINDS) | {"used", "total", "window", "sparse", "dense"}
-              | {"updated", "slots"} | {"greedy", "full"}
+              | {"updated", "slots"} | {"greedy", "full"} | {"1", "0"}
               | set(MOE_FORMS))
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
@@ -716,6 +719,8 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
         workers = "workers_swa"
     if entry.get("workloads") and all("minicpm-sala" in w for w in entry["workloads"]):
         workers = "workers_sala"
+    if entry.get("workloads") and all("qwen3-next" in w for w in entry["workloads"]):
+        workers = "workers_gdn"
     flags = {o for a in build_parser()._actions for o in a.option_strings}  # noqa: SLF001
     for key in ("per_flag", "percent_of_worker_flag"):
         assert params.get(key) in flags | {None}, (name, key)
@@ -741,7 +746,8 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
             at = [v for x in at for v in (x if key == "*" else [x[key]])]
     else:
         assert spec["reader"] in (
-            "trace", "hybrid_roofline", "mla_roofline", "swa_roofline", "sala_roofline")
+            "trace", "hybrid_roofline", "mla_roofline", "swa_roofline", "sala_roofline",
+            "gdn_roofline")
         assert ("POST", "/debug/profile") in served["routes"]
         for key, family in params.items():
             if key.endswith("_metric"):
