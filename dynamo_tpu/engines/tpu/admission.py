@@ -786,7 +786,7 @@ class Admitter:
                 # prefill-seconds-saved estimate.
                 kv_reuse_plane().note_prefill_cost(dt, int(lens.sum()))
                 if e.moe_prefill_tokens is not None:
-                    form = e.runner.prefill_expert_form(Bp * c_bucket)
+                    form = e.runner.prefill_expert_form(Bp, c_bucket)
                     e.moe_prefill_tokens[form] += int(lens.sum())
                 if e._tick_budget_left is not None:
                     e._tick_budget_left -= int(lens.sum())

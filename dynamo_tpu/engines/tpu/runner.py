@@ -491,7 +491,7 @@ class DeviceRunner:
         self.host_params: Optional[Any] = None
         self.expert_ffn = self._describe_expert_ffn()
         self.ssd_step = self._describe_ssd_step()
-        self._prefill_expert_forms: Dict[int, Optional[str]] = {}
+        self._prefill_expert_forms: Dict[Tuple[int, int], Optional[str]] = {}
         logger.info(
             "device runner: platform=%s device_kind=%s devices=%d mesh=%s | "
             "attention: %s (%s) | expert_ffn: %s | ssd_step: %s",
@@ -599,7 +599,7 @@ class DeviceRunner:
 
     def _describe_expert_ffn(self) -> Optional[str]:
         """The form a decode step's expert layers take and why
-        (ops/moe.form_in_use at ``max_num_seqs`` tokens); None for a model
+        (ops/moe.form_in_use at ``[max_num_seqs, 1]`` tokens); None for a model
         without expert layers. Not a choice: ``moe_ffn`` makes it, from the
         same arguments, every time it is traced."""
         from dynamo_tpu.ops.moe import form_in_use
@@ -608,7 +608,7 @@ class DeviceRunner:
         if not self.hybrid:
             return "xla, the stacked layer loop takes no kernel" if c.is_moe else None
         forms = {
-            form_in_use(self.use_kernel, self.args.max_num_seqs, lp, spec)
+            form_in_use(self.use_kernel, (self.args.max_num_seqs, 1), lp, spec)
             for spec, lp in zip(c.layer_specs, self.params["layers"])
             if spec.kind == "experts"
         }
@@ -632,15 +632,16 @@ class DeviceRunner:
             self.SSD_STEP_LIVE if why is None else f"xla every slot, {why}"
             for why in whys))
 
-    def prefill_expert_form(self, tokens: int) -> Optional[str]:
-        """ops/moe.form_of for a prefill step of ``tokens`` static tokens
-        (rows bucket x chunk bucket): what ``moe_ffn`` branches on when that
-        program is traced, at the first expert layer; None for a model
-        without expert layers."""
+    def prefill_expert_form(self, rows: int, chunk: int) -> Optional[str]:
+        """ops/moe.form_of for a prefill step of ``[rows, chunk]`` static
+        tokens (rows bucket, chunk bucket): what ``moe_ffn`` branches on
+        when that program is traced, at the first expert layer; None for a
+        model without expert layers."""
         from dynamo_tpu.ops.moe import form_of
 
         c = self.config
-        if tokens not in self._prefill_expert_forms:
+        step = (rows, chunk)
+        if step not in self._prefill_expert_forms:
             layers = self.params["layers"]
             if self.hybrid:
                 given = next(
@@ -651,9 +652,9 @@ class DeviceRunner:
                 given = (False, lp, c.experts_spec())
             else:
                 given = None
-            self._prefill_expert_forms[tokens] = (
-                given and form_of(given[0], tokens, *given[1:])[0])
-        return self._prefill_expert_forms[tokens]
+            self._prefill_expert_forms[step] = (
+                given and form_of(given[0], step, *given[1:])[0])
+        return self._prefill_expert_forms[step]
 
     # -- path selection ----------------------------------------------------
 

@@ -12,19 +12,24 @@ expert matrices are sharded on their first axis and GSPMD partitions the
 dense form's einsums by itself: no exchange of tokens, every shard sees the
 whole batch, as the two chips of the served deployment do.
 
-Four forms of the held experts' part, two on each side of
-``DENSE_TOKENS_MAX`` tokens a step. ``form_of`` chooses from what it is given
-(the caller's ``use_kernel``, the static token count, the matrices, the
-spec): ``hit_list_reason`` says why a small step is not the first,
-``grouped_reason`` why a large one is not the third. No flag, no name of a
-model:
+Four forms of the held experts' part. ``form_of`` chooses from what it is
+given (the caller's ``use_kernel``, the step's static shape [B, C], the
+matrices, the spec): ``hit_list_reason`` says why a step is not the first,
+``grouped_reason`` why one the first gave up is not the third; where no
+kernel serves, the second up to ``DENSE_TOKENS_MAX`` tokens a step and the
+fourth above. No flag, no name of a model:
 
-* hit list (a decode step or a prefill step of up to 256 tokens on one TPU
-  chip): the dense form's own products, float32 accumulation, over the
-  experts that got a LIVE token and no others, by a Pallas kernel whose
-  grid is that list (ops/pallas/expert_ffn.py). A step streams from HBM the
+* hit list (a decode step of any width up to 256 slots, and a prefill chunk
+  of up to 256 tokens whose experts are wide enough to hide the chunk's
+  products behind their stream, on one TPU chip): the dense form's own
+  products, float32 accumulation, over the experts that got a LIVE token
+  and no others, by a Pallas kernel whose grid is that list
+  (ops/pallas/expert_ffn.py). A step streams from HBM the
   matrices of the experts hit, which is what ``want_stats`` counts: a dead
-  slot (``row_mask`` false) routes to no expert. The kernel reads ``relu2``
+  slot (``row_mask`` false) routes to no expert. Every one of the step's T
+  rows goes through every expert hit, whatever that expert got: right for a
+  decode step, whose few live rows hit few experts, and what
+  ``chunk_costs`` prices for a prefill chunk. The kernel reads ``relu2``
   experts (two matrices) and ``silu_gated`` ones (three), with ``we_up`` /
   ``we_gate`` in either layout XLA holds them in: d minor-most where f does
   not fill the 128 lanes (the hybrid cell, 2688 x 1856), f minor-most where
@@ -36,7 +41,11 @@ model:
   experts stream once, and the einsums are static shapes that GSPMD
   partitions over an ``experts`` sharded axis by itself.
 * grouped kernel (a prefill step of more than 256 tokens on one TPU chip,
-  since PR 45): assignments sorted by held expert, dead rows and absent
+  since PR 45; since PR 51 also a chunk of up to 256 over many small
+  experts, as a turn of the window and delta-rule cells, where the hit list
+  would run all T rows through each of ~250 experts hit and this kernel the
+  4-8 an expert got, padded to one tile): assignments sorted by held
+  expert, dead rows and absent
   experts last; each expert's rows padded to whole row tiles, so that a
   tile belongs to one expert; ONE Pallas kernel whose grid is the list of
   (expert, row tile) pairs (``expert_ffn_grouped``): a step is ``act(x_tile
@@ -63,6 +72,7 @@ where the layer has one.
 
 from __future__ import annotations
 
+import math
 from typing import TYPE_CHECKING, Any, Dict, Optional, Tuple
 
 import jax
@@ -71,15 +81,15 @@ import jax.numpy as jnp
 from dynamo_tpu.ops.pallas.expert_ffn import (
     ACTIVATIONS, GROUPED_ROW_TILE_MAX, GROUPED_VMEM_BYTES_MAX, expert_ffn,
     expert_ffn_grouped, grouped_row_tile, grouped_tiles,
-    grouped_vmem_bytes, grouped_work_list, hit_list,
+    grouped_vmem_bytes, grouped_work_list, hit_list, hit_list_steps,
 )
 from dynamo_tpu.ops.quant import qeinsum
 
 if TYPE_CHECKING:  # models/ imports this module: the spec is data, named only
     from dynamo_tpu.models.config import ExpertsSpec
 
-# Token count up to which every token goes through every expert hit (the
-# hit-list kernel, where ``hit_list_reason`` finds none against it) or
+# Token count up to which every token may go through every expert hit (the
+# hit-list kernel, where ``hit_list_reason`` finds nothing against it) or
 # through every held expert (the dense form); above it the grouped forms
 # serve. At the hybrid cell's widths (d 2688, f 1856, 64 held) the dense
 # form's FLOPs pass the time the weights take to stream at about 256 tokens
@@ -93,6 +103,38 @@ if TYPE_CHECKING:  # models/ imports this module: the spec is data, named only
 # tokens against 2,127 / 2,136 / 2,444 (a tie at 256, where a step with two
 # experts hit takes 347 us; my chip run, PR 43), so one bound serves both.
 DENSE_TOKENS_MAX = 256
+
+# Below that bound, which kernel a PREFILL chunk takes (``chunk_costs``; a
+# decode step, C = 1, always keeps the hit list: its slots are mostly dead
+# and it reads the 16-25 experts its live rows hit). The hit-list kernel runs
+# all T rows of the step through every expert hit, whatever the expert got;
+# the grouped kernel runs the assignments, ~T x top_k / n_experts an expert,
+# padded to a row tile. Both stream an expert hit once, so what parts them is
+# whether those T rows of products hide behind the stream. An expert the hit
+# list takes in several tiles (``hit_list_steps``: 4 at the hybrid widths,
+# 28 at the latent ones) overlaps a tile's products with the next tile's
+# DMA: max(stream, products), and the two cross at the chip's ridge,
+# ``RIDGE_TOKENS`` rows (v5e, bf16: 197 TFLOP/s over 819 GB/s, two FLOPs and
+# two bytes a weight). A SMALL expert is two short grid steps there (one
+# tile a matrix) with nothing to overlap, so its products add to its
+# stream, stream x (1 + T / ridge); in the grouped kernel it is one resident
+# block whose successor streams during its one padded tile of rows. The
+# grouped form pays a sort and two gathers around the kernel, the bytes of
+# the padded rows in and out (``moved``). Held against chip_check (my chip
+# run, PR 51, ``expert_ffn_grouped`` rows, both forms over ONE routing, a
+# third of the rows dead, us a layer, hit list -> grouped at the tile these
+# steps now take, ``grouped_row_tile`` 16):
+#   [256 held, 2048 x 512] x 3, top-8 of 256: 64 tokens (147 experts hit)
+#     1,593 -> 1,374; 128 (191 hit) 2,351 -> 1,759; 256 (228 hit) 3,518 ->
+#     2,163. Top-10 of 512, 256 held: 64 (119 hit) 1,301 -> 1,137; 128 (163)
+#     2,011 -> 1,539; 256 (211) 3,252 -> 2,010. An expert hit costs the hit
+#     list 10.8 / 12.3 / 15.4 us at 64 / 128 / 256 tokens (its stream is 7.7
+#     at the peak) and the grouped form 9.3-9.8 all in, at any of them.
+#   [64 held, 2688 x 1856] x 2, top-6 of 128: 128 tokens (57 hit) 1,552 ->
+#     1,643; 256 (56 hit) 1,704 -> 1,680: products hidden, the hit list stays
+#     (``CHUNK_MARGIN``: a difference no row decides changes no program).
+RIDGE_TOKENS = 240
+CHUNK_MARGIN = 1.05
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -153,24 +195,63 @@ def _kernel_refusal(lp: Dict[str, Any], spec: ExpertsSpec) -> Optional[str]:
     return None
 
 
+def _n_matrices(spec: ExpertsSpec) -> int:
+    return 3 if spec.activation == "silu_gated" else 2
+
+
+def chunk_costs(
+    step: Tuple[int, int], lp: Dict[str, Any], spec: ExpertsSpec
+) -> Tuple[float, float]:
+    """(hit list, grouped kernel): what a step of ``step`` = [B, C] static
+    tokens costs a layer through each kernel, in bytes of HBM time (FLOPs
+    counted as the bytes that take as long: ``RIDGE_TOKENS``). From the
+    shapes and the spec alone; the comment above ``RIDGE_TOKENS`` says what
+    each term is and which ``chip_check`` rows it was held against."""
+    T = step[0] * step[1]
+    A = T * spec.top_k
+    n_held, d, f = lp["we_up"].shape
+    itemsize = lp["we_up"].dtype.itemsize
+    per_expert = A / spec.n_experts  # assignments a held expert expects
+    streamed = n_held * min(1.0, per_expert) * _n_matrices(spec) * d * f * itemsize
+    products = T / RIDGE_TOKENS  # of T rows, beside one stream of the expert
+    listed = streamed * (
+        max(1.0, products) if hit_list_steps(d, f, itemsize) > 2 else 1.0 + products)
+    tm = grouped_row_tile(A, spec.n_experts)
+    padded = tm * math.ceil(per_expert / tm)  # rows the grouped kernel runs an expert
+    # the padded rows gathered in (tokens) and out (float32), each token's K rows
+    moved = grouped_tiles(A, n_held, tm) * tm * d * (itemsize + 4) + A * d * 4
+    return listed, streamed * max(1.0, padded / RIDGE_TOKENS) + moved
+
+
 def hit_list_reason(
-    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+    use_kernel: bool, step: Tuple[int, int], lp: Dict[str, Any], spec: ExpertsSpec
 ) -> Optional[str]:
-    """None where the held experts' part goes through the hit-list kernel;
-    otherwise why it keeps the XLA forms."""
+    """None where the held experts' part of a step of ``step`` = [B, C]
+    static tokens goes through the hit-list kernel; otherwise why not: no
+    kernel, a step over ``DENSE_TOKENS_MAX`` tokens, matrices the kernel does
+    not read, or a prefill chunk (C > 1) that ``chunk_costs`` prices dearer
+    through the hit list than through the grouped kernel."""
     if not use_kernel:
         return NO_KERNELS
-    if T > DENSE_TOKENS_MAX:
-        return f"{T} tokens a step is over {DENSE_TOKENS_MAX}"
-    return _kernel_refusal(lp, spec)
+    B, C = step
+    if B * C > DENSE_TOKENS_MAX:
+        return f"{B * C} tokens a step is over {DENSE_TOKENS_MAX}"
+    why = _kernel_refusal(lp, spec)
+    if why is None and C > 1 and grouped_reason(use_kernel, lp, spec) is None:
+        listed, grouped = chunk_costs(step, lp, spec)
+        if listed > CHUNK_MARGIN * grouped:
+            return (f"a chunk of {B * C} tokens through every expert hit prices "
+                    f"{listed / grouped:.1f} times the grouped kernel")
+    return why
 
 
 def grouped_reason(
     use_kernel: bool, lp: Dict[str, Any], spec: ExpertsSpec
 ) -> Optional[str]:
-    """None where a step of more than ``DENSE_TOKENS_MAX`` tokens goes
-    through the grouped kernel; otherwise why it keeps ``ragged_dot``. From
-    the caller's ``use_kernel``, the matrices and the spec alone."""
+    """None where a step the hit list gave up (more than ``DENSE_TOKENS_MAX``
+    tokens, or a prefill chunk ``chunk_costs`` sends here) goes through the
+    grouped kernel; otherwise why it keeps the XLA forms. From the caller's
+    ``use_kernel``, the matrices and the spec alone."""
     if not use_kernel:
         return NO_KERNELS
     why = _kernel_refusal(lp, spec)
@@ -179,7 +260,7 @@ def grouped_reason(
     # The kernel keeps an expert's matrices whole in VMEM, so that the
     # second row tile of an expert streams nothing.
     _, d, f = lp["we_up"].shape
-    matrices = 3 if spec.activation == "silu_gated" else 2
+    matrices = _n_matrices(spec)
     need = grouped_vmem_bytes(
         GROUPED_ROW_TILE_MAX, d, f, matrices, lp["we_up"].dtype.itemsize)
     if need > GROUPED_VMEM_BYTES_MAX:
@@ -189,34 +270,37 @@ def grouped_reason(
 
 
 def form_of(
-    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+    use_kernel: bool, step: Tuple[int, int], lp: Dict[str, Any], spec: ExpertsSpec
 ) -> Tuple[str, Optional[str]]:
-    """(form, why not a kernel) of a step of ``T`` tokens: ``hit_list`` or
-    ``dense`` up to ``DENSE_TOKENS_MAX`` tokens (and ``dense`` for quantized
-    matrices, which neither ``ragged_dot`` nor a kernel takes),
-    ``grouped_kernel`` or ``grouped_xla`` above. What ``moe_ffn`` branches
-    on, the runner logs and the engine counts prefill tokens by."""
-    why = hit_list_reason(use_kernel, T, lp, spec)
+    """(form, why not a kernel) of a step of ``step`` = [B, C] static tokens
+    (C = 1: a decode step): ``hit_list`` where ``hit_list_reason`` has
+    nothing against it, else ``grouped_kernel`` where ``grouped_reason`` has
+    nothing against that; where no kernel serves, ``dense`` up to
+    ``DENSE_TOKENS_MAX`` tokens (and for quantized matrices, which neither
+    ``ragged_dot`` nor a kernel takes), ``grouped_xla`` above. What
+    ``moe_ffn`` branches on, the runner logs and the engine counts prefill
+    tokens by."""
+    why = hit_list_reason(use_kernel, step, lp, spec)
     if why is None:
         return "hit_list", None
-    if T <= DENSE_TOKENS_MAX:
+    grouped_why = grouped_reason(use_kernel, lp, spec)
+    if grouped_why is None:
+        return "grouped_kernel", None
+    if step[0] * step[1] <= DENSE_TOKENS_MAX:
         return "dense", why
-    why = grouped_reason(use_kernel, lp, spec)
-    if isinstance(lp["we_up"], dict):
-        return "dense", why
-    return ("grouped_kernel" if why is None else "grouped_xla"), why
+    return ("dense" if isinstance(lp["we_up"], dict) else "grouped_xla"), grouped_why
 
 
 FORMS = ("hit_list", "dense", "grouped_kernel", "grouped_xla")
 
 
 def form_in_use(
-    use_kernel: bool, T: int, lp: Dict[str, Any], spec: ExpertsSpec
+    use_kernel: bool, step: Tuple[int, int], lp: Dict[str, Any], spec: ExpertsSpec
 ) -> str:
-    """Which form a step of ``T`` tokens takes, for the log: ``pallas hit
-    list``, ``pallas grouped``, or ``xla dense|grouped, <why not the
-    kernel>``."""
-    form, why = form_of(use_kernel, T, lp, spec)
+    """Which form a step of ``step`` = [B, C] tokens takes, for the log:
+    ``pallas hit list``, ``pallas grouped``, or ``xla dense|grouped, <why
+    not the kernel>``."""
+    form, why = form_of(use_kernel, step, lp, spec)
     if why is None:
         return {"hit_list": "pallas hit list", "grouped_kernel": "pallas grouped"}[form]
     return f"xla {form.split('_')[0]}, {why}"
@@ -335,7 +419,7 @@ def moe_ffn(
     valid = (local >= 0) & (local < n_held)
     if row_mask is not None:
         valid = valid & row_mask.reshape(T, 1)
-    form, _ = form_of(use_kernel, T, lp, spec)
+    form, _ = form_of(use_kernel, (B, C), lp, spec)
     hit_listed = form == "hit_list"
     if hit_listed or want_stats:  # tokens on each held expert, [Eh] float32
         load = jnp.zeros((n_held + 1,), jnp.float32).at[

@@ -494,23 +494,36 @@ def expert_ffn_jobs(interpret: bool):
         # the other two cells take few long ones.
         + family("laguna-xs.2-pp8", 2048, 512, 256, 8, "silu_gated",
                  [(64, 1), (64, 16), (64, 57), (64, 128), (64, 256), (128, 128),
-                  (128, 256), (256, 256)])
+                  (128, 256), (256, 256)]
+                 # a turn's chunk of 64 / 128 / 256 tokens, a third of the
+                 # rows dead: the experts ``expert_ffn_grouped``'s rows at
+                 # the same tokens hit (PR 51: the two forms side by side)
+                 + [(64, 147), (128, 191), (256, 228)])
+        # Qwen3-Next at EP-2: the same tile, top-10 of a router 512 wide
+        # with 256 held, so a token brings ~5 assignments here.
+        + family("qwen3-next-80b-a3b-ep2", 2048, 512, 256, 10, "silu_gated",
+                 [(64, 119), (128, 163), (256, 211), (256, 256)])
     )
 
 
 def expert_ffn_grouped_jobs(interpret: bool):
-    """The grouped expert kernel (a prefill step of more than
-    ``DENSE_TOKENS_MAX`` tokens) against ``ragged_dot``, both as ops/moe.py
-    serves them, sort and scatter included, at the three served widths:
-    top-K over the router's whole width with uneven expert popularity
-    (lognormal, as random routers give: the most loaded held expert gets
-    several times the mean), a third of the rows dead as a padded batch has
-    them, the experts past ``n_held`` absent. Where ``moe.grouped_reason``
-    keeps a width on ``ragged_dot`` the row says why and times that alone.
-    The ``required`` row is a work list of ONE row tile (PR 25)."""
+    """The grouped expert kernel (a prefill step) against ``ragged_dot``,
+    both as ops/moe.py serves them, sort and scatter included, at the four
+    served expert shapes: top-K over the router's whole width with uneven
+    expert popularity (lognormal, as random routers give: the most loaded
+    held expert gets several times the mean), a third of the rows dead as a
+    padded batch has them, the experts past ``n_held`` absent. Where
+    ``moe.grouped_reason`` keeps a width on ``ragged_dot`` the row says why
+    and times that alone. Up to ``moe.DENSE_TOKENS_MAX`` tokens the row also
+    times the hit-list kernel over the SAME routing: the two forms
+    ``moe.form_of`` chooses between for a turn's chunk, side by side. The
+    ``required`` rows are a work list of ONE row tile (PR 25) and a step
+    whose every row is dead (a prefix-hit family's empty sibling)."""
     from dynamo_tpu.models.config import ExpertsSpec
     from dynamo_tpu.ops import moe
-    from dynamo_tpu.ops.pallas.expert_ffn import expert_ffn_grouped, grouped_row_tile
+    from dynamo_tpu.ops.pallas.expert_ffn import (
+        expert_ffn, expert_ffn_grouped, grouped_row_tile, hit_list,
+    )
 
     dtype = jnp.float32 if interpret else jnp.bfloat16
     if interpret:
@@ -521,7 +534,7 @@ def expert_ffn_grouped_jobs(interpret: bool):
                            activation=activation, held=(0, n_held))
         weights = functools.partial(_expert_weights, n_held, d, f, activation, dtype)
 
-        def job(T, one_tile=False):
+        def job(T, one_tile=False, all_dead=False):
             rng = np.random.default_rng(T + n_held)
             xs = jnp.asarray(rng.standard_normal((T, d)), dtype)
             popularity = rng.normal(0.0, 1.0, n_experts)
@@ -529,8 +542,9 @@ def expert_ffn_grouped_jobs(interpret: bool):
                 -(popularity + rng.gumbel(size=(T, n_experts))), axis=1)[:, :K]
             live = rng.random(T) < 2 / 3
             valid = (local < n_held) & live[:, None]
-            if one_tile:  # three assignments in all, on held expert 1
+            if one_tile or all_dead:
                 valid[:] = False
+            if one_tile:  # three assignments in all, on held expert 1
                 valid[:3, 0], local[:3, 0] = True, 1
             top_w = jnp.asarray(rng.random((T, K)) + 0.1, jnp.float32)
             local, valid = jnp.asarray(local.astype(np.int32)), jnp.asarray(valid)
@@ -542,7 +556,7 @@ def expert_ffn_grouped_jobs(interpret: bool):
                          f"tm{grouped_row_tile(T * K, n_experts)}: {int(sizes.sum())} rows on "
                          f"{int((sizes > 0).sum())} experts, most {int(sizes.max())}",
                 "presets": [preset],
-                "required": one_tile,
+                "required": one_tile or all_dead,
             }
 
             def kernel(xs, lp):
@@ -575,29 +589,41 @@ def expert_ffn_grouped_jobs(interpret: bool):
                          lambda: dense(xs, weights()), ulps=4)
             if row["status"] == "compiled" and not interpret:
 
+                def listed(xs, lp):
+                    comb = moe._combine(top_w, local, valid, n_held)
+                    return expert_ffn(
+                        xs, comb, lp["we_up"], lp["we_down"], *hit_list(comb.sum(0)),
+                        lp.get("we_gate")).astype(xs.dtype)
+
                 def both():
                     row["message"] = (
                         f"xla grouped {_us_per_call(grouped, xs, weights())} us/call")
+                    if T <= moe.DENSE_TOKENS_MAX:
+                        row["message"] += (
+                            f", hit list {_us_per_call(listed, xs, weights())} us/call")
                     return _us_per_call(kernel, xs, weights())
 
                 row["time"] = both
             return row
 
         return [functools.partial(job, T) for T in tokens] + [
-            functools.partial(job, tokens[0], True)]
+            functools.partial(job, tokens[0], True),
+            functools.partial(job, tokens[1], all_dead=True)]
 
     if interpret:
         return (
             family("tiny-hybrid", 128, 48, 8, 16, 2, "relu2", [320, 512])
-            + family("tiny-swa", 128, 128, 8, 8, 2, "silu_gated", [320])
+            + family("tiny-swa", 128, 128, 8, 8, 2, "silu_gated", [320, 64])
         )
     return (
-        # (128 and 256 tokens are the hit-list kernel's today: the rows say
-        # where the two kernels cross, ops/moe.DENSE_TOKENS_MAX)
+        # (64, 128 and 256 tokens: where the two kernels cross at each
+        # shape, what ops/moe.form_of's rule for a turn's chunk rests on)
         family("nemotron-3-nano-30b-a3b-ep2", 2688, 1856, 64, 128, 6, "relu2",
-               [512, 256, 1024, 2048, 4096, 8192])
+               [512, 256, 128, 1024, 2048, 4096, 8192])
         + family("laguna-xs.2-pp8", 2048, 512, 256, 256, 8, "silu_gated",
-                 [512, 128, 256, 1024, 2048])
+                 [512, 256, 64, 128, 1024, 2048])
+        + family("qwen3-next-80b-a3b-ep2", 2048, 512, 256, 512, 10, "silu_gated",
+                 [512, 256, 64, 128])
         + family("openpangu-ultra-moe-718b-ep16", 7680, 2048, 16, 256, 8,
                  "silu_gated", [512, 2048])
     )

@@ -54,7 +54,11 @@ list) applied to expert weights in place of KV pages.
     Operands in the weights' dtype (bf16 served), float32 accumulation, the
     activation in float32.
 
-A prefill step of more than ``ops/moe.DENSE_TOKENS_MAX`` tokens takes the
+A prefill step of more than ``ops/moe.DENSE_TOKENS_MAX`` tokens, and since
+PR 51 a smaller chunk over many small experts (``ops/moe.chunk_costs``: the
+kernel above runs ALL its rows through every expert hit, and an expert it
+takes in two short grid steps, ``hit_list_steps``, hides none of those
+products behind its stream), takes the
 second kernel of this file, ``expert_ffn_grouped`` (PR 45; the grouped form
 it replaces was an ``argsort``, a gather and three ``jax.lax.ragged_dot``
 calls with the [T*K, f] intermediate through HBM and, because ``ragged_dot``
@@ -175,6 +179,16 @@ def f_minor(f: int) -> bool:
     with d minor-most rather than pad f (seen compiling for the described
     v5e, PR 37; tests/test_mosaic_compile.py pins both)."""
     return f % LANES == 0
+
+
+def hit_list_steps(d: int, f: int, itemsize: int) -> int:
+    """Grid steps the hit-list kernel takes ONE expert in, from the widths
+    (``_expert_ffn_impl``'s own tiling): 4 at the hybrid cell's, 28 at the
+    latent cell's, 2 at the window and delta-rule cells' (2048 x 512: one
+    step for the up and gate matrices whole, one for the down matrix)."""
+    if f_minor(f):
+        return d // lane_tile(d, f * itemsize) + f // lane_tile(f, d * itemsize)
+    return f // f_tile(f, d, itemsize)
 
 
 def hit_list(load: jnp.ndarray):
@@ -381,16 +395,22 @@ GROUPED_ROW_TILE_MAX = 64
 
 def grouped_row_tile(assignments: int, n_experts: int) -> int:
     """Rows of a tile of the grouped kernel, from the step's static shape:
-    32 where an expert expects at most 64 assignments (all of them over the
-    router's width), 64 above. A tile belongs to one expert and each
-    expert's last tile is padded; an expert's matrices stay in VMEM over its
-    tiles, so a small tile costs a grid step, not a stream, and what pads
+    16 where an expert expects at most 8 assignments (all of them over the
+    router's width), 32 up to 64, 64 above. A tile belongs to one expert and
+    each expert's last tile is padded; an expert's matrices stay in VMEM over
+    its tiles, so a small tile costs a grid step, not a stream, and what pads
     costs FLOPs and rows of the gather. Measured (scratch sweep of 32-256,
     my chip run, PR 45, us a layer, tiles of 32 / 64 / 128 / 256): hybrid
     widths 512 tokens 2,005 / 2,066 / 2,424 / 2,925, 8,192 tokens 11,053 /
     10,204 / 10,343 / 10,313; window-cell widths 512 tokens 2,957 / 3,603 /
-    4,826 / 7,146, 2,048 tokens 4,869 / 5,319 / 6,327 / 8,413."""
-    return 32 if assignments <= 64 * max(n_experts, 1) else 64
+    4,826 / 7,146, 2,048 tokens 4,869 / 5,319 / 6,327 / 8,413. A turn's chunk
+    of 64 / 128 / 256 tokens over 256 small experts (4-8 rows an expert:
+    scratch sweep, my chip run, PR 51, tiles of 16 / 32): top-8 of 256 1,374
+    / 1,457, 1,759 / 1,842, 2,163 / 2,244; top-10 of 512 with 256 held 1,137
+    / 1,201, 1,539 / 1,641, 2,010 / 2,113 (the kernel alone 1,997 / 2,118
+    over 247 / 231 tiles at 256 tokens: a bf16 tile is 16 sublanes)."""
+    per_expert = assignments / max(n_experts, 1)
+    return 16 if per_expert <= 8 else 32 if per_expert <= 64 else 64
 
 
 def grouped_tiles(assignments: int, n_held: int, tm: int) -> int:

@@ -129,8 +129,10 @@ ENGINE_MOE_MEAN_EXPERT_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_mean_expert_tokens_t
 # the deployment's routed work, 50% for a balanced half.
 ENGINE_MOE_ASSIGNMENTS_TOTAL = f"{ENGINE_PREFIX}_moe_assignments_total"
 # Live prompt tokens of reaped prefill steps that passed expert layers, by the
-# form the step's STATIC token count gives (ops/moe.form_of: label
-# form=hit_list|dense|grouped_kernel|grouped_xla); all four from start-up in an
+# form the step's STATIC shape [rows bucket, chunk bucket] gives
+# (ops/moe.form_of: label form=hit_list|dense|grouped_kernel|grouped_xla; a
+# chunk of up to 256 tokens is hit_list or, over many small experts,
+# grouped_kernel); all four from start-up in an
 # engine with expert layers: grouped_kernel / all four = how often a prefill
 # step's experts run through the grouped Pallas kernel.
 ENGINE_MOE_PREFILL_TOKENS_TOTAL = f"{ENGINE_PREFIX}_moe_prefill_tokens_total"

@@ -650,23 +650,24 @@ def test_mechanisms_that_carry_only_kv_refuse_a_hybrid_configuration(mechanism):
 
 
 FORM_CASES = {
-    # name: (use_kernel, tokens, d, f, held, activation, quantized) -> the form's first words
-    "decode_slots_on_the_chip": ((True, 64, 128, 48, 4, "relu2", False), "pallas hit list"),
-    "small_prefill_on_the_chip": ((True, 256, 128, 48, 4, "relu2", False), "pallas hit list"),
-    "no_kernels_here": ((False, 64, 128, 48, 4, "relu2", False), "xla dense, no Pallas"),
-    "a_prefill_chunk": ((True, 512, 128, 48, 4, "relu2", False), "pallas grouped"),
-    "a_prefill_chunk_off_the_chip": ((False, 512, 128, 48, 4, "relu2", False),
+    # name: (use_kernel, step [B, C], d, f, held, activation, quantized) -> the form's first words
+    "decode_slots_on_the_chip": ((True, (64, 1), 128, 48, 4, "relu2", False), "pallas hit list"),
+    "small_prefill_on_the_chip": ((True, (1, 256), 128, 48, 4, "relu2", False),
+                                  "pallas hit list"),
+    "no_kernels_here": ((False, (64, 1), 128, 48, 4, "relu2", False), "xla dense, no Pallas"),
+    "a_prefill_chunk": ((True, (1, 512), 128, 48, 4, "relu2", False), "pallas grouped"),
+    "a_prefill_chunk_off_the_chip": ((False, (1, 512), 128, 48, 4, "relu2", False),
                                      "xla grouped, no Pallas"),
-    "quantized_matrices": ((True, 64, 128, 48, 4, "relu2", True), "xla dense, quantized"),
-    "gated_experts": ((True, 64, 128, 48, 4, "silu_gated", False), "pallas hit list"),
-    "unknown_activation": ((True, 64, 128, 48, 4, "gelu", False), "xla dense, activation gelu"),
-    "latent_cell_widths": ((True, 32, 7680, 2048, 16, "silu_gated", False), "pallas hit list"),
-    "latent_cell_document_chunk": ((True, 256, 7680, 2048, 16, "silu_gated", False),
+    "quantized_matrices": ((True, (64, 1), 128, 48, 4, "relu2", True), "xla dense, quantized"),
+    "gated_experts": ((True, (64, 1), 128, 48, 4, "silu_gated", False), "pallas hit list"),
+    "unknown_activation": ((True, (64, 1), 128, 48, 4, "gelu", False), "xla dense, activation gelu"),
+    "latent_cell_widths": ((True, (32, 1), 7680, 2048, 16, "silu_gated", False), "pallas hit list"),
+    "latent_cell_document_chunk": ((True, (1, 256), 7680, 2048, 16, "silu_gated", False),
                                    "pallas hit list"),
-    "hybrid_cell_widths": ((True, 64, 2688, 1856, 64, "relu2", False), "pallas hit list"),
-    "no_expert_held": ((True, 64, 128, 48, 0, "relu2", False), "xla dense, no expert held"),
-    "narrow_model_width": ((True, 64, 64, 48, 4, "relu2", False), "xla dense, widths d 64"),
-    "expert_width_fills_lanes": ((True, 64, 128, 256, 4, "relu2", False), "pallas hit list"),
+    "hybrid_cell_widths": ((True, (64, 1), 2688, 1856, 64, "relu2", False), "pallas hit list"),
+    "no_expert_held": ((True, (64, 1), 128, 48, 0, "relu2", False), "xla dense, no expert held"),
+    "narrow_model_width": ((True, (64, 1), 64, 48, 4, "relu2", False), "xla dense, widths d 64"),
+    "expert_width_fills_lanes": ((True, (64, 1), 128, 256, 4, "relu2", False), "pallas hit list"),
 }
 
 
@@ -674,25 +675,25 @@ FORM_CASES = {
 def test_expert_form_follows_what_moe_ffn_is_given(case):
     """``form_in_use`` (the runner's log line) and ``hit_list_reason`` (what
     ``moe_ffn`` branches on) from the arguments alone: no flag, no name."""
-    (use_kernel, T, d, f, held, act, quantized), want = FORM_CASES[case]
+    (use_kernel, step, d, f, held, act, quantized), want = FORM_CASES[case]
     spec = ExpertsSpec(n_experts=8, top_k=2, d_ff=f, activation=act, held=(0, held))
     up = jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16)
     lp = {"we_up": {"q8": up, "s": None} if quantized else up}
-    form = moe.form_in_use(use_kernel, T, lp, spec)
+    form = moe.form_in_use(use_kernel, step, lp, spec)
     assert form.startswith(want)
-    assert (moe.hit_list_reason(use_kernel, T, lp, spec) is None) == (
+    assert (moe.hit_list_reason(use_kernel, step, lp, spec) is None) == (
         form == "pallas hit list")
 
 
-@pytest.mark.parametrize("preset,tokens,want", [
-    ("nemotron-3-nano-30b-a3b-ep2", 64, None),
-    ("nemotron-3-nano-30b-a3b-ep2", 256, None),
-    ("nemotron-3-nano-30b-a3b-ep2", 512, "512 tokens a step is over 256"),
-    ("openpangu-ultra-moe-718b-ep16", 32, None),
-    ("openpangu-ultra-moe-718b-ep16", 256, None),
-    ("openpangu-ultra-moe-718b-ep16", 2048, "2048 tokens a step is over 256"),
+@pytest.mark.parametrize("preset,step,want", [
+    ("nemotron-3-nano-30b-a3b-ep2", (64, 1), None),
+    ("nemotron-3-nano-30b-a3b-ep2", (1, 256), None),
+    ("nemotron-3-nano-30b-a3b-ep2", (1, 512), "512 tokens a step is over 256"),
+    ("openpangu-ultra-moe-718b-ep16", (32, 1), None),
+    ("openpangu-ultra-moe-718b-ep16", (1, 256), None),
+    ("openpangu-ultra-moe-718b-ep16", (2, 1024), "2048 tokens a step is over 256"),
 ])
-def test_both_served_expert_configurations_take_the_kernel(preset, tokens, want):
+def test_both_served_expert_configurations_take_the_kernel(preset, step, want):
     """``hit_list_reason`` at the two cells' published widths, from the
     spec and the matrices' shapes alone: the hybrid cell's relu2 experts
     (d-minor ``we_up``) and the latent cell's gated-silu ones (f-minor, three
@@ -706,8 +707,8 @@ def test_both_served_expert_configurations_take_the_kernel(preset, tokens, want)
     spec = next(s for s in config.layer_specs if isinstance(s, ExpertsSpec))
     lo, hi = spec.held_
     lp = {"we_up": jax.ShapeDtypeStruct((hi - lo, config.d_model, spec.d_ff), jnp.bfloat16)}
-    assert moe.hit_list_reason(True, tokens, lp, spec) == want
-    assert moe.hit_list_reason(False, tokens, lp, spec).startswith("no Pallas kernels")
+    assert moe.hit_list_reason(True, step, lp, spec) == want
+    assert moe.hit_list_reason(False, step, lp, spec).startswith("no Pallas kernels")
 
 
 @pytest.mark.parametrize("model", ["tiny-hybrid", "tiny-moe", "tiny"])

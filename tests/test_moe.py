@@ -287,7 +287,9 @@ def test_grouped_kernel_goes_chunk_by_chunk_inside_a_step(
 
 @pytest.mark.parametrize("tokens,n_experts,want", [
     (512 * 6, 128, 32), (1024 * 6, 128, 32), (2048 * 6, 128, 64),
-    (8192 * 6, 128, 64), (512 * 8, 256, 32), (2048 * 8, 256, 32), (24, 8, 32),
+    (8192 * 6, 128, 64), (512 * 8, 256, 32), (2048 * 8, 256, 32), (24, 8, 16),
+    (128 * 8, 256, 16), (256 * 8, 256, 16), (256 * 10, 512, 16), (512 * 10, 512, 32),
+    (341 * 6, 128, 32),
 ])
 def test_grouped_row_tile_follows_the_assignments_an_expert_expects(
     tokens, n_experts, want
@@ -297,39 +299,85 @@ def test_grouped_row_tile_follows_the_assignments_an_expert_expects(
     assert grouped_row_tile(tokens, n_experts) == want
 
 
+@pytest.mark.parametrize("d,f,itemsize,want", [
+    (2688, 1856, 2, 4), (7680, 2048, 2, 28), (2048, 512, 2, 2), (128, 1024, 4, 2),
+    (128, 48, 4, 1),
+])
+def test_hit_list_steps_are_the_kernels_own_tiling(d, f, itemsize, want):
+    """Grid steps an expert takes in the hit-list kernel, from the widths:
+    what ``ops/moe.chunk_costs`` reads to tell an expert whose products hide
+    behind its stream (several tiles) from a small one (two short steps)."""
+    from dynamo_tpu.ops.pallas.expert_ffn import hit_list_steps
+
+    assert hit_list_steps(d, f, itemsize) == want
+
+
+HYBRID = (2688, 1856, 64, 128, 6, "relu2")  # d, f, held, router width, top-k, activation
+LATENT = (7680, 2048, 16, 256, 8, "silu_gated")
+WINDOW = (2048, 512, 256, 256, 8, "silu_gated")
+DELTA_RULE = (2048, 512, 256, 512, 10, "silu_gated")
+TOY = (128, 48, 4, 8, 2, "relu2")
+
 GROUPED_FORM_CASES = {
-    # name: ((use_kernel, tokens, d, f, held, router width, activation,
-    #         quantized), form, the log line's start)
+    # name: ((use_kernel, step [B, C], widths, quantized), form, the log line's start)
     "hybrid_cell_two_prompts": (
-        (True, 512, 2688, 1856, 64, 128, "relu2", False),
-        "grouped_kernel", "pallas grouped"),
+        (True, (2, 256), HYBRID, False), "grouped_kernel", "pallas grouped"),
     "hybrid_cell_eight_long_prompts": (
-        (True, 8192, 2688, 1856, 64, 128, "relu2", False),
-        "grouped_kernel", "pallas grouped"),
+        (True, (8, 1024), HYBRID, False), "grouped_kernel", "pallas grouped"),
     "window_cell_two_turns": (
-        (True, 512, 2048, 512, 256, 256, "silu_gated", False),
-        "grouped_kernel", "pallas grouped"),
+        (True, (2, 256), WINDOW, False), "grouped_kernel", "pallas grouped"),
     "latent_cell_two_questions": (
-        (True, 512, 7680, 2048, 16, 256, "silu_gated", False),
+        (True, (2, 256), LATENT, False),
         "grouped_xla", "xla grouped, an expert's 3 matrices of 7680 x 2048, twice, are"),
     "no_kernels_here": (
-        (False, 512, 2688, 1856, 64, 128, "relu2", False),
+        (False, (2, 256), HYBRID, False),
         "grouped_xla", "xla grouped, no Pallas kernels here"),
     "unknown_activation": (
-        (True, 512, 128, 48, 4, 8, "gelu", False),
+        (True, (1, 512), TOY[:5] + ("gelu",), False),
         "grouped_xla", "xla grouped, activation gelu is not in the kernel"),
     "no_expert_held": (
-        (True, 512, 128, 48, 0, 8, "relu2", False),
+        (True, (1, 512), (128, 48, 0, 8, 2, "relu2"), False),
         "grouped_xla", "xla grouped, no expert held"),
     "narrow_model_width": (
-        (True, 512, 64, 48, 4, 8, "relu2", False),
+        (True, (1, 512), (64, 48, 4, 8, 2, "relu2"), False),
         "grouped_xla", "xla grouped, widths d 64, f 48: d does not fill"),
     "quantized_matrices_stay_dense": (
-        (True, 512, 128, 48, 4, 8, "relu2", True),
-        "dense", "xla dense, quantized expert matrices"),
+        (True, (1, 512), TOY, True), "dense", "xla dense, quantized expert matrices"),
     "a_lone_prompt_keeps_the_hit_list": (
-        (True, 256, 2688, 1856, 64, 128, "relu2", False),
-        "hit_list", "pallas hit list"),
+        (True, (1, 256), HYBRID, False), "hit_list", "pallas hit list"),
+    # PR 51: a turn's chunk of at most 256 tokens over 256 small experts
+    # streams them once; the same shapes' decode steps keep the hit list, and
+    # so does every step of the wide experts
+    "window_cell_a_turn": (
+        (True, (1, 256), WINDOW, False), "grouped_kernel", "pallas grouped"),
+    "window_cell_two_short_turns": (
+        (True, (2, 128), WINDOW, False), "grouped_kernel", "pallas grouped"),
+    "window_cell_a_short_turn": (
+        (True, (1, 128), WINDOW, False), "grouped_kernel", "pallas grouped"),
+    "delta_rule_cell_a_turn": (
+        (True, (1, 256), DELTA_RULE, False), "grouped_kernel", "pallas grouped"),
+    "delta_rule_cell_two_short_turns": (
+        (True, (2, 128), DELTA_RULE, False), "grouped_kernel", "pallas grouped"),
+    "delta_rule_cell_a_short_turn": (
+        (True, (1, 128), DELTA_RULE, False), "grouped_kernel", "pallas grouped"),
+    "window_cell_decode_slots": (
+        (True, (64, 1), WINDOW, False), "hit_list", "pallas hit list"),
+    "window_cell_256_decode_slots": (
+        (True, (256, 1), WINDOW, False), "hit_list", "pallas hit list"),
+    "delta_rule_cell_decode_slots": (
+        (True, (64, 1), DELTA_RULE, False), "hit_list", "pallas hit list"),
+    "delta_rule_cell_256_decode_slots": (
+        (True, (256, 1), DELTA_RULE, False), "hit_list", "pallas hit list"),
+    "hybrid_cell_a_short_prompt": (
+        (True, (1, 128), HYBRID, False), "hit_list", "pallas hit list"),
+    "hybrid_cell_two_short_prompts": (
+        (True, (2, 128), HYBRID, False), "hit_list", "pallas hit list"),
+    "latent_cell_a_document_chunk": (
+        (True, (1, 256), LATENT, False), "hit_list", "pallas hit list"),
+    "a_turn_where_no_kernel_serves": (
+        (False, (1, 256), WINDOW, False), "dense", "xla dense, no Pallas kernels here"),
+    "a_turn_over_quantized_matrices": (
+        (True, (1, 256), WINDOW, True), "dense", "xla dense, quantized expert matrices"),
 }
 
 
@@ -337,20 +385,22 @@ GROUPED_FORM_CASES = {
 def test_grouped_form_follows_what_moe_ffn_is_given(case):
     """``form_of`` (what ``moe_ffn`` branches on and the engine counts
     prefill tokens by), ``form_in_use`` (the log line) and
-    ``grouped_reason``'s words for each refusal, from the arguments alone."""
+    ``grouped_reason``'s words for each refusal, from the step's static
+    shape, the matrices' shapes and the spec alone."""
     from dynamo_tpu.models.config import ExpertsSpec
     from dynamo_tpu.ops import moe
 
-    (use_kernel, T, d, f, held, width, act, quantized), form, line = \
+    (use_kernel, step, (d, f, held, width, top_k, act), quantized), form, line = \
         GROUPED_FORM_CASES[case]
-    spec = ExpertsSpec(n_experts=width, top_k=2, d_ff=f, activation=act, held=(0, held))
+    spec = ExpertsSpec(
+        n_experts=width, top_k=top_k, d_ff=f, activation=act, held=(0, held))
     up = jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16)
     lp = {"we_up": {"q8": up, "s": None} if quantized else up}
-    got, why = moe.form_of(use_kernel, T, lp, spec)
+    got, why = moe.form_of(use_kernel, step, lp, spec)
     assert got == form and got in moe.FORMS
-    assert moe.form_in_use(use_kernel, T, lp, spec).startswith(line)
+    assert moe.form_in_use(use_kernel, step, lp, spec).startswith(line)
     assert (why is None) == form.endswith(("hit_list", "kernel"))
-    if form.startswith("grouped"):
+    if form != "hit_list":
         assert moe.grouped_reason(use_kernel, lp, spec) == why
 
 
@@ -375,7 +425,7 @@ def test_moe_ffn_takes_the_grouped_form_for_a_prefill_batch(use_kernel, grouped_
     )
     x = jnp.asarray(rng.standard_normal((B, C, d)), jnp.float32)
     mask = jnp.asarray(np.arange(C)[None, :] < np.asarray([96, 40, 71])[:, None])
-    assert moe.form_of(use_kernel, B * C, lp, spec)[0] == (
+    assert moe.form_of(use_kernel, (B, C), lp, spec)[0] == (
         "grouped_kernel" if use_kernel else "grouped_xla")
     y = moe.moe_ffn(x, lp, spec, row_mask=mask, use_kernel=use_kernel)
     assert len(grouped_calls) == (1 if use_kernel else 0)
@@ -392,6 +442,117 @@ def test_moe_ffn_takes_the_grouped_form_for_a_prefill_batch(use_kernel, grouped_
     if use_kernel:
         # 288 tokens x 2 picks over 8 experts expect 72 rows an expert: tiles of 64
         assert grouped_calls[0][2] == 64
+
+
+@pytest.mark.parametrize("tokens,live", [
+    (128, "two_thirds"), (256, "two_thirds"), (256, "no_row"),
+])
+def test_a_turn_over_many_small_experts_takes_the_grouped_kernel(
+    tokens, live, grouped_calls
+):
+    """``moe_ffn`` over ONE chunk of 128 / 256 tokens (at most
+    ``DENSE_TOKENS_MAX``), holding the middle half [16, 48) of a router 64
+    wide, experts the hit-list kernel takes in two grid steps (128 x 1024,
+    f minor): ``form_of`` prices every row through every expert hit over the
+    grouped kernel, so the step is ONE grouped call of 16-row tiles (an
+    expert expects 4 or 8 rows), a third of its rows dead, and is the
+    per-token loop's result to the 288-token case's tolerance; with every row
+    dead (a prefix-hit family's empty
+    sibling) the work list is empty and the result zero. The same tokens as
+    a decode step ([T, 1]) keep the hit list."""
+    from dynamo_tpu.models.config import ExpertsSpec
+    from dynamo_tpu.ops import moe
+
+    rng = np.random.default_rng(51)
+    d, E, f, K, lo, hi = 128, 64, 1024, 2, 16, 48
+    spec = ExpertsSpec(n_experts=E, top_k=K, d_ff=f, routing="sigmoid",
+                       activation="relu2", held=(lo, hi))
+    lp = dict(
+        router_w=jnp.asarray(rng.standard_normal((d, E)) * 0.1, jnp.float32),
+        we_up=jnp.asarray(rng.standard_normal((hi - lo, d, f)) * 0.1, jnp.float32),
+        we_down=jnp.asarray(rng.standard_normal((hi - lo, f, d)) * 0.05, jnp.float32),
+    )
+    x = jnp.asarray(rng.standard_normal((1, tokens, d)), jnp.float32)
+    mask = jnp.asarray(
+        (np.arange(tokens) < (2 * tokens // 3 if live == "two_thirds" else 0))[None, :])
+    assert moe.form_of(True, (1, tokens), lp, spec) == ("grouped_kernel", None)
+    assert moe.form_of(True, (tokens, 1), lp, spec) == ("hit_list", None)
+    y = moe.moe_ffn(x, lp, spec, row_mask=mask, use_kernel=True)
+    [(tile_expert, n_work, tm)] = grouped_calls
+    assert tm == 16
+    top_w, top_i = moe.route(x.reshape(tokens, d), lp, spec)
+    want = np.zeros((tokens, d), np.float64)
+    hit = set()
+    for t in np.flatnonzero(np.asarray(mask).reshape(-1)):
+        for w, e in zip(np.asarray(top_w[t]), np.asarray(top_i[t])):
+            if lo <= e < hi:
+                hit.add(int(e))
+                h = np.maximum(np.asarray(x[0, t], np.float64)
+                               @ np.asarray(lp["we_up"][e - lo], np.float64), 0.0) ** 2
+                want[t] += w * (h @ np.asarray(lp["we_down"][e - lo], np.float64))
+    np.testing.assert_allclose(np.asarray(y)[0], want, rtol=1e-4, atol=1e-4)
+    assert not np.asarray(y)[~np.asarray(mask)].any()
+    # a tile an expert hit at least (few rows each: most of a tile is padding)
+    assert len(hit) <= n_work <= len(hit) + 2 * tokens // 3 * K // 16
+    assert (n_work == 0) == (live == "no_row")
+    assert live == "no_row" or np.abs(want).max() > 1e-2
+
+
+@pytest.mark.parametrize("rows,chunk,want", [
+    (1, 128, "grouped_kernel"), (2, 128, "grouped_kernel"), (1, 256, "grouped_kernel"),
+    (2, 256, "grouped_kernel"), (1, 16, "hit_list"),
+])
+def test_the_prefill_counters_label_is_the_programs_branch(rows, chunk, want, monkeypatch):
+    """``runner.prefill_expert_form(rows, chunk)`` (the label the engine
+    counts a reaped prefill step's tokens under) against the form ``moe_ffn``
+    takes when the runner's prefill program of that rows and chunk bucket is
+    TRACED (``jax.eval_shape``: no kernel runs), with the kernels on, for a
+    toy configuration whose experts the rule sends to the grouped kernel at
+    128, 256 and 512 static tokens and leaves on the hit list at 16 (half of
+    the held experts expected hit: the gathers outweigh the products): the
+    label is the program's own branch, at every expert layer."""
+    import types
+
+    from dynamo_tpu.engines.tpu.runner import DeviceRunner
+    from dynamo_tpu.models import hybrid
+    from dynamo_tpu.models.config import AttentionSpec, ExpertsSpec, tiny_hybrid_config
+    from dynamo_tpu.ops import moe
+
+    experts = ExpertsSpec(n_experts=64, top_k=2, d_ff=1024, routing="sigmoid",
+                          activation="relu2", held=(16, 48))
+    attn = AttentionSpec(n_heads=2, n_kv_heads=1, head_dim=128, positions="none")
+    cfg = tiny_hybrid_config(layer_specs=(attn, experts, attn, experts), n_layers=4,
+                             n_heads=2, n_kv_heads=1, head_dim=128)
+    args = JaxEngineArgs(config=cfg, block_size=16, num_kv_blocks=64, max_num_seqs=4,
+                         max_model_len=1024, prefill_chunk=256, use_kernel=True)
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    runner = types.SimpleNamespace(
+        config=cfg, args=args, use_kernel=True, hybrid=True, params=params,
+        _prefill_expert_forms={}, _decode_sig_budget=None)
+    label = DeviceRunner.prefill_expert_form(runner, rows, chunk)
+
+    traced = []
+    form_of = moe.form_of
+
+    def recorded(use_kernel, step, lp, spec):
+        given = form_of(use_kernel, step, lp, spec)
+        traced.append((step, given[0]))
+        return given
+
+    monkeypatch.setattr(moe, "form_of", recorded)
+    k, v = jax.eval_shape(lambda: llama.init_kv_cache(cfg, 64, 16, layered=True))
+    empty = jax.eval_shape(lambda: hybrid.init_ssm_state(cfg, rows))
+    arr = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    jax.eval_shape(
+        DeviceRunner._build_step_fn_hybrid(runner, False, 0, False)._fn,
+        params, k, v, empty, empty, arr((rows, chunk), i32), arr((rows,), i32),
+        arr((rows,), i32), arr((rows, 16), i32), arr((rows, 0), i32),
+        arr((rows,), i32), arr((2,), jnp.uint32), arr((rows,), f32),
+        arr((rows,), i32), arr((rows,), f32),
+    )
+    assert traced == [((rows, chunk), label)] * 2
+    assert label == want
 
 
 @pytest.mark.parametrize("model", ["tiny-hybrid", "tiny-moe", "tiny"])

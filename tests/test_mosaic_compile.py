@@ -231,6 +231,10 @@ def _grouped_kernel_shapes(widths, tokens, top_k, router_width, sds):
 @pytest.mark.parametrize("widths,tokens,top_k,router_width", [
     ("hybrid", 512, 6, 128), ("hybrid", 2048, 6, 128), ("hybrid", 8192, 6, 128),
     ("laguna", 512, 8, 256), ("laguna", 2048, 8, 256),
+    # a turn's chunk (PR 51): 128 and 256 tokens at the window cell's router
+    # and at the delta-rule cell's (the same tile, top-10 of 512)
+    ("laguna", 128, 8, 256), ("laguna", 256, 8, 256),
+    ("laguna", 128, 10, 512), ("laguna", 256, 10, 512),
 ])
 def test_grouped_expert_kernel_compiles_for_v5e_at_the_served_widths(
     one_chip, widths, tokens, top_k, router_width
@@ -730,8 +734,9 @@ def test_laguna_served_programs_compile_for_the_chip(one_chip, program):
     for the v5e as the runner builds them: both paged kernels lower at 48 and
     64 query heads, the two page groups' pools (two shapes) alias in and out,
     no program copies a whole pool or an expert stack, the burst's 64 slots
-    and a turn's 256 tokens go through the hit-list expert kernel at [256,
-    2048, 512], and a prefill program holds no ``while``."""
+    go through the hit-list expert kernel at [256, 2048, 512], a turn's 256
+    tokens (since PR 51) and eight fresh rows through the grouped one, and a
+    prefill program holds no ``while``."""
     from dynamo_tpu.ops.pallas.chip_check import whole_pool_copies
 
     compiled, (params, k, args) = _laguna_program(one_chip, program)
@@ -741,11 +746,12 @@ def test_laguna_served_programs_compile_for_the_chip(one_chip, program):
     assert whole_pool_copies(text, k[0]) == whole_pool_copies(text, k[1]) == 0
     resident = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in k) * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= resident
-    # 8 rows of 256 go through the grouped kernel (f minor, gated silu: an
-    # expert's three matrices whole in VMEM), no ``ragged_dot`` since PR 45
-    hit_listed = program != "prefill_fresh"
-    assert ("expert_ffn_hit_list" in text) == hit_listed
-    assert ("expert_ffn_grouped" in text) == (not hit_listed)
+    # every prefill step goes through the grouped kernel (f minor, gated
+    # silu: an expert's three matrices whole in VMEM): 8 rows of 256 since
+    # PR 45 (no ``ragged_dot``), a lone turn of 256 since PR 51
+    hit_listed = program == "decode_burst"
+    assert ("expert_ffn_hit_list/pallas_call" in text) == hit_listed
+    assert ("expert_ffn_grouped/pallas_call" in text) == (not hit_listed)
     assert "ragged-dot" not in text
     experts = params["layers"][3]
     assert [whole_pool_copies(text, experts[m])
@@ -928,6 +934,8 @@ def _gdn_program(one_chip, program):
     else:
         fresh = program == "prefill_fresh"
         B, C, width = (8, 256, 2) if fresh else (8, 256, P)
+        if program == "prefill_tail_one_row":  # a turn admitted alone
+            B = 1
         state = shapes(lambda: hybrid.init_ssm_state(cfg, B))
         lowered = DeviceRunner._build_step_fn_hybrid(runner, False, 0, fresh).lower(
             params, k, v, store, state, arr((B, C), i32), arr((B,), i32),
@@ -936,7 +944,8 @@ def _gdn_program(one_chip, program):
     return lowered.compile(), (params, k, v, store, state, entries)
 
 
-@pytest.mark.parametrize("program", ["decode_burst", "prefill_fresh", "prefill_tail"])
+@pytest.mark.parametrize("program", [
+    "decode_burst", "prefill_fresh", "prefill_tail", "prefill_tail_one_row"])
 def test_gdn_served_programs_compile_for_the_chip(one_chip, program):
     """The decode burst, a batch of fresh first chunks and eight turns' chunks
     over 33,792-token tables, of the Qwen3-Next stage at its published widths,
@@ -947,7 +956,7 @@ def test_gdn_served_programs_compile_for_the_chip(one_chip, program):
     and no operation of it yields a whole ``f32[64, 32, 128, 128]`` state;
     pools, state and snapshot store alias in and out; the burst's 64 slots go
     through the hit-list expert kernel over 256 held experts, 8 x 256 tokens
-    through the grouped one."""
+    and (since PR 51) a lone turn's 256 through the grouped one."""
     from dynamo_tpu.ops.pallas.chip_check import whole_array_ops, whole_pool_copies
 
     compiled, (params, k, v, store, state, entries) = _gdn_program(one_chip, program)
