@@ -227,6 +227,20 @@ class EngineStepMetrics:
         )
         for path in ("greedy", "full"):  # both series from start-up
             self.sampler_decode_steps.inc(0, path=path)
+        self.prefill_positions = self.registry.counter(
+            mn.ENGINE_PREFILL_POSITIONS_TOTAL,
+            "Positions of dispatched prefill steps (rows bucket x chunk "
+            "bucket each): kind=live the prompt tokens among them, "
+            "kind=padded the rest",
+            ["kind"],
+        )
+        for kind in ("live", "padded"):  # both series from start-up
+            self.prefill_positions.inc(0, kind=kind)
+        self.prefill_dispatches = self.registry.counter(
+            mn.ENGINE_PREFILL_DISPATCHES_TOTAL,
+            "Dispatched prefill steps by static shape: rows bucket, chunk bucket",
+            ["rows", "chunk"],
+        )
         self.ssm_state_slots = self.registry.gauge(
             mn.ENGINE_SSM_STATE_SLOTS,
             "Per-sequence recurrent-state slots (one per decode row)",
@@ -437,10 +451,18 @@ class EngineStepMetrics:
         if decode is not None:
             self.request_decode_tokens.inc(max(decode_tokens, 0))
 
-    def observe_prefill(self, duration_s: float, occupancy: int, tokens: int) -> None:
+    def observe_prefill(
+        self, duration_s: float, occupancy: int, tokens: int, rows: int, chunk: int
+    ) -> None:
+        """One dispatched prefill step of static shape [rows, chunk] with
+        ``tokens`` prompt tokens among its positions, ``occupancy`` of its
+        rows still prefilling."""
         self.step_duration.observe(duration_s, phase="prefill")
         self.batch_occupancy.observe(occupancy, phase="prefill")
         self.prefill_tokens.observe(tokens)
+        self.prefill_positions.inc(tokens, kind="live")
+        self.prefill_positions.inc(rows * chunk - tokens, kind="padded")
+        self.prefill_dispatches.inc(1, rows=str(rows), chunk=str(chunk))
 
     def observe_decode(self, duration_s: float, occupancy: int, tokens: int) -> None:
         self.step_duration.observe(duration_s, phase="decode")

@@ -2,9 +2,20 @@
 
 Split from the engine monolith (the engine owns the scheduler loop; this
 owns the admission policy): batched prefix-cache matching + block leasing,
-joint chunked prefill over a [Bp, C] ragged batch, failure containment
+chunked prefill of the rows admitted together as the [Bp, C] programs that
+price least (PrefillPrice, prefill_partition), failure containment
 (poisoned-request quarantine with a systemic-failure breaker), and slot
 installation including logits-processor bookkeeping.
+
+An admission is up to ``prefill_batch`` requests popped together. Where
+every row is a fresh prompt, ``_finish_admission`` partitions them into
+groups of like length and ``_run_prefill`` runs the groups back to back,
+each its own PendingPrefill, rounds and install, each first round a program
+of the start-up ladder; an admission any of whose rows resumes cached
+context, or whose joint program prices least, is one group. One path: a
+joint batch is the partition with one group. The price reads the parameter
+tree's shapes and nothing at run time, so a given batch partitions the same
+way in every run.
 
 Reference parity: the role of vLLM's scheduler admission + prefix-cache
 lookup behind components/src/dynamo/vllm (SURVEY §2.2), restructured
@@ -19,11 +30,13 @@ import collections
 import math
 import time
 from dataclasses import dataclass, field
-from typing import Any, Deque, Dict, List, Optional, Set, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from dynamo_tpu.engines.tpu.runner import _next_pow2
+from dynamo_tpu.models.llama import step_weights
+from dynamo_tpu.ops.moe import RIDGE_TOKENS
 from dynamo_tpu.runtime import lifecycle
 from dynamo_tpu.runtime.device_observe import global_compile_watcher
 from dynamo_tpu.runtime.kv_reuse_observe import global_plane as kv_reuse_plane
@@ -65,12 +78,106 @@ def prefill_table_bucket(nb_needed: int, c_bucket: int, args: Any) -> int:
     )
 
 
+# The price of a prefill program, held against the chip's own times of the
+# start-up ladder's 16 programs (wall time of a step through
+# ``engine._run_step`` with its readback, and for the hybrid configuration the
+# state programs beside it; builders' chip runs, PRs 52 and 53, PERF.md §5 has
+# the table): the dense 0.5B configuration reads 4.7 ms at [1, 128] and 119.7
+# at [8, 1024], the hybrid one 11.8 and 155.7, whatever ``lens`` says. A
+# program costs the SUM of what it streams and what its positions multiply
+# by, not the longer of the two (no shape of either ladder sits on a flat:
+# from 128 positions on each further one costs 7.2 us dense, 17.6 hybrid),
+# and its positions' products run at ``PREFILL_PEAK_SHARE`` of the bf16 peak
+# (3.6 and 3.9 us a position at the peak: thin matrices, the scan, the
+# gathers round the grouped expert kernel). ``DISPATCH_BYTES`` is what a
+# dispatch costs beside its program, in bytes of HBM time: the arrays built
+# and enqueued, the first tokens read back, the rows installed, a hybrid
+# model's state gathered and scattered (1.8 ms). With these the price reads
+# the dense ladder's programs within -16 / +39% and the hybrid one's within
+# -26 / -5% (PR 53's timing; what decides a partition is the price's ORDER
+# of the ways to cut a batch, and it picks within 1-5% of the table's own
+# optimum); ``engines/tpu/ladder_times.py`` times a configuration's ladder
+# and prints the two constants its times give (dense 0.37 and 1.55e9,
+# hybrid 0.23 and 1.31e9: the constants sit between the two). A split is taken only where it prices
+# ``PARTITION_MARGIN`` under the joint program: a difference the price cannot
+# resolve changes no dispatch. Raise either only against the ladder's table,
+# never against a cell's tail.
+PREFILL_PEAK_SHARE = 0.3
+DISPATCH_BYTES = 1.5e9
+PARTITION_MARGIN = 1.1
+
+
+@dataclass(frozen=True)
+class PrefillPrice:
+    """What a prefill program of static shape ``[B, C]`` costs, in the
+    currency of ``ops/moe.chunk_costs`` (bytes of HBM time; FLOPs through
+    ``RIDGE_TOKENS``, two bytes a weight): what it streams, plus what its
+    ``T = B x C`` positions multiply by, live or padded alike (``lens`` only
+    masks), plus a dispatch. ``streamed`` is every matrix a step reads
+    whatever its tokens (the head among them, the embedding table not: it
+    is looked up) plus, an expert layer, the held experts expected hit;
+    ``active`` is the weights one position multiplies by (the head not: it
+    runs over ``B`` rows). The three numbers are the model's own account of
+    its parameter tree's shapes (``models/llama.step_weights``)."""
+
+    always_bytes: float
+    active_weights: float
+    # (bytes of a layer's held experts, the share of them one token's
+    # choices are expected to hit), a layer
+    experts: Tuple[Tuple[float, float], ...] = ()
+
+    def __call__(self, B: int, C: int) -> float:
+        T = B * C
+        streamed = self.always_bytes + sum(
+            held * min(1.0, T * hit) for held, hit in self.experts)
+        products = T * 2.0 * self.active_weights / (RIDGE_TOKENS * PREFILL_PEAK_SHARE)
+        return streamed + products + DISPATCH_BYTES
+
+
+def prefill_partition(
+    left: Sequence[int], prefill_chunk: int, price: Callable[[int, int], float]
+) -> List[List[int]]:
+    """The rows admitted together (``left[r]`` tokens still to prefill, in
+    arrival order) as the groups whose programs price least: rows sorted by
+    what they have left, each group contiguous in that order and run as its
+    own ``[rows bucket, chunk bucket]`` rounds, its longest row deciding how
+    many rounds and at which chunk; the split with the least total price,
+    one group where none prices ``PARTITION_MARGIN`` under the joint
+    program. Groups come oldest member first, a group's rows in arrival
+    order."""
+    order = sorted(range(len(left)), key=lambda r: (left[r], r))
+
+    def rounds(lo: int, hi: int) -> float:  # rows order[lo:hi] as one program a round
+        Bp, todo, total = _next_pow2(hi - lo), left[order[hi - 1]], 0.0
+        while True:
+            total += price(Bp, prefill_chunk_bucket(min(todo, prefill_chunk), prefill_chunk))
+            todo -= prefill_chunk
+            if todo <= 0:
+                return total
+
+    best: List[Tuple[float, int]] = [(0.0, 0)]  # (price of order[:hi], where its last group starts)
+    for hi in range(1, len(order) + 1):
+        # ties go to the larger last group (the lower ``lo``)
+        best.append(min((best[lo][0] + rounds(lo, hi), lo) for lo in range(hi)))
+    if rounds(0, len(order)) <= PARTITION_MARGIN * best[-1][0]:
+        return [list(range(len(left)))]
+    groups, hi = [], len(order)
+    while hi:
+        lo = best[hi][1]
+        groups.append(sorted(order[lo:hi]))
+        hi = lo
+    return sorted(groups, key=lambda g: g[0])
+
+
 @dataclass
 class PendingPrefill:
-    """A joint chunked prefill's full loop state: the loop-invariant
-    arrays built once per batch (_begin_prefill) plus per-row progress.
-    The budgeted tick (engine._admit_tick_budgeted) parks one of these
-    when the prefill token grant runs out mid-batch — a chunk boundary is
+    """The full loop state of ONE group of an admission (the rows of it
+    that run as one ``[Bp, C]`` program a round; a joint batch is the
+    admission's only group): the loop-invariant arrays built once per group
+    (_begin_prefill) plus per-row progress, and what of the admission is
+    still to come (``held``, ``later``). The budgeted tick
+    (engine._admit_tick_budgeted) parks one of these when the prefill token
+    grant runs out mid-group — a chunk boundary is
     a clean resume point (positions, tables and sampling arrays are
     exactly what the next round needs, and the position-keyed sampling
     RNG draws the identical first tokens on resume), which is what keeps
@@ -98,6 +205,19 @@ class PendingPrefill:
     # if it fails before its programs ran).
     ssm: Any = None
     snap_keys: Optional[List[int]] = None
+    # The rows of the same admission that still hold blocks and
+    # slots-to-be, in the order they were admitted: this group's and those
+    # of ``later``, the groups not yet begun (prefill_partition), each a
+    # batch in that order too. They run after this one, ahead of any new
+    # admission, and wait with it where a budget pause parks it.
+    held: List[Tuple[Any, Any]] = field(default_factory=list)
+    later: List[List[Tuple[Any, Any]]] = field(default_factory=list)
+
+    def behind(self) -> List[Tuple[Any, Any]]:
+        """The rows of the groups not yet begun, in the order they were
+        admitted."""
+        mine = {id(seq) for seq, _ in self.batch}
+        return [row for row in self.held if id(row[0]) not in mine]
 
 
 @dataclass
@@ -120,11 +240,15 @@ class Admitter:
         self._families: Dict[Tuple[int, int, bool], "_Family"] = {}
         self.family_pending: Deque[Tuple[int, int, int, bool]] = collections.deque()
         self.family_programs = 0
+        # What a prefill program [rows bucket, chunk bucket] of this
+        # configuration costs: decides how an admission's rows are grouped.
+        self.price = PrefillPrice(*step_weights(engine.runner.params, engine.config))
 
     async def _admit_batch(self) -> int:
-        """Admit + prefill up to ``prefill_batch`` waiting sequences in ONE
-        batched device dispatch per chunk round. Returns how many were
-        installed into the decode batch.
+        """Admit + prefill up to ``prefill_batch`` waiting sequences: one
+        batched device dispatch per chunk round of each group they run as
+        (``_finish_admission``). Returns how many were installed into the
+        decode batch.
 
         Failure containment matches the round-2 breaker semantics: a
         poisoned batch is retried per-sequence (one retry then an error
@@ -197,41 +321,95 @@ class Admitter:
             e._admitting = 0
 
     async def _finish_admission(self, batch: "List[Tuple[Any, Any]]") -> int:
-        return await self._run_prefill(self._begin_prefill(batch))
+        """Prefill and install the rows admitted together: as the groups
+        that price least (``prefill_partition`` over the tokens each still
+        has to prefill), one after another inside this admission, each its
+        own ``[rows bucket, chunk bucket]`` rounds and install. A batch
+        whose joint program prices least is one group, and so is a batch
+        any of whose rows resumes cached context: its rounds run over a
+        table as wide as that context, a program no start-up ladder holds
+        and the family mechanism compiles as its ``(chunk, table)`` recurs,
+        so a worker's prefix-hit traffic dispatches the shapes it did as
+        one batch and meets none it has not compiled. Fresh rows' first
+        round is the ladder's program at whatever ``[rows, chunk]``."""
+        self._stamp_prefill_start(batch)
+        groups = [batch]
+        if not any(prep.matched_tokens for _, prep in batch):
+            groups = [
+                [batch[r] for r in rows]
+                for rows in prefill_partition(
+                    [len(seq.all_tokens) for seq, _ in batch],
+                    self.e.args.prefill_chunk, self.price,
+                )
+            ]
+        return await self._run_prefill(self._begin_group(groups, batch))
+
+    def _begin_group(
+        self, groups: "List[List[Tuple[Any, Any]]]", held: "List[Tuple[Any, Any]]"
+    ) -> "PendingPrefill":
+        """The first of an admission's remaining ``groups`` begun, the rest
+        behind it; ``held`` the rows of them all, as admitted."""
+        pending = self._begin_prefill(groups[0])
+        pending.held, pending.later = held, groups[1:]
+        return pending
 
     async def _run_prefill(self, pending: "PendingPrefill") -> int:
-        """Run (or resume) a prefill's chunk rounds to completion or to
-        budget exhaustion, then install. Returns rows installed; 0 covers
-        both containment (batch ejected/requeued) and a budget park (the
-        pending state is stashed on the engine, blocks still pinned)."""
+        """Run (or resume) an admission group by group: each group's chunk
+        rounds to completion or to budget exhaustion, then its install,
+        then the next group. Returns rows installed; 0 covers both
+        containment (a failing group is ejected/requeued, its own rows
+        only) and a budget park (the pending state is stashed on the
+        engine, blocks still pinned, the groups not yet begun with it)."""
         e = self.e
-        try:
-            done = await self._prefill_rounds(pending)
-        except asyncio.CancelledError:
-            self._forget_snapshots(pending)
-            for seq, _ in pending.batch:
-                e._release_blocks(seq)
-                e._requeue(seq)
-            raise
-        except Exception as exc:
-            self._forget_snapshots(pending)
-            for seq, _ in pending.batch:
-                e._release_blocks(seq)
-            e._contain_admission_failure([s for s, _ in pending.batch], exc)
-            return 0
-        if not done:
-            # Tick budget exhausted at a chunk boundary: park. Blocks stay
-            # pinned and per-row positions are kept — the engine resumes
-            # this exact state with the next tick's grant, ahead of any
-            # new admission (FIFO order is preserved).
-            e._pending_prefill = pending
-            e._record_budget_event(
-                "prefill_pause",
-                rows=pending.rows,
-                done=sum(pending.pos),
-                total=sum(len(p) for p in pending.prompts),
-            )
-            return 0
+        installed = 0
+        while True:
+            try:
+                done = await self._prefill_rounds(pending)
+            except asyncio.CancelledError:
+                self._forget_snapshots(pending)
+                self._back_to_queue(pending.held)
+                raise
+            except Exception as exc:
+                self._forget_snapshots(pending)
+                for seq, _ in pending.batch:
+                    e._release_blocks(seq)
+                e._contain_admission_failure([s for s, _ in pending.batch], exc)
+                if e._failure is not None:
+                    # Systemic: the groups behind go back to the queue, where
+                    # the engine's shutdown ends their streams.
+                    self._back_to_queue(pending.behind())
+                    return installed
+            else:
+                if not done:
+                    # Tick budget exhausted at a chunk boundary: park. Blocks
+                    # stay pinned and per-row positions are kept — the engine
+                    # resumes this exact state with the next tick's grant,
+                    # ahead of any new admission (FIFO order is preserved).
+                    e._pending_prefill = pending
+                    e._record_budget_event(
+                        "prefill_pause",
+                        rows=pending.rows,
+                        done=sum(pending.pos),
+                        total=sum(len(p) for p in pending.prompts),
+                    )
+                    return installed
+                await self._install_group(pending)
+                installed += len(pending.batch)
+            # Its rows hold slots-to-be no longer (adopt_handoff counts them).
+            e._admitting = max(0, e._admitting - len(pending.batch))
+            if not pending.later:
+                return installed
+            pending = self._begin_group(pending.later, pending.behind())
+
+    def _back_to_queue(self, rows: "List[Tuple[Any, Any]]") -> None:
+        """Rows in arrival order back to the front of the waiting queue in
+        that order, their blocks released."""
+        for seq, _ in reversed(rows):
+            self.e._release_blocks(seq)
+            self.e._requeue(seq)
+
+    async def _install_group(self, pending: "PendingPrefill") -> None:
+        e = self.e
         e._admission_failure_streak = 0
         free_iter = (i for i, s in enumerate(e._slots) if s is None)
         with e.step_metrics.phase("tick.install", rows=len(pending.batch)):
@@ -247,7 +425,6 @@ class Admitter:
                     e.runner.ssm_install, slots, pending.ssm,
                     list(range(len(slots))),
                 )
-        return len(pending.batch)
 
     def _forget_snapshots(self, pending: "PendingPrefill") -> None:
         if pending.snap_keys and self.e.snapshots is not None:
@@ -429,22 +606,6 @@ class Admitter:
             snap_src=snap_src,
         )
 
-    async def _prefill_batch(
-        self, batch: "List[Tuple[Any, Any]]"
-    ) -> List[Tuple[int, float]]:
-        """Joint chunked prefill to COMPLETION — the tick budget does not
-        apply (callers outside the budgeted admission path want the whole
-        batch: tests, checkpoint warmup). Returns each row's
-        (first_token, logprob, top)."""
-        e = self.e
-        pending = self._begin_prefill(batch)
-        saved, e._tick_budget_left = e._tick_budget_left, None
-        try:
-            await self._prefill_rounds(pending)
-        finally:
-            e._tick_budget_left = saved
-        return pending.first  # type: ignore[return-value]
-
     def _row_buckets(self) -> List[int]:
         return sorted(
             {_next_pow2(r) for r in range(1, self.e.args.prefill_batch + 1)}
@@ -581,15 +742,10 @@ class Admitter:
             dtype=np.int32,
         )
 
-    def _begin_prefill(self, batch: "List[Tuple[Any, Any]]") -> PendingPrefill:
-        """Per-batch prefill preamble: lifecycle/ROI stamps plus every
-        loop-invariant device array, captured as a PendingPrefill so the
-        chunk rounds can pause and resume across ticks."""
-        e = self.e
-        args = e.args
-        rows = len(batch)
-        prompts = [seq.all_tokens for seq, _ in batch]
-        pos = [prep.matched_tokens for _, prep in batch]
+    def _stamp_prefill_start(self, batch: "List[Tuple[Any, Any]]") -> None:
+        """Lifecycle/ROI stamps of the rows admitted together, once an
+        admission: the prefill phase of every row opens here, whichever
+        group of the admission it runs in."""
         for seq, prep in batch:
             seq.t_prefill_start = time.monotonic()
             lifecycle.record(
@@ -607,6 +763,16 @@ class Admitter:
                 tier=getattr(seq, "kv_hit_tier", "device"),
                 trace_id=lifecycle.trace_id_of(seq.context),
             )
+
+    def _begin_prefill(self, batch: "List[Tuple[Any, Any]]") -> PendingPrefill:
+        """Per-group prefill preamble: every loop-invariant device array,
+        captured as a PendingPrefill so the chunk rounds can pause and
+        resume across ticks."""
+        e = self.e
+        args = e.args
+        rows = len(batch)
+        prompts = [seq.all_tokens for seq, _ in batch]
+        pos = [prep.matched_tokens for _, prep in batch]
         first: List[Optional[Tuple[int, float, Optional[list]]]] = [None] * rows
         # Any row asking for top-N logprobs routes the batch through the
         # top-variant prefill program so the FIRST generated token carries
@@ -781,6 +947,7 @@ class Admitter:
                     dt,
                     int(np.count_nonzero(lens[:rows])),
                     int(lens.sum()),
+                    Bp, c_bucket,
                 )
                 # Per-token prefill cost EWMA — the basis for the plane's
                 # prefill-seconds-saved estimate.

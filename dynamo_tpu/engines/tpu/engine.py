@@ -1421,7 +1421,14 @@ class JaxEngine:
         ejects. Returns rows installed."""
         pending = self._pending_prefill
         self._pending_prefill = None
-        return await self._admitter._run_prefill(pending)
+        # While its rounds run the parked admission is no longer parked:
+        # adopt_handoff counts its slots-to-be here, as it does a new
+        # admission's (_run_prefill lets go of each group's as it installs).
+        self._admitting = len(pending.held)
+        try:
+            return await self._admitter._run_prefill(pending)
+        finally:
+            self._admitting = 0
 
     def _unpark_pending(self) -> None:
         """Return a parked prefill batch to the waiting queue whole:
@@ -1433,10 +1440,9 @@ class JaxEngine:
         if pending is None:
             return
         self._pending_prefill = None
-        for seq, _ in reversed(pending.batch):
-            self._release_blocks(seq)
-            self._requeue(seq)
-        self.flight.record("prefill_unpark", rows=len(pending.batch))
+        # its group and the groups of the same admission not yet begun
+        self._admitter._back_to_queue(pending.held)
+        self.flight.record("prefill_unpark", rows=len(pending.held))
 
     def _record_budget_event(self, kind: str, **fields) -> None:
         """Flight-ring seam for the tick budgeter and the admission pause
@@ -1461,9 +1467,6 @@ class JaxEngine:
 
     async def _prepare_admission(self, seq: _Sequence):
         return await self._admitter._prepare_admission(seq)
-
-    async def _prefill_batch(self, batch):
-        return await self._admitter._prefill_batch(batch)
 
     def _install(self, seq: _Sequence, prep, slot: int, first_token: int,
                  first_logprob: float, first_top=None) -> None:
@@ -2469,7 +2472,7 @@ class JaxEngine:
         if self._pending_prefill is not None:
             # A budget-parked prefill batch holds slots-to-be exactly
             # like an in-flight admission does.
-            earmarked += len(self._pending_prefill.batch)
+            earmarked += len(self._pending_prefill.held)
         if live + earmarked >= self.args.max_num_seqs:
             raise HandoffRefused(
                 f"no free slot ({live} live + {len(self._adoptions)} "

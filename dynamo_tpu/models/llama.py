@@ -832,3 +832,46 @@ def decode_multi(
     if hybrid_state:
         out = out + (ssm, moe_acc)
     return out
+
+
+_EXPERT_MATRICES = ("we_up", "we_gate", "we_down")
+
+
+def step_weights(
+    params: Params, config: ModelConfig
+) -> Tuple[float, float, Tuple[Tuple[float, float], ...]]:
+    """What a step over this tree reads and multiplies by, from its leaves'
+    shapes alone (arrays or ``jax.eval_shape`` structs; ``layers`` stacked
+    or one tree a layer; a quantized leaf by its own item size):
+
+    - the bytes every step streams whatever its tokens: every matrix outside
+      the expert layers, the head among them, the embedding table not (it is
+      looked up) unless it is the head too;
+    - the weights one position multiplies by: the same matrices without the
+      head (it runs over a step's rows, not its positions) plus the experts
+      one token's choices hit;
+    - an expert layer: (the bytes of its held experts, the share of them one
+      token's choices are expected to hit).
+
+    What ``engines/tpu/admission.PrefillPrice`` prices a prefill program by."""
+    always = active = 0.0
+    held: Dict[int, list] = {}  # expert layer (0: stacked layers) -> [bytes, weights]
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        keys = [getattr(k, "key", getattr(k, "idx", None)) for k in path]
+        nbytes = float(leaf.size) * leaf.dtype.itemsize
+        if any(k in _EXPERT_MATRICES for k in keys):
+            of = held.setdefault(keys[1] if isinstance(keys[1], int) else 0, [0.0, 0.0])
+            of[0] += nbytes
+            of[1] += leaf.size
+        elif keys[0] == "embed":
+            always += nbytes if config.tie_word_embeddings else 0.0
+        else:
+            always += nbytes
+            active += 0.0 if keys[0] == "lm_head" else leaf.size
+    experts = []
+    for layer, (nbytes, weights) in sorted(held.items()):
+        spec = config.layer_specs[layer] if config.is_hybrid else config.experts_spec()
+        hit = spec.top_k / spec.n_experts
+        experts.append((nbytes, hit))
+        active += weights * hit
+    return always, active, tuple(experts)

@@ -153,6 +153,16 @@ ENGINE_SSM_DECODE_ROWS_TOTAL = f"{ENGINE_PREFIX}_ssm_decode_rows_total"
 # neither. greedy / both = how often the sampler's work is skipped. Both series
 # at 0 from start-up.
 ENGINE_SAMPLER_DECODE_STEPS_TOTAL = f"{ENGINE_PREFIX}_sampler_decode_steps_total"
+# Positions of dispatched prefill steps (label kind=live|padded): a step of
+# static shape [rows bucket, chunk bucket] computes every one of its rows x
+# chunk positions; ``live`` are the prompt tokens among them (the rows' lens),
+# ``padded`` the rest. live / both = the share of a prefill program's work
+# that is prompt: what admission's grouping of unequal rows
+# (admission.prefill_partition) raises. Both series at 0 from start-up. The
+# dispatches themselves by shape (labels rows, chunk: the two buckets), a
+# series from its first dispatch on.
+ENGINE_PREFILL_POSITIONS_TOTAL = f"{ENGINE_PREFIX}_prefill_positions_total"
+ENGINE_PREFILL_DISPATCHES_TOTAL = f"{ENGINE_PREFIX}_prefill_dispatches_total"
 # Recurrent (state-space) state beside the paged K/V: slots are one per decode
 # row, snapshots are the block-aligned state copies prefix reuse resumes from
 # (label state=used|total).
@@ -693,6 +703,8 @@ ALL_ENGINE = (
     ENGINE_MOE_ASSIGNMENTS_TOTAL,
     ENGINE_SSM_DECODE_ROWS_TOTAL,
     ENGINE_SAMPLER_DECODE_STEPS_TOTAL,
+    ENGINE_PREFILL_POSITIONS_TOTAL,
+    ENGINE_PREFILL_DISPATCHES_TOTAL,
     ENGINE_SSM_STATE_SLOTS,
     ENGINE_SSM_SNAPSHOTS,
     ENGINE_SSM_SNAPSHOT_HITS_TOTAL,

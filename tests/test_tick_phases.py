@@ -705,6 +705,7 @@ def test_layer_metric_file_reads_what_the_program_exports(name, served):
     labels = (set(mn.TICK_PHASES) | set(mn.REQUEST_PHASES)
               | set(mn.FRAME_KINDS) | {"used", "total", "window", "sparse", "dense"}
               | {"updated", "slots"} | {"greedy", "full"} | {"1", "0"}
+              | {"live", "padded"}
               | set(MOE_FORMS))
     # A metric listed for a hybrid configuration's cells alone is read off a
     # hybrid engine's scrape: a dense engine never moves its families.
